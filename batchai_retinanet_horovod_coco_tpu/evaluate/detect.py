@@ -29,8 +29,7 @@ sequential path survives as ``pipelined=False`` and stays bit-identical):
    thread behind a bounded queue with the shm-pipeline's error contract:
    a consumer crash re-raises in the driver, ``close()`` never hangs.
 
-EVALBENCH.json is the committed perf record of this path (``bench.py
---mode eval``; ``make evalbench-check`` is the regression tripwire).
+``bench.py --mode eval`` (``make evalbench``) measures this path.
 """
 
 from __future__ import annotations
@@ -43,10 +42,8 @@ from typing import Any, Callable, Iterable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from batchai_retinanet_horovod_coco_tpu.parallel.shmap import (
-    shard_map,
-)
 
 from batchai_retinanet_horovod_coco_tpu.data import pipeline as pipeline_lib
 from batchai_retinanet_horovod_coco_tpu.data.coco import CocoDataset

@@ -18,6 +18,10 @@ import threading
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LOCK = threading.Lock()
 _CACHE: dict[str, ctypes.CDLL | None] = {}
+# How each loaded library came to be, for callers that must say so
+# (chip_smoke.py): "built" from the .cpp by this process, or "reused"
+# from a .so an earlier process in this checkout built.
+_ORIGIN: dict[str, str] = {}
 
 
 def _compile(src: str, lib: str, extra_flags: tuple[str, ...] = ()) -> bool:
@@ -77,7 +81,14 @@ def load_library(name: str = "cocoeval", sanitize: bool = False) -> ctypes.CDLL 
             if fresh or _compile(src, lib, flags):
                 try:
                     result = ctypes.CDLL(lib)
+                    _ORIGIN[key] = "reused" if fresh else "built"
                 except OSError:
                     result = None
         _CACHE[key] = result
         return result
+
+
+def library_origin(name: str = "cocoeval") -> str | None:
+    """"built" | "reused" for a library :func:`load_library` has loaded,
+    None when it has not been loaded (or could not be)."""
+    return _ORIGIN.get(name)

@@ -22,7 +22,6 @@ jax-free: the analyzer reads artifacts, never devices.
 
 from batchai_retinanet_horovod_coco_tpu.obs.analyze.report import (
     AnalyzeError,
-    CPU_NOMINAL_PEAK_TFLOPS,
     PEAK_TFLOPS,
     SCHEMA_VERSION,
     analyze_dir,
@@ -37,7 +36,6 @@ from batchai_retinanet_horovod_coco_tpu.obs.analyze.report import (
 
 __all__ = [
     "AnalyzeError",
-    "CPU_NOMINAL_PEAK_TFLOPS",
     "PEAK_TFLOPS",
     "SCHEMA_VERSION",
     "analyze_dir",

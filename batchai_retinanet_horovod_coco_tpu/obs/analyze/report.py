@@ -85,11 +85,6 @@ PEAK_TFLOPS = (
     ("v6", 918.0),  # Trillium
 )
 
-# Nominal per-host figure for CPU smokes: MFU against it is order-of-
-# magnitude only (the report labels it ``peak_source: "nominal-cpu"``),
-# but it keeps the roofline field populated end-to-end on dev boxes.
-CPU_NOMINAL_PEAK_TFLOPS = 0.05
-
 # The train loop's top-level span vocabulary (train/loop.py): these names
 # partition the loop thread's wall clock, so their fractions + "other"
 # sum to ~1 by construction.
@@ -148,24 +143,15 @@ def _r(x: float | None, nd: int = 6) -> float | None:
 
 
 def device_peak_tflops(device_kind: str | None) -> tuple[float | None, str | None]:
-    """(peak TFLOP/s, provenance) for a device kind.  Provenance is
-    ``spec`` (public sheet), ``nominal-cpu`` (order-of-magnitude host
-    figure), ``env`` (RETINANET_PEAK_TFLOPS override for kinds the table
-    doesn't know), or None/None when unresolvable."""
+    """(peak TFLOP/s, ``"spec"``) for a device kind the table knows,
+    else (None, None): no peak is assumed for an unknown device — a CPU
+    included — and the report then carries ``mfu: null``."""
     if not device_kind:
         return None, None
     kind = device_kind.lower()
     for sub, peak in PEAK_TFLOPS:
         if sub in kind:
             return peak, "spec"
-    env = os.environ.get("RETINANET_PEAK_TFLOPS")
-    if env:
-        try:
-            return float(env), "env"
-        except ValueError:
-            pass
-    if "cpu" in kind:
-        return CPU_NOMINAL_PEAK_TFLOPS, "nominal-cpu"
     return None, None
 
 
@@ -548,10 +534,6 @@ def _mfu_section(
             )
         if peak:
             out["mfu"] = _r(achieved / peak)
-    if peak_source == "nominal-cpu":
-        out["note"] = (
-            "peak is a nominal CPU figure; mfu is order-of-magnitude only"
-        )
     return out
 
 

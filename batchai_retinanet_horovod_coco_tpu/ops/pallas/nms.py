@@ -1,8 +1,7 @@
 """Fused blocked-suppression NMS Pallas kernel (ISSUE 6) — opt-in.
 
-The detect ceiling that caps serve throughput end to end is the NMS stage
-of the postprocess (SERVEBENCH ``vs_ceiling``; ops/nms.py's rewrite
-history).  The XLA lowering of :func:`ops.nms.greedy_keep` materializes
+NMS is the stage of the detect postprocess with the rewrite history
+(ops/nms.py).  The XLA lowering of :func:`ops.nms.greedy_keep` materializes
 the full (K, K) IoU matrix in one shot and re-reduces ALL of it on every
 fixed-point iteration — at K=1000 that is ~4 MB re-read per iteration per
 image, and the iteration count is the worst suppression-chain depth.
@@ -38,9 +37,14 @@ dot is exact on {0, 1} values.
 generated by ``tune.candidates.nms_candidates`` and the measured winner
 per device_kind is recorded in the schedule registry
 (artifacts/schedules/<device_kind>.json), which ``DetectConfig``
-resolution consumes (evaluate/detect.py).  The pure-jnp fallback
-(``use_kernel=False``) is the vmapped ``ops.nms.greedy_keep`` — the
-usual pattern of every kernel in this package.
+resolution consumes (evaluate/detect.py).  The jnp reference
+(``use_kernel=False``) is the vmapped ``ops.nms.greedy_keep``.
+
+Compiled by Mosaic (libtpu 0.0.34) and bit-identical to the XLA path on a
+v5e at B=8, K=1000, every ``block_k`` tune/ offers: chip_smoke.py's
+``kernels`` phase repeats that on every run.  The rows and class ids of
+a block are read through the refs (``ref[0, pl.ds(start, block_k), :]``):
+Mosaic has no ``dynamic_slice`` of a loaded value.
 """
 
 from __future__ import annotations
@@ -92,8 +96,6 @@ def _kernel(
     iou_threshold: float,
 ):
     i = pl.program_id(1)
-    rows_all = rows_ref[0]  # (K_pad, 4)
-    clsr_all = clsr_ref[0]  # (K_pad, 1)
     cols_t = cols_ref[0]  # (4, BK)
     cls_c = clsc_ref[0]  # (1, BK)
     valid_c = valid_ref[0]  # (1, BK)
@@ -108,8 +110,8 @@ def _kernel(
     # triangular mask is needed — kept ∧ IoU > t suffices.
     def prev_block(j, supp):
         rj = pl.multiple_of(j * block_k, block_k)
-        rows = lax.dynamic_slice(rows_all, (rj, 0), (block_k, 4))
-        cls_r = lax.dynamic_slice(clsr_all, (rj, 0), (block_k, 1))
+        rows = rows_ref[0, pl.ds(rj, block_k), :]  # (BK, 4)
+        cls_r = clsr_ref[0, pl.ds(rj, block_k), :]  # (BK, 1)
         kept = keep_ref[pl.ds(rj, block_k), :]  # (BK, 1)
         hit = (masked_tile(rows, cls_r) > iou_threshold) & (kept > 0.5)
         return jnp.maximum(
@@ -123,8 +125,8 @@ def _kernel(
     # Intra-block greedy fixed point on one (BK, BK) tile (the
     # ops.nms.greedy_keep iteration, block-local: chain depth ≤ block_k).
     ci = pl.multiple_of(i * block_k, block_k)
-    rows_i = lax.dynamic_slice(rows_all, (ci, 0), (block_k, 4))
-    cls_ri = lax.dynamic_slice(clsr_all, (ci, 0), (block_k, 1))
+    rows_i = rows_ref[0, pl.ds(ci, block_k), :]
+    cls_ri = clsr_ref[0, pl.ds(ci, block_k), :]
     rr = lax.broadcasted_iota(jnp.int32, (block_k, block_k), 0)
     cc = lax.broadcasted_iota(jnp.int32, (block_k, block_k), 1)
     sup_ii = (masked_tile(rows_i, cls_ri) > iou_threshold) & (rr < cc)

@@ -51,10 +51,8 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from batchai_retinanet_horovod_coco_tpu.parallel.shmap import (
-    shard_map,
-)
 
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
 

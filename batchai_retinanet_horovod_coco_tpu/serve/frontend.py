@@ -908,6 +908,16 @@ def main(argv: list[str] | None = None) -> dict:
     elif args.export_dir is None:
         raise SystemExit("--export-dir is required (or pass --stub-engine)")
     else:
+        # Only a replica that serves an export touches the device; stub
+        # replicas stay backend-free (N of them share a host with the
+        # one process that owns the chip).
+        from batchai_retinanet_horovod_coco_tpu.utils.backend import (
+            announce_devices,
+            enable_compile_cache,
+        )
+
+        enable_compile_cache()
+        announce_devices("serve")
         engine = DetectEngine.from_export(args.export_dir)
     print(
         f"engine: buckets={engine.buckets} "

@@ -534,8 +534,10 @@ def spawn_http_replica(
         if stub_delay_ms is not None:
             cmd += ["--stub-delay-ms", str(stub_delay_ms)]
     cmd += extra_args or []
+    # The child inherits the caller's environment as is: an export
+    # replica serves on whatever backend JAX finds there (and says so in
+    # its first line), never on a quietly substituted CPU.
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
     # The repo is path-based (not pip-installed): make sure the child
     # resolves the package no matter the caller's cwd.
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(

@@ -24,10 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from batchai_retinanet_horovod_coco_tpu.parallel.shmap import (
-    shard_map,
-)
 
 from batchai_retinanet_horovod_coco_tpu import losses as losses_lib
 from batchai_retinanet_horovod_coco_tpu.data import pipeline as pipeline_lib

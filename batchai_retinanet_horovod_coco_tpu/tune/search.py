@@ -19,10 +19,9 @@ Measurement policy is bench.py's, not a new one:
   "Autotuning schedules").
 
 Error policy: a candidate that fails to compile or run is a FAILED TRIAL
-(recorded, skipped) — a too-big tile must not kill the search — EXCEPT
-accelerator-unreachable errors (bench.py's UNAVAILABLE classification),
-which raise :class:`DeviceUnavailable` so the CLI can exit 75 with the
-structured outage line instead of composing a winner from a dead device.
+(recorded with its error, never the winner) — a too-big tile must not
+kill the search.  An op with no successful exact-semantics trial fails
+the search.
 
 Semantics policy (tune/candidates.py): ``pre_nms_size`` changes detection
 semantics, so non-default values are measured only when the caller opts
@@ -30,9 +29,10 @@ in, every such trial is recorded with ``semantics: "approx"``, and the
 WINNER is always chosen among exact-semantics trials — a human promotes
 an approx trial to a winner deliberately, never the harness.
 
-Pallas candidates only run where Mosaic exists (TPU): elsewhere they are
-recorded as skipped trials and the winner comes from the XLA candidates —
-which is exactly what a CPU smoke run (``make tune-smoke``) commits.
+Pallas candidates only run on a TPU backend (Mosaic compiles them):
+elsewhere they are recorded as skipped trials and the winner comes from
+the XLA candidates — which is exactly what a CPU smoke run
+(``make tune-smoke``) commits.
 """
 
 from __future__ import annotations
@@ -53,43 +53,6 @@ from batchai_retinanet_horovod_coco_tpu.tune import schedule as schedule_lib
 DEFAULT_HW = (800, 1344)
 DEFAULT_BATCH = 8
 DEFAULT_STEPS = 30  # per trial, split into two windows
-
-# bench.py's outage vocabulary, duplicated as data (not imported: bench.py
-# is a repo-root script, and this module must import cleanly from an
-# installed package).  tests/unit/test_tune.py pins the two sets equal.
-UNAVAILABLE_MARKERS = (
-    "unavailable",
-    "unable to initialize backend",
-    "deadline_exceeded",
-    "failed to connect",
-    "backend init hang",
-)
-
-
-class DeviceUnavailable(RuntimeError):
-    """A trial died because the accelerator became unreachable — the
-    search must stop and the CLI must exit 75, not record a winner."""
-
-
-def _is_unavailable(err: BaseException) -> bool:
-    # Whole __cause__/__context__ chain, exactly like bench.py's
-    # classifier: jax re-wraps the backend-init UNAVAILABLE RuntimeError
-    # one link down (the BENCH_r05 crash class), and a chain-wrapped
-    # outage misread as a failed trial would cascade into an rc-1
-    # "no successful trial" crash instead of the exit-75 contract.
-    seen: set[int] = set()
-    stack: list = [err]
-    while stack:
-        e = stack.pop()
-        if e is None or id(e) in seen:
-            continue
-        seen.add(id(e))
-        text = str(e).lower()
-        if any(m in text for m in UNAVAILABLE_MARKERS):
-            return True
-        stack.extend((e.__cause__, e.__context__))
-    return False
-
 
 @dataclasses.dataclass
 class Trial:
@@ -119,10 +82,7 @@ class Trial:
 
 def mosaic_available() -> bool:
     """Pallas TPU kernels need Mosaic — i.e. an actual TPU backend."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def time_compiled(fn: Callable[[], Any], steps: int) -> tuple[float, list[float]]:
@@ -150,8 +110,8 @@ def run_trial(
     steps: int,
     semantics: str = "exact",
 ) -> Trial:
-    """Compile + warm + time one candidate; failures become failed trials
-    unless the device itself went away (:class:`DeviceUnavailable`)."""
+    """Compile + warm + time one candidate; a failure becomes a failed
+    trial carrying its error."""
     with trace.span("tune_trial", op=op, **{
         k: v for k, v in params.items() if isinstance(v, (int, str))
     }):
@@ -162,9 +122,7 @@ def run_trial(
                 out = fn()  # warmup call 2 (autotune/cache settled)
                 jax.block_until_ready(out)
             ms, window_ms = time_compiled(fn, steps)
-        except Exception as e:  # noqa: BLE001 — classified below
-            if _is_unavailable(e):
-                raise DeviceUnavailable(str(e)) from e
+        except Exception as e:  # noqa: BLE001 — recorded on the trial
             return Trial(
                 op=op, params=params, ms_per_call=None, window_ms=[],
                 noise_pct=None, semantics=semantics, status="failed",

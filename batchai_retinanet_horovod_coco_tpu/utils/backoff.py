@@ -1,13 +1,11 @@
 """Bounded retry/backoff policy — ONE schedule implementation repo-wide.
 
-Extracted from the machinery ``bench.py`` grew around its device probe
-(ISSUE 12 satellite): bounded attempts, a geometric (or explicitly
-listed) delay schedule with a ceiling, and *deterministic-seeded* jitter
-so two processes never thundering-herd a recovering dependency while a
-test can still pin the exact schedule.  Consumers:
+Bounded attempts, a geometric (or explicitly listed) delay schedule with
+a ceiling, and *deterministic-seeded* jitter so two processes never
+thundering-herd a recovering dependency while a test can still pin the
+exact schedule.  Consumers:
 
-- ``bench.py``'s availability probe (the original call site — env knobs
-  ``BENCH_PROBE_ATTEMPTS`` / ``BENCH_PROBE_BACKOFF_S`` build a policy);
+- the replica launcher's wait-for-health loop (serve/replica.py);
 - the fleet router's health poller and circuit-breaker half-open probe
   cadence (serve/fleet.py) — there the policy is *consulted* for delays
   against an injectable clock, never slept on, so the breaker state
@@ -25,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 import random
 import time
-from typing import Callable, Sequence
+from typing import Callable
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,8 +34,7 @@ class BackoffPolicy:
     Delay for attempt ``i`` (0-based, i.e. the sleep AFTER the i-th
     failure) is ``min(ceiling_s, base_s * multiplier**i)`` — or
     ``schedule[min(i, len-1)]`` when an explicit ``schedule`` overrides
-    the geometric rule (the bench probe's "10,30" env grammar: last
-    value reused past the end).  ``jitter`` then scales it by a
+    the geometric rule (last value reused past the end).  ``jitter`` then scales it by a
     uniform factor in ``[1 - jitter, 1 + jitter]`` drawn from a
     per-(seed, attempt) RNG, so the schedule is deterministic given the
     seed but decorrelated across seeds (replicas seed from their id).
@@ -113,18 +110,6 @@ class BackoffPolicy:
             if i + 1 < self.max_tries:
                 sleep(self.delay_s(i))
         return self.max_tries, last
-
-    @classmethod
-    def from_env_schedule(
-        cls, attempts: int, schedule_csv: str, default: Sequence[float] = (10.0,)
-    ) -> "BackoffPolicy":
-        """The bench probe's env grammar: an attempt count plus a comma
-        list of seconds ("10,30"), last value reused; no jitter (the
-        probe predates the policy and its tests pin unjittered sleeps)."""
-        parsed = tuple(
-            float(x) for x in schedule_csv.split(",") if x.strip()
-        ) or tuple(default)
-        return cls(max_tries=max(1, attempts), schedule=parsed)
 
 
 __all__ = ["BackoffPolicy"]

@@ -118,6 +118,13 @@ def main(argv: list[str] | None = None) -> str:
 
     if args.platform != "auto":
         jax.config.update("jax_platforms", args.platform)
+    from batchai_retinanet_horovod_coco_tpu.utils.backend import (
+        announce_devices,
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
+    announce_devices("convert_model")
 
     import jax.numpy as jnp
     import optax

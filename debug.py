@@ -25,9 +25,9 @@ annotation file alone (COCO records carry width/height; nothing is
 decoded): for every image it applies the reference resize rule + bucket
 pick the pipeline uses (data/pipeline.resize_scale/pick_bucket) and prints
 per-bucket image counts/shares — the measured replacement for the
-estimated COCO aspect shares baked into bench.py's weighted mix
-(BUCKETBENCH.json).  With --bucketbench it also recomputes the
-mix-weighted imgs/s/chip from the recorded per-bucket rates.
+estimated COCO aspect shares baked into bench.py's weighted mix.  With
+--bucketbench it also recomputes the mix-weighted imgs/s/chip from a
+saved record's per-bucket rates.
 """
 
 from __future__ import annotations
@@ -64,8 +64,8 @@ def build_parser() -> argparse.ArgumentParser:
     bk.add_argument("--image-max-side", type=int, default=1333)
     bk.add_argument(
         "--bucketbench", default=None,
-        help="path to a BUCKETBENCH.json; recompute its weighted_mix "
-        "with the measured shares",
+        help="path to a saved `python bench.py` JSON line; recompute its "
+        "weighted_mix with the measured shares",
     )
     for sp in (coco, synth):
         sp.add_argument("--limit", type=int, default=8)
@@ -126,8 +126,8 @@ def _run_buckets(args) -> dict:
     if args.bucketbench:
         with open(args.bucketbench) as f:
             bench = json.load(f)
-        # Accept both schemas: the committed BUCKETBENCH.json (long keys)
-        # and a saved `python bench.py` JSON line (short keys).
+        # Accept both key spellings: a saved `python bench.py` JSON line
+        # ("per_bucket") and the long form older records used.
         rates = bench.get("per_bucket_imgs_per_sec_per_chip") or bench.get(
             "per_bucket"
         )

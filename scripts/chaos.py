@@ -36,13 +36,13 @@ promises (utils/checkpoint.py):
   ROADMAP asks for: save overhead (wall time of N checkpointed steps vs
   the same N without) and time-to-first-step on resume; writes
   CKPTBENCH.json.  ``--check`` re-measures against the committed
-  artifact with bench-check's device-class guard, and a non-CPU target
-  (CKPTBENCH_PLATFORM) gets the probe + exit-75 outage contract.
+  artifact.  Every training subprocess runs ``--platform cpu``: the
+  harness never touches an accelerator.
 
 Modes: ``--smoke`` (one mid-save kill + one NaN leg; the check-static
 CI leg), default full schedule (>= 20 kills), ``--bench``/``--check``.
 Exit 0 = contract held; 1 = violation (each printed as one
-``chaos FAIL:`` line); 75 = accelerator unreachable (bench only).
+``chaos FAIL:`` line).
 """
 
 from __future__ import annotations
@@ -61,7 +61,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-EXIT_UNREACHABLE = 75
 _failures: list[str] = []
 
 # Every save-protocol phase, in write order (utils/checkpoint.py).
@@ -1234,28 +1233,6 @@ def _last_run_segment(metrics_path: str) -> list[dict]:
 
 
 def run_bench(check_mode: bool, out_path: str) -> int:
-    platform = os.environ.get("CKPTBENCH_PLATFORM", "cpu")
-    if platform != "cpu":
-        # The outage contract (bench.py's): probe in a subprocess (init
-        # can HANG), classify unreachable as exit 75 with the committed
-        # last-known-good attached.
-        probe = subprocess.run(
-            [sys.executable, "-c",
-             "import jax; print('probe_ok', jax.devices()[0].device_kind)"],
-            capture_output=True, text=True,
-            timeout=float(os.environ.get("BENCH_PROBE_TIMEOUT_S", "120")),
-        )
-        if probe.returncode != 0 or "probe_ok" not in probe.stdout:
-            committed = None
-            if os.path.exists(out_path):
-                with open(out_path) as f:
-                    committed = json.load(f)
-            print(json.dumps({
-                "event": "ckptbench_outage",
-                "error": (probe.stderr or probe.stdout)[-800:],
-                "last_known_good": committed,
-            }), flush=True)
-            return EXIT_UNREACHABLE
     steps = int(os.environ.get("CKPTBENCH_STEPS", "10"))
 
     # Leg A: save overhead — same stream, with and without checkpointing.
@@ -1298,7 +1275,7 @@ def run_bench(check_mode: bool, out_path: str) -> int:
     record = {
         "bench": "ckptbench",
         "schema_version": 1,
-        "device_kind": platform,
+        "device_kind": "cpu",  # _base_cmd pins --platform cpu
         "steps": steps,
         "save": {
             "saves": len(saves),

@@ -27,16 +27,16 @@ def main(log_path: str) -> None:
     )
     wall = f"{float(total.group(1)):.0f} s wall" if total else "wall unknown"
     lines = [
-        "# Fast-tier test timings (`pytest -m \"not slow\"`, per-session compile cache)",
+        "# Fast-tier test timings (`pytest -m \"not slow\"`)",
         "",
         f"Snapshot: {date.today().isoformat()} — regenerate with `make test-timings`.",
         f"Result: {tail.group(1) if tail else 'unknown'} ({wall}; budget 1200 s)",
         "",
         "Budget: 1200 s per session (tests/conftest.py warns, listing offenders,",
-        "when a fast-tier session exceeds it; every session pays each unique",
-        "program's compile once — the machine-persistent cache is gone, see",
-        "conftest.py). A capability that adds a slower test than these either",
-        "earns its seconds or takes a `slow` mark.",
+        "when a fast-tier session exceeds it).  Timings depend on how warm",
+        "tests/.jax_cache is: a cold run pays each unique program's compile",
+        "once, a later run loads them (conftest.py).  A capability that adds a",
+        "slower test than these either earns its seconds or takes a `slow` mark.",
         "",
         "| seconds | phase | test |",
         "|---|---|---|",

@@ -20,9 +20,7 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from batchai_retinanet_horovod_coco_tpu.parallel.shmap import (
-    shard_map,
-)
+from jax import shard_map
 
 from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
 from batchai_retinanet_horovod_coco_tpu.models import RetinaNetConfig, build_retinanet

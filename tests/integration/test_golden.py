@@ -6,10 +6,8 @@ shows up as a diff here before it shows up as silent mAP loss.  Loss also
 must strictly decrease — the 'loss goes down' smoke the reference relied on,
 made deterministic.
 
-Goldens recorded on the 8-device virtual CPU mesh, f32, jax 0.4.37 (the
-container's pinned runtime; re-recorded from the jax 0.9.0 goldens when the
-environment moved — the trajectory shifted up to 8% by step 5, well beyond
-scheduling noise, as expected for a major XLA version change).
+Goldens recorded on the 8-device virtual CPU mesh, f32, jax 0.9.0, and
+reproduced on the installed jax 0.9.0 to ≤ 2e-6 relative.
 Regenerate (only for an INTENDED numerics change or a runtime move) with:
   python -m tests.integration.test_golden
 """
@@ -17,7 +15,7 @@ Regenerate (only for an INTENDED numerics change or a runtime move) with:
 if __name__ == "__main__":
     # Regeneration must run on the same backend the pytest assertion uses
     # (conftest.py forces CPU only under pytest; bare python would pick the
-    # container's TPU backend and record wrong goldens).
+    # host's default backend and could record goldens from an accelerator).
     import os
 
     _flags = os.environ.get("XLA_FLAGS", "")
@@ -39,11 +37,11 @@ from batchai_retinanet_horovod_coco_tpu.train import create_train_state, make_tr
 
 HW = (64, 64)
 GOLDEN_LOSSES = (
-    5.7810754776,
-    5.7719092369,
-    5.7526111603,
-    5.7122411728,
-    5.6021413803,
+    5.7837281227,
+    5.7642784119,
+    5.7254600525,
+    5.6187024117,
+    5.1890058517,
 )
 
 

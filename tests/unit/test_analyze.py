@@ -73,8 +73,10 @@ class TestGoldenFixture:
     def test_golden_satisfies_the_acceptance_properties(self):
         """The acceptance criteria, pinned on the committed recording: a
         schema-valid report with decomposition summing to ~1, an eval
-        overlap ratio, a cost-analysis-derived MFU estimate, and a
-        non-empty ranked top-3 verdict."""
+        overlap ratio, cost-analysis-derived achieved FLOP/s (and NO
+        utilization: the recording is a CPU run, and no peak is assumed
+        for a device the table does not know), and a non-empty ranked
+        top-3 verdict."""
         report = json.loads(_golden_bytes())
         assert validate_report(report) == []
         d = report["steps"]["decomposition"]
@@ -88,7 +90,8 @@ class TestGoldenFixture:
         mfu = report["mfu"]
         assert mfu["flops_source"] == "trace_cost_analysis"
         assert mfu["flops_per_step"] > 0
-        assert mfu["mfu"] is not None and mfu["mfu"] > 0
+        assert mfu["achieved_tflops"] > 0
+        assert mfu["peak_tflops"] is None and mfu["mfu"] is None
         assert 1 <= len(report["bottlenecks"]) <= 3
         assert [b["rank"] for b in report["bottlenecks"]] == list(
             range(1, len(report["bottlenecks"]) + 1)
@@ -452,10 +455,28 @@ class TestPeakTable:
     def test_known_kinds_and_fallbacks(self, monkeypatch):
         assert device_peak_tflops("TPU v5 lite") == (197.0, "spec")
         assert device_peak_tflops("TPU v4") == (275.0, "spec")
-        assert device_peak_tflops("cpu")[1] == "nominal-cpu"
-        assert device_peak_tflops(None) == (None, None)
+        # Unknown to the table → no peak, whatever the environment says.
         monkeypatch.setenv("RETINANET_PEAK_TFLOPS", "123.5")
-        assert device_peak_tflops("weird-npu") == (123.5, "env")
+        assert device_peak_tflops("cpu") == (None, None)
+        assert device_peak_tflops("weird-npu") == (None, None)
+        assert device_peak_tflops(None) == (None, None)
+
+    def test_mfu_is_computed_only_against_a_known_peak(self):
+        from batchai_retinanet_horovod_coco_tpu.obs.analyze.report import (
+            _mfu_section,
+        )
+
+        cost = [{
+            "ph": "i", "name": "cost_analysis", "ts": 0, "pid": 1, "tid": 1,
+            "args": {"target": "train_step", "flops": 19.7e12, "batch": 8},
+        }]
+        steps = {"steps_per_s": 5.0}
+        known = _mfu_section(cost, steps, "TPU v5 lite")
+        assert known["peak_tflops"] == 197.0
+        assert known["mfu"] == pytest.approx(0.5)
+        unknown = _mfu_section(cost, steps, "cpu")
+        assert unknown["achieved_tflops"] == known["achieved_tflops"]
+        assert unknown["mfu"] is None
 
     def test_bench_uses_the_shared_table(self):
         """bench.py's MFU peak resolves through obs/analyze (one table)."""

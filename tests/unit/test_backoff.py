@@ -26,13 +26,6 @@ class TestSchedule:
     def test_explicit_schedule_reuses_last_value(self):
         p = BackoffPolicy(max_tries=5, schedule=(10.0, 30.0))
         assert p.delays() == [10.0, 30.0, 30.0, 30.0]
-        # The bench probe's env grammar builds the same policy.
-        q = BackoffPolicy.from_env_schedule(5, "10,30")
-        assert q.delays() == p.delays()
-
-    def test_env_schedule_empty_falls_back_to_default(self):
-        p = BackoffPolicy.from_env_schedule(3, "", default=(7.0,))
-        assert p.delays() == [7.0, 7.0]
 
     def test_delay_is_pure_per_attempt(self):
         p = BackoffPolicy(max_tries=4, base_s=1.0, jitter=0.3, seed=42)

@@ -46,7 +46,7 @@ from batchai_retinanet_horovod_coco_tpu.parallel import (
     make_mesh,
 )
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
-from batchai_retinanet_horovod_coco_tpu.parallel.shmap import shard_map
+from jax import shard_map
 from batchai_retinanet_horovod_coco_tpu.train import make_train_step
 from batchai_retinanet_horovod_coco_tpu.train.state import TrainState
 

@@ -55,7 +55,7 @@ from batchai_retinanet_horovod_coco_tpu.parallel.mesh import (
     COMM_SLICES_ENV,
     DATA_AXIS,
 )
-from batchai_retinanet_horovod_coco_tpu.parallel.shmap import shard_map
+from jax import shard_map
 from batchai_retinanet_horovod_coco_tpu.train import make_train_step
 
 N = 8

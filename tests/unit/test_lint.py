@@ -268,7 +268,7 @@ class TestJitPurity:
         got = findings(
             """
             import numpy as np
-            from parallel.shmap import shard_map
+            from jax import shard_map
 
             def step(x):
                 noise = np.random.rand(4)

@@ -443,9 +443,7 @@ class TestReplicaAgreement:
         from batchai_retinanet_horovod_coco_tpu.parallel.mesh import (
             DATA_AXIS,
         )
-        from batchai_retinanet_horovod_coco_tpu.parallel.shmap import (
-            shard_map,
-        )
+        from jax import shard_map
 
         mesh = make_mesh(8)
         norms = jnp.array([1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 4.0])

@@ -712,6 +712,15 @@ def _run(args) -> dict[str, float]:
             process_id=args.process_id,
         )
     )
+    # After the distributed bring-up (jax.distributed.initialize must
+    # precede the first backend touch), before anything is built.
+    from batchai_retinanet_horovod_coco_tpu.utils.backend import (
+        announce_devices,
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
+    announce_devices("train")
     num_devices = args.num_devices or len(jax.devices())
     spatial_shards = int(getattr(args, "spatial_shards", 1) or 1)
     if spatial_shards > 1:

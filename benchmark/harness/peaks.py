@@ -1,0 +1,27 @@
+"""Published peaks per chip, keyed by ``device_kind`` as JAX reports it.
+One table; an unknown device is an error, never a default."""
+
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
+    # int8, 16 GB HBM2e at 819 GB/s, 1600 Gbit/s chip-to-chip.
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "ops_int8": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "ici_bits_per_s": 1600e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; add a row "
+            "with its source to benchmark/harness/peaks.py"
+        ) from None

@@ -122,6 +122,7 @@ def make_stage_tap(
         del token
         return params_sub, res_sub
 
+    @jax.named_scope("grad_allreduce")  # train/step.py::STEP_SCOPES
     def bwd(res_sub, ct):
         leaf_map = _stage_leaf_map(ct, raw_root)
         out_map, new_res, sat = reduce_leaves(

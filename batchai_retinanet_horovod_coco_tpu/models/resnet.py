@@ -27,6 +27,7 @@ from collections.abc import Callable, Sequence
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -622,33 +623,36 @@ class ResNet(nn.Module):
             and x.shape[1] % 2 == 0
             and x.shape[2] % 4 == 0
         )
-        x = StemConv(
-            features=64,
-            space_to_depth=self.stem != "conv",
-            block=4 if self.stem == "space_to_depth4" else 2,
-            dtype=self.dtype,
-            packed_output=packed_stem,
-            name="stem_conv",
-        )(x)
-        if packed_stem:
-            x = norm.packed("stem_norm", train, slot_major=True)(x)
-            x = nn.relu(x)
-            x = maxpool_packed_w(x)
-        else:
-            x = norm("stem_norm", train)(x)
-            x = nn.relu(x)
-            if self.stem_pool == "avg":
-                # Tie-free diagnostic downsample (see stem_pool field doc).
-                x = nn.avg_pool(
-                    x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1))
-                )
+        # named_scope "stem", "stage2".."stage5": the backbone's slices in a
+        # device trace read by scope (train/step.py::STEP_SCOPES).
+        with jax.named_scope("stem"):
+            x = StemConv(
+                features=64,
+                space_to_depth=self.stem != "conv",
+                block=4 if self.stem == "space_to_depth4" else 2,
+                dtype=self.dtype,
+                packed_output=packed_stem,
+                name="stem_conv",
+            )(x)
+            if packed_stem:
+                x = norm.packed("stem_norm", train, slot_major=True)(x)
+                x = nn.relu(x)
+                x = maxpool_packed_w(x)
             else:
-                # Symmetric (1, 1) padding (torch geometry; SAME would pad
-                # (0, 1) on even dims).  -inf pad so padding never wins the
-                # max.
-                x = nn.max_pool(
-                    x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1))
-                )
+                x = norm("stem_norm", train)(x)
+                x = nn.relu(x)
+                if self.stem_pool == "avg":
+                    # Tie-free diagnostic downsample (see stem_pool field doc).
+                    x = nn.avg_pool(
+                        x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1))
+                    )
+                else:
+                    # Symmetric (1, 1) padding (torch geometry; SAME would pad
+                    # (0, 1) on even dims).  -inf pad so padding never wins the
+                    # max.
+                    x = nn.max_pool(
+                        x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1))
+                    )
 
         features: dict[str, jnp.ndarray] = {}
         filters = 64
@@ -662,15 +666,16 @@ class ResNet(nn.Module):
                         f"{x.shape[2]} (make W divisible by 8)"
                     )
                 x = _pack_w(x)
-            for block in range(num_blocks):
-                x = BottleneckBlock(
-                    filters=filters,
-                    stride=stride if block == 0 else 1,
-                    norm=norm,
-                    dtype=self.dtype,
-                    packed=packed,
-                    name=f"stage{stage + 2}_block{block}",
-                )(x, train=train)
+            with jax.named_scope(f"stage{stage + 2}"):
+                for block in range(num_blocks):
+                    x = BottleneckBlock(
+                        filters=filters,
+                        stride=stride if block == 0 else 1,
+                        norm=norm,
+                        dtype=self.dtype,
+                        packed=packed,
+                        name=f"stage{stage + 2}_block{block}",
+                    )(x, train=train)
             if packed:
                 x = _unpack_w(x)
             if stage >= 1:  # C3 at stride 8, C4 at 16, C5 at 32

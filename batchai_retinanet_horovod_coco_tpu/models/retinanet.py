@@ -170,7 +170,8 @@ class RetinaNet(nn.Module):
         measured ~4 ms of the b8 flagship step, round-3 profile).
         """
         cfg = self.config
-        # named_scope: phase labels in profiler traces (SURVEY.md §5.1).
+        # named_scope: the slices a device trace is read by
+        # (train/step.py::STEP_SCOPES; SURVEY.md §5.1).
         with jax.named_scope("backbone"):
             features = build_backbone(cfg)(images, train=train)
         with jax.named_scope("fpn"):
@@ -200,8 +201,10 @@ class RetinaNet(nn.Module):
         with jax.named_scope("heads"):
             for level in cfg.anchor.levels:  # P3 → P7, matching anchor order
                 feat = pyramid[f"p{level}"]
-                cls_out.append(cls_head(feat, flatten=flatten))
-                box_out.append(box_head(feat, flatten=flatten))
+                with jax.named_scope("cls"):
+                    cls_out.append(cls_head(feat, flatten=flatten))
+                with jax.named_scope("box"):
+                    box_out.append(box_head(feat, flatten=flatten))
 
         if return_levels == "nhwc":
             # Raw dtype (bf16): an f32 cast here would double the final

@@ -40,6 +40,16 @@ one ``trace.json``.
 Child-process propagation: ``configure()`` exports ``RETINANET_OBS_DIR`` so
 ``spawn``-ed children (the shm workers) can self-enable via
 ``maybe_configure_from_env()`` without widening any pickled config surface.
+
+Profiler annotations: where ``install_annotation_factory`` has been called
+(``train/loop.py`` does at import, with ``jax.profiler.TraceAnnotation``;
+this module still never imports jax), ``span()`` and ``begin()``/``end()``
+are ALSO a profiler annotation named ``rn.<span name>``, ring on or off.
+An annotation costs a TraceMe that checks one flag while no profiler
+session runs, so whoever starts one (``--profile-dir``, the benchmark's
+tracer, ``jax.profiler.start_server``) finds the program's spans on the
+device trace's clock with no switch to flip.  A process that never
+installs a factory (the decode workers) keeps the shared null span.
 """
 
 from __future__ import annotations
@@ -90,6 +100,22 @@ _config_pid: int | None = None  # which process this config belongs to
 _registry_lock = threading.Lock()
 _rings: list["_Ring"] = []
 _tls = threading.local()
+
+# What a span is called on the profiler's timeline: "rn." + its name.
+ANNOTATION_PREFIX = "rn."
+# ``(name, **kwargs) -> context manager`` or None; see the module docstring.
+_annotation_factory = None
+
+
+def install_annotation_factory(factory) -> None:
+    """Make every span a profiler annotation too (``None`` undoes it).
+    Called by code that has jax anyway, with ``jax.profiler.TraceAnnotation``."""
+    global _annotation_factory
+    _annotation_factory = factory
+
+
+def annotation_factory():
+    return _annotation_factory
 
 
 def monotonic_s() -> float:
@@ -201,48 +227,72 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0")
+    """A ring span, wrapped around its profiler annotation if it has one."""
 
-    def __init__(self, name: str, args: dict | None):
+    __slots__ = ("name", "args", "t0", "annotation")
+
+    def __init__(self, name: str, args: dict | None, annotation=None):
         self.name = name
         self.args = args
+        self.annotation = annotation
 
     def __enter__(self):
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = monotonic_s()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = monotonic_s()
         _ring().add(("X", self.name, self.t0, t1 - self.t0, self.args))
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         return False
 
 
 def span(name: str, **args: Any):
     """Context manager timing a named region on the current thread's track.
 
-    Disabled: returns the shared no-op singleton (one bool check).  Keyword
-    args become the Chrome event's ``args`` payload — avoid them on
-    per-step hot paths (the dict is built before the enabled check)."""
+    No annotation factory and the ring off: returns the shared no-op
+    singleton (two checks).  With a factory the region is a profiler
+    annotation ``rn.<name>`` (the kwargs its metadata); with the ring on it
+    is a ring event (the kwargs its Chrome ``args``); with both, both.
+    Avoid kwargs on per-step hot paths (the dict is built before any
+    check)."""
+    if _annotation_factory is None:
+        if not _enabled:
+            return _NULL_SPAN
+        return _Span(name, args or None)
+    annotation = _annotation_factory(ANNOTATION_PREFIX + name, **args)
     if not _enabled:
-        return _NULL_SPAN
-    return _Span(name, args or None)
+        return annotation
+    return _Span(name, args or None, annotation)
 
 
 def begin(name: str, **args: Any):
     """Explicit begin half of a cross-thread span: the returned handle may
-    be ``end()``-ed by ANY thread; the span lands on the beginning thread's
-    track.  Returns None when disabled (``end(None)`` is a no-op)."""
+    be ``end()``-ed by ANY thread; the ring event lands on the beginning
+    thread's track, the profiler annotation (if a factory is installed) on
+    the ending thread's.  Returns None when there is neither (``end(None)``
+    is a no-op)."""
+    annotation = None
+    if _annotation_factory is not None:
+        annotation = _annotation_factory(ANNOTATION_PREFIX + name, **args)
+        annotation.__enter__()
     if not _enabled:
-        return None
-    return (name, monotonic_s(), _ring(), args or None)
+        return None if annotation is None else (name, None, None, None, annotation)
+    return (name, monotonic_s(), _ring(), args or None, annotation)
 
 
 def end(handle) -> None:
     """Complete a ``begin()`` handle (any thread)."""
-    if handle is None or not _enabled:
+    if handle is None:
         return
-    name, t0, ring, args = handle
-    ring.add(("X", name, t0, monotonic_s() - t0, args))
+    name, t0, ring, args, annotation = handle
+    if ring is not None and _enabled:
+        ring.add(("X", name, t0, monotonic_s() - t0, args))
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
 
 
 def instant(name: str, **args: Any) -> None:

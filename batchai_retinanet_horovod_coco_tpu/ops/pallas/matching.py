@@ -202,6 +202,9 @@ def assign_fused(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
+        # The Mosaic call's HLO instruction is named after this, not after
+        # whichever jit holds it; trace readers match "assign_fused".
+        name="assign_fused",
     )(jnp.moveaxis(anchors.astype(jnp.float32), 0, 1), gt, packed_t)
 
     matched_boxes = (

@@ -255,33 +255,39 @@ def sharded_update(
     params themselves).  The gradient reduce-scatter, the sharded
     optimizer update, and the global clip norm are UNCHANGED either way.
     """
+    # Scopes as in train/step.py::STEP_SCOPES: the collectives are
+    # grad_allreduce, the shard-local arithmetic between them optimizer.
     index = lax.axis_index(DATA_AXIS)
-    gshards = jax.tree.map(
-        lambda g: lax.psum_scatter(_pad_flat(g, n), DATA_AXIS, tiled=True) / n,
-        grads,
-    )
-    # The shards partition the mean gradient exactly (padding is zeros), so
-    # the global norm is the psum of per-shard square sums.
-    sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(gshards))
-    info = {"grad_norm": jnp.sqrt(lax.psum(sq, DATA_AXIS))}
-    pshards = jax.tree.map(lambda p: _local_shard(p, n, index), params)
-    if isinstance(tx, optax.GradientTransformationExtraArgs):
-        # Forward the already-psum-ed pre-clip norm so the in-chain
-        # sharded clip reuses it instead of a second psum; value= feeds
-        # reduce_on_plateau when the schedule carries one.
-        extra = {"grad_norm": info["grad_norm"]}
-        if loss_value is not None:
-            extra["value"] = loss_value
-        updates, new_opt_state = tx.update(
-            gshards, opt_state, pshards, **extra
+    with jax.named_scope("grad_allreduce"):
+        gshards = jax.tree.map(
+            lambda g: lax.psum_scatter(_pad_flat(g, n), DATA_AXIS, tiled=True) / n,
+            grads,
         )
-    else:
-        updates, new_opt_state = tx.update(gshards, opt_state, pshards)
+        # The shards partition the mean gradient exactly (padding is zeros), so
+        # the global norm is the psum of per-shard square sums.
+        sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(gshards))
+        info = {"grad_norm": jnp.sqrt(lax.psum(sq, DATA_AXIS))}
+    with jax.named_scope("optimizer"):
+        pshards = jax.tree.map(lambda p: _local_shard(p, n, index), params)
+        if isinstance(tx, optax.GradientTransformationExtraArgs):
+            # Forward the already-psum-ed pre-clip norm so the in-chain
+            # sharded clip reuses it instead of a second psum; value= feeds
+            # reduce_on_plateau when the schedule carries one.
+            extra = {"grad_norm": info["grad_norm"]}
+            if loss_value is not None:
+                extra["value"] = loss_value
+            updates, new_opt_state = tx.update(
+                gshards, opt_state, pshards, **extra
+            )
+        else:
+            updates, new_opt_state = tx.update(gshards, opt_state, pshards)
     if gather_updates is not None:
         # Compressed path: every device applies the identical
         # dequantized full update to its replicated params, so the
         # params stay bitwise replicated without an f32 gather.
         return gather_updates(updates, params), new_opt_state, info
-    new_pshards = optax.apply_updates(pshards, updates)
-    new_params = jax.tree.map(_unshard, new_pshards, params)
+    with jax.named_scope("optimizer"):
+        new_pshards = optax.apply_updates(pshards, updates)
+    with jax.named_scope("grad_allreduce"):
+        new_params = jax.tree.map(_unshard, new_pshards, params)
     return new_params, new_opt_state, info

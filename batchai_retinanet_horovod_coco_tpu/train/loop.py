@@ -47,6 +47,11 @@ from batchai_retinanet_horovod_coco_tpu.train.state import model_variables
 from batchai_retinanet_horovod_coco_tpu.utils.checkpoint import CheckpointManager
 from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
 
+# Every obs/trace.py span of this process is from here on a profiler
+# annotation too ("rn.data_wait", "rn.step", "rn.device-prefetch", ...):
+# obs/trace.py never imports jax, this module has it anyway.
+trace.install_annotation_factory(jax.profiler.TraceAnnotation)
+
 
 # With --log-every 0 the loop still pulls the loss scalar at this cadence so
 # a NaN cannot train garbage for the rest of a long run before aborting
@@ -379,25 +384,79 @@ class _AsyncEvalRunner:
             warnings.warn(f"async eval failed during loop unwind: {exc!r}")
 
 
-def _step_cost_flops(step_fn, state, device_arrays) -> float | None:
-    """XLA-counted FLOPs of one train step, from the UNOPTIMIZED lowering
-    (``Lowered.cost_analysis`` — tracing cost only, no second backend
-    compile).  Feeds the ``cost_analysis`` trace instant + compile event
-    the perf doctor's MFU/roofline estimate reads (obs/analyze), so the
-    number exists per RUN, not only per bench.  None when the step
-    wrapper has no AOT surface or the backend offers no cost analysis —
-    the report then carries ``mfu: null`` instead of a guess."""
-    lower = getattr(step_fn, "lower", None)
-    if lower is None:
-        return None
+# The step functions the last ``run_training`` call built, by (H, W)
+# bucket, each with the abstract arguments it was first called on.  What
+# ``compiled_step`` lowers again; no device buffer of the state or a batch
+# is kept (the state is donated to the step).  The functions, and so their
+# loaded executables, live until the next call replaces them.
+_built_steps: dict[tuple[int, int], tuple[Callable, tuple]] = {}
+
+
+def _abstract(tree):
+    """Shapes, dtypes and (where committed) shardings of a tree of arrays."""
+
+    def leaf(x):
+        if not isinstance(x, jax.Array):  # restored state: host numpy
+            return jax.ShapeDtypeStruct(np.shape(x), np.result_type(x))
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=x.weak_type,
+            sharding=x.sharding if x.committed else None,
+        )
+
+    return jax.tree.map(leaf, tree)
+
+
+def compiled_step(hw: tuple[int, int] | None = None):
+    """The ``jax.stages.Compiled`` of the train step the last
+    ``run_training`` call built for bucket ``hw`` (default: the bucket it
+    built last): for its ``cost_analysis()``, its ``as_text()``
+    (``train/step.py::scope_table``), its ``memory_analysis()``.
+
+    Lowers the SAME function on the abstract arguments of its first call
+    and compiles.  Once the step has run, jit's own caches answer both with
+    the executable the loop ran (no request reaches the compiler or the
+    persistent cache; tests/unit/test_step_scopes.py counts them), so its
+    instruction names are the ones a device trace shows: call it AFTER the
+    step's first execution, never before.  Raises ``LookupError`` when no
+    step was built, or none for ``hw``."""
+    if hw is None and _built_steps:
+        hw = next(reversed(_built_steps))
+    if hw not in _built_steps:
+        raise LookupError(f"run_training has built no train step for bucket {hw}")
+    step_fn, args = _built_steps[hw]
+    return step_fn.lower(*args).compile()
+
+
+def _record_step_cost(hw, batch: int) -> None:
+    """The ``cost_analysis`` trace instant (the perf doctor's MFU estimate
+    reads it, obs/analyze): XLA-counted FLOPs of the compiled step for
+    bucket ``hw``, after its first execution.  Nothing where the backend
+    offers no cost analysis — the report then carries ``mfu: null``
+    instead of a guess."""
     try:
-        cost = lower(state, device_arrays).cost_analysis()
+        cost = compiled_step(hw).cost_analysis()
         if isinstance(cost, list):
             cost = cost[0] if cost else None
         flops = float(cost.get("flops", 0.0)) if cost else 0.0
     except Exception:
-        return None
-    return flops if flops > 0 else None
+        return
+    if flops > 0:
+        trace.instant(
+            "cost_analysis", target="train_step", bucket=f"{hw[0]}x{hw[1]}",
+            flops=flops, batch=batch,
+        )
+
+
+def _profile_options():
+    """``--profile-dir``'s profiler session: device operations and host
+    annotations (this program's ``rn.*`` spans among them), no Python call
+    tracing.  JAX's defaults wrote 850 MB for 40 flagship steps and took
+    minutes to stop (PERF.md, PR 22).  The HLO proto stays on, so XProf
+    shows the step's scopes (train/step.py::STEP_SCOPES) itself."""
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 1
+    options.python_tracer_level = 0
+    return options
 
 
 def _compile_barrier(step_fn, state, device_arrays, hw) -> None:
@@ -611,6 +670,7 @@ def run_training(
             pass  # metadata must never block training bring-up
 
     step_fns: dict[tuple[int, int], Callable] = {}
+    _built_steps.clear()
     start_step = int(state.step)
     last_saved: int | None = None
     # Clamp the profile window into the steps this run will actually take
@@ -666,7 +726,8 @@ def run_training(
             window_steps += 1
             hw = images_shape[1:3]
             step_fn = step_fns.get(hw)
-            if step_fn is None:
+            new_step = step_fn is None
+            if new_step:
                 # AOT point: build + (multi-process) compile-and-barrier.
                 # The span/event turn each bucket's one-time multi-minute
                 # gap into an attributed compile, not an apparent stall —
@@ -708,22 +769,9 @@ def run_training(
                     # peer is still compiling (collective timeouts <<
                     # compile times).
                     _compile_barrier(step_fn, state, device_arrays, hw)
-                    # Obs runs also record the step's XLA-counted FLOPs
-                    # (one extra trace of the step, no extra compile) so
-                    # PERF_REPORT.json can carry an MFU estimate.
-                    flops = (
-                        _step_cost_flops(step_fn, state, device_arrays)
-                        if trace.enabled()
-                        else None
+                    _built_steps[hw] = (
+                        step_fn, _abstract((state, device_arrays))
                     )
-                    if flops is not None:
-                        trace.instant(
-                            "cost_analysis",
-                            target="train_step",
-                            bucket=f"{hw[0]}x{hw[1]}",
-                            flops=flops,
-                            batch=int(images_shape[0]),
-                        )
                 loop_hb.beat()
                 # Live-telemetry record site (one bool check while off):
                 # the status server's train_compiles_total/last_compile.
@@ -739,12 +787,19 @@ def run_training(
                         bucket=f"{hw[0]}x{hw[1]}",
                         step=step,
                         build_s=round(monotonic_s() - t_compile, 3),
-                        flops=flops,
                     )
             if config.profile_dir and step == prof_start:
-                jax.profiler.start_trace(config.profile_dir)
+                jax.profiler.start_trace(
+                    config.profile_dir, profiler_options=_profile_options()
+                )
             with trace.span("step"):
                 state, metrics = step_fn(state, device_arrays)
+            if new_step and trace.enabled():
+                # Obs runs record the step's XLA-counted FLOPs so
+                # PERF_REPORT.json can carry an MFU estimate: from the
+                # executable the call above just compiled or loaded,
+                # which the compile cache hands back.
+                _record_step_cost(hw, int(images_shape[0]))
             if config.profile_dir and step == prof_end:
                 jax.block_until_ready(metrics)
                 jax.profiler.stop_trace()

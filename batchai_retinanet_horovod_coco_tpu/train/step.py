@@ -16,6 +16,7 @@ per the north star (BASELINE.json:5).
 
 from __future__ import annotations
 
+import re
 from functools import partial
 from typing import Any, Callable
 
@@ -36,6 +37,75 @@ from batchai_retinanet_horovod_coco_tpu.ops import matching as matching_lib
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
 from batchai_retinanet_horovod_coco_tpu.train.state import TrainState, model_variables
 
+# The step program's slices, written down once: every ``jax.named_scope`` a
+# train step enters (here, in models/, parallel/zero.py and comm/overlap.py)
+# bears one of these names, and whoever reads a device trace by slice
+# (``scope_table``, the benchmark's readers) files an operation under the
+# OUTERMOST of them on its ``op_name``.  slice -> the scopes beneath it.
+# Scopes are metadata: the lowered program is the same with and without.
+# The backward needs none of its own (``transpose(jvp(<scope>))``).
+STEP_SCOPES: dict[str, tuple[str, ...]] = {
+    "backbone": ("stem", "stage2", "stage3", "stage4", "stage5"),
+    "fpn": (),
+    "heads": ("cls", "box"),
+    "assign": (),  # anchor targets, Pallas or jnp
+    "loss": (),  # focal, smooth-L1, target encoding
+    "optimizer": (),  # clip, decay, momentum, apply, the numerics summary
+    # every pmean / psum / reduce-scatter / gather of gradients, metrics and
+    # batch statistics in sharded_step, zero_step and comm_step (the spatial
+    # step has none of its own: GSPMD derives its collectives from the
+    # operations it partitions, and they keep those operations' scopes)
+    "grad_allreduce": (),
+}
+UNSCOPED = "unscoped"
+
+# One instruction of ``Compiled.as_text()``: its name, and its op_name if
+# it carries metadata.
+_HLO_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?(?:metadata=\{[^}]*?op_name="([^"]*)"|$)'
+)
+_TRANSFORM = re.compile(r"^\w+\((.*)\)$")
+
+
+def scope_of(op_name: str) -> tuple[str, str, str]:
+    """``(slice, direction, path)`` of one HLO ``op_name``
+    (``jit(train_step)/transpose(jvp(RetinaNet))/backbone/stage2/...``):
+    the outermost ``STEP_SCOPES`` name on it (``UNSCOPED`` if none),
+    ``bwd`` if a ``transpose(`` is on it else ``fwd``, and the names from
+    the slice down with their transforms peeled off."""
+    names = []
+    for part in op_name.split("/"):
+        while (m := _TRANSFORM.match(part)) is not None:
+            part = m.group(1)
+        names.append(part)
+    direction = "bwd" if "transpose(" in op_name else "fwd"
+    for i, name in enumerate(names):
+        if name in STEP_SCOPES:
+            return name, direction, "/".join(names[i:])
+    return UNSCOPED, direction, "/".join(names)
+
+
+def scope_table(compiled) -> dict[str, tuple[str, str, str]]:
+    """``{instruction name: (slice, direction, path)}`` of a compiled step
+    (a ``jax.stages.Compiled``, e.g. ``train/loop.py::compiled_step()``).
+
+    The device trace names an operation by its HLO instruction
+    (``fusion.1992``) and carries no scope; the optimized module keeps each
+    instruction's ``op_name``, so the join is by name.  Parsed once from
+    ``compiled.as_text()``, every computation of the module (a fusion's
+    inner instructions are never traced on their own, and harmless).  A
+    fusion carries ONE op_name: an update fused into a weight-gradient
+    convolution counts with that convolution.  Instructions without
+    metadata (parameter copies) are ``UNSCOPED``.  An executable that came
+    out of a compile cache filled before the scopes existed has the OLD
+    metadata (the cache key leaves metadata out)."""
+    table = {}
+    for line in compiled.as_text().splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is not None:
+            table[m.group(1)] = scope_of(m.group(2) or "")
+    return table
+
 
 def _forward_and_loss(
     model,
@@ -53,7 +123,8 @@ def _forward_and_loss(
     variables = dict(model_variables(state), params=params)
     has_bn = "batch_stats" in variables
     # uint8 batches normalize here, on device (data/pipeline.normalize_images).
-    images = pipeline_lib.normalize_images(images)
+    with jax.named_scope("backbone"):
+        images = pipeline_lib.normalize_images(images)
 
     # NHWC-direct loss path: raw per-level head outputs, no anchor-major
     # retile/concat (losses.total_loss_compact_nhwc — measured ~4 ms/step
@@ -78,35 +149,37 @@ def _forward_and_loss(
     # Planar (B, 4, A) box targets on the NHWC path: dense lane layout end
     # to end instead of the 32x-padded 4-minor form (ops.matching docstring).
     planar = return_levels == "nhwc"
-    targets = matching_lib.anchor_targets_compact_batched(
-        anchors, gt_boxes, gt_labels, gt_mask, matching_config,
-        planar_box_targets=planar,
-    )
-    targets = jax.tree.map(lax.stop_gradient, targets)
+    with jax.named_scope("assign"):
+        targets = matching_lib.anchor_targets_compact_batched(
+            anchors, gt_boxes, gt_labels, gt_mask, matching_config,
+            planar_box_targets=planar,
+        )
+        targets = jax.tree.map(lax.stop_gradient, targets)
 
-    if return_levels == "nhwc":
-        metrics = losses_lib.total_loss_compact_nhwc(
-            outputs["cls_levels"],
-            outputs["box_levels"],
-            targets.matched_labels,
-            targets.box_targets,
-            targets.state,
-            model.config.anchors_per_location,
-            loss_config,
-            planar_box_targets=True,
+    with jax.named_scope("loss"):
+        if return_levels == "nhwc":
+            metrics = losses_lib.total_loss_compact_nhwc(
+                outputs["cls_levels"],
+                outputs["box_levels"],
+                targets.matched_labels,
+                targets.box_targets,
+                targets.state,
+                model.config.anchors_per_location,
+                loss_config,
+                planar_box_targets=True,
+            )
+        else:
+            metrics = losses_lib.total_loss_compact(
+                outputs["cls_logits"],
+                outputs["box_deltas"],
+                targets.matched_labels,
+                targets.box_targets,
+                targets.state,
+                loss_config,
+            )
+        metrics["num_pos"] = jnp.sum(
+            (targets.state == matching_lib.POSITIVE).astype(jnp.float32)
         )
-    else:
-        metrics = losses_lib.total_loss_compact(
-            outputs["cls_logits"],
-            outputs["box_deltas"],
-            targets.matched_labels,
-            targets.box_targets,
-            targets.state,
-            loss_config,
-        )
-    metrics["num_pos"] = jnp.sum(
-        (targets.state == matching_lib.POSITIVE).astype(jnp.float32)
-    )
     return metrics["loss"], (metrics, new_batch_stats)
 
 
@@ -205,6 +278,42 @@ def _cached_step_entry(make_step: Callable) -> Callable:
     return entry
 
 
+def _grad_norm(grads, metrics):
+    """SURVEY.md §5.5: grad-norm is a first-class per-step metric —
+    computed ONCE (pre-clip, on the gradients the optimizer consumes) and
+    fed to the clip chain via extra args (clip_by_global_norm_precomputed),
+    so the recorded value IS the norm the clip acted on, never a
+    recomputation."""
+    metrics["grad_norm"] = gnorm = optax.global_norm(grads)
+    return gnorm
+
+
+def _update(state: TrainState, grads, gnorm, metrics, new_bs, numerics) -> TrainState:
+    """The replicated update the single-device, spatial and data-parallel
+    steps share: apply, the post-update norm and the numerics summary;
+    ``metrics`` gains its entries in place."""
+    new_state = state.apply_gradients(
+        grads, new_bs, loss_value=metrics["loss"], grad_norm=gnorm
+    )
+    # Norm of the POST-update params: the loss was computed from the
+    # pre-update params, so it cannot witness a poisoned update — this
+    # can, and the loop checks it before any checkpoint save (a norm read
+    # of params the next step reloads anyway; cost is noise).
+    metrics["param_norm"] = optax.global_norm(new_state.params)
+    if numerics.enabled:
+        # In-step numerics summary (ISSUE 10): ~2 extra reduces; the
+        # disabled step's HLO is unchanged (trace-time Python gate).
+        # Post-allreduce grads + params are replicated on a mesh, so the
+        # shared summary is replicated-out safe there.
+        metrics.update(
+            numerics_lib.step_summary(
+                grads, state.params, new_state.params,
+                metrics["param_norm"], numerics,
+            )
+        )
+    return new_state
+
+
 def _global_math_step(local_step, numerics: NumericsConfig | None = None):
     """Plain global-batch step body: grads → metrics → update.
 
@@ -217,30 +326,9 @@ def _global_math_step(local_step, numerics: NumericsConfig | None = None):
 
     def train_step(state: TrainState, batch: dict[str, Any]):
         grads, metrics, new_bs = local_step(state, batch)
-        # SURVEY.md §5.5: grad-norm is a first-class per-step metric —
-        # computed ONCE here and fed to the clip chain via extra args
-        # (clip_by_global_norm_precomputed), so the recorded value IS the
-        # pre-clip norm the clip acted on, never a recomputation.
-        gnorm = optax.global_norm(grads)
-        metrics["grad_norm"] = gnorm
-        new_state = state.apply_gradients(
-            grads, new_bs, loss_value=metrics["loss"], grad_norm=gnorm
-        )
-        # Norm of the POST-update params: the loss above was computed
-        # from the pre-update params, so it cannot witness a poisoned
-        # update — this can, and the loop checks it before any
-        # checkpoint save (a norm read of params the next step reloads
-        # anyway; cost is noise).
-        metrics["param_norm"] = optax.global_norm(new_state.params)
-        if numerics.enabled:
-            # In-step numerics summary (ISSUE 10): ~2 extra reduces; the
-            # disabled step's HLO is unchanged (trace-time Python gate).
-            metrics.update(
-                numerics_lib.step_summary(
-                    grads, state.params, new_state.params,
-                    metrics["param_norm"], numerics,
-                )
-            )
+        with jax.named_scope("optimizer"):
+            gnorm = _grad_norm(grads, metrics)
+            new_state = _update(state, grads, gnorm, metrics, new_bs, numerics)
         return new_state, metrics
 
     return train_step
@@ -407,6 +495,7 @@ def make_train_step(
                 compress as compress_lib,
             )
 
+        @jax.named_scope("grad_allreduce")
         def reduce_metrics(metrics):
             num_pos = lax.psum(metrics["num_pos"], DATA_AXIS)
             metrics = lax.pmean(metrics, DATA_AXIS)
@@ -455,7 +544,8 @@ def make_train_step(
                     )
                 metrics = reduce_metrics(metrics)
                 if state.batch_stats:
-                    new_bs = lax.pmean(new_bs, DATA_AXIS)
+                    with jax.named_scope("grad_allreduce"):
+                        new_bs = lax.pmean(new_bs, DATA_AXIS)
                 # Reduce-scatter + sharded update + all_gather replaces the
                 # pmean-allreduce + replicated update (parallel/zero.py).
                 # Comm-on (ISSUE 13): the gradient reduce-scatter stays
@@ -472,6 +562,7 @@ def make_train_step(
                         else {}
                     )
 
+                    @jax.named_scope("grad_allreduce")
                     def gather(updates, params):
                         new_p, new_res, sat = (
                             compress_lib.zero_gather_updates(
@@ -493,39 +584,41 @@ def make_train_step(
                     gather_updates=gather,
                 )
                 metrics.update(info)
-                # Post-update param norm (see the single-device step): the
-                # gathered new_params are replicated, so the norm is too.
-                metrics["param_norm"] = optax.global_norm(new_params)
-                if numerics.enabled:
-                    # Hand-assembled summary: the reduced gradient only
-                    # ever exists as 1/N shards here, so the non-finite
-                    # count psums the LOCAL counts (a NaN anywhere
-                    # poisons the reduce-scatter, so local detection is
-                    # global detection) and group norms are the pmean of
-                    # per-replica local-grad norms; params are
-                    # replicated, so the update ratio is the same math
-                    # as the replicated step's.
-                    metrics["nonfinite_grads"] = lax.psum(
-                        numerics_lib.nonfinite_count(grads), DATA_AXIS
-                    )
-                    metrics["update_ratio"] = numerics_lib.update_ratio(
-                        state.params, new_params, metrics["param_norm"]
-                    )
-                    if numerics.per_group:
-                        for key, norm in numerics_lib.group_norms(
-                            grads
-                        ).items():
-                            metrics[f"gnorm/{key}"] = lax.pmean(
-                                norm, DATA_AXIS
-                            )
+                with jax.named_scope("optimizer"):
+                    # Post-update param norm (see the single-device step): the
+                    # gathered new_params are replicated, so the norm is too.
+                    metrics["param_norm"] = optax.global_norm(new_params)
+                    if numerics.enabled:
+                        # Hand-assembled summary: the reduced gradient only
+                        # ever exists as 1/N shards here, so the non-finite
+                        # count psums the LOCAL counts (a NaN anywhere
+                        # poisons the reduce-scatter, so local detection is
+                        # global detection) and group norms are the pmean of
+                        # per-replica local-grad norms; params are
+                        # replicated, so the update ratio is the same math
+                        # as the replicated step's.
+                        metrics["nonfinite_grads"] = lax.psum(
+                            numerics_lib.nonfinite_count(grads), DATA_AXIS
+                        )
+                        metrics["update_ratio"] = numerics_lib.update_ratio(
+                            state.params, new_params, metrics["param_norm"]
+                        )
+                        if numerics.per_group:
+                            for key, norm in numerics_lib.group_norms(
+                                grads
+                            ).items():
+                                metrics[f"gnorm/{key}"] = lax.pmean(
+                                    norm, DATA_AXIS
+                                )
                 new_comm_state = state.comm_state
                 if comm_on:
-                    metrics.update(
-                        compress_lib.comm_metrics(
-                            zplan, comm_out["res"], comm_out["sat"],
-                            DATA_AXIS, mesh.size, zero=True,
+                    with jax.named_scope("grad_allreduce"):
+                        metrics.update(
+                            compress_lib.comm_metrics(
+                                zplan, comm_out["res"], comm_out["sat"],
+                                DATA_AXIS, mesh.size, zero=True,
+                            )
                         )
-                    )
                     if isinstance(state.comm_state, dict):
                         new_comm_state = comm_out["res"]
                 new_state = state.replace(
@@ -624,40 +717,47 @@ def make_train_step(
                         )
                     # One fused pass: exact f32 reduce-scatter + EF
                     # add-back + compressed gather per bucket.
-                    grads, new_comm, sat = compress_lib.reduce_tree(
-                        grads, comm_cs, plan, comm, DATA_AXIS, mesh.size,
-                        comm_topology,
-                    )
-                num_pos = lax.psum(metrics["num_pos"], DATA_AXIS)
-                metrics = lax.pmean(metrics, DATA_AXIS)
-                metrics["num_pos"] = num_pos
-                # Pre-clip global norm of the DEQUANTIZED gradients —
-                # the values the optimizer actually consumes — shared
-                # with the clip chain (clip_by_global_norm_precomputed).
-                gnorm = optax.global_norm(grads)
-                metrics["grad_norm"] = gnorm
+                    with jax.named_scope("grad_allreduce"):
+                        grads, new_comm, sat = compress_lib.reduce_tree(
+                            grads, comm_cs, plan, comm, DATA_AXIS,
+                            mesh.size, comm_topology,
+                        )
+                with jax.named_scope("grad_allreduce"):
+                    num_pos = lax.psum(metrics["num_pos"], DATA_AXIS)
+                    metrics = lax.pmean(metrics, DATA_AXIS)
+                    metrics["num_pos"] = num_pos
+                with jax.named_scope("optimizer"):
+                    # On the DEQUANTIZED gradients — the values the
+                    # optimizer actually consumes.
+                    gnorm = _grad_norm(grads, metrics)
                 if state.batch_stats:
-                    new_bs = lax.pmean(new_bs, DATA_AXIS)
-                new_state = state.apply_gradients(
-                    grads, new_bs, loss_value=metrics["loss"],
-                    grad_norm=gnorm,
-                )
-                metrics["param_norm"] = optax.global_norm(new_state.params)
-                metrics.update(
-                    compress_lib.comm_metrics(
-                        plan, new_comm, sat, DATA_AXIS, mesh.size,
-                        topology=comm_topology,
+                    with jax.named_scope("grad_allreduce"):
+                        new_bs = lax.pmean(new_bs, DATA_AXIS)
+                with jax.named_scope("optimizer"):
+                    new_state = state.apply_gradients(
+                        grads, new_bs, loss_value=metrics["loss"],
+                        grad_norm=gnorm,
                     )
-                )
+                    metrics["param_norm"] = optax.global_norm(
+                        new_state.params
+                    )
+                with jax.named_scope("grad_allreduce"):
+                    metrics.update(
+                        compress_lib.comm_metrics(
+                            plan, new_comm, sat, DATA_AXIS, mesh.size,
+                            topology=comm_topology,
+                        )
+                    )
                 if isinstance(state.comm_state, dict):
                     new_state = new_state.replace(comm_state=new_comm)
                 if numerics.enabled:
-                    metrics.update(
-                        numerics_lib.step_summary(
-                            grads, state.params, new_state.params,
-                            metrics["param_norm"], numerics,
+                    with jax.named_scope("optimizer"):
+                        metrics.update(
+                            numerics_lib.step_summary(
+                                grads, state.params, new_state.params,
+                                metrics["param_norm"], numerics,
+                            )
                         )
-                    )
                 return new_state, metrics
 
             return jax.jit(
@@ -685,31 +785,19 @@ def make_train_step(
             metrics["replica_agreement"] = numerics_lib.replica_agreement(
                 optax.global_norm(grads), DATA_AXIS
             )
-        # THE allreduce: Horovod's NCCL ring → one compiled pmean over ICI.
-        grads = lax.pmean(grads, DATA_AXIS)
-        num_pos = lax.psum(metrics["num_pos"], DATA_AXIS)  # a count, not a mean
-        metrics = lax.pmean(metrics, DATA_AXIS)
-        metrics["num_pos"] = num_pos
-        # Pre-clip global norm, computed once and shared with the clip
-        # chain via extra args (clip_by_global_norm_precomputed).
-        gnorm = optax.global_norm(grads)
-        metrics["grad_norm"] = gnorm
+        with jax.named_scope("grad_allreduce"):
+            # THE allreduce: Horovod's NCCL ring → one compiled pmean over ICI.
+            grads = lax.pmean(grads, DATA_AXIS)
+            num_pos = lax.psum(metrics["num_pos"], DATA_AXIS)  # a count, not a mean
+            metrics = lax.pmean(metrics, DATA_AXIS)
+            metrics["num_pos"] = num_pos
+        with jax.named_scope("optimizer"):
+            gnorm = _grad_norm(grads, metrics)
         if state.batch_stats:
-            new_bs = lax.pmean(new_bs, DATA_AXIS)  # sync-BN semantics
-        new_state = state.apply_gradients(
-            grads, new_bs, loss_value=metrics["loss"], grad_norm=gnorm
-        )
-        # Post-update param norm (see the single-device step for why).
-        metrics["param_norm"] = optax.global_norm(new_state.params)
-        if numerics.enabled:
-            # Post-allreduce grads + params are replicated, so the shared
-            # summary is replicated-out safe here.
-            metrics.update(
-                numerics_lib.step_summary(
-                    grads, state.params, new_state.params,
-                    metrics["param_norm"], numerics,
-                )
-            )
+            with jax.named_scope("grad_allreduce"):
+                new_bs = lax.pmean(new_bs, DATA_AXIS)  # sync-BN semantics
+        with jax.named_scope("optimizer"):
+            new_state = _update(state, grads, gnorm, metrics, new_bs, numerics)
         return new_state, metrics
 
     return jax.jit(sharded_step, donate_argnums=(0,) if donate_state else ())

@@ -306,3 +306,42 @@ def test_mixed_bucket_stream_compiles_per_shape():
     train_recs = [r for r in logger.records if r[1] == "train"]
     assert len(train_recs) == 4
     assert all(np.isfinite(float(r[2]["loss"])) for r in train_recs)
+
+
+class TestProfilerAnnotations:
+    def test_profile_dir_trace_holds_the_programs_spans(self, tmp_path):
+        """``--profile-dir``'s session (no Python tracing, the HLO proto left
+        on) records the loop's own spans as ``rn.*`` annotations on the host
+        plane, with the obs ring never enabled."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        from batchai_retinanet_horovod_coco_tpu.obs import trace
+        from batchai_retinanet_horovod_coco_tpu.train import loop
+
+        trace.reset()
+        trace.install_annotation_factory(jax.profiler.TraceAnnotation)
+        options = loop._profile_options()
+        assert (options.host_tracer_level, options.python_tracer_level) == (1, 0)
+        assert options.enable_hlo_proto is True
+        model = tiny_model()
+        run_training(
+            model, fresh_state(model), batch_stream(), NUM_CLASSES,
+            LoopConfig(total_steps=5, log_every=5, profile_dir=str(tmp_path),
+                       profile_start_step=2, profile_steps=3),
+        )
+        assert not trace.enabled()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        names = [
+            e.name
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for e in line.events
+            if e.name.startswith("rn.")
+        ]
+        # steps 2..4 are inside the session; the prefetch thread runs ahead
+        assert names.count("rn.step") == 3 and names.count("rn.data_wait") >= 2
+        assert "rn.device-prefetch" in names
+        assert not [n for n in names if n.startswith("rn.rn.")]

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -28,10 +29,19 @@ from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
 
 @pytest.fixture(autouse=True)
 def _clean_trace_state():
-    """Every test starts and ends with tracing disabled (module-global)."""
+    """Every test starts and ends with tracing disabled (module-global),
+    and runs without a profiler-annotation factory: another test file in
+    this process may have imported train/loop.py, which installs one."""
+    installed = trace.annotation_factory()
+    trace.install_annotation_factory(None)
     trace.reset()
-    yield
+    yield installed
     trace.reset()
+    if installed is None and "batchai_retinanet_horovod_coco_tpu.train.loop" in sys.modules:
+        import jax  # the test imported train/loop.py first: keep what that installed
+
+        installed = jax.profiler.TraceAnnotation
+    trace.install_annotation_factory(installed)
 
 
 def _load_trace(path):
@@ -56,6 +66,36 @@ def _validate_chrome_schema(doc):
             assert ev["name"] in (
                 "process_name", "thread_name", "process_labels"
             )
+
+
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps what it was
+    made with and counts how often it was entered and left, and where."""
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+        self.entered = self.exited = 0
+        self.exit_thread = None
+
+    def __enter__(self):
+        self.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.exited += 1
+        self.exit_thread = threading.get_ident()
+        return False
+
+
+def _install_recording_factory() -> list:
+    made = []
+
+    def factory(name, **kwargs):
+        made.append(_RecordingAnnotation(name, **kwargs))
+        return made[-1]
+
+    trace.install_annotation_factory(factory)
+    return made
 
 
 class TestTrace:
@@ -214,6 +254,82 @@ class TestTrace:
             if e["ph"] == "M" and e["name"] == "process_name"
         ]
         assert any("shm-worker-0" in n for n in proc_names)
+
+    def test_no_factory_means_null_span_and_no_jax(self):
+        """A process that installs no annotation factory (a decode worker)
+        gets the shared null span, and importing obs/trace.py pulls in no
+        jax: checked in a fresh interpreter."""
+        import subprocess
+
+        code = (
+            "import sys\n"
+            "from batchai_retinanet_horovod_coco_tpu.obs import trace\n"
+            "assert trace.annotation_factory() is None\n"
+            "assert trace.span('a') is trace.span('b', k=1)\n"
+            "assert trace.begin('a') is None\n"
+            "assert not [m for m in sys.modules if m == 'jax' or m.startswith('jax.')]\n"
+        )
+        repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        proc = subprocess.run(
+            [sys.executable, "-c", code], cwd=repo, capture_output=True, text=True
+        )
+        assert proc.returncode == 0, proc.stderr
+
+    def test_factory_with_ring_off_is_the_annotation_alone(self):
+        made = _install_recording_factory()
+        with trace.span("step"):
+            pass
+        with trace.span("compile_train_step", bucket="64x96"):
+            pass
+        assert [(a.name, a.kwargs, a.entered, a.exited) for a in made] == [
+            ("rn.step", {}, 1, 1),
+            ("rn.compile_train_step", {"bucket": "64x96"}, 1, 1),
+        ]
+        # The span IS the annotation object: nothing of the ring around it.
+        assert trace.span("x") is made[-1]
+        assert trace.snapshot_events() == [] and not trace.enabled()
+
+    def test_factory_with_ring_on_is_both(self, tmp_path):
+        made = _install_recording_factory()
+        trace.configure(str(tmp_path), process_label="t")
+        with trace.span("data_wait"):
+            time.sleep(0.001)
+        assert [(a.name, a.entered, a.exited) for a in made] == [("rn.data_wait", 1, 1)]
+        spans = [e for e in trace.snapshot_events() if e["ph"] == "X"]
+        assert [e["name"] for e in spans] == ["data_wait"]  # no prefix in the ring
+        assert spans[0]["dur"] >= 1000
+
+    @pytest.mark.parametrize("ring", [False, True])
+    def test_begin_end_across_threads_with_factory(self, tmp_path, ring):
+        made = _install_recording_factory()
+        if ring:
+            trace.configure(str(tmp_path), process_label="t")
+        handle = trace.begin("cross_thread", id=7)
+        assert (made[0].entered, made[0].exited) == (1, 0)
+        t = threading.Thread(target=trace.end, args=(handle,))
+        t.start()
+        t.join()
+        assert [(a.name, a.kwargs, a.entered, a.exited) for a in made] == [
+            ("rn.cross_thread", {"id": 7}, 1, 1)]
+        assert made[0].exit_thread != threading.get_ident()
+        spans = [e["name"] for e in trace.snapshot_events() if e["ph"] == "X"]
+        assert spans == (["cross_thread"] if ring else [])
+
+    def test_train_loop_installs_the_profilers_annotation(self, _clean_trace_state):
+        """Importing train/loop.py is what makes spans profiler annotations
+        (jax.profiler.TraceAnnotation under the rn. prefix)."""
+        import jax
+
+        from batchai_retinanet_horovod_coco_tpu.train import loop  # noqa: F401
+
+        # Installed by this import, or by an earlier one (the fixture then
+        # set it aside and yields it).
+        installed = trace.annotation_factory() or _clean_trace_state
+        assert installed is jax.profiler.TraceAnnotation
+        assert trace.ANNOTATION_PREFIX == "rn."
+        trace.install_annotation_factory(installed)
+        with trace.span("step"):  # a real TraceMe, no profiler session: a flag check
+            pass
 
     def test_monotonic_clock_alignment(self):
         t = trace.monotonic_s()

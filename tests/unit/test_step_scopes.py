@@ -1,0 +1,282 @@
+"""The step program's named scopes (``train/step.py::STEP_SCOPES``), the
+table that files a compiled step's instructions under them
+(``scope_table``), and ``train/loop.py::compiled_step``.
+
+On the CPU mesh at ``resnet_test`` size: which slice an instruction lands
+in is a property of the program's metadata, not of the backend.
+"""
+
+import contextlib
+import gc
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
+from batchai_retinanet_horovod_coco_tpu.comm.compress import init_comm_state
+from batchai_retinanet_horovod_coco_tpu.data.pipeline import Batch
+from batchai_retinanet_horovod_coco_tpu.models import RetinaNetConfig, build_retinanet
+from batchai_retinanet_horovod_coco_tpu.obs import trace
+from batchai_retinanet_horovod_coco_tpu.parallel import make_mesh, zero
+from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
+from batchai_retinanet_horovod_coco_tpu.train import create_train_state, loop
+from batchai_retinanet_horovod_coco_tpu.train.optim import OptimizerConfig, make_optimizer
+from batchai_retinanet_horovod_coco_tpu.train.step import (
+    STEP_SCOPES,
+    UNSCOPED,
+    make_train_step,
+    scope_of,
+    scope_table,
+)
+
+HW = (64, 64)
+NUM_CLASSES = 3
+BATCH = 4
+MODEL_SLICES = ("backbone", "fpn", "heads")
+COLLECTIVE = re.compile(r"^(all-reduce|reduce-scatter|all-gather|all-to-all|collective-permute)")
+
+
+def _model():
+    return build_retinanet(RetinaNetConfig(
+        num_classes=NUM_CLASSES, backbone="resnet_test", norm_kind="frozen_bn",
+        fpn_channels=16, head_width=16, head_depth=1, dtype=jnp.float32))
+
+
+def _optimizer(**kw):
+    return make_optimizer(OptimizerConfig(schedule="constant", warmup_steps=0), **kw)[0]
+
+
+def _batch_arrays():
+    return dict(
+        images=jnp.zeros((BATCH, *HW, 3), jnp.uint8),
+        gt_boxes=jnp.tile(jnp.asarray([[8.0, 8.0, 40.0, 40.0]]), (BATCH, 2, 1)),
+        gt_labels=jnp.ones((BATCH, 2), jnp.int32),
+        gt_mask=jnp.ones((BATCH, 2), bool),
+    )
+
+
+def _host_batches():
+    arrays = {k: np.asarray(v) for k, v in _batch_arrays().items()}
+    while True:
+        yield Batch(**arrays, image_ids=np.arange(BATCH, dtype=np.int64),
+                    scales=np.ones((BATCH,), np.float32), valid=np.ones((BATCH,), bool))
+
+
+def _instructions(compiled) -> dict[str, str]:
+    """instruction name -> opcode, from the optimized module's text."""
+    out = {}
+    for line in compiled.as_text().splitlines():
+        # the first "word(" after the "=": a result shape has none, tuple or not
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? ([a-z][\w\-]*)\(", line)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
+
+
+@pytest.fixture(scope="module")
+def compiled_steps():
+    """One compiled step per flavor: single device, and data-parallel,
+    ZeRO and compressed-gradient steps over four virtual devices."""
+    model = _model()
+    batch = _batch_arrays()
+    state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))
+    mesh = make_mesh(4)
+    out = {"single": make_train_step(model, HW, NUM_CLASSES, donate_state=False).lower(state, batch).compile()}
+    out["dp4"] = make_train_step(model, HW, NUM_CLASSES, mesh=mesh, donate_state=False).lower(state, batch).compile()
+    comm = CommConfig(compress="int8")
+    comm_state = {k: jnp.asarray(v) for k, v in init_comm_state(state.params, comm, 4).items()}
+    out["comm4"] = make_train_step(model, HW, NUM_CLASSES, mesh=mesh, comm=comm, donate_state=False).lower(
+        state.replace(comm_state=comm_state), batch).compile()
+    tx = _optimizer(shard_clip_axis=DATA_AXIS)
+    zstate = create_train_state(model, tx, (1, *HW, 3), jax.random.key(0), init_opt_state=False)
+    zstate = zstate.replace(opt_state=zero.init_sharded_opt_state(tx, zstate.params, mesh))
+    out["zero4"] = make_train_step(model, HW, NUM_CLASSES, mesh=mesh, shard_weight_update=True,
+                                   donate_state=False).lower(zstate, batch).compile()
+    return out
+
+
+@pytest.mark.parametrize("op_name,expected", [
+    ("jit(train_step)/jvp(RetinaNet)/backbone/backbone/stage2/stage2_block0/conv1/conv_general_dilated",
+     ("backbone", "fwd", "backbone/backbone/stage2/stage2_block0/conv1/conv_general_dilated")),
+    ("jit(train_step)/transpose(jvp(RetinaNet))/heads/cls/cls_head/logits/conv_general_dilated",
+     ("heads", "bwd", "heads/cls/cls_head/logits/conv_general_dilated")),
+    # the transform wraps whichever scope comes first after it
+    ("jit(train_step)/jvp(loss)/jit(softplus)/log1p", ("loss", "fwd", "loss/softplus/log1p")),
+    ("jit(train_step)/transpose(jvp(loss))/mul", ("loss", "bwd", "loss/mul")),
+    ("jit(sharded_step)/shard_map/grad_allreduce/psum", ("grad_allreduce", "fwd", "grad_allreduce/psum")),
+    # the OUTERMOST vocabulary scope wins
+    ("jit(zero_step)/shard_map/optimizer/grad_allreduce/all_gather", ("optimizer", "fwd", "optimizer/grad_allreduce/all_gather")),
+    ("jit(train_step)/jvp(assign)/jit(assign_fused)/assign_fused", ("assign", "fwd", "assign/assign_fused/assign_fused")),
+    ("jit(train_step)/convert_element_type", (UNSCOPED, "fwd", "train_step/convert_element_type")),
+    ("", (UNSCOPED, "fwd", "")),
+])
+def test_scope_of_files_an_op_name_under_its_outermost_scope(op_name, expected):
+    assert scope_of(op_name) == expected
+
+
+@pytest.mark.parametrize("flavor", ["single", "dp4", "comm4", "zero4"])
+def test_every_convolution_is_filed_under_the_model(compiled_steps, flavor):
+    compiled = compiled_steps[flavor]
+    table = scope_table(compiled)
+    convs = [table[n] for n, op in _instructions(compiled).items() if op == "convolution"]
+    assert len(convs) > 30
+    # XLA:CPU rewrites some weight-gradient convolutions into instructions
+    # without metadata (empty path): those are unscoped, never misfiled.
+    named = [c for c in convs if c[2]]
+    assert len(named) >= 0.7 * len(convs)
+    assert {s for s, _, _ in named} == set(MODEL_SLICES)
+    assert {s for s, _, path in convs if not path} <= {UNSCOPED}
+    # forward and backward of each
+    for s in MODEL_SLICES:
+        assert {d for t, d, _ in named if t == s} == {"fwd", "bwd"}, s
+
+
+@pytest.mark.parametrize("flavor", ["single", "dp4", "comm4", "zero4"])
+def test_every_scope_of_the_vocabulary_reaches_the_compiled_step(compiled_steps, flavor):
+    table = scope_table(compiled_steps[flavor])
+    filed = {(s, d) for s, d, _ in table.values()}
+    on_a_mesh = flavor != "single"
+    for s in STEP_SCOPES:
+        if s == "grad_allreduce" and not on_a_mesh:
+            assert not {d for t, d in filed if t == s}
+            continue
+        assert (s, "fwd") in filed, s
+    # what is differentiated has a backward; targets (stop_gradient), the
+    # update and the reduction of finished gradients have none
+    assert {s for s, d in filed if d == "bwd"} == {*MODEL_SLICES, "loss"}
+    for s, beneath in STEP_SCOPES.items():
+        paths = {p for t, _, p in table.values() if t == s}
+        for name in beneath:
+            assert any(f"/{name}/" in p for p in paths), (s, name)
+
+
+@pytest.mark.parametrize("flavor,expected", [
+    ("dp4", {"all-reduce"}),
+    ("comm4", {"all-reduce", "reduce-scatter", "all-gather"}),
+    ("zero4", {"all-reduce", "reduce-scatter", "all-gather"}),
+])
+def test_every_collective_is_filed_under_grad_allreduce(compiled_steps, flavor, expected):
+    compiled = compiled_steps[flavor]
+    table = scope_table(compiled)
+    collectives = {n: op for n, op in _instructions(compiled).items() if COLLECTIVE.match(op)}
+    kinds = {COLLECTIVE.match(op).group(1) for op in collectives.values()}
+    assert expected <= kinds, kinds
+    assert {table[n][0] for n in collectives} == {"grad_allreduce"}, {
+        n: table[n] for n in collectives if table[n][0] != "grad_allreduce"}
+
+
+def test_scopes_are_metadata_only(monkeypatch):
+    """The lowered module is the same text with the scopes and without
+    them; only its debug information differs."""
+    model = _model()
+    state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))
+    batch = _batch_arrays()
+    mesh = make_mesh(4)
+
+    def texts():
+        out = []
+        for kw in ({}, {"mesh": mesh}):
+            lowered = make_train_step(model, HW, NUM_CLASSES, donate_state=False, **kw).lower(state, batch)
+            out.append((lowered.as_text(), lowered.as_text(debug_info=True)))
+        return out
+
+    scoped = texts()
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    bare = texts()
+    for (plain_a, debug_a), (plain_b, debug_b) in zip(scoped, bare):
+        assert plain_a == plain_b
+        assert "stage2" in debug_a and "grad_allreduce" not in plain_a
+        assert debug_a != debug_b and "optimizer" not in debug_b
+
+
+class _Compiles:
+    """Compile requests of this process while the block runs, and how many
+    of them the persistent cache answered."""
+
+    _EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "requests",
+               "/jax/compilation_cache/cache_hits": "hits"}
+
+    def __enter__(self):
+        self.requests = self.hits = 0
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_listener(self._on_event)
+
+    def _on_event(self, event, **_kw):
+        name = self._EVENTS.get(event)
+        if name:
+            setattr(self, name, getattr(self, name) + 1)
+
+
+@pytest.fixture
+def _no_ring():
+    trace.reset()
+    yield
+    trace.reset()
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_compiled_step_after_a_run_compiles_nothing_and_keeps_no_buffer(_no_ring, devices):
+    model = _model()
+    before = {id(x) for x in jax.live_arrays()}  # other tests' fixtures, in this process
+    state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))
+    largest_leaf = max(x.size for x in jax.tree.leaves(state.params))
+    mesh = make_mesh(devices) if devices > 1 else None
+    state = loop.run_training(model, state, _host_batches(), NUM_CLASSES,
+                              loop.LoopConfig(total_steps=2, log_every=0), mesh=mesh)
+    with _Compiles() as c:
+        compiled = loop.compiled_step()
+        assert loop.compiled_step(HW).as_text() == compiled.as_text()
+    # the executable the loop ran, not another compilation of it
+    assert (c.requests, c.hits) in ((0, 0), (2, 2)), (c.requests, c.hits)
+    table = scope_table(compiled)
+    assert {"backbone", "assign", "loss", "optimizer"} <= {s for s, _, _ in table.values()}
+    assert ("grad_allreduce" in {s for s, _, _ in table.values()}) == (devices > 1)
+    flops = compiled.cost_analysis()
+    assert (flops[0] if isinstance(flops, list) else flops)["flops"] > 0
+    with pytest.raises(LookupError):
+        loop.compiled_step((32, 32))
+    # What the loop remembers is abstract: with the returned state gone no
+    # parameter-sized or batch-sized buffer of the run is alive.
+    step_fn, (abstract_state, abstract_batch) = loop._built_steps[HW]
+    assert all(isinstance(x, jax.ShapeDtypeStruct)
+               for x in jax.tree.leaves((abstract_state, abstract_batch)))
+    assert abstract_batch["images"].shape == (BATCH, *HW, 3)
+    del state, compiled
+    gc.collect()
+    alive = [x for x in jax.live_arrays() if id(x) not in before and x.size >= largest_leaf]
+    anchors = [x for x in alive if x.ndim == 2 and x.shape[1] == 4]  # the step's constant
+    assert len(alive) == len(anchors) <= 1, [(x.shape, x.dtype) for x in alive]
+
+
+def test_compiled_step_before_any_run_says_so(_no_ring):
+    loop._built_steps.clear()
+    with pytest.raises(LookupError, match="has built no train step"):
+        loop.compiled_step()
+
+
+def test_cost_analysis_is_recorded_after_the_first_execution_from_the_cache(_no_ring, tmp_path):
+    """With the ring on, the step's FLOPs come from the executable the first
+    call compiled or loaded: no lowering before it, and nothing compiled
+    for it (the warm start of ``train.py --obs-trace`` loads the step)."""
+    model = _model()
+    state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))
+    # Warm the persistent cache the way an earlier run would have.
+    state = loop.run_training(model, state, _host_batches(), NUM_CLASSES,
+                              loop.LoopConfig(total_steps=1, log_every=0))
+    trace.configure(str(tmp_path), process_label="t")
+    with _Compiles() as c:
+        loop.run_training(model, state, _host_batches(), NUM_CLASSES,
+                          loop.LoopConfig(total_steps=3, log_every=0))
+    assert c.requests == c.hits == 1  # the rebuilt step function: loaded, and once
+    events = trace.snapshot_events()
+    cost = [e for e in events if e["name"] == "cost_analysis"]
+    assert len(cost) == 1 and cost[0]["args"]["flops"] > 0
+    assert cost[0]["args"] == dict(cost[0]["args"], target="train_step", bucket="64x64", batch=BATCH)
+    first_step = min(e["ts"] for e in events if e["name"] == "step")
+    assert cost[0]["ts"] >= first_step

@@ -226,7 +226,10 @@ def build_parser() -> argparse.ArgumentParser:
         g.add_argument("--log-dir", default=None)
         g.add_argument("--tensorboard", action="store_true")
         g.add_argument("--profile-dir", default=None,
-                       help="write a jax.profiler trace of a few steps here")
+                       help="write a jax.profiler trace of a few steps here "
+                            "(device operations with the step's named scopes, "
+                            "and the loop's own spans as rn.* annotations; "
+                            "RUNBOOK section 9)")
         # --obs-trace / --obs-dir / --obs-stall-timeout: structured trace
         # spans + stall watchdog across train/data/eval (utils/cli.py —
         # shared surface, obs/ subsystem).

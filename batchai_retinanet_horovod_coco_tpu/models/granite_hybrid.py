@@ -1,0 +1,287 @@
+"""Granite 4.0-H: a decoder of Mamba-2 mixers with a grouped-query attention
+layer in every period, a gated MLP after every mixer, and a tied head.
+
+Equations (ibm-granite/granite-4.0-h-micro ``config.json``, ``model_type``
+``granitemoehybrid`` with no experts; ``d`` the hidden size):
+
+- ``x0 = embedding_multiplier * E[tokens]``.
+- every layer: ``h = x + r * Mixer(RMSNorm(x))``, ``x' = h + r * MLP(RMSNorm(h))``
+  with ``r = residual_multiplier``; ``MLP(u) = W_down (silu(W_g u) * W_u u)``.
+- attention layer: grouped-query, no positional encoding, causal and within
+  one document, ``softmax(attention_multiplier * q k^T) v``, then ``W_o``.
+- mamba layer: ``[z, xBC, dt] = W_in u``; ``xBC = silu(conv1d(xBC) + b)``
+  (depthwise, causal, not reaching into the previous document); split into
+  ``X`` (heads x head size), ``B``, ``C`` (state size each, one group);
+  ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the recurrence of
+  ``ops/ssd.py`` plus ``D * X``; ``RMSNorm(Y * silu(z)) * w`` over all inner
+  channels; ``W_out``.
+- ``logits = RMSNorm(x_L) E^T / logits_scaling``; the loss is the mean
+  cross-entropy of the next token over positions whose next token lies in
+  the same document.
+
+Plain functions over a parameter tree (no flax): the tree's top level is the
+kind of parameter (``embed``, ``mamba``, ``attention``, ``mlp``, ``norms``),
+so that the numerics plane's per-group gradient norms (obs/numerics.py) and
+a reader of a checkpoint see the model's parts.  Parameters are float32;
+activations and matmul operands ``config.dtype`` (bfloat16); norms, softmax,
+the scan's decays and state and the loss reduce in float32.  Every layer is
+recomputed in the backward pass: only the layers' inputs are kept.  Single device: sharding comes with its own issue.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from batchai_retinanet_horovod_coco_tpu.ops import ssd
+
+MAMBA, ATTENTION = "mamba", "attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    vocab_size: int
+    hidden_size: int
+    intermediate_size: int
+    layer_types: tuple[str, ...]
+    num_attention_heads: int
+    num_key_value_heads: int
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_d_state: int
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    attention_multiplier: float = 0.015625
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 8.0
+    rms_norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    # Queries per block of the attention layer: block i attends keys
+    # [0, end of block i), so scores are never (T, T) at once.
+    attention_q_block: int = 1024
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def attention_head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @classmethod
+    def from_hf(cls, hf: dict, **overrides) -> "GraniteHybridConfig":
+        """From the keys of the published ``config.json``; refuses what this
+        model does not compute rather than ignoring it."""
+        want = {"hidden_act": "silu", "position_embedding_type": "nope", "tie_word_embeddings": True,
+                "normalization_function": "rmsnorm", "mamba_n_groups": 1, "num_local_experts": 0,
+                "attention_bias": False, "mamba_proj_bias": False, "mamba_conv_bias": True}
+        wrong = {k: hf[k] for k, v in want.items() if k in hf and hf[k] != v}
+        if wrong:
+            raise ValueError(f"granite_hybrid does not compute {wrong}; it computes {want}")
+        layer_types = tuple(hf["layer_types"][: hf["num_hidden_layers"]])
+        if len(layer_types) != hf["num_hidden_layers"] or set(layer_types) - {MAMBA, ATTENTION}:
+            raise ValueError(f"layer_types {hf['layer_types']} for {hf['num_hidden_layers']} layers")
+        if hf["mamba_expand"] * hf["hidden_size"] != hf["mamba_n_heads"] * hf["mamba_d_head"]:
+            raise ValueError("mamba_expand x hidden_size must equal mamba_n_heads x mamba_d_head")
+        if hf.get("shared_intermediate_size", hf["intermediate_size"]) != hf["intermediate_size"]:
+            raise ValueError("shared_intermediate_size differs from intermediate_size")
+        keys = {f.name for f in dataclasses.fields(cls)} - {"layer_types", "dtype"}
+        return cls(layer_types=layer_types, **{**{k: hf[k] for k in keys if k in hf}, **overrides})
+
+
+# The CPU tests' and ``train.py lm-synthetic``'s default: one period of ten
+# layers (local layer 5 the attention layer) at toy widths.
+TINY = GraniteHybridConfig(
+    vocab_size=128, hidden_size=64, intermediate_size=128,
+    layer_types=(MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4,
+    num_attention_heads=4, num_key_value_heads=2,
+    mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16, mamba_chunk_size=8,
+    attention_multiplier=0.25, attention_q_block=32,
+)
+
+# What is set here and not by the published configuration (the benchmark's
+# configuration file lists them under ``assumed``).
+INIT_STD = 0.02
+A_INIT_RANGE = (1.0, 16.0)
+DT_INIT_RANGE = (1e-3, 1e-1)
+
+
+def init_params(config: GraniteHybridConfig, rng: jax.Array) -> dict:
+    d, ff = config.hidden_size, config.intermediate_size
+    inner, n, heads = config.mamba_d_inner, config.mamba_d_state, config.mamba_n_heads
+    conv_dim = inner + 2 * n
+    hd = config.attention_head_dim
+    kv = config.num_key_value_heads * hd
+
+    def normal(key, shape):
+        return INIT_STD * jax.random.normal(key, shape, jnp.float32)
+
+    keys = iter(jax.random.split(rng, 1 + 8 * len(config.layer_types)))
+    params: dict = {"embed": {"embedding": normal(next(keys), (config.vocab_size, d))},
+                    MAMBA: {}, ATTENTION: {}, "mlp": {}, "norms": {"final": jnp.ones((d,), jnp.float32)}}
+    for i, kind in enumerate(config.layer_types):
+        name = f"layer_{i}"
+        if kind == MAMBA:
+            dt = jnp.exp(jax.random.uniform(next(keys), (heads,), jnp.float32,
+                                            math.log(DT_INIT_RANGE[0]), math.log(DT_INIT_RANGE[1])))
+            params[MAMBA][name] = {
+                "in_proj": normal(next(keys), (d, 2 * inner + 2 * n + heads)),
+                "conv_w": normal(next(keys), (config.mamba_d_conv, conv_dim)),
+                "conv_b": jnp.zeros((conv_dim,), jnp.float32),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+                "A_log": jnp.log(jax.random.uniform(next(keys), (heads,), jnp.float32, *A_INIT_RANGE)),
+                "D": jnp.ones((heads,), jnp.float32),
+                "norm_w": jnp.ones((inner,), jnp.float32),
+                "out_proj": normal(next(keys), (inner, d)),
+            }
+        else:
+            params[ATTENTION][name] = {
+                "q": normal(next(keys), (d, d)), "k": normal(next(keys), (d, kv)),
+                "v": normal(next(keys), (d, kv)), "o": normal(next(keys), (d, d)),
+            }
+        params["mlp"][name] = {"gate_up": normal(next(keys), (d, 2 * ff)), "down": normal(next(keys), (ff, d))}
+        params["norms"][name] = {"mixer": jnp.ones((d,), jnp.float32), "mlp": jnp.ones((d,), jnp.float32)}
+    return params
+
+
+def _rms_norm(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (y * w).astype(x.dtype)
+
+
+def _operand(config, x):
+    """An operand of a matmul with a weight, in ``config.dtype``."""
+    return x.astype(config.dtype)
+
+
+def _matmul(config, x, w):
+    return jnp.dot(_operand(config, x), _operand(config, w))
+
+
+def _same_document_shift(x, segment_ids, j: int):
+    """``x`` delayed by ``j`` tokens, zero where that token is before the
+    sequence or in another document."""
+    if j == 0:
+        return x
+    moved = jnp.pad(x[:, :-j], [(0, 0), (j, 0), (0, 0)])
+    same = jnp.pad(segment_ids[:, :-j], [(0, 0), (j, 0)], constant_values=-1) == segment_ids
+    return jnp.where(same[..., None], moved, 0)
+
+
+def _mamba_mixer(config, p, u, segment_ids):
+    inner, n, heads = config.mamba_d_inner, config.mamba_d_state, config.mamba_n_heads
+    batch, t, _ = u.shape
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = jnp.split(_matmul(config, u, p["in_proj"]), [inner, 2 * inner + 2 * n], axis=-1)
+    with jax.named_scope("conv"):
+        k = config.mamba_d_conv
+        xbc32 = xbc.astype(jnp.float32)
+        conv = p["conv_b"] + sum(p["conv_w"][k - 1 - j] * _same_document_shift(xbc32, segment_ids, j)
+                                 for j in range(k))
+        xbc = jax.nn.silu(conv).astype(config.dtype)
+        x, b, c = jnp.split(xbc, [inner, inner + n], axis=-1)
+        x = x.reshape(batch, t, heads, config.mamba_d_head)
+    with jax.named_scope("ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        y = ssd.ssd_chunked(x, dt, -jnp.exp(p["A_log"]), b, c, segment_ids, config.mamba_chunk_size)
+        y = y + p["D"][:, None] * x.astype(jnp.float32)
+    with jax.named_scope("gate_norm"):
+        y = y.reshape(batch, t, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = _rms_norm(y, p["norm_w"], config.rms_norm_eps).astype(config.dtype)
+    with jax.named_scope("out_proj"):
+        return _matmul(config, y, p["out_proj"])
+
+
+def _attention_mixer(config, p, u, segment_ids):
+    batch, t, _ = u.shape
+    hd, kvh = config.attention_head_dim, config.num_key_value_heads
+    group = config.num_attention_heads // kvh
+    q = _matmul(config, u, p["q"]).reshape(batch, t, kvh, group, hd)
+    k = _matmul(config, u, p["k"]).reshape(batch, t, kvh, hd)
+    v = _matmul(config, u, p["v"]).reshape(batch, t, kvh, hd)
+
+    def block(q_blk, seg_q, start, k_seen, v_seen, seg_k):
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", q_blk, k_seen, preferred_element_type=jnp.float32)
+        pos_q = start + jnp.arange(q_blk.shape[1])
+        allowed = (pos_q[:, None] >= jnp.arange(k_seen.shape[1])[None, :]) & (
+            seg_q[:, :, None] == seg_k[:, None, :])  # (b, q, s)
+        scores = jnp.where(allowed[:, None, None], scores * config.attention_multiplier, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(config.dtype)
+        return jnp.einsum("bkgqs,bskd->bqkgd", probs, v_seen)
+
+    block = jax.checkpoint(block, static_argnums=(2,))  # scores are recomputed, never kept
+    step = config.attention_q_block
+    out = [block(q[:, s:s + step], segment_ids[:, s:s + step], s, k[:, :min(s + step, t)],
+                 v[:, :min(s + step, t)], segment_ids[:, :min(s + step, t)])
+           for s in range(0, t, step)]
+    return _matmul(config, jnp.concatenate(out, axis=1).reshape(batch, t, -1), p["o"])
+
+
+def _mlp(config, p, u):
+    gate, up = jnp.split(_matmul(config, u, p["gate_up"]), 2, axis=-1)
+    return _matmul(config, jax.nn.silu(gate) * up, p["down"])
+
+
+def _layer(config, kind, mixer_params, mlp_params, norms, x, segment_ids):
+    r = config.residual_multiplier
+    with jax.named_scope(kind):
+        mixer = _mamba_mixer if kind == MAMBA else _attention_mixer
+        u = _rms_norm(x, norms["mixer"], config.rms_norm_eps)
+        h = x + (r * mixer(config, mixer_params, u, segment_ids)).astype(x.dtype)
+    with jax.named_scope("mlp"):
+        u = _rms_norm(h, norms["mlp"], config.rms_norm_eps)
+        return h + (r * _mlp(config, mlp_params, u)).astype(x.dtype)
+
+
+def hidden_states(config: GraniteHybridConfig, params: dict, tokens, segment_ids):
+    """The last layer's output before the final norm, (batch, T, d)."""
+    with jax.named_scope("embed"):
+        x = (config.embedding_multiplier * params["embed"]["embedding"][tokens]).astype(config.dtype)
+    for i, kind in enumerate(config.layer_types):
+        name = f"layer_{i}"
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1))  # only the layer's input is kept
+        x = layer(config, kind, params[kind][name], params["mlp"][name], params["norms"][name], x, segment_ids)
+    return x
+
+
+def logits_of(config: GraniteHybridConfig, params: dict, hidden):
+    """float32 logits over the vocabulary held here, from ``hidden_states``."""
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(hidden, params["norms"]["final"], config.rms_norm_eps)
+        logits = jnp.einsum("btd,vd->btv", _operand(config, x), _operand(config, params["embed"]["embedding"]),
+                            preferred_element_type=jnp.float32)
+        return logits / config.logits_scaling
+
+
+def next_token_loss(logits, tokens, segment_ids):
+    """Mean cross-entropy of the next token over positions whose next token
+    lies in the same document; also the number of such positions."""
+    targets = tokens[:, 1:]
+    counted = (segment_ids[:, 1:] == segment_ids[:, :-1]).astype(jnp.float32)
+    logits = logits[:, :-1].astype(jnp.float32)
+    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    n = jnp.sum(counted)
+    return jnp.sum(nll * counted) / jnp.maximum(n, 1.0), n
+
+
+class GraniteHybrid:
+    """The model as the train state and the loop hold it: ``init`` gives
+    ``{"params": ...}``, ``apply`` the float32 logits."""
+
+    def __init__(self, config: GraniteHybridConfig):
+        self.config = config
+
+    def init(self, rng: jax.Array, tokens=None) -> dict:
+        del tokens  # the parameters do not depend on the sequence's length
+        return {"params": init_params(self.config, rng)}
+
+    def apply(self, variables: dict, tokens, segment_ids, train: bool = False):
+        del train  # no dropout, no batch statistics
+        params = variables["params"]
+        return logits_of(self.config, params, hidden_states(self.config, params, tokens, segment_ids))

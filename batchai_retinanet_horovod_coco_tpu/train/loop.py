@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 from batchai_retinanet_horovod_coco_tpu import losses as losses_lib
-from batchai_retinanet_horovod_coco_tpu.data.pipeline import Batch
 from batchai_retinanet_horovod_coco_tpu.data.prefetch import prefetch_map
 from batchai_retinanet_horovod_coco_tpu.ops import matching as matching_lib
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import (
@@ -44,6 +43,7 @@ from batchai_retinanet_horovod_coco_tpu.obs.events import device_memory_stats
 from batchai_retinanet_horovod_coco_tpu.obs.numerics import NumericsConfig
 from batchai_retinanet_horovod_coco_tpu.obs.trace import monotonic_s
 from batchai_retinanet_horovod_coco_tpu.train.state import model_variables
+from batchai_retinanet_horovod_coco_tpu.train.task import DetectionTask
 from batchai_retinanet_horovod_coco_tpu.utils.checkpoint import CheckpointManager
 from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
 
@@ -206,8 +206,9 @@ class LoopConfig:
     ckpt_metadata: dict | None = None
 
 
-def _device_batch(batch: Batch, mesh: Mesh | None) -> dict[str, Any]:
-    """Host Batch → the device-resident dict the train step consumes.
+def _device_batch(batch, mesh: Mesh | None, task) -> dict[str, Any]:
+    """Host batch → the device-resident dict the train step consumes: the
+    task's fields of it (train/task.py).
 
     Multi-host: each process holds its LOCAL shard of the global batch; the
     global jax.Array is assembled per process via
@@ -218,12 +219,7 @@ def _device_batch(batch: Batch, mesh: Mesh | None) -> dict[str, Any]:
     at dispatch (the reference relied on Keras' implicit feed; TPU input
     overlap must be explicit).
     """
-    arrays = {
-        "images": batch.images,
-        "gt_boxes": batch.gt_boxes,
-        "gt_labels": batch.gt_labels,
-        "gt_mask": batch.gt_mask,
-    }
+    arrays = task.host_arrays(batch)
     if mesh is None:
         return {k: jax.device_put(v) for k, v in arrays.items()}
     if SPACE_AXIS in mesh.axis_names:
@@ -244,13 +240,15 @@ def _device_batch(batch: Batch, mesh: Mesh | None) -> dict[str, Any]:
 
 
 def _prefetch_to_device(
-    batches: Iterable[Batch], mesh: Mesh | None, depth: int = 2
-) -> Iterator[tuple[tuple[int, ...], np.ndarray, dict[str, Any]]]:
-    """Yield (images_shape, image_ids, device_batch), ``depth`` ahead.
+    batches: Iterable, mesh: Mesh | None, depth: int, task
+) -> Iterator[tuple[tuple[int, ...], int, np.ndarray, dict[str, Any]]]:
+    """Yield (bucket, examples, ids, device_batch), ``depth`` ahead: the
+    task's description of the host batch (train/task.py::describe) and its
+    fields on the device.
 
-    ``image_ids`` is the HOST copy of the batch's source ids — the
-    numerics provenance dump records which images fed a tripped step
-    (the device batch deliberately carries no ids).
+    ``ids`` is the HOST copy of the batch's source ids — the numerics
+    provenance dump records which examples fed a tripped step (the device
+    batch deliberately carries no ids).
 
     Double-buffered device prefetch (the standard ``prefetch_to_device``
     idiom): a background thread pulls host batches and calls
@@ -268,9 +266,8 @@ def _prefetch_to_device(
     return prefetch_map(
         batches,
         lambda batch: (
-            batch.images.shape,
-            batch.image_ids,
-            _device_batch(batch, mesh),
+            *task.describe(batch),
+            _device_batch(batch, mesh, task),
         ),
         depth=depth,
         thread_name="device-prefetch",
@@ -384,12 +381,17 @@ class _AsyncEvalRunner:
             warnings.warn(f"async eval failed during loop unwind: {exc!r}")
 
 
-# The step functions the last ``run_training`` call built, by (H, W)
-# bucket, each with the abstract arguments it was first called on.  What
-# ``compiled_step`` lowers again; no device buffer of the state or a batch
-# is kept (the state is donated to the step).  The functions, and so their
-# loaded executables, live until the next call replaces them.
-_built_steps: dict[tuple[int, int], tuple[Callable, tuple]] = {}
+# The step functions the last ``run_training`` call built, by the task's
+# bucket ((H, W) for detection), each with the abstract arguments it was
+# first called on.  What ``compiled_step`` lowers again; no device buffer of
+# the state or a batch is kept (the state is donated to the step).  The
+# functions, and so their loaded executables, live until the next call
+# replaces them.
+_built_steps: dict[tuple[int, ...], tuple[Callable, tuple]] = {}
+
+
+def _bucket_name(bucket: tuple[int, ...]) -> str:
+    return "x".join(str(n) for n in bucket)
 
 
 def _abstract(tree):
@@ -406,7 +408,7 @@ def _abstract(tree):
     return jax.tree.map(leaf, tree)
 
 
-def compiled_step(hw: tuple[int, int] | None = None):
+def compiled_step(hw: tuple[int, ...] | None = None):
     """The ``jax.stages.Compiled`` of the train step the last
     ``run_training`` call built for bucket ``hw`` (default: the bucket it
     built last): for its ``cost_analysis()``, its ``as_text()``
@@ -442,7 +444,7 @@ def _record_step_cost(hw, batch: int) -> None:
         return
     if flops > 0:
         trace.instant(
-            "cost_analysis", target="train_step", bucket=f"{hw[0]}x{hw[1]}",
+            "cost_analysis", target="train_step", bucket=_bucket_name(hw),
             flops=flops, batch=batch,
         )
 
@@ -500,14 +502,14 @@ def _compile_barrier(step_fn, state, device_arrays, hw) -> None:
     client = distributed.global_state.client
     if client is None:
         return  # no coordination service (external world bring-up)
-    client.wait_at_barrier(f"train_step_compiled_{hw[0]}x{hw[1]}", 600_000)
+    client.wait_at_barrier(f"train_step_compiled_{_bucket_name(hw)}", 600_000)
 
 
 def run_training(
     model,
     state: TrainState,
-    batches: Iterable[Batch],
-    num_classes: int,
+    batches: Iterable,
+    num_classes: int | None,
     config: LoopConfig,
     mesh: Mesh | None = None,
     loss_config: losses_lib.LossConfig = losses_lib.LossConfig(),
@@ -521,12 +523,17 @@ def run_training(
     comm=None,
     topology=None,
     allow_data_axis_divergence: bool = False,
+    task=None,
 ) -> TrainState:
     """Run ``config.total_steps`` of SPMD training; returns the final state.
 
+    ``task`` (train/task.py) says what the batches are and what loss they
+    train; unset it is detection from ``num_classes``, ``loss_config``,
+    ``matching_config`` and ``anchor_config``.  One train step is compiled
+    per bucket of the task seen in the stream ((H, W) for detection).
+
     ``eval_fn(state) -> metrics`` is the CocoEval-callback equivalent, called
-    every ``eval_every`` steps and at the end.  One train step is compiled
-    per (H, W) shape bucket seen in the stream.
+    every ``eval_every`` steps and at the end.
 
     ``comm`` (a ``comm.CommConfig``, ISSUE 13) selects the gradient-
     communication policy — bucketed int8/bf16 compression with error
@@ -541,7 +548,11 @@ def run_training(
     step (image-H sharding; train/step.py::make_train_step_spatial) —
     exclusive with the ZeRO and comm-compression flavors.
     """
+    if task is None:
+        task = DetectionTask(num_classes, loss_config, matching_config, anchor_config)
     spatial = mesh is not None and SPACE_AXIS in mesh.axis_names
+    if spatial and not isinstance(task, DetectionTask):
+        raise ValueError(f"spatial partitioning shards images: not the {task.name} task")
     comm_on = comm is not None and getattr(comm, "enabled", False)
     if spatial and (shard_weight_update or quantized_allreduce or comm_on):
         raise ValueError(
@@ -560,9 +571,10 @@ def run_training(
         )
         if config.resume and ckpt.latest_step() is not None:
             t_restore = monotonic_s()
+            template = state
             try:
                 with trace.span("ckpt_restore"):
-                    state = ckpt.restore(state)
+                    state = ckpt.restore(template)
             except Exception as e:
                 raise RuntimeError(
                     f"restoring {config.checkpoint_dir} failed (root cause "
@@ -599,9 +611,20 @@ def run_training(
                 # block's global device_put wants process-local host
                 # data (TPU puts always copy; the alias hazard is
                 # CPU-backend-only).
+                # The template's buffer goes as each restored leaf arrives:
+                # the caller's state is consumed here as the first
+                # (donating) step would consume it, and a state that fills
+                # most of the chip (9.3 GB of parameters and Adam slots of
+                # 16: PERF.md section 6, PR 26) cannot be restored beside
+                # its own template.
                 import jax.numpy as jnp
 
-                state = jax.tree.map(jnp.copy, state)
+                def _replace(old, new):
+                    if isinstance(old, jax.Array) and not old.is_deleted():
+                        old.delete()
+                    return jnp.copy(new)
+
+                state = jax.tree.map(_replace, template, state)
 
     if mesh is not None:
         # Replicate state over the mesh (restored arrays land committed to a
@@ -669,7 +692,7 @@ def run_training(
         except Exception:
             pass  # metadata must never block training bring-up
 
-    step_fns: dict[tuple[int, int], Callable] = {}
+    step_fns: dict[tuple[int, ...], Callable] = {}
     _built_steps.clear()
     start_step = int(state.step)
     last_saved: int | None = None
@@ -701,7 +724,7 @@ def run_training(
     # provenance context for a tripped finite-check.
     numerics_config = NumericsConfig(enabled=config.numerics)
 
-    it = _prefetch_to_device(batches, mesh, config.device_prefetch)
+    it = _prefetch_to_device(batches, mesh, config.device_prefetch, task)
     # The loop's own heartbeat: one beat per step.  Long legitimate gaps
     # (sync eval, final epilogue) are bracketed with idle() so only a
     # genuinely wedged step stream — or the data stall it is blocked on —
@@ -721,10 +744,9 @@ def run_training(
             last_step[0] = step
             t_data = monotonic_s()
             with trace.span("data_wait"):
-                images_shape, image_ids, device_arrays = next(it)
+                hw, examples, image_ids, device_arrays = next(it)
             window_data_wait += monotonic_s() - t_data
             window_steps += 1
-            hw = images_shape[1:3]
             step_fn = step_fns.get(hw)
             new_step = step_fn is None
             if new_step:
@@ -736,7 +758,7 @@ def run_training(
                 loop_hb.idle()
                 t_compile = monotonic_s()
                 with trace.span(
-                    "compile_train_step", bucket=f"{hw[0]}x{hw[1]}"
+                    "compile_train_step", bucket=_bucket_name(hw)
                 ):
                     if spatial:
                         step_fn = step_fns[hw] = make_train_step_spatial(
@@ -764,6 +786,7 @@ def run_training(
                             comm=comm,
                             topology=topology,
                             numerics=numerics_config,
+                            task=task,
                         )
                     # No process may enter the step's collectives while a
                     # peer is still compiling (collective timeouts <<
@@ -776,7 +799,7 @@ def run_training(
                 # Live-telemetry record site (one bool check while off):
                 # the status server's train_compiles_total/last_compile.
                 telemetry.record_compile(
-                    f"{hw[0]}x{hw[1]}", monotonic_s() - t_compile
+                    _bucket_name(hw), monotonic_s() - t_compile
                 )
                 # Duck-typed: tests pass bare .log-only logger fakes.
                 log_event = getattr(logger, "event", None)
@@ -784,7 +807,7 @@ def run_training(
                     log_event(
                         "compile",
                         target="train_step",
-                        bucket=f"{hw[0]}x{hw[1]}",
+                        bucket=_bucket_name(hw),
                         step=step,
                         build_s=round(monotonic_s() - t_compile, 3),
                     )
@@ -799,13 +822,13 @@ def run_training(
                 # PERF_REPORT.json can carry an MFU estimate: from the
                 # executable the call above just compiled or loaded,
                 # which the compile cache hands back.
-                _record_step_cost(hw, int(images_shape[0]))
+                _record_step_cost(hw, int(examples))
             if config.profile_dir and step == prof_end:
                 jax.block_until_ready(metrics)
                 jax.profiler.stop_trace()
             # Global batch size = local batch × process_count (each process
             # feeds its shard of the global batch).
-            window_images += images_shape[0] * (
+            window_images += examples * (
                 jax.process_count() if mesh is not None else 1
             )
 

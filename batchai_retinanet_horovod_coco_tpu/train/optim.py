@@ -60,10 +60,11 @@ def clip_by_global_norm_precomputed(
 
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
-    optimizer: str = "sgd"  # "sgd" | "adam"
+    optimizer: str = "sgd"  # "sgd" | "adam" | "adamw"
     base_lr: float = 0.01  # per-256-global-batch for sgd (detectron rule)
     # Linear-scaling rule: effective lr = base_lr * global_batch / 256 for
-    # sgd, or base_lr * world_size for adam (the reference's hvd.size() rule).
+    # sgd, or base_lr * world_size for adam and adamw (the reference's
+    # hvd.size() rule).
     global_batch_size: int = 16
     world_size: int = 1
     warmup_steps: int = 500
@@ -83,13 +84,24 @@ class OptimizerConfig:
     plateau_window: int = 1000
     plateau_min_delta: float = 1e-4
     momentum: float = 0.9
+    # sgd: added to every leaf's gradient.  adamw: decoupled, and only for
+    # the matrices (``decays``); adam: none.
     weight_decay: float = 1e-4
+    adam_b2: float = 0.999
+    adam_eps: float = 1e-8
     clip_global_norm: float = 10.0
     freeze_backbone: bool = False
 
 
+def decays(params):
+    """adamw's decay mask: leaves of two or more dimensions (embeddings,
+    projections, convolution taps) decay; vectors and scalars (norm scales,
+    biases, Mamba's ``A_log``, ``D`` and ``dt_bias``) do not."""
+    return jax.tree.map(lambda p: jnp.ndim(p) >= 2, params)
+
+
 def peak_lr(config: OptimizerConfig) -> float:
-    if config.optimizer == "adam":
+    if config.optimizer in ("adam", "adamw"):
         return config.base_lr * config.world_size
     return config.base_lr * config.global_batch_size / 256.0
 
@@ -142,7 +154,12 @@ def make_optimizer(
             optax.sgd(schedule, momentum=config.momentum),
         )
     elif config.optimizer == "adam":
-        core = optax.adam(schedule)
+        core = optax.adam(schedule, b2=config.adam_b2, eps=config.adam_eps)
+    elif config.optimizer == "adamw":
+        core = optax.adamw(
+            schedule, b2=config.adam_b2, eps=config.adam_eps,
+            weight_decay=config.weight_decay, mask=decays,
+        )
     else:
         raise ValueError(f"unknown optimizer: {config.optimizer!r}")
 

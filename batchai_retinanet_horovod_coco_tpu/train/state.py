@@ -100,8 +100,13 @@ def create_train_state(
     example_image_shape: tuple[int, int, int, int],
     rng: jax.Array,
     init_opt_state: bool = True,
+    example_dtype=jnp.float32,
 ) -> TrainState:
     """Initialize params; identical on every process (same PRNG key).
+
+    The example the model is initialised on is zeros of
+    ``example_image_shape`` in ``example_dtype``: an image, or, for a task
+    whose ``example_dtype`` is an integer (train/task.py), token ids.
 
     ``model.init`` is wrapped in jit: eager init dispatches thousands of tiny
     ops, each its own host round trip and its own small compile; jitted it
@@ -111,7 +116,7 @@ def create_train_state(
     sharded mode (parallel/zero.py) initializes its 1/N layout directly and
     must not pay the peak memory of a throwaway replicated ``tx.init``.
     """
-    variables = jax.jit(model.init)(rng, jnp.zeros(example_image_shape, jnp.float32))
+    variables = jax.jit(model.init)(rng, jnp.zeros(example_image_shape, example_dtype))
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     return TrainState(

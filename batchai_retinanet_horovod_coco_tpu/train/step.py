@@ -36,6 +36,12 @@ from batchai_retinanet_horovod_coco_tpu.ops import anchors as anchors_lib
 from batchai_retinanet_horovod_coco_tpu.ops import matching as matching_lib
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
 from batchai_retinanet_horovod_coco_tpu.train.state import TrainState, model_variables
+from batchai_retinanet_horovod_coco_tpu.train.task import (  # noqa: F401  (re-exported)
+    DetectionTask,
+    LossFn,
+    _forward_and_loss,
+    resolve_kernel_schedule,
+)
 
 # The step program's slices, written down once: every ``jax.named_scope`` a
 # train step enters (here, in models/, parallel/zero.py and comm/overlap.py)
@@ -45,11 +51,19 @@ from batchai_retinanet_horovod_coco_tpu.train.state import TrainState, model_var
 # Scopes are metadata: the lowered program is the same with and without.
 # The backward needs none of its own (``transpose(jvp(<scope>))``).
 STEP_SCOPES: dict[str, tuple[str, ...]] = {
+    # the detection task's (train/task.py::DetectionTask.scopes)
     "backbone": ("stem", "stage2", "stage3", "stage4", "stage5"),
     "fpn": (),
     "heads": ("cls", "box"),
     "assign": (),  # anchor targets, Pallas or jnp
-    "loss": (),  # focal, smooth-L1, target encoding
+    # the language-model task's (LMTask.scopes; models/granite_hybrid.py)
+    "embed": (),
+    "mamba": ("in_proj", "conv", "ssd", "gate_norm", "out_proj"),  # with its norm
+    "attention": (),
+    "mlp": (),
+    "lm_head": (),  # final norm, tied head, 1 / logits_scaling
+    # every task's
+    "loss": (),  # focal, smooth-L1, target encoding; next-token cross-entropy
     "optimizer": (),  # clip, decay, momentum, apply, the numerics summary
     # every pmean / psum / reduce-scatter / gather of gradients, metrics and
     # batch statistics in sharded_step, zero_step and comm_step (the spatial
@@ -107,140 +121,12 @@ def scope_table(compiled) -> dict[str, tuple[str, str, str]]:
     return table
 
 
-def _forward_and_loss(
-    model,
-    state: TrainState,
-    params,
-    images: jnp.ndarray,
-    gt_boxes: jnp.ndarray,
-    gt_labels: jnp.ndarray,
-    gt_mask: jnp.ndarray,
-    anchors: jnp.ndarray,
-    loss_config: losses_lib.LossConfig,
-    matching_config: matching_lib.MatchingConfig,
-    train: bool,
-):
-    variables = dict(model_variables(state), params=params)
-    has_bn = "batch_stats" in variables
-    # uint8 batches normalize here, on device (data/pipeline.normalize_images).
-    with jax.named_scope("backbone"):
-        images = pipeline_lib.normalize_images(images)
-
-    # NHWC-direct loss path: raw per-level head outputs, no anchor-major
-    # retile/concat (losses.total_loss_compact_nhwc — measured ~4 ms/step
-    # of layout traffic at the flagship bucket).  The Pallas focal kernel
-    # consumes the concatenated (B, A, K) form instead.
-    return_levels = False if loss_config.pallas_focal else "nhwc"
-    apply_kwargs = dict(train=train, return_levels=return_levels)
-    if has_bn and train:
-        outputs, mutated = model.apply(
-            variables, images, mutable=["batch_stats"], **apply_kwargs
-        )
-        new_batch_stats = mutated["batch_stats"]
-    else:
-        outputs = model.apply(variables, images, **apply_kwargs)
-        new_batch_stats = state.batch_stats
-
-    # On-device target assignment; no gradients flow into the matching.
-    # Compact form: integer labels instead of a dense (A, K) one-hot — the
-    # focal loss fuses the implicit one-hot (losses.focal_loss_compact).
-    # Batched entrypoint: fused Pallas assignment on TPU, vmapped XLA
-    # elsewhere (ops/matching.py).
-    # Planar (B, 4, A) box targets on the NHWC path: dense lane layout end
-    # to end instead of the 32x-padded 4-minor form (ops.matching docstring).
-    planar = return_levels == "nhwc"
-    with jax.named_scope("assign"):
-        targets = matching_lib.anchor_targets_compact_batched(
-            anchors, gt_boxes, gt_labels, gt_mask, matching_config,
-            planar_box_targets=planar,
-        )
-        targets = jax.tree.map(lax.stop_gradient, targets)
-
-    with jax.named_scope("loss"):
-        if return_levels == "nhwc":
-            metrics = losses_lib.total_loss_compact_nhwc(
-                outputs["cls_levels"],
-                outputs["box_levels"],
-                targets.matched_labels,
-                targets.box_targets,
-                targets.state,
-                model.config.anchors_per_location,
-                loss_config,
-                planar_box_targets=True,
-            )
-        else:
-            metrics = losses_lib.total_loss_compact(
-                outputs["cls_logits"],
-                outputs["box_deltas"],
-                targets.matched_labels,
-                targets.box_targets,
-                targets.state,
-                loss_config,
-            )
-        metrics["num_pos"] = jnp.sum(
-            (targets.state == matching_lib.POSITIVE).astype(jnp.float32)
-        )
-    return metrics["loss"], (metrics, new_batch_stats)
-
-
-def resolve_kernel_schedule(
-    loss_config: losses_lib.LossConfig,
-    matching_config: matching_lib.MatchingConfig,
-    device_kind: str | None = None,
-) -> tuple[losses_lib.LossConfig, matching_lib.MatchingConfig]:
-    """Fill schedule-resolved kernel params (the train-side consumer of
-    the tune/ registry): focal impl + fwd/bwd tiles, matching impl + tile.
-
-    ``None`` fields mean "look the measured winner up in the per-device
-    schedule" (tune/schedule.py; built-in defaults reproduce the
-    hand-picked values, so an untuned device behaves exactly as before
-    ISSUE 6).  Explicit values always win — a CLI/test override must not
-    be silently re-tuned.  ``matching.impl == "auto"`` preserves the
-    backend-conditional dispatch (fused on TPU, jnp elsewhere).
-    """
-    import dataclasses as _dc
-
-    from batchai_retinanet_horovod_coco_tpu.tune import (
-        schedule as schedule_lib,
-    )
-
-    sched = schedule_lib.lookup(device_kind)
-    m, f = sched["matching"], sched["focal"]
-    if matching_config.pallas_tile_a is None:
-        matching_config = _dc.replace(
-            matching_config, pallas_tile_a=int(m["tile_a"])
-        )
-    if matching_config.fused_pallas is None and m["impl"] != "auto":
-        matching_config = _dc.replace(
-            matching_config, fused_pallas=m["impl"] == "pallas"
-        )
-    if loss_config.pallas_focal is None and f["impl"] != "auto":
-        loss_config = _dc.replace(
-            loss_config, pallas_focal=f["impl"] == "pallas"
-        )
-    if loss_config.focal_fwd_tile_a is None:
-        loss_config = _dc.replace(
-            loss_config, focal_fwd_tile_a=int(f["fwd_tile_a"])
-        )
-    if loss_config.focal_bwd_tile_a is None:
-        loss_config = _dc.replace(
-            loss_config, focal_bwd_tile_a=int(f["bwd_tile_a"])
-        )
-    return loss_config, matching_config
-
-
-def _make_local_step(model, anchors, loss_config, matching_config):
+def _make_local_step(loss_fn: LossFn):
     """The per-shard (or single-device) grad computation every step shares."""
 
     def local_step(state: TrainState, batch: dict[str, Any]):
         (_, (metrics, new_bs)), grads = jax.value_and_grad(
-            lambda p: _forward_and_loss(
-                model, state, p,
-                batch["images"], batch["gt_boxes"], batch["gt_labels"],
-                batch["gt_mask"], anchors, loss_config,
-                matching_config, train=True,
-            ),
-            has_aux=True,
+            lambda p: loss_fn(state, p, batch), has_aux=True,
         )(state.params)
         return grads, metrics, new_bs
 
@@ -348,8 +234,14 @@ def make_train_step(
     comm=None,
     topology=None,
     numerics: NumericsConfig | None = None,
+    task=None,
 ) -> Callable[[TrainState, dict[str, Any]], tuple[TrainState, dict[str, jnp.ndarray]]]:
     """Build the jitted train step for one shape bucket.
+
+    ``task`` (train/task.py) says what is trained: its batch fields and its
+    loss.  Unset, it is detection from ``num_classes``, ``loss_config``,
+    ``matching_config`` and ``anchor_config``, and ``image_hw`` is the
+    task's bucket; every flavour below differentiates the task's loss.
 
     With ``mesh``: the step is a ``shard_map`` over the mesh — the batch is
     consumed shard-by-shard (each device sees batch/n_devices images),
@@ -414,6 +306,13 @@ def make_train_step(
     and returns (new_state, metrics).
     """
     numerics = numerics or NumericsConfig()
+    if task is None:
+        task = DetectionTask(num_classes, loss_config, matching_config, anchor_config)
+    if mesh is not None and not task.supports_mesh:
+        raise ValueError(
+            f"the {task.name} task trains on one device: its step has no "
+            "sharding yet (train/task.py)"
+        )
     if shard_weight_update and mesh is None:
         raise ValueError("shard_weight_update requires a mesh")
     if quantized_allreduce and mesh is None:
@@ -468,16 +367,8 @@ def make_train_step(
             "backward-pass gradient collectives (comm/overlap.py is a "
             "DP-path mechanism)"
         )
-    anchors = jnp.asarray(
-        anchors_lib.anchors_for_image_shape(image_hw, anchor_config or anchors_lib.AnchorConfig())
-    )
-
-    # Schedule-resolved kernel params (tune/): tile shapes + impl choices
-    # come from the per-device registry unless explicitly pinned.
-    loss_config, matching_config = resolve_kernel_schedule(
-        loss_config, matching_config
-    )
-    local_step = _make_local_step(model, anchors, loss_config, matching_config)
+    loss_fn = task.loss_fn(model, image_hw)
+    local_step = _make_local_step(loss_fn)
 
     if mesh is None:
         return jax.jit(
@@ -485,7 +376,7 @@ def make_train_step(
             donate_argnums=(0,) if donate_state else (),
         )
 
-    batch_spec = {k: P(DATA_AXIS) for k in ("images", "gt_boxes", "gt_labels", "gt_mask")}
+    batch_spec = {k: P(DATA_AXIS) for k in task.batch_fields}
 
     if shard_weight_update:
         from batchai_retinanet_horovod_coco_tpu.parallel import zero
@@ -696,13 +587,7 @@ def make_train_step(
                     # grads, which this schedule never materializes as
                     # one tree — structurally absent here.)
                     def loss_of_params(p):
-                        return _forward_and_loss(
-                            model, state, p,
-                            batch["images"], batch["gt_boxes"],
-                            batch["gt_labels"], batch["gt_mask"],
-                            anchors, loss_config, matching_config,
-                            train=True,
-                        )
+                        return loss_fn(state, p, batch)
 
                     (_, (metrics, new_bs)), grads, new_comm, sat = (
                         grad_fn(loss_of_params, state.params, comm_cs)
@@ -1030,17 +915,12 @@ def make_train_step_spatial(
     )
     loss_config = _dc.replace(loss_config, pallas_focal=False)
     matching_config = _dc.replace(matching_config, fused_pallas=False)
-    anchors = jnp.asarray(
-        anchors_lib.anchors_for_image_shape(
-            image_hw, anchor_config or anchors_lib.AnchorConfig()
-        )
-    )
     # Numerics summary rides the global-math body (grads are global under
     # GSPMD); the per-replica agreement probe needs a named axis shard_map
     # does not exist here, so it is structurally absent on this path.
+    task = DetectionTask(num_classes, loss_config, matching_config, anchor_config)
     train_step = _global_math_step(
-        _make_local_step(model, anchors, loss_config, matching_config),
-        numerics,
+        _make_local_step(task.loss_fn(model, image_hw)), numerics
     )
 
     from batchai_retinanet_horovod_coco_tpu.parallel.mesh import (

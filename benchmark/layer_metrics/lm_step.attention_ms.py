@@ -1,0 +1,10 @@
+"""ms per step of device time in the attention layer (norm, projections,
+blocked masked softmax): forward, recomputed forward and backward; the device
+trace joined with the compiled step's scope ``attention``
+(``harness/lm_trace.py``)."""
+
+from benchmark.harness import lm_trace
+
+
+def read(ctx):
+    return lm_trace.slice_ms(ctx, "attention")

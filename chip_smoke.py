@@ -649,8 +649,8 @@ def _watch_placement(seen: dict):
 
     device_batch, run_training = loop._device_batch, loop.run_training
 
-    def noting_device_batch(batch, mesh):
-        arrays = device_batch(batch, mesh)
+    def noting_device_batch(batch, mesh, task):
+        arrays = device_batch(batch, mesh, task)
         seen["batch_devices"] = sorted(
             s.device.id for s in arrays["images"].addressable_shards
         )

@@ -116,11 +116,15 @@ class TestRunTraining:
             LoopConfig(total_steps=3, **cfg),
         )
         # Run 2: fresh state, resumes at 3, continues to 5.
+        template = fresh_state(model, seed=99)
         s2 = run_training(
-            model, fresh_state(model, seed=99), batch_stream(), NUM_CLASSES,
+            model, template, batch_stream(), NUM_CLASSES,
             LoopConfig(total_steps=5, **cfg),
         )
         assert int(s2.step) == 5
+        # The template's buffers were released as the restored leaves
+        # arrived: a state that fills the chip is never held twice.
+        assert all(x.is_deleted() for x in jax.tree.leaves(template))
 
         # Bitwise parity: an uninterrupted 5-step run from the same init and
         # the same stream yields the resumed run's params exactly (the data

@@ -31,6 +31,11 @@ from batchai_retinanet_horovod_coco_tpu.train.step import (
     scope_of,
     scope_table,
 )
+from batchai_retinanet_horovod_coco_tpu.train.task import DetectionTask, LMTask
+
+# The vocabulary is one; a step enters its task's scopes and every step's.
+EVERY_STEPS = ("optimizer", "grad_allreduce")
+DETECTION_SCOPES = (*DetectionTask.scopes, *EVERY_STEPS)
 
 HW = (64, 64)
 NUM_CLASSES = 3
@@ -139,7 +144,9 @@ def test_every_scope_of_the_vocabulary_reaches_the_compiled_step(compiled_steps,
     table = scope_table(compiled_steps[flavor])
     filed = {(s, d) for s, d, _ in table.values()}
     on_a_mesh = flavor != "single"
-    for s in STEP_SCOPES:
+    assert set(STEP_SCOPES) == {*DetectionTask.scopes, *LMTask.scopes, *EVERY_STEPS}
+    assert not set(LMTask.scopes) - {"loss"} & {s for s, _ in filed}
+    for s in DETECTION_SCOPES:
         if s == "grad_allreduce" and not on_a_mesh:
             assert not {d for t, d in filed if t == s}
             continue
@@ -147,10 +154,49 @@ def test_every_scope_of_the_vocabulary_reaches_the_compiled_step(compiled_steps,
     # what is differentiated has a backward; targets (stop_gradient), the
     # update and the reduction of finished gradients have none
     assert {s for s, d in filed if d == "bwd"} == {*MODEL_SLICES, "loss"}
-    for s, beneath in STEP_SCOPES.items():
+    for s in DETECTION_SCOPES:
         paths = {p for t, _, p in table.values() if t == s}
-        for name in beneath:
+        for name in STEP_SCOPES[s]:
             assert any(f"/{name}/" in p for p in paths), (s, name)
+
+
+def _lm_state_and_batch():
+    from batchai_retinanet_horovod_coco_tpu.models import granite_hybrid
+
+    model = granite_hybrid.GraniteHybrid(granite_hybrid.TINY)
+    tx = make_optimizer(OptimizerConfig(optimizer="adamw", schedule="constant", warmup_steps=0))[0]
+    state = create_train_state(model, tx, (1, 8), jax.random.key(0), example_dtype=LMTask.example_dtype)
+    seg = jnp.asarray(np.repeat([[0, 1, 2]], [20, 30, 14], axis=1), jnp.int32)
+    return model, state, {"tokens": jnp.zeros((1, 64), jnp.int32), "segment_ids": seg}
+
+
+def test_the_lm_tasks_scopes_reach_the_compiled_step_through_recomputation():
+    """Every layer of the LM step is recomputed in its backward pass
+    (``jax.checkpoint``): forward, recomputed forward and backward all keep
+    the layer's scope, and nothing of detection's is there."""
+    model, state, batch = _lm_state_and_batch()
+    compiled = make_train_step(model, (1, 64), None, task=LMTask(), donate_state=False).lower(state, batch).compile()
+    table = scope_table(compiled)
+    filed = {(s, d) for s, d, _ in table.values()}
+    for s in (*LMTask.scopes, "optimizer"):
+        assert (s, "fwd") in filed, s
+    assert {s for s, d in filed if d == "bwd"} >= {"embed", "mamba", "attention", "mlp", "lm_head", "loss"}
+    assert not set(DetectionTask.scopes) - {"loss"} & {s for s, _ in filed}
+    paths = {p for t, _, p in table.values() if t == "mamba"}
+    for name in STEP_SCOPES["mamba"]:
+        assert any(f"/{name}/" in p for p in paths), name
+    # the dot products of each kind of layer are filed under it
+    dots = [table[n] for n, op in _instructions(compiled).items() if op == "dot" and table[n][2]]
+    assert {s for s, _, _ in dots} >= {"mamba", "attention", "mlp", "lm_head"}
+
+
+def test_a_step_built_for_an_explicit_detection_task_is_the_default_step():
+    model = _model()
+    state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))
+    batch = _batch_arrays()
+    default = make_train_step(model, HW, NUM_CLASSES, donate_state=False).lower(state, batch).as_text()
+    explicit = make_train_step(model, HW, None, task=DetectionTask(NUM_CLASSES), donate_state=False)
+    assert explicit.lower(state, batch).as_text() == default
 
 
 @pytest.mark.parametrize("flavor,expected", [

@@ -18,6 +18,15 @@ The five BASELINE.json configs are runnable by name via ``--preset``:
   dp8            single-host 8-chip data-parallel training (configs[2])
   pod            multi-host pod training, full COCO2017 1333x800 (configs[3])
   eval           on-device batched NMS + mAP@[.5:.95] eval (configs[4])
+
+A second kind of model trains through the same loop (train/task.py):
+``train.py lm-synthetic`` builds a Granite 4.0-H hybrid (Mamba-2 mixers and
+a grouped-query attention layer per period, models/granite_hybrid.py) and
+trains it on seeded packed token sequences (data/tokens.py).  ``--model
+tiny`` (the default: one period of ten layers at width 64) is what the CPU
+tests run; ``--model <config.json>`` takes the published keys, e.g.
+benchmark/configs/granite-4.0-h-micro-p1.json.  The LM task is single-chip
+until an issue brings its sharding: ``--num-devices`` above 1 is refused.
 """
 
 from __future__ import annotations
@@ -137,6 +146,8 @@ def build_parser() -> argparse.ArgumentParser:
                        help="source image size: N (square) or HxW — e.g. "
                             "800x1344 generates images that land exactly in "
                             "the flagship bucket (make convergence-full)")
+
+    _add_lm_parser(sub)
 
     for sp in (coco, csvp, pascal, synth):
         # Also accepted after the subcommand; SUPPRESS so the subparser
@@ -290,6 +301,52 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def _add_lm_parser(sub) -> None:
+    """``lm-synthetic``: its own, short flag surface (no image, anchor,
+    eval or mesh flags apply)."""
+    lm = sub.add_parser(
+        "lm-synthetic", allow_abbrev=False,
+        help="train a Granite 4.0-H hybrid language model on seeded packed "
+             "token sequences (single chip; --model tiny on a CPU)",
+    )
+    g = lm.add_argument_group("model")
+    g.add_argument("--model", default="tiny",
+                   help="'tiny' (one period of ten layers at width 64, "
+                        "vocabulary 128: the CPU tests' preset) or a JSON "
+                        "file with the published config.json keys, e.g. "
+                        "benchmark/configs/granite-4.0-h-micro-p1.json")
+    g = lm.add_argument_group("data")
+    g.add_argument("--seq-len", type=int, default=64,
+                   help="tokens per packed sequence")
+    g.add_argument("--batch-size", type=int, default=2,
+                   help="sequences per step")
+    g.add_argument("--doc-len-median", type=float, default=16.0,
+                   help="median document length (log-normal, sigma 1.3, "
+                        "clipped to [--doc-len-min, --seq-len]); the "
+                        "benchmark's mix uses 512 at --seq-len 8192")
+    g.add_argument("--doc-len-min", type=int, default=4)
+    g = lm.add_argument_group("optimization")
+    g.add_argument("--steps", type=int, default=20)
+    g.add_argument("--lr", type=float, default=3e-4,
+                   help="constant; AdamW beta 0.9/0.95, decoupled decay "
+                        "0.1 on matrices only, clip at global norm 1.0")
+    g.add_argument("--seed", type=int, default=0)
+    g = lm.add_argument_group("loop / io")
+    g.add_argument("--snapshot-path", default=None,
+                   help="checkpoint directory (enables checkpointing)")
+    g.add_argument("--checkpoint-every", type=int, default=1000)
+    g.add_argument("--no-resume", action="store_true")
+    g.add_argument("--log-every", type=int, default=4)
+    g.add_argument("--log-dir", default=None)
+    g.add_argument("--profile-dir", default=None,
+                   help="write a jax.profiler trace of a few steps here")
+    add_obs_flags(g)
+    g = lm.add_argument_group("distributed")
+    g.add_argument("--num-devices", type=int, default=1,
+                   help="must be 1: the LM task has no sharding yet")
+    g.add_argument("--platform", default="auto", choices=["auto", "cpu", "tpu"])
+
+
 def parse_args(argv=None):
     parser = build_parser()
     args = parser.parse_args(argv)
@@ -386,15 +443,16 @@ def main(argv=None) -> dict[str, float]:
     # pipeline is built.  The finalize runs even when the run dies — the
     # partial trace (+ the watchdog's stall dump) IS the post-mortem.
     obs_dir = configure_obs(args, process_label="train")
+    run = _run_lm if args.dataset_type == "lm-synthetic" else _run
     if obs_dir is None:
-        return _run(args)
+        return run(args)
     if not args.log_dir:
         # The perf doctor (obs/analyze) reads the run's events JSONL next
         # to its trace: an obs-enabled run without an explicit --log-dir
         # logs into the obs dir so the report never lacks its events half.
         args.log_dir = obs_dir
     try:
-        return _run(args)
+        return run(args)
     finally:
         from batchai_retinanet_horovod_coco_tpu import obs
 
@@ -656,6 +714,99 @@ class _NanInjector:
                 file=sys.stderr, flush=True,
             )
         return batch
+
+
+def _run_lm(args) -> dict[str, float]:
+    """``lm-synthetic``: model, optimizer, state and the packed source, then
+    the same ``run_training`` as detection, with the LM task."""
+    if args.num_devices != 1:
+        raise SystemExit(
+            "lm-synthetic trains on one chip: the LM task has no sharding "
+            "yet (train/task.py); drop --num-devices"
+        )
+    if args.platform != "auto":
+        jax.config.update("jax_platforms", args.platform)
+
+    from batchai_retinanet_horovod_coco_tpu.data.tokens import (
+        PackedTokensConfig,
+        packed_token_batches,
+    )
+    from batchai_retinanet_horovod_coco_tpu.models import granite_hybrid
+    from batchai_retinanet_horovod_coco_tpu.train import create_train_state
+    from batchai_retinanet_horovod_coco_tpu.train.loop import LoopConfig, run_training
+    from batchai_retinanet_horovod_coco_tpu.train.optim import (
+        OptimizerConfig,
+        make_optimizer,
+    )
+    from batchai_retinanet_horovod_coco_tpu.train.task import LMTask
+    from batchai_retinanet_horovod_coco_tpu.utils.backend import (
+        announce_devices,
+        enable_compile_cache,
+    )
+    from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
+
+    enable_compile_cache()
+    announce_devices("train")
+    if args.model == "tiny":
+        config = granite_hybrid.TINY
+    else:
+        with open(args.model) as f:
+            config = granite_hybrid.GraniteHybridConfig.from_hf(json.load(f))
+    model, task = granite_hybrid.GraniteHybrid(config), LMTask()
+    tx, schedule = make_optimizer(OptimizerConfig(
+        optimizer="adamw", schedule="constant", warmup_steps=0,
+        base_lr=args.lr, world_size=1, adam_b2=0.95,
+        weight_decay=0.1, clip_global_norm=1.0,
+    ))
+    state = jax.jit(
+        lambda key: create_train_state(
+            model, tx, (1, 8), key, example_dtype=task.example_dtype
+        )
+    )(jax.random.key(args.seed))
+    n_params = sum(x.size for x in jax.tree.leaves(state.params))
+    print(
+        f"lm-synthetic: {len(config.layer_types)} layers "
+        f"({config.layer_types.count('mamba')} mamba), d={config.hidden_size}, "
+        f"vocabulary {config.vocab_size}, {n_params / 1e6:.1f} M parameters; "
+        f"{args.batch_size} x {args.seq_len} tokens per step",
+        flush=True,
+    )
+    batches = packed_token_batches(PackedTokensConfig(
+        vocab_size=config.vocab_size, seq_len=args.seq_len,
+        batch_size=args.batch_size, doc_len_median=args.doc_len_median,
+        doc_len_min=args.doc_len_min, seed=args.seed,
+    ))
+    logger = MetricLogger(log_dir=args.log_dir)
+    telem_server, slo_monitor = _start_telemetry(args, logger)
+    try:
+        state = run_training(
+            model, state, batches, None,
+            LoopConfig(
+                total_steps=args.steps,
+                log_every=args.log_every,
+                checkpoint_every=(
+                    args.checkpoint_every if args.snapshot_path else 0
+                ),
+                checkpoint_dir=args.snapshot_path,
+                resume=not args.no_resume,
+                profile_dir=args.profile_dir,
+                numerics=args.numerics,
+                numerics_dump_dir=args.obs_dir or args.log_dir or None,
+                rng_seed=args.seed,
+                ckpt_metadata={
+                    "global_batch_size": args.batch_size,
+                    "data_seed": args.seed,
+                },
+            ),
+            schedule=schedule, logger=logger, task=task,
+        )
+        return {"final_step": float(int(state.step))}
+    finally:
+        batches.close()
+        if slo_monitor is not None:
+            slo_monitor.stop()
+        if telem_server is not None:
+            telem_server.close()
 
 
 def _run(args) -> dict[str, float]:

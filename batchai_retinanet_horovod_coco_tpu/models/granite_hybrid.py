@@ -8,7 +8,8 @@ Equations (ibm-granite/granite-4.0-h-micro ``config.json``, ``model_type``
 - every layer: ``h = x + r * Mixer(RMSNorm(x))``, ``x' = h + r * MLP(RMSNorm(h))``
   with ``r = residual_multiplier``; ``MLP(u) = W_down (silu(W_g u) * W_u u)``.
 - attention layer: grouped-query, no positional encoding, causal and within
-  one document, ``softmax(attention_multiplier * q k^T) v``, then ``W_o``.
+  one document, ``softmax(attention_multiplier * q k^T) v`` (ops/attention.py:
+  a blocked kernel on a TPU, XLA elsewhere), then ``W_o``.
 - mamba layer: ``[z, xBC, dt] = W_in u``; ``xBC = silu(conv1d(xBC) + b)``
   (depthwise, causal, not reaching into the previous document); split into
   ``X`` (heads x head size), ``B``, ``C`` (state size each, one group);
@@ -37,7 +38,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from batchai_retinanet_horovod_coco_tpu.ops import ssd
+from batchai_retinanet_horovod_coco_tpu.ops import attention, ssd
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -61,8 +62,9 @@ class GraniteHybridConfig:
     logits_scaling: float = 8.0
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # Queries per block of the attention layer: block i attends keys
-    # [0, end of block i), so scores are never (T, T) at once.
+    # Queries per block of the attention layer where XLA lowers it
+    # (ops/attention.py): block i attends keys [0, end of block i), so scores
+    # are never (T, T) at once.
     attention_q_block: int = 1024
 
     @property
@@ -200,27 +202,13 @@ def _mamba_mixer(config, p, u, segment_ids):
 
 def _attention_mixer(config, p, u, segment_ids):
     batch, t, _ = u.shape
-    hd, kvh = config.attention_head_dim, config.num_key_value_heads
-    group = config.num_attention_heads // kvh
-    q = _matmul(config, u, p["q"]).reshape(batch, t, kvh, group, hd)
-    k = _matmul(config, u, p["k"]).reshape(batch, t, kvh, hd)
-    v = _matmul(config, u, p["v"]).reshape(batch, t, kvh, hd)
-
-    def block(q_blk, seg_q, start, k_seen, v_seen, seg_k):
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", q_blk, k_seen, preferred_element_type=jnp.float32)
-        pos_q = start + jnp.arange(q_blk.shape[1])
-        allowed = (pos_q[:, None] >= jnp.arange(k_seen.shape[1])[None, :]) & (
-            seg_q[:, :, None] == seg_k[:, None, :])  # (b, q, s)
-        scores = jnp.where(allowed[:, None, None], scores * config.attention_multiplier, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(config.dtype)
-        return jnp.einsum("bkgqs,bskd->bqkgd", probs, v_seen)
-
-    block = jax.checkpoint(block, static_argnums=(2,))  # scores are recomputed, never kept
-    step = config.attention_q_block
-    out = [block(q[:, s:s + step], segment_ids[:, s:s + step], s, k[:, :min(s + step, t)],
-                 v[:, :min(s + step, t)], segment_ids[:, :min(s + step, t)])
-           for s in range(0, t, step)]
-    return _matmul(config, jnp.concatenate(out, axis=1).reshape(batch, t, -1), p["o"])
+    hd = config.attention_head_dim
+    q = _matmul(config, u, p["q"]).reshape(batch, t, config.num_attention_heads, hd)
+    k = _matmul(config, u, p["k"]).reshape(batch, t, config.num_key_value_heads, hd)
+    v = _matmul(config, u, p["v"]).reshape(batch, t, config.num_key_value_heads, hd)
+    out = attention.packed_causal_attention(
+        q, k, v, segment_ids, config.attention_multiplier, config.attention_q_block)
+    return _matmul(config, out.reshape(batch, t, -1), p["o"])
 
 
 def _mlp(config, p, u):

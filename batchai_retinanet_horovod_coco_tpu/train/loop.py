@@ -449,6 +449,23 @@ def _record_step_cost(hw, batch: int) -> None:
         )
 
 
+def _record_run_meta(task, bucket) -> None:
+    """Run metadata INTO the trace (the perf doctor resolves device peak
+    TFLOP/s and process topology from artifacts alone — the events JSONL
+    may not exist for this run), with what the task says of the step it is
+    about to build for ``bucket`` (train/task.py::run_meta)."""
+    try:
+        trace.instant(
+            "run_meta",
+            device_kind=jax.devices()[0].device_kind,
+            local_device_count=jax.local_device_count(),
+            process_count=jax.process_count(),
+            **task.run_meta(bucket),
+        )
+    except Exception:
+        pass  # metadata must never block training bring-up
+
+
 def _profile_options():
     """``--profile-dir``'s profiler session: device operations and host
     annotations (this program's ``rn.*`` spans among them), no Python call
@@ -678,20 +695,6 @@ def run_training(
         else:
             state = jax.device_put(state, replicated_sharding(mesh))
 
-    if trace.enabled():
-        # Run metadata INTO the trace (the perf doctor resolves device
-        # peak TFLOP/s and process topology from artifacts alone — the
-        # events JSONL may not exist for this run).
-        try:
-            trace.instant(
-                "run_meta",
-                device_kind=jax.devices()[0].device_kind,
-                local_device_count=jax.local_device_count(),
-                process_count=jax.process_count(),
-            )
-        except Exception:
-            pass  # metadata must never block training bring-up
-
     step_fns: dict[tuple[int, ...], Callable] = {}
     _built_steps.clear()
     start_step = int(state.step)
@@ -756,6 +759,8 @@ def run_training(
                 # and the heartbeat goes idle for the same reason (a cold
                 # flagship compile is minutes, far past any stall budget).
                 loop_hb.idle()
+                if not step_fns and trace.enabled():
+                    _record_run_meta(task, hw)
                 t_compile = monotonic_s()
                 with trace.span(
                     "compile_train_step", bucket=_bucket_name(hw)

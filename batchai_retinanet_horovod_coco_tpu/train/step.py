@@ -78,6 +78,9 @@ UNSCOPED = "unscoped"
 _HLO_INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?(?:metadata=\{[^}]*?op_name="([^"]*)"|$)'
 )
+# A kernel call whose frontend attributes hold newlines (a Pallas call given
+# ``metadata=``) runs over several lines of text: its metadata opens a later one.
+_HLO_CONTINUED_METADATA = re.compile(r'^\}+, metadata=\{[^}]*?op_name="([^"]*)"')
 _TRANSFORM = re.compile(r"^\w+\((.*)\)$")
 
 
@@ -114,10 +117,15 @@ def scope_table(compiled) -> dict[str, tuple[str, str, str]]:
     out of a compile cache filled before the scopes existed has the OLD
     metadata (the cache key leaves metadata out)."""
     table = {}
+    unfinished = None  # the instruction whose text the next lines continue
     for line in compiled.as_text().splitlines():
         m = _HLO_INSTRUCTION.match(line)
         if m is not None:
             table[m.group(1)] = scope_of(m.group(2) or "")
+            unfinished = m.group(1) if m.group(2) is None and line.endswith("{") else None
+        elif unfinished is not None and (m := _HLO_CONTINUED_METADATA.match(line)) is not None:
+            table[unfinished] = scope_of(m.group(1))
+            unfinished = None
     return table
 
 

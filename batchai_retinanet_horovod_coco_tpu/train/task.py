@@ -168,6 +168,12 @@ class _Task:
     def host_arrays(self, batch) -> dict[str, Any]:
         return {k: getattr(batch, k) for k in self.batch_fields}
 
+    def run_meta(self, bucket) -> dict[str, Any]:
+        """What the loop's ``run_meta`` instant says of the step for ``bucket``
+        besides the devices."""
+        del bucket
+        return {}
+
 
 @dataclasses.dataclass(frozen=True)
 class DetectionTask(_Task):
@@ -225,6 +231,12 @@ class LMTask(_Task):
     def describe(self, batch) -> tuple[tuple[int, ...], int, Any]:
         """The bucket is (sequences, tokens per sequence)."""
         return tuple(batch.tokens.shape), batch.tokens.shape[0], batch.sequence_ids
+
+    def run_meta(self, bucket) -> dict[str, Any]:
+        """Which lowering the step's attention layer takes (ops/attention.py)."""
+        from batchai_retinanet_horovod_coco_tpu.ops import attention
+
+        return {"attention_lowering": attention.lowering(jax.default_backend(), bucket[1])}
 
     def loss_fn(self, model, bucket) -> LossFn:
         from batchai_retinanet_horovod_coco_tpu.models.granite_hybrid import next_token_loss

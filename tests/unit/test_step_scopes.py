@@ -122,6 +122,34 @@ def test_scope_of_files_an_op_name_under_its_outermost_scope(op_name, expected):
     assert scope_of(op_name) == expected
 
 
+def test_scope_table_reads_a_kernel_call_whose_text_runs_over_several_lines():
+    """As the v5e compiler prints a Pallas call that was given ``metadata=``
+    (the attention kernel, ops/attention.py): the frontend attributes hold
+    newlines, and ``metadata={op_name=...}`` opens the third line."""
+
+    class Compiled:
+        def as_text(self):
+            return "\n".join([
+                "ENTRY %main.1 (p: bf16[8]) -> bf16[8] {",
+                '  %copy.1 = bf16[8]{0} copy(%p)',
+                '  %splash_mha_dq.1 = (f32[8,64]{1,0}, bf16[8]{0}) custom-call(%copy.1), '
+                'custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={',
+                '"xprof_metadata":"{\\"block_q_dq\\": 1024}"',
+                '}}, metadata={op_name="jit(train_step)/transpose(jvp(jvp()))/checkpoint/attention/'
+                'vmap(jit(_splash_attention))/splash_mha_dq/pallas_call" stack_frame_id=229}, backend_config={}',
+                '  %fusion.2 = bf16[8]{0} fusion(%copy.1), kind=kLoop, calls=%f, '
+                'metadata={op_name="jit(train_step)/jvp(mlp)/mul"}',
+                '  ROOT %copy.3 = bf16[8]{0} copy(%fusion.2)',
+                "}",
+                '}}, metadata={op_name="jit(train_step)/jvp(mlp)/nobody"}',
+            ])
+
+    table = scope_table(Compiled())
+    assert table["splash_mha_dq.1"] == ("attention", "bwd", "attention/_splash_attention/splash_mha_dq/pallas_call")
+    assert table["fusion.2"] == ("mlp", "fwd", "mlp/mul")
+    assert table["copy.1"][0] == table["copy.3"][0] == UNSCOPED  # a later line's metadata is not theirs
+
+
 @pytest.mark.parametrize("flavor", ["single", "dp4", "comm4", "zero4"])
 def test_every_convolution_is_filed_under_the_model(compiled_steps, flavor):
     compiled = compiled_steps[flavor]
@@ -326,3 +354,37 @@ def test_cost_analysis_is_recorded_after_the_first_execution_from_the_cache(_no_
     assert cost[0]["args"] == dict(cost[0]["args"], target="train_step", bucket="64x64", batch=BATCH)
     first_step = min(e["ts"] for e in events if e["name"] == "step")
     assert cost[0]["ts"] >= first_step
+
+
+def _lm_run():
+    from batchai_retinanet_horovod_coco_tpu.data.tokens import PackedTokensConfig, packed_token_batches
+    from batchai_retinanet_horovod_coco_tpu.models import granite_hybrid
+
+    model = granite_hybrid.GraniteHybrid(granite_hybrid.TINY)
+    tx = make_optimizer(OptimizerConfig(optimizer="adamw", schedule="constant", warmup_steps=0))[0]
+    state = create_train_state(model, tx, (1, 8), jax.random.key(0), example_dtype=LMTask.example_dtype)
+    batches = packed_token_batches(PackedTokensConfig(vocab_size=128, seq_len=64, batch_size=1, seed=0))
+    return model, state, batches, None, LMTask()
+
+
+def _detection_run():
+    model = _model()
+    state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))
+    return model, state, _host_batches(), NUM_CLASSES, None
+
+
+@pytest.mark.parametrize("run,more", [
+    (_detection_run, {}),
+    (_lm_run, {"attention_lowering": "xla"}),  # 64 tokens on the CPU (ops/attention.py::lowering)
+])
+def test_run_meta_says_which_lowering_the_lm_steps_attention_took(_no_ring, tmp_path, run, more):
+    """One ``run_meta`` instant a run, before the step's compile span: the
+    devices, and for the language model what its attention layer lowers to."""
+    model, state, batches, num_classes, task = run()
+    trace.configure(str(tmp_path), process_label="t")
+    loop.run_training(model, state, batches, num_classes, loop.LoopConfig(total_steps=1, log_every=0), task=task)
+    events = trace.snapshot_events()
+    (meta,) = [e for e in events if e["name"] == "run_meta"]
+    assert meta["args"] == {"device_kind": jax.devices()[0].device_kind, "process_count": 1,
+                            "local_device_count": jax.local_device_count(), **more}
+    assert meta["ts"] <= min(e["ts"] for e in events if e["name"] == "compile_train_step")

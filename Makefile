@@ -8,7 +8,7 @@
 #   make submit NAME=ret-pod TRAIN_ARGS="--preset pod coco /mnt/coco"
 #   make status NAME=ret-pod
 #   make delete NAME=ret-pod
-#   make test | make bench | make smoke | make chip-smoke
+#   make test | make smoke | make chip-smoke
 
 NAME ?= retinanet-pod
 ZONE ?= us-east5-b
@@ -19,14 +19,9 @@ DRYFLAG = $(if $(DRY),--dry-run,)
 CLUSTER = python -m batchai_retinanet_horovod_coco_tpu.launch.cluster
 
 .PHONY: create submit status delete test test-timings smoke chip-smoke \
-	bench bench-pipeline pipebench pipebench-check evalbench \
-	servebench canaries \
-	convergence-full lint lint-obs check-static tune-smoke tune \
-	perf-report perf-report-check telemetry-smoke \
-	numerics-smoke chaos chaos-smoke chaos-comm ckptbench \
-	ckptbench-check fleet-smoke fleet-obs-smoke stream-smoke scale-smoke \
-	commbench \
-	commbench-check
+	canaries convergence-full lint lint-obs check-static tune-smoke tune \
+	perf-report telemetry-smoke numerics-smoke chaos chaos-smoke \
+	chaos-comm fleet-smoke fleet-obs-smoke stream-smoke scale-smoke
 
 create:
 	$(CLUSTER) create --name $(NAME) --zone $(ZONE) --accelerator $(ACCEL) $(DRYFLAG)
@@ -46,10 +41,12 @@ test:
 # Regenerate the committed per-test timing snapshot (budget mechanism,
 # tests/conftest.py): run the fast tier, write TEST_TIMINGS.md.  Timings
 # depend on how warm tests/.jax_cache is (see conftest.py): a cold run
-# pays each unique program's compile once.
+# pays each unique program's compile once.  Six workers by file: the
+# shape of the driver's own run of the tier.
 # bash + pipefail: a failing tier must NOT regenerate/bless the snapshot.
 test-timings:
 	bash -o pipefail -c 'python -m pytest tests/ -q -m "not slow" \
+	  -p xdist -n 6 --dist loadfile \
 	  --durations=40 | tee /tmp/fast_tier_timings.log'
 	python scripts/update_test_timings.py /tmp/fast_tier_timings.log
 
@@ -65,25 +62,6 @@ smoke:
 # without one (there is no CPU mode; `make smoke` is the CPU smoke).
 chip-smoke:
 	python chip_smoke.py
-
-bench:
-	python bench.py
-
-# Eval/detect fast-path bench (ISSUE 2): per-bucket AOT detect + NMS-only
-# ms/batch + sequential-vs-pipelined end-to-end comparison, one JSON line.
-evalbench:
-	python bench.py --mode eval
-
-# Dynamic-batching serve bench (ISSUE 4): per-bucket closed-loop server
-# throughput vs the in-run detect ceiling (vs_ceiling ≥ 0.9 is the chip
-# acceptance bar), request p50/p99, and an overload leg proving bounded
-# queues SHED instead of queueing unboundedly.
-# The continuous-vs-deadline leg (ISSUE 14) races the same seeded
-# open-loop mixed-arrival schedule in both batching modes, on the live
-# flagship executable with the in-run bit-identity cross-check
-# (SERVEBENCH_E2E=0 keeps only the device-independent stub leg).
-servebench:
-	python bench.py --mode serve
 
 # All four XLA-partitioner canaries in one shot (VERDICT r5 next-round #5):
 # each asserts its bug's PRESENCE on the current jax/XLA (or skips when the
@@ -146,21 +124,6 @@ chaos:
 chaos-smoke:
 	JAX_PLATFORMS=cpu RETINANET_LOCK_DEBUG=1 python scripts/chaos.py --smoke
 
-# COMMBENCH (ISSUE 13, bench.py --mode comm + scripts/commbench_sweep.py):
-# the gradient-compression subsystem's committed evidence — bytes-on-wire
-# vs exact (the <= 0.65x claim), step-time delta, and parity drift after
-# N identical steps, per variant (int8 / int8+overlap / bf16 / 1MB
-# buckets), on a forced 8-device virtual CPU mesh (bytes + parity are
-# device-independent; timing is indicative).  commbench-check is the
-# tripwire: int8-only re-measure vs the committed COMMBENCH.json (bytes
-# ratio hard <= 0.65 AND <= committed + 0.02, drift band, device-class
-# guard).
-commbench:
-	JAX_PLATFORMS=cpu python scripts/commbench_sweep.py
-
-commbench-check:
-	JAX_PLATFORMS=cpu BENCH_SWEEP=0 BENCH_CHECK=1 python bench.py --mode comm
-
 # Comm chaos leg alone (ISSUE 13, scripts/chaos.py --comm): SIGKILL a
 # compressed+EF training run mid-save, assert the resume restores the EF
 # residual state from the checkpoint (or cleanly zeros it with ONE
@@ -214,19 +177,6 @@ stream-smoke:
 scale-smoke:
 	JAX_PLATFORMS=cpu RETINANET_LOCK_DEBUG=1 python scripts/chaos.py --autoscale
 
-# CKPTBENCH (ISSUE 11): the two durability numbers — async-save overhead
-# (wall of N checkpointed steps vs the same N without) and resume
-# time-to-first-step — committed as CKPTBENCH.json.  ckptbench-check
-# re-measures with a device-class guard (cross-class comparisons pass
-# with a loud re-capture note); the band is wide (CKPTBENCH_BAND, default
-# 75%) because subprocess wall times on small shared boxes are
-# noise-dominated.
-ckptbench:
-	JAX_PLATFORMS=cpu python scripts/chaos.py --bench
-
-ckptbench-check:
-	JAX_PLATFORMS=cpu python scripts/chaos.py --bench --check
-
 # Aggregate for everything chip-free: one target CI can
 # run without touching an accelerator (chaos-smoke DOES run a few real
 # CPU training subprocesses over generated synthetic data — budget the
@@ -261,7 +211,7 @@ tune:
 
 # Perf doctor (ISSUE 8, obs/analyze): turn an obs dir's own artifacts
 # (merged trace.json + metrics.jsonl) into one machine-readable
-# PERF_REPORT.json — step-time decomposition, pipeline overlap
+# <OBS_DIR>/PERF_REPORT.json — step-time decomposition, pipeline overlap
 # efficiency, queue/stall correlation, MFU estimate, ranked top-3
 # bottleneck verdict (RUNBOOK "Perf doctor").  perf-report analyzes an
 # existing obs dir (OBS_DIR, default artifacts/obs — any --obs-trace run
@@ -269,34 +219,6 @@ tune:
 OBS_DIR ?= artifacts/obs
 perf-report:
 	python -m batchai_retinanet_horovod_coco_tpu.obs.analyze $(OBS_DIR)
-
-# perf-report-check: regression tripwire — run the standard traced CPU
-# smoke (train+eval, ~2 min; --platform cpu so the attribution baseline
-# is device-stable), analyze it, schema-validate the report, and enforce
-# the attribution-fraction band (PERF_BAND_ABS, default ±0.20 absolute)
-# against the committed repo-root PERF_REPORT.json, with a device-class
-# guard (a baseline captured on another device class passes with a loud
-# re-capture note).
-PERF_OBS_DIR ?= /tmp/perf_report_check_obs
-perf-report-check:
-	rm -rf $(PERF_OBS_DIR)
-	python train.py synthetic --platform cpu --backbone resnet_test --f32 \
-	  --image-min-side 64 --image-max-side 64 --batch-size 4 \
-	  --num-devices 1 --steps 20 --eval-every 10 --synthetic-size 64 \
-	  --synthetic-root /tmp/perf_report_check_data \
-	  --obs-trace --obs-dir $(PERF_OBS_DIR)
-	python -m batchai_retinanet_horovod_coco_tpu.obs.analyze \
-	  $(PERF_OBS_DIR) --check
-
-# Host input-pipeline bench: threads-vs-procs sweep (bench_pipeline.py).
-# pipebench-check is its regression tripwire: measured best vs the
-# committed PIPEBENCH.json value minus the noise band (exit 1).
-bench-pipeline: pipebench
-pipebench:
-	python bench_pipeline.py
-
-pipebench-check:
-	python bench_pipeline.py --check
 
 # Flagship-resolution convergence artifact (VERDICT r2 #2): the REAL recipe
 # — resnet50 frozen_bn, multistep decays at 2/3 and 8/9 of --steps, warmup,

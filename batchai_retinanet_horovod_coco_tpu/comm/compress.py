@@ -242,8 +242,7 @@ class CommPlan:
 
     def hop_quant_bytes(self, topology) -> dict:
         """Per-device QUANTIZED payload bytes per hop.  The ICI hops
-        are exact f32 by construction, so ``"ici"`` is identically 0 —
-        the COMMBENCH "ICI exact" headline is this number."""
+        are exact f32 by construction, so ``"ici"`` is identically 0."""
         S = topology.num_slices
         fd = (S - 1) / max(S, 1)
         dcn = 0.0
@@ -679,9 +678,8 @@ def reduce_tree(
 
 
 def bucketed_pmean(grads: Any, axis_name: str, n: int, config=None):
-    """Stateless (no-EF) bucketed compressed pmean — the drop-in for the
-    deprecated ``parallel/quantize.quantized_pmean`` alias.  Builds the
-    plan at trace time from the tree itself."""
+    """Stateless (no-EF) bucketed compressed pmean.  Builds the plan at
+    trace time from the tree itself."""
     config = config or CommConfig(compress="int8", error_feedback=False)
     plan = plan_buckets(grads, config)
     reduced, _, _ = reduce_tree(grads, {}, plan, config, axis_name, n)

@@ -1,8 +1,6 @@
 """CommConfig: the policy layer for how gradients cross the interconnect.
 
-ISSUE 13 replaces the bare ``quantized_allreduce`` bool (one ``if`` in
-train/step.py, per-leaf, no error feedback, unmeasured) with a first-class
-policy object the whole stack resolves from:
+A first-class policy object the whole stack resolves from:
 
 - ``compress`` selects the wire format of the compressible collective
   phase: ``"none"`` (exact f32 — the compiled step is byte-identical to

@@ -202,9 +202,9 @@ def stop_gated_put(q: queue.Queue, item, stop: threading.Event) -> bool:
 def default_buckets(min_side: int, max_side: int) -> tuple[tuple[int, int], ...]:
     """Static (H, W) shape buckets covering the resize rule's output range.
 
-    The single source of truth for bucket derivation — train.py, debug.py
-    and bench.py all consume this, so the shapes the tools report match the
-    shapes the train step compiles for.
+    The single source of truth for bucket derivation — train.py and
+    debug.py consume this, so the shapes the tools report match the shapes
+    the train step compiles for.
 
     Two buckets suffice, PROVABLY: ``resize_scale`` maps every source to
     resized dims with min(rh, rw) <= min_side <= lo and max(rh, rw) <=
@@ -216,8 +216,7 @@ def default_buckets(min_side: int, max_side: int) -> tuple[tuple[int, int], ...]
     for the images it targeted the portrait bucket pads less anyway
     (933x800 resized: 0.33 Mpx waste in 1344x800 vs 0.44 in 1088x1088).
     Dropping it removes a dead compiled program per run (one fewer
-    ~minutes-long bucket compile at pod bring-up, a third off the bench
-    sweep) and a phantom 4% share in the weighted-mix arithmetic.
+    ~minutes-long bucket compile at pod bring-up).
     """
     lo = round_up(min_side, 32)
     hi = round_up(max_side, 32)

@@ -1,9 +1,9 @@
 """Multiprocess shared-memory input pipeline: the GIL-free producer.
 
-Why this exists (PIPEBENCH.json round 5): the thread-pool producer in
-``pipeline.py`` plateaus at ~2 workers because PIL's JPEG decode holds the
-GIL (cv2's resize releases it, but decode dominates), capping a host at
-~37 imgs/s — far below the ~67 imgs/s/chip the train step consumes.  Here
+Why this exists: the thread-pool producer in ``pipeline.py`` plateaus at
+~2 workers because PIL's JPEG decode holds the GIL (cv2's resize releases
+it, but decode dominates), capping a host below what one chip's train step
+consumes (no cell measures the host pipeline yet: PERF.md section 7).  Here
 the decode/augment/resize fan-out runs in ``num_worker_procs`` WORKER
 PROCESSES instead, each writing its decoded image directly into a
 preallocated POSIX shared-memory ring buffer, so the only things crossing

@@ -28,8 +28,6 @@ sequential path survives as ``pipelined=False`` and stays bit-identical):
    incremental COCOeval matching (``StreamingCocoEval``) run in a consumer
    thread behind a bounded queue with the shm-pipeline's error contract:
    a consumer crash re-raises in the driver, ``close()`` never hangs.
-
-``bench.py --mode eval`` (``make evalbench``) measures this path.
 """
 
 from __future__ import annotations
@@ -146,9 +144,7 @@ def nms_fn_for(
     config: DetectConfig,
 ) -> Callable[[jnp.ndarray, jnp.ndarray], nms_lib.Detections]:
     """``(boxes (B, A, 4), scores (B, A, K)) → Detections`` for a RESOLVED
-    config — the one place the XLA-vs-Pallas suppression dispatch lives
-    (bench.py's postprocess tripwire uses it too, so the tuned winner is
-    what the committed number measures)."""
+    config — the one place the XLA-vs-Pallas suppression dispatch lives."""
     config = resolve_detect_config(config)
     if config.nms_impl == "pallas":
         from batchai_retinanet_horovod_coco_tpu.ops.pallas import (
@@ -251,9 +247,9 @@ def compile_detect_fn(
     """AOT-lower + compile ONE bucket's detect program at a fixed batch
     size; returns ``call(images) -> Detections`` with ``state`` closed over.
 
-    The shared load/dispatch path of the eval bench (bench.py --mode eval)
-    and the serve engine (serve/engine.py): both need every
-    (bucket, batch-size) executable built BEFORE traffic arrives, with the
+    The load/dispatch path of the serve engine (serve/engine.py), which
+    needs every (bucket, batch-size) executable built BEFORE traffic
+    arrives, with the
     multi-second compile attributed by a trace span instead of hiding
     inside the first dispatch.  Inputs default to uint8 — the raw pipeline
     format; normalization runs inside the program (``_detect_body``).
@@ -531,8 +527,7 @@ def collect_detections(
 
     One detect function is compiled per shape bucket encountered (static
     shapes, SURVEY.md §7.3 hard part 1); the cache keys on (H, W).  Pass
-    ``detect_fns`` to share compiled programs across calls (the eval bench
-    times sequential vs pipelined on the same executables).
+    ``detect_fns`` to share compiled programs across calls.
 
     ``pipelined`` selects the three-stage overlapped driver (module
     docstring); ``False`` is the strictly sequential reference path.  Both

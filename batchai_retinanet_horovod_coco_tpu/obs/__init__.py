@@ -7,8 +7,7 @@ Three coordinated pieces:
   ``trace_event`` JSON export, cross-process merge) and the subsystem's
   ONE clock (``monotonic_s``);
 - ``obs.events`` — the structured JSONL sink (run headers, metrics,
-  counters/gauges, device memory) that ``utils.metrics.MetricLogger``
-  now shims over;
+  counters/gauges, device memory);
 - ``obs.watchdog`` — the heartbeat registry every long-lived thread
   registers with, and the stall diagnoser that dumps the post-mortem
   before a timeout kills the run.

@@ -9,13 +9,11 @@ verdict naming the spans and ``tune/`` problems to attack next.
 
 Three entrypoints:
 
-- inline auto-emit at ``train.py``/``bench.py`` finalize (``auto_emit``
-  — never raises; failure is one structured event);
+- inline auto-emit at ``train.py``'s finalize (``auto_emit`` — never
+  raises; failure is one structured event);
 - offline CLI: ``python -m batchai_retinanet_horovod_coco_tpu.obs.analyze
   <obs_dir>`` (byte-identical to the inline report for the same dir);
-- ``make perf-report`` / ``make perf-report-check`` (schema validation +
-  regression band on the attribution fractions vs the committed
-  PERF_REPORT.json, bench-check's device-class guard).
+- ``make perf-report`` (the CLI over ``OBS_DIR``).
 
 jax-free: the analyzer reads artifacts, never devices.
 """

@@ -1,13 +1,12 @@
 """Perf-doctor CLI: ``python -m batchai_retinanet_horovod_coco_tpu.obs.analyze``.
 
 Post-hoc analysis of any obs dir (the offline twin of the finalize-time
-auto-emit — byte-identical output for the same artifacts), plus the
-``--check`` mode behind ``make perf-report-check``: schema-validate the
-fresh report and enforce an absolute regression band on the step-time
-attribution fractions against the committed repo-root PERF_REPORT.json,
-with bench-check's device-class guard (reports from different device
-kinds are not comparable — a mismatch passes with a loud re-capture
-note, never a false REGRESSION).
+auto-emit — byte-identical output for the same artifacts), plus
+``--check BASELINE``: schema-validate the fresh report and enforce an
+absolute band on the step-time attribution fractions against an earlier
+report of the operator's own (reports from different device kinds are
+not comparable — a mismatch passes with a loud re-capture note, never a
+false REGRESSION).
 
 Exit codes: 0 ok, 1 schema problem / regression, 2 usage (missing
 artifacts).
@@ -33,18 +32,6 @@ from batchai_retinanet_horovod_coco_tpu.obs.analyze.report import (
 # shift data_wait by whole points), so the default band is generous; a
 # real inversion — data_wait% doubling, step% collapsing — still trips it.
 DEFAULT_BAND_ABS = 0.20
-
-
-def _repo_root() -> str:
-    return os.path.dirname(
-        os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-    )
-
-
-def _default_baseline() -> str:
-    return os.path.join(_repo_root(), "PERF_REPORT.json")
 
 
 def _summary_line(report: dict, path: str | None) -> str:
@@ -74,24 +61,21 @@ def _check(fresh: dict, baseline_path: str, band: float) -> int:
             committed = json.load(f)
     except (OSError, ValueError) as e:
         print(
-            f"# perf-report-check: cannot read committed baseline "
+            f"# perf-report-check: cannot read baseline "
             f"{baseline_path!r}: {e}"
         )
         return 1
     problems = validate_report(committed)
     if problems:
-        print(
-            f"# perf-report-check: committed baseline invalid: {problems} "
-            "— re-capture with `make perf-report-check` after fixing"
-        )
+        print(f"# perf-report-check: baseline invalid: {problems}")
         return 1
     fresh_dev = (fresh.get("source") or {}).get("device_kind")
     committed_dev = (committed.get("source") or {}).get("device_kind")
     if committed_dev != fresh_dev:
-        # bench-check's device-class guard: fractions shift with the
-        # host/device balance, so cross-class comparison is meaningless.
+        # Fractions shift with the host/device balance, so cross-class
+        # comparison is meaningless.
         print(
-            f"# perf-report-check: committed report was captured on "
+            f"# perf-report-check: the baseline was captured on "
             f"{committed_dev!r} but this run is on {fresh_dev!r}; "
             "attribution fractions are not comparable across device "
             "classes — re-capture the baseline on this device"
@@ -112,7 +96,7 @@ def _check(fresh: dict, baseline_path: str, band: float) -> int:
         delta = got - want
         verdict = "ok" if abs(delta) <= band else "REGRESSION"
         print(
-            f"# perf-report-check: {key}: {got:.3f} vs committed "
+            f"# perf-report-check: {key}: {got:.3f} vs baseline "
             f"{want:.3f} (band ±{band:.2f}): {verdict}"
         )
         if verdict != "ok":
@@ -128,8 +112,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("obs_dir", help="observability artifact directory "
                                     "(as left by an --obs-trace run)")
     ap.add_argument("--trace", default="trace.json",
-                    help="trace file name inside obs_dir (bench runs "
-                         "write bench_<mode>_trace.json)")
+                    help="trace file name inside obs_dir")
     ap.add_argument("--events", default="metrics.jsonl",
                     help="events JSONL name inside obs_dir (enrichment; "
                          "analysis proceeds without it)")
@@ -148,18 +131,12 @@ def main(argv: list[str] | None = None) -> int:
                     help="report path (default <obs_dir>/PERF_REPORT.json)")
     ap.add_argument("--print", action="store_true", dest="print_report",
                     help="print the full report to stdout as well")
-    ap.add_argument("--check", nargs="?", const="", default=None,
-                    metavar="BASELINE",
-                    help="perf-report-check mode: schema-validate and "
-                         "enforce the attribution-fraction band against "
-                         "BASELINE (default: the committed repo-root "
-                         "PERF_REPORT.json)")
-    ap.add_argument("--band", type=float,
-                    default=float(
-                        os.environ.get("PERF_BAND_ABS", str(DEFAULT_BAND_ABS))
-                    ),
-                    help="absolute per-fraction band for --check "
-                         "(env PERF_BAND_ABS)")
+    ap.add_argument("--check", default=None, metavar="BASELINE",
+                    help="schema-validate and enforce the "
+                         "attribution-fraction band against BASELINE, "
+                         "an earlier report")
+    ap.add_argument("--band", type=float, default=DEFAULT_BAND_ABS,
+                    help="absolute per-fraction band for --check")
     args = ap.parse_args(argv)
 
     try:
@@ -190,7 +167,7 @@ def main(argv: list[str] | None = None) -> int:
     print(_summary_line(report, out))
 
     if args.check is not None:
-        return _check(report, args.check or _default_baseline(), args.band)
+        return _check(report, args.check, args.band)
     problems = validate_report(report)
     if problems:
         print(f"# obs.analyze: report failed schema validation: {problems}")

@@ -23,8 +23,7 @@ function from a run's own artifacts to
 - **an MFU estimate** — the XLA-counted step FLOPs the train loop records
   at each compile point (``cost_analysis`` trace instants, from the
   unoptimized lowering — no second backend compile) against the device's
-  peak TFLOP/s, so the roofline number exists per RUN, not only per
-  bench;
+  peak TFLOP/s, so the number exists per RUN;
 - **a ranked top-3 bottleneck verdict** — each entry names the spans to
   stare at in Perfetto and the ``tune/`` problems (``nms``, ``focal``,
   ``matching``, ``batch``) the next optimization PR should search;
@@ -47,7 +46,7 @@ function from a run's own artifacts to
 Determinism contract: the report is a pure function of the artifact
 files — no wall clocks, no environment probes (the peak-TFLOPs env
 override excepted), floats rounded through one helper — so the inline
-auto-emit at ``train.py``/``bench.py`` finalize and the offline CLI
+auto-emit at ``train.py``'s finalize and the offline CLI
 (``python -m batchai_retinanet_horovod_coco_tpu.obs.analyze <obs_dir>``)
 produce byte-identical files from the same obs dir (pinned against the
 committed fixture in tests/unit/test_analyze.py).
@@ -75,8 +74,7 @@ from batchai_retinanet_horovod_coco_tpu.obs.events import (
 # v2 (ISSUE 9): + the ``violations`` section and its slo:* verdicts.
 SCHEMA_VERSION = 3
 
-# Peak dense bf16 TFLOP/s per chip by device kind (public spec sheets) —
-# THE table, shared with bench.py's MFU line (one source of truth).
+# Peak dense bf16 TFLOP/s per chip by device kind (public spec sheets).
 PEAK_TFLOPS = (
     ("v5 lite", 197.0),  # v5e
     ("v5e", 197.0),
@@ -785,7 +783,7 @@ def _bottlenecks(
     """Ranked verdicts, scores all expressed as fractions of the main
     window so they are mutually comparable.  Non-empty whenever the trace
     carries any span at all (the generic fallback ranks raw span
-    families when the train vocabulary is absent — bench traces).
+    families when the train vocabulary is absent).
 
     SLO violations outrank everything inferred: a breach of a DECLARED
     objective is evidence by fiat, so each violated rule contributes a
@@ -874,7 +872,7 @@ def _bottlenecks(
             }
         )
     # Pipeline fetch-blocking verdicts exist with or WITHOUT a train loop
-    # (a bench eval/serve trace has no `step` spans, but its fetch
+    # (an eval/serve trace has no `step` spans, but its fetch
     # blocking IS the detect-ceiling evidence tune/ exists to attack):
     # normalized by the loop window when one exists, else by the
     # pipeline's own wall.
@@ -915,7 +913,7 @@ def _bottlenecks(
             }
         )
     if steps is None:
-        # No train loop in this trace (bench/serve/tune artifacts): also
+        # No train loop in this trace (eval/serve/tune artifacts): also
         # rank raw span families by their share of the span-covered
         # wall, skipping families a pipeline verdict already claims.
         claimed = {s for c in cands for s in c["spans"]}
@@ -1108,8 +1106,7 @@ def analyze_dir(
     """The offline entrypoint: an obs dir (as left by a --obs-trace run)
     → the report dict.  The trace is required; the events JSONL is
     enrichment (MFU falls back to trace instants, run metadata degrades
-    to None).  ``events_name=None`` skips the JSONL entirely — the bench
-    emitters use this: bench never writes events, and a shared obs dir
+    to None).  ``events_name=None`` skips the JSONL entirely: a shared obs dir
     may hold a PREVIOUS train run's metrics.jsonl whose header/compile
     records must not be attributed to this trace.  A NUMERICS_DUMP.json
     next to the trace (the loop's abort-path artifact) is
@@ -1519,10 +1516,9 @@ def analyze_fleet_dir(
 
 
 def span_attribution(events: list[dict]) -> dict | None:
-    """Compact attribution for an in-process event snapshot — the piece
-    ``bench.py --trace`` folds into its committed JSON line so the
-    BENCH_rNN trajectory carries data_wait%/overlap% history, not bare
-    imgs/s.  None when there is nothing to attribute."""
+    """Compact attribution for an in-process event snapshot
+    (``trace.snapshot_events()``).  None when there is nothing to
+    attribute."""
     spans = _spans_by_name(events)
     all_spans = [e for group in spans.values() for e in group]
     if not all_spans:
@@ -1566,7 +1562,7 @@ def write_report(report: dict, path: str) -> str:
 
 def validate_report(report: Any) -> list[str]:
     """Structural schema check → list of problems (empty = valid).  Used
-    by the CLI, perf-report-check, and the fixture tests."""
+    by the CLI and the fixture tests."""
     problems: list[str] = []
     if not isinstance(report, dict):
         return ["report is not an object"]
@@ -1651,7 +1647,7 @@ def auto_emit(
     sink: Any | None = None,
     events_name: str | None = "metrics.jsonl",
 ) -> str | None:
-    """The finalize-path hook (train.py / bench.py): analyze + write the
+    """The finalize-path hook (train.py): analyze + write the
     report next to the trace.  NEVER raises — a run that trained for
     hours must not die in its post-mortem; failure is ONE structured
     ``perf_report_error`` event (to ``sink`` when given, and stderr

@@ -1,8 +1,7 @@
 """Unified structured-event sink: one JSONL stream for metrics AND events.
 
-Subsumes the 89-line ``utils/metrics.MetricLogger`` (which survives as a
-thin compat shim over this class) and fixes its two recorded holes
-(ISSUE 3 satellites):
+Grew out of the 89-line ``MetricLogger`` of PR 3 and fixes its two
+recorded holes (ISSUE 3 satellites):
 
 - ``_scalarize`` silently dropped non-castable metrics and let non-finite
   ones through indistinguishably.  A NaN loss is the single most
@@ -171,8 +170,7 @@ def _device_header_fields() -> dict[str, Any]:
 class EventSink:
     """Process-0 structured sink: JSONL + stdout + optional TensorBoard.
 
-    Surface-compatible superset of the old ``MetricLogger`` (``log``,
-    ``close``); adds ``event``/``gauge``/``log_device_memory`` and writes
+    ``log`` and ``close``, ``event``/``gauge``/``log_device_memory``; writes
     the ``run_header`` record on open."""
 
     def __init__(
@@ -236,7 +234,7 @@ class EventSink:
                 self._jsonl.write(json.dumps(rec) + "\n")
                 self._jsonl.flush()
 
-    # ---- the MetricLogger surface ---------------------------------------
+    # ---- metrics ---------------------------------------------------------
 
     def log(self, step: int, metrics: Mapping[str, Any], prefix: str = "train") -> None:
         if not self._enabled:

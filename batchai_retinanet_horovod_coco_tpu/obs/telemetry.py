@@ -38,7 +38,7 @@ Windowed histograms are encoded as *summary* families (quantile series
 from ``obs.events.latency_percentiles`` — one quantile implementation
 repo-wide) plus ``_count``/``_sum`` over the window; counters and gauges
 are the plain families.  ``parse_exposition`` is the matching reader the
-bench consistency check and the smoke's schema check use.
+smoke's schema check uses.
 """
 
 from __future__ import annotations
@@ -427,9 +427,9 @@ def parse_exposition(text: str) -> tuple[dict[str, str], dict[str, float]]:
     """The matching reader for ``prometheus_text``: returns
     ``(types, samples)`` where ``types`` maps family name → TYPE and
     ``samples`` maps the raw sample key (``name`` or ``name{...}``) →
-    float value.  Consumed by the bench consistency check and the
-    telemetry smoke's schema check; unparseable lines are skipped (a
-    schema check then fails on the MISSING family, loudly)."""
+    float value.  Consumed by the telemetry smoke's schema check;
+    unparseable lines are skipped (a schema check then fails on the
+    MISSING family, loudly)."""
     types: dict[str, str] = {}
     samples: dict[str, float] = {}
     for line in text.splitlines():

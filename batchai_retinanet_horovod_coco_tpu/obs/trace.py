@@ -3,7 +3,7 @@
 The attribution half of the observability subsystem (ISSUE 3): PRs 1–2 grew
 four concurrent machines (shm decode workers, the device-prefetch thread,
 the eval consumer, async mid-training eval) whose interleaving decides
-whether the chips are fed — and ``bench.py`` can only measure end-to-end.
+whether the chips are fed — and a benchmark can only measure end-to-end.
 This module records *where the time went*: named spans, ring-buffered per
 thread, exported as Chrome ``trace_event`` JSON that Perfetto/``chrome://
 tracing`` renders as one aligned timeline with a track per thread and a
@@ -492,9 +492,8 @@ def _chrome_events() -> Iterator[dict]:
 
 def snapshot_events() -> list[dict]:
     """This process's recorded events as Chrome ``trace_event`` dicts —
-    the export payload without the file.  The inline analysis hooks
-    (``obs.analyze.span_attribution`` in ``bench.py --trace``) read the
-    live rings through this; empty while tracing is disabled."""
+    the export payload without the file; empty while tracing is
+    disabled."""
     if not _enabled:
         return []
     return list(_chrome_events())

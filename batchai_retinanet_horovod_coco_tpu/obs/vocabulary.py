@@ -24,7 +24,7 @@ literal — no comprehensions, no computed keys).  Entry shape:
   ``"instant"`` (trace.instant), ``"series"`` (telemetry counter/gauge/
   histogram constructors and trace.counter samples).
 - ``consumers`` — repo-relative paths of the files that READ the name
-  (report sections, SLO rules, bench checks, smoke drivers).  Empty means
+  (report sections, SLO rules, smoke drivers).  Empty means
   "emitted for ad-hoc analysis"; the rule only checks listed paths.
 
 Runtime code may import :data:`VOCABULARY` (stdlib-only, jax-free) but
@@ -210,7 +210,6 @@ VOCABULARY: dict[str, dict] = {
         "kinds": ("instant",),
         "consumers": (
             "batchai_retinanet_horovod_coco_tpu/obs/analyze/report.py",
-            "bench.py",
         ),
     },
     "serve.admission_qsize": {
@@ -237,7 +236,6 @@ VOCABULARY: dict[str, dict] = {
         "kinds": ("series",),
         "consumers": (
             "batchai_retinanet_horovod_coco_tpu/obs/analyze/report.py",
-            "bench.py",
             "scripts/telemetry_smoke.py",
         ),
     },

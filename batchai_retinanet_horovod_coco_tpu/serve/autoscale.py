@@ -521,7 +521,7 @@ class Autoscaler:
 
 class LocalLauncher:
     """In-process launcher over ``LocalReplica`` handles — the unit-test
-    and bench actuator (the fleet CLI uses its subprocess launcher).
+    actuator (the fleet CLI uses its subprocess launcher).
 
     ``factory(replica_id)`` builds one replica handle; ``terminate`` is
     deliberately lazy (the router's ``begin_drain`` already unroutes the

@@ -16,8 +16,7 @@ preprocess router, per-bucket dynamic batcher, one-behind device dispatcher
   error) from "the server is broken" (worker crash, ``ServerError``);
 - ``LatencyStats`` — the thread-safe completed/shed/timeout counters and
   the bounded latency window the p50/p99 numbers come from (emitted into
-  the obs event sink by the frontend, reported by ``bench.py --mode
-  serve``).
+  the obs event sink by the frontend).
 """
 
 from __future__ import annotations
@@ -84,7 +83,7 @@ class ServeConfig:
     # device can take it (the dispatch gate) OR at the deadline,
     # whichever first, so the device never idles waiting for a "full"
     # batch.  False = the classic deadline-only coalescing (seal only at
-    # full/deadline), kept alive for comparison benches and as the
+    # full/deadline), kept alive for comparison and as the
     # conservative fallback; both modes run on the same slot pool and
     # produce bit-identical detections — only WHEN rows ride changes.
     continuous: bool = True

@@ -10,8 +10,7 @@ small table of them.  Two constructors:
   in, NO model code needed.  Routing metadata (buckets, batch sizes,
   resize rule, label→category mapping) comes from the manifest.
 - ``from_state(model, state, ...)`` — live params, AOT-compiled through
-  the same ``evaluate.detect.compile_detect_fn`` path the eval bench
-  uses, so a serve executable can never drift from the benched one.
+  ``evaluate.detect.compile_detect_fn``.
 
 Both AOT-build every executable at construction and ``warmup()`` runs
 each once on zeros — no request ever pays a compile (SURVEY.md §7.3's

@@ -138,8 +138,8 @@ def drift_frames(
     pixel delta between consecutive frames is ≈ ``step`` — the delta
     cache's hit/miss dial), with an optional hard "scene cut" every
     ``cut_every`` frames (a large jump: guaranteed cache miss AND a
-    track break).  Pure function of ``seed`` — the streaming tests,
-    smoke, and SERVEBENCH leg all replay identical footage."""
+    track break).  Pure function of ``seed`` — the streaming tests
+    and smoke replay identical footage."""
     rng = np.random.default_rng(seed)
     v = float(rng.integers(30, 90))
     frames = []

@@ -39,13 +39,12 @@ from batchai_retinanet_horovod_coco_tpu.train.step import (
 )
 from batchai_retinanet_horovod_coco_tpu.obs import telemetry, trace, watchdog
 from batchai_retinanet_horovod_coco_tpu.obs import numerics as numerics_lib
-from batchai_retinanet_horovod_coco_tpu.obs.events import device_memory_stats
+from batchai_retinanet_horovod_coco_tpu.obs.events import EventSink, device_memory_stats
 from batchai_retinanet_horovod_coco_tpu.obs.numerics import NumericsConfig
 from batchai_retinanet_horovod_coco_tpu.obs.trace import monotonic_s
 from batchai_retinanet_horovod_coco_tpu.train.state import model_variables
 from batchai_retinanet_horovod_coco_tpu.train.task import DetectionTask
 from batchai_retinanet_horovod_coco_tpu.utils.checkpoint import CheckpointManager
-from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
 
 # Every obs/trace.py span of this process is from here on a profiler
 # annotation too ("rn.data_wait", "rn.step", "rn.device-prefetch", ...):
@@ -534,9 +533,8 @@ def run_training(
     anchor_config=None,
     schedule: Callable[[int], float] | None = None,
     eval_fn: Callable[[TrainState], dict[str, float]] | None = None,
-    logger: MetricLogger | None = None,
+    logger: EventSink | None = None,
     shard_weight_update: bool = False,
-    quantized_allreduce: bool = False,
     comm=None,
     topology=None,
     allow_data_axis_divergence: bool = False,
@@ -556,7 +554,7 @@ def run_training(
     communication policy — bucketed int8/bf16 compression with error
     feedback, optional backward overlap; composes with
     ``shard_weight_update`` (the compression moves to the ZeRO update
-    gather).  ``quantized_allreduce`` is the deprecated bool alias.
+    gather).
     ``topology`` (a ``parallel.mesh.CommTopology``, ISSUE 16) makes the
     comm collective hierarchical — exact within each ICI slice,
     compressed only on the cross-slice DCN hop (train/step.py).
@@ -571,12 +569,12 @@ def run_training(
     if spatial and not isinstance(task, DetectionTask):
         raise ValueError(f"spatial partitioning shards images: not the {task.name} task")
     comm_on = comm is not None and getattr(comm, "enabled", False)
-    if spatial and (shard_weight_update or quantized_allreduce or comm_on):
+    if spatial and (shard_weight_update or comm_on):
         raise ValueError(
             "spatial partitioning is exclusive with --shard-weight-update "
-            "and --comm-compress/--quantized-allreduce"
+            "and --comm-compress"
         )
-    logger = logger or MetricLogger(log_dir=None)
+    logger = logger or EventSink(log_dir=None)
     ckpt = None
     if config.checkpoint_every and config.checkpoint_dir:
         ckpt = CheckpointManager(
@@ -787,7 +785,6 @@ def run_training(
                             matching_config=matching_config,
                             anchor_config=anchor_config,
                             shard_weight_update=shard_weight_update,
-                            quantized_allreduce=quantized_allreduce,
                             comm=comm,
                             topology=topology,
                             numerics=numerics_config,

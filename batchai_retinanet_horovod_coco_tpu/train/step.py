@@ -238,7 +238,6 @@ def make_train_step(
     anchor_config: anchors_lib.AnchorConfig | None = None,
     donate_state: bool = True,
     shard_weight_update: bool = False,
-    quantized_allreduce: bool = False,
     comm=None,
     topology=None,
     numerics: NumericsConfig | None = None,
@@ -251,67 +250,49 @@ def make_train_step(
     ``matching_config`` and ``anchor_config``, and ``image_hw`` is the
     task's bucket; every flavour below differentiates the task's loss.
 
-    With ``mesh``: the step is a ``shard_map`` over the mesh — the batch is
-    consumed shard-by-shard (each device sees batch/n_devices images),
-    gradients and metrics are ``lax.pmean``-ed over the ``data`` axis, and
-    every device applies the identical update to its replicated state.
+    ``mesh``: the step is a ``shard_map`` over it; each device consumes
+    batch/n_devices examples, gradients and metrics are ``lax.pmean``-ed
+    over the ``data`` axis and every device applies the same update to its
+    replicated state.  Unset: a single-device jit.
 
-    Without ``mesh``: plain single-device jit (BASELINE.json configs[1]).
+    ``donate_state``: the step donates its input state's buffers.
 
-    ``shard_weight_update`` (requires ``mesh``): ZeRO-style mode — gradients
-    reduce-scatter instead of all-reduce, each device updates its 1/N of the
-    params with its 1/N optimizer-state shard, updated params all-gather
-    back (parallel/zero.py).  ``state.opt_state`` must come from
-    ``init_sharded_opt_state`` and ``state.tx`` from
-    ``make_optimizer(..., shard_clip_axis=DATA_AXIS)`` so gradient clipping
-    uses the global (cross-shard) norm.
+    ``shard_weight_update`` (requires ``mesh``): gradients reduce-scatter,
+    each device updates its 1/N of the params with its 1/N optimizer-state
+    shard, updated params all-gather back (parallel/zero.py).
+    ``state.opt_state`` must come from ``init_sharded_opt_state`` and
+    ``state.tx`` from ``make_optimizer(..., shard_clip_axis=DATA_AXIS)`` so
+    clipping uses the global norm.
 
     ``comm`` (a ``comm.CommConfig``; requires ``mesh``): the gradient-
-    communication policy (ISSUE 13).  On the plain-DP path the all-reduce
-    becomes the bucketed, error-feedback int8/bf16 scheme of
-    ``comm/compress.py`` (exact f32 reduce-scatter, EF add-back from
-    ``state.comm_state``, per-block compressed gather; with
-    ``comm.overlap`` each schedule stage's collective is issued inside
-    the backward via ``comm/overlap.py``).  Combined with
+    communication policy.  The all-reduce becomes the bucketed int8/bf16
+    scheme of ``comm/compress.py`` (exact f32 reduce-scatter, error
+    feedback from ``state.comm_state`` where the state carries it,
+    compressed gather; with ``comm.overlap`` each stage's collective is
+    issued inside the backward, ``comm/overlap.py``).  With
     ``shard_weight_update`` the gradient reduce-scatter stays exact and
-    the compression moves to the ZeRO param gather (quantized UPDATE
-    gather with per-leaf EF — the old exclusivity is lifted).  The
-    pre-clip ``grad_norm`` is computed on the DEQUANTIZED gradients, so
-    the clip chain acts on the values the optimizer actually consumes.
-    EF health lands in the metrics (``ef_residual_norm`` /
-    ``ef_saturation`` / ``comm_compressed_bytes``).  With ``comm`` unset
-    (or ``compress="none"``) the compiled step is byte-identical to the
-    pre-ISSUE-13 program.
+    the update gather is compressed.  ``grad_norm`` is taken on the
+    dequantized gradients, and ``ef_residual_norm`` / ``ef_saturation`` /
+    ``comm_compressed_bytes`` join the metrics.  Unset or
+    ``compress="none"``: the exact step, the same program.
 
-    ``topology`` (a ``parallel.mesh.CommTopology``; ISSUE 16): the
-    two-level slice x intra-slice device grouping.  When it names more
-    than one slice AND ``comm``'s per-hop modes differ, the gradient
-    collective becomes the HIERARCHICAL tree — exact f32
-    reduce-scatter within each ICI slice, quantized exchange only on
-    the cross-slice DCN hop, exact intra-slice all-gather — with the
-    EF residuals keyed per hop and the wire accounting split into
-    ``comm_ici_bytes`` / ``comm_dcn_bytes``.  Otherwise the hierarchy
-    degenerates and the step compiles the FLAT tree at the effective
-    single-hop mode, byte-identical to passing no topology at all
-    (single-slice worlds run the whole tree at ``ici_mode``, i.e.
-    exact by default — there is no slow wire to compress).  The mesh
-    must be built with the same topology (``make_mesh(..., topology)``)
-    so slice-index devices sit in the interleaved order the groups
-    assume.  ZeRO runs ignore the topology (the update gather stays
-    flat) with a structured warning.
+    ``topology`` (a ``parallel.mesh.CommTopology``): the slice x
+    intra-slice grouping.  With more than one slice and per-hop modes
+    that differ, the collective is the hierarchical tree (exact within a
+    slice, compressed across slices; ``comm_ici_bytes`` /
+    ``comm_dcn_bytes``); otherwise the flat tree at the effective mode,
+    the same program as with no topology.  The mesh must be built with
+    the same topology (``make_mesh(..., topology)``).  A
+    ``shard_weight_update`` step ignores it, with a warning.
 
-    ``quantized_allreduce``: DEPRECATED alias for
-    ``comm=CommConfig(compress="int8")`` (stateless unless the state
-    carries EF residuals) — the pre-ISSUE-13 per-leaf path is gone.
+    ``numerics`` (obs/numerics.py): the fused in-step numerics summary —
+    update/param ratio, non-finite gradient count, per-group norms and, on
+    a mesh, the cross-replica agreement probe.  Unset: the same program
+    as without it.
 
-    ``numerics`` (obs/numerics.py): enable the fused in-step numerics
-    summary — update/param ratio, non-finite gradient count, per-layer-
-    group norms, and (mesh steps) the cross-replica agreement probe.
-    Disabled (the default) the compiled program is unchanged.
-
-    The returned callable takes (state, batch_dict) where batch_dict holds
-    ``images, gt_boxes, gt_labels, gt_mask`` (leading axis = GLOBAL batch)
-    and returns (new_state, metrics).
+    The returned callable takes ``(state, batch)``, where ``batch`` holds
+    the task's fields with the GLOBAL batch on the leading axis, and
+    returns ``(new_state, metrics)``.
     """
     numerics = numerics or NumericsConfig()
     if task is None:
@@ -323,15 +304,6 @@ def make_train_step(
         )
     if shard_weight_update and mesh is None:
         raise ValueError("shard_weight_update requires a mesh")
-    if quantized_allreduce and mesh is None:
-        raise ValueError("quantized_allreduce requires a mesh")
-    if quantized_allreduce and comm is None:
-        # Deprecated alias (ISSUE 13): the bool maps onto the comm
-        # subsystem's int8 policy.  EF engages iff the caller's state
-        # carries comm residuals (comm.init_comm_state).
-        from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
-
-        comm = CommConfig(compress="int8")
     # Hop-policy resolution (ISSUE 16): the hierarchical tree engages
     # only for a real multi-slice topology with distinct per-hop modes;
     # every other case resolves to the flat tree BEFORE tracing so the

@@ -12,7 +12,7 @@ per-bucket batch sizes:
   ONE loud structured event, never a crash.  Import-light (no jax).
 - ``candidates`` — candidate generation per op family.
 - ``search``     — the timed search harness: AOT-compile each candidate,
-  two disjoint timed windows (bench.py's noise policy), trial spans/events
+  two disjoint timed windows, trial spans/events
   through obs, winner composition into a registry artifact.
 
 Consumers look winners up instead of hardcoding: ``train/step.py``
@@ -26,7 +26,6 @@ manifest).  CLI: ``python -m batchai_retinanet_horovod_coco_tpu.tune``
 from batchai_retinanet_horovod_coco_tpu.tune.schedule import (
     DEFAULT_SCHEDULE,
     ScheduleError,
-    eval_batch_for,
     load_schedule,
     lookup,
     provenance,
@@ -39,7 +38,6 @@ from batchai_retinanet_horovod_coco_tpu.tune.schedule import (
 __all__ = [
     "DEFAULT_SCHEDULE",
     "ScheduleError",
-    "eval_batch_for",
     "load_schedule",
     "lookup",
     "provenance",

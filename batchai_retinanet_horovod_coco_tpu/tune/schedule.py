@@ -278,18 +278,6 @@ def lookup(
     return copy.deepcopy(merged)
 
 
-def eval_batch_for(
-    hw: tuple[int, int],
-    default: int,
-    device_kind: str | None = None,
-    root: str | None = None,
-) -> int:
-    """Per-bucket eval batch size from the device's schedule (bench
-    ``--mode eval``'s consumer); ``default`` when the bucket is untuned."""
-    table = lookup(device_kind, root)["eval"]["batch"]
-    return int(table.get(f"{hw[0]}x{hw[1]}", default))
-
-
 def serve_batch_sizes_for(
     hw: tuple[int, int],
     default: tuple[int, ...],
@@ -306,7 +294,7 @@ def serve_batch_sizes_for(
 def provenance(
     device_kind: str | None = None, root: str | None = None
 ) -> dict[str, Any]:
-    """Where this device's schedule came from (for bench/manifest records):
+    """Where this device's schedule came from (for manifest records):
     ``{"device_kind", "source" (path or "defaults"), "found"}``."""
     kind = _resolve_device_kind(device_kind)
     path = schedule_path(kind, root)
@@ -321,7 +309,7 @@ def provenance(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
     if found and os.path.abspath(path).startswith(repo + os.sep):
-        # Repo-relative in committed records (manifests, BENCH lines):
+        # Repo-relative in committed records (manifests):
         # an absolute sandbox path says nothing to the next machine.
         path = os.path.relpath(path, repo)
     return {

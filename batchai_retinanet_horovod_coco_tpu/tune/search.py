@@ -6,7 +6,7 @@ NMS, ``pre_nms_size``, per-bucket batch sizes — are cheap enough to
 search EXHAUSTIVELY (tune/candidates.py's menus are a handful of entries
 each), so the harness is a measured argmin, not a learned cost model.
 
-Measurement policy is bench.py's, not a new one:
+Measurement policy:
 
 - **AOT compile first** (``jax.jit(...).lower(...).compile()``), so a
   trial never times tracing;
@@ -48,8 +48,8 @@ from batchai_retinanet_horovod_coco_tpu.obs import trace
 from batchai_retinanet_horovod_coco_tpu.tune import candidates as cand_lib
 from batchai_retinanet_horovod_coco_tpu.tune import schedule as schedule_lib
 
-# Matches bench.py's flagship bucket; the search defaults to measuring
-# where the train/serve money is.
+# The flagship bucket (benchmark/configs/retinanet-r50-fpn-800.json): the
+# search defaults to measuring where the train/serve money is.
 DEFAULT_HW = (800, 1344)
 DEFAULT_BATCH = 8
 DEFAULT_STEPS = 30  # per trial, split into two windows
@@ -88,7 +88,7 @@ def mosaic_available() -> bool:
 def time_compiled(fn: Callable[[], Any], steps: int) -> tuple[float, list[float]]:
     """Two disjoint timed windows over an already-compiled nullary call;
     returns (ms_per_call, [window_ms, window_ms]).  Syncs inside each
-    window (bench.py's policy: dispatch half the steps, one hard sync)."""
+    window (dispatch half the steps, one hard sync)."""
     half = max(1, steps // 2)
     window_ms: list[float] = []
     for _ in range(2):
@@ -142,14 +142,13 @@ def run_trial(
 
 
 # ---------------------------------------------------------------------------
-# Per-op trial programs (synthetic inputs, bench.py's distributions)
+# Per-op trial programs (synthetic inputs)
 # ---------------------------------------------------------------------------
 
 
 def _postprocess_inputs(batch: int, hw: tuple[int, int]):
-    """The NMS search's input field: bench.run_postprocess_bucket's
-    realistic sparse score distribution (sigmoid(-4 ± 1) ≈ 2% foreground)
-    over the flagship anchor grid."""
+    """The NMS search's input field: a sparse score distribution
+    (sigmoid(-4 ± 1) ≈ 2% foreground) over the flagship anchor grid."""
     from batchai_retinanet_horovod_coco_tpu.evaluate.detect import DetectConfig
     from batchai_retinanet_horovod_coco_tpu.ops import anchors as anchors_lib
 
@@ -357,9 +356,8 @@ def search_batch(
     batch.  ``nms_entry`` (the just-searched NMS winner, when given) pins
     the suppression backend so the batch axis measures the tuned kernel.
 
-    NOTE: this measures the postprocess program only (no backbone) — on a
-    chip, confirm the winner end-to-end with ``bench.py --mode eval``
-    before committing it; the RUNBOOK section spells out the workflow.
+    NOTE: this measures the postprocess program only (no backbone); no
+    benchmark cell runs eval yet, so a winner has no end-to-end check.
     """
     entry = {"impl": "xla", **(nms_entry or {})}
     trials: list[Trial] = []

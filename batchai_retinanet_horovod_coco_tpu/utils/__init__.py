@@ -12,7 +12,7 @@ import`` here would drag jax into all of them.
 
 from typing import Any
 
-__all__ = ["CheckpointManager", "MetricLogger", "latest_step"]
+__all__ = ["CheckpointManager", "latest_step"]
 
 
 def __getattr__(name: str) -> Any:
@@ -20,10 +20,4 @@ def __getattr__(name: str) -> Any:
         from batchai_retinanet_horovod_coco_tpu.utils import checkpoint
 
         return getattr(checkpoint, name)
-    if name == "MetricLogger":
-        from batchai_retinanet_horovod_coco_tpu.utils.metrics import (
-            MetricLogger,
-        )
-
-        return MetricLogger
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

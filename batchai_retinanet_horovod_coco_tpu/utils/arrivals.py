@@ -1,13 +1,11 @@
 """Seeded open-loop arrival schedules — the shared load-shape vocabulary.
 
-``bench.py --mode serve`` introduced the mixed steady → burst → lull
-schedule as a private helper (ISSUE 14: the load shape that exposes
-deadline-only partial-batch waste); the streaming leg (ISSUE 18) needs
-the SAME generator for per-stream frame traces plus a multi-stream
-composition, and a bench-private copy would drift.  One module, pure
-NumPy, no serve imports — both bench legs and the stream smoke build
-their offered load here, and the unit tests pin determinism per seed
-(same seed ⇒ byte-identical schedule ⇒ comparable runs).
+The mixed steady → burst → lull schedule (the load shape that exposes
+deadline-only partial-batch waste), per-stream frame traces and a
+multi-stream composition.  One module, pure NumPy, no serve imports —
+the autoscale chaos leg builds its offered load here, and the unit
+tests pin determinism per seed (same seed ⇒ byte-identical schedule ⇒
+comparable runs).
 """
 
 from __future__ import annotations
@@ -59,8 +57,8 @@ def diurnal_spike_schedule(
     ``amplitude < 1`` keeps the off-peak rate positive so the schedule
     always terminates.  Exponential inter-arrivals at the instantaneous
     rate, same generator family as ``mixed_arrival_schedule`` — one
-    seed pins the entire offered-load trace, so the chaos leg and the
-    SERVEBENCH autoscale leg replay the identical day."""
+    seed pins the entire offered-load trace, so every run of the chaos
+    leg replays the identical day."""
     if not 0.0 <= amplitude < 1.0:
         raise ValueError(f"amplitude must be in [0, 1), got {amplitude}")
     rng = np.random.default_rng(seed)

@@ -97,8 +97,8 @@ class BackoffPolicy:
         """Call ``fn`` up to ``max_tries`` times, sleeping the schedule
         between failures; returns ``(attempts_used, last_result)``.
 
-        ``ok(result)`` decides success (default: the bench-probe
-        convention — None means reachable, anything else is the error).
+        ``ok(result)`` decides success (default: None means reachable,
+        anything else is the error).
         Exceptions propagate immediately: this is the result-style retry
         loop; wrap the callable if exceptions should count as failures.
         """

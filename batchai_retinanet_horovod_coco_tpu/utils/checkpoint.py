@@ -425,7 +425,7 @@ class CheckpointManager:
       data-order facts ``--resume-elastic`` re-derives from);
     - ``sink``: optional EventSink — the writer emits one structured
       ``ckpt_saved`` event per landed checkpoint (step, write seconds,
-      bytes), the artifact CKPTBENCH and the RUNBOOK triage read;
+      bytes), the artifact the RUNBOOK triage reads;
     - ``restore()`` is world-size-elastic for the optimizer state (see
       module docstring) and returns HOST numpy leaves — placement onto a
       mesh is the caller's job (run_training's replication block).

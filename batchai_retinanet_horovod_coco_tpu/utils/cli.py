@@ -81,13 +81,7 @@ def add_data_pipeline_flags(parser) -> None:
 
 
 def add_comm_flags(parser) -> None:
-    """The gradient-communication flag surface (ISSUE 13, train.py).
-
-    One definition so the chaos harness, COMMBENCH sweep, and any future
-    tool that grows a compressed collective expose identical knobs.
-    ``--quantized-allreduce`` (train.py) survives as a deprecated alias
-    that maps onto ``--comm-compress int8`` with one structured
-    deprecation warning (``make_comm_config``)."""
+    """The gradient-communication flag surface of train.py."""
     parser.add_argument("--comm-compress", default="none",
                         choices=["none", "int8", "bf16"],
                         help="gradient-compression wire format "
@@ -160,33 +154,10 @@ def add_comm_flags(parser) -> None:
 
 
 def make_comm_config(args):
-    """CommConfig (or None) from the flags above + the deprecated
-    ``--quantized-allreduce`` alias.  The alias maps onto the comm
-    subsystem with ONE structured deprecation warning on stderr — the
-    behavior change (bucketed + EF instead of per-leaf, no EF) is
-    announced, never silent."""
-    import json as _json
-    import sys as _sys
-
+    """CommConfig (or None) from the flags above."""
     from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
 
     compress = getattr(args, "comm_compress", "none") or "none"
-    if getattr(args, "quantized_allreduce", False):
-        if compress == "none":
-            compress = "int8"
-        print(
-            _json.dumps({
-                "event": "deprecated_flag",
-                "flag": "--quantized-allreduce",
-                "mapped_to": f"--comm-compress {compress}",
-                "note": (
-                    "the per-leaf quantized allreduce was subsumed by "
-                    "the comm/ subsystem (bucketed, error-feedback; "
-                    "ISSUE 13) — switch to --comm-compress"
-                ),
-            }),
-            file=_sys.stderr, flush=True,
-        )
     overlap = bool(getattr(args, "comm_overlap", False))
     ici_mode = getattr(args, "comm_ici_mode", None)
     dcn_mode = getattr(args, "comm_dcn_mode", None)
@@ -315,9 +286,8 @@ def add_durability_flags(parser) -> None:
 
 
 def add_serve_flags(parser) -> None:
-    """The inference-server flag surface (serve/frontend.py CLI and
-    ``bench.py --mode serve``; ISSUE 4).  One definition so the bench's
-    load generator and the real server can never drift on knob names."""
+    """The inference-server flag surface (serve/frontend.py and
+    serve/fleet.py CLIs)."""
     parser.add_argument("--serve-max-delay-ms", type=float, default=10.0,
                         help="dynamic-batching deadline: a partial batch "
                              "fires at most this long after its first "

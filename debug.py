@@ -24,10 +24,7 @@ subcommand is a thin formatter over ``load_dump``/``format_dump``.
 annotation file alone (COCO records carry width/height; nothing is
 decoded): for every image it applies the reference resize rule + bucket
 pick the pipeline uses (data/pipeline.resize_scale/pick_bucket) and prints
-per-bucket image counts/shares — the measured replacement for the
-estimated COCO aspect shares baked into bench.py's weighted mix.  With
---bucketbench it also recomputes the mix-weighted imgs/s/chip from a
-saved record's per-bucket rates.
+per-bucket image counts/shares.
 """
 
 from __future__ import annotations
@@ -62,11 +59,6 @@ def build_parser() -> argparse.ArgumentParser:
     bk.add_argument("annotation_file")
     bk.add_argument("--image-min-side", type=int, default=800)
     bk.add_argument("--image-max-side", type=int, default=1333)
-    bk.add_argument(
-        "--bucketbench", default=None,
-        help="path to a saved `python bench.py` JSON line; recompute its "
-        "weighted_mix with the measured shares",
-    )
     for sp in (coco, synth):
         sp.add_argument("--limit", type=int, default=8)
         sp.add_argument("--image-min-side", type=int, default=800)
@@ -115,64 +107,12 @@ def bucket_shares(
 
 
 def _run_buckets(args) -> dict:
-    import json
-
     shares = bucket_shares(
         args.annotation_file, args.image_min_side, args.image_max_side
     )
     for name, row in shares.items():
         print(f"{name}: {row['count']} images ({row['share']:.1%})")
-    out = {"shares": shares}
-    if args.bucketbench:
-        with open(args.bucketbench) as f:
-            bench = json.load(f)
-        # Accept both key spellings: a saved `python bench.py` JSON line
-        # ("per_bucket") and the long form older records used.
-        rates = bench.get("per_bucket_imgs_per_sec_per_chip") or bench.get(
-            "per_bucket"
-        )
-        if rates is None:
-            raise SystemExit(
-                f"{args.bucketbench}: no per-bucket rates found (expected "
-                "'per_bucket_imgs_per_sec_per_chip' or bench.py's "
-                "'per_bucket')"
-            )
-        recorded = bench.get(
-            "weighted_mix_imgs_per_sec_per_chip", bench.get("weighted_mix")
-        )
-        missing = [
-            name
-            for name, row in shares.items()
-            if row["share"] > 0 and name not in rates
-        ]
-        if missing:
-            raise SystemExit(
-                f"{args.bucketbench} has no rate for bucket(s) {missing} "
-                f"(it records {sorted(rates)}): the bench was recorded at "
-                "a different --image-min-side/--image-max-side bucket "
-                "config — re-run bench.py at this config first"
-            )
-        # Harmonic mix: average seconds/image under the measured shares.
-        cost = sum(
-            row["share"] / rates[name]
-            for name, row in shares.items()
-            if row["share"] > 0
-        )
-        mix = 1.0 / cost if cost else None
-        out["weighted_mix_imgs_per_sec_per_chip"] = mix
-        if mix is None:
-            print("no images landed in any bucket; weighted mix undefined")
-        else:
-            note = (
-                f" (recorded estimate: {recorded})"
-                if recorded is not None
-                else ""
-            )
-            print(
-                f"mix-weighted rate at these shares: {mix:.2f} "
-                f"imgs/s/chip{note}"
-            )
-    return out
+    return {"shares": shares}
 
 
 def _run_nans(args) -> dict:

@@ -32,15 +32,12 @@ promises (utils/checkpoint.py):
   devices and additionally requires the surviving residual leaves to be
   keyed per hop (``@dcn``) — proving the per-hop EF state survives
   SIGKILL + resume.
-- **CKPTBENCH** (``--bench``) — measures the two durability numbers the
-  ROADMAP asks for: save overhead (wall time of N checkpointed steps vs
-  the same N without) and time-to-first-step on resume; writes
-  CKPTBENCH.json.  ``--check`` re-measures against the committed
-  artifact.  Every training subprocess runs ``--platform cpu``: the
-  harness never touches an accelerator.
+
+Every training subprocess runs ``--platform cpu``: the harness never
+touches an accelerator.
 
 Modes: ``--smoke`` (one mid-save kill + one NaN leg; the check-static
-CI leg), default full schedule (>= 20 kills), ``--bench``/``--check``.
+CI leg), default full schedule (>= 20 kills).
 Exit 0 = contract held; 1 = violation (each printed as one
 ``chaos FAIL:`` line).
 """
@@ -1205,156 +1202,6 @@ def run_autoscale_legs() -> None:
 
 
 # ---------------------------------------------------------------------------
-# CKPTBENCH
-# ---------------------------------------------------------------------------
-
-
-def _wall_of_steps(metrics_path: str, first: int, last: int) -> float | None:
-    """Wall seconds from step ``first`` to ``last`` via the records'
-    sink-relative wall_s stamps (one clock per run)."""
-    recs = {
-        int(r["step"]): r.get("wall_s")
-        for r in _records(metrics_path)
-        if "step" in r and "event" not in r
-    }
-    if recs.get(first) is None or recs.get(last) is None:
-        return None
-    return float(recs[last]) - float(recs[first])
-
-
-def _last_run_segment(metrics_path: str) -> list[dict]:
-    runs: list[list[dict]] = []
-    for rec in _records(metrics_path):
-        if rec.get("event") == "run_header":
-            runs.append([])
-        if runs:
-            runs[-1].append(rec)
-    return runs[-1] if runs else []
-
-
-def run_bench(check_mode: bool, out_path: str) -> int:
-    steps = int(os.environ.get("CKPTBENCH_STEPS", "10"))
-
-    # Leg A: save overhead — same stream, with and without checkpointing.
-    plain = _fresh_workdir("bench_plain")
-    cmd = _base_cmd(plain, steps)
-    cmd.remove("--snapshot-path")
-    cmd.remove(os.path.join(plain, "ckpt"))
-    r = _run(cmd)
-    check(r.returncode == 0, f"bench plain run failed rc={r.returncode}")
-    wall_plain = _wall_of_steps(
-        os.path.join(plain, "logs", "metrics.jsonl"), 1, steps
-    )
-
-    ck = _fresh_workdir("bench_ckpt")
-    r = _run(_base_cmd(ck, steps) + ["--checkpoint-every", "1"])
-    check(r.returncode == 0, f"bench ckpt run failed rc={r.returncode}")
-    ck_metrics = os.path.join(ck, "logs", "metrics.jsonl")
-    wall_ckpt = _wall_of_steps(ck_metrics, 1, steps)
-    saves = _events(ck_metrics, "ckpt_saved")
-    write_s = [float(e["write_s"]) for e in saves if "write_s" in e]
-    ckpt_bytes = saves[-1].get("bytes") if saves else None
-
-    # Leg B: resume time-to-first-step (restore + compile + first step),
-    # measured from the resumed run's own clock (run_header at 0).
-    r = _run(_base_cmd(ck, steps + 2, ["--resume-elastic"]))
-    check(r.returncode == 0, f"bench resume run failed rc={r.returncode}")
-    seg = _last_run_segment(ck_metrics)
-    first_step = next(
-        (rec for rec in seg if "step" in rec and "event" not in rec), None
-    )
-    restored = [rec for rec in seg if rec.get("event") == "ckpt_restored"]
-    time_to_first_step = (
-        float(first_step["wall_s"]) if first_step else None
-    )
-    restore_s = float(restored[0]["restore_s"]) if restored else None
-
-    overhead_pct = None
-    if wall_plain and wall_ckpt:
-        overhead_pct = round((wall_ckpt - wall_plain) / wall_plain * 100, 2)
-    record = {
-        "bench": "ckptbench",
-        "schema_version": 1,
-        "device_kind": "cpu",  # _base_cmd pins --platform cpu
-        "steps": steps,
-        "save": {
-            "saves": len(saves),
-            "mean_write_s": round(sum(write_s) / len(write_s), 4)
-            if write_s else None,
-            "bytes": ckpt_bytes,
-            "wall_plain_s": round(wall_plain, 3) if wall_plain else None,
-            "wall_ckpt_s": round(wall_ckpt, 3) if wall_ckpt else None,
-            "overhead_pct": overhead_pct,
-        },
-        "resume": {
-            "time_to_first_step_s": round(time_to_first_step, 3)
-            if time_to_first_step is not None else None,
-            "restore_s": restore_s,
-        },
-        "note": (
-            "CPU capture at the WORST-CASE cadence (checkpoint_every=1): "
-            "on a small shared box the writer competes with the step for "
-            "the same cores and the per-save write exceeds the tiny step "
-            "time, so the one-behind contract serializes on the disk "
-            "write and overhead_pct is an upper bound, not the "
-            "production expectation (chip runs save every O(1000) steps; "
-            "steady-state overhead ~= one device->host snapshot per "
-            "save, amortized).  Wall numbers are host-noise-dominated; "
-            "the check band is wide (CKPTBENCH_BAND) and the "
-            "device-class guard refuses cross-class comparisons"
-        ),
-    }
-    check(bool(write_s), "bench: no ckpt_saved events recorded")
-    check(
-        time_to_first_step is not None,
-        "bench: resume leg produced no first-step record",
-    )
-
-    if not check_mode:
-        from batchai_retinanet_horovod_coco_tpu.utils.atomicio import (
-            atomic_write_text,
-        )
-
-        atomic_write_text(
-            out_path, json.dumps(record, indent=2, sort_keys=True) + "\n"
-        )
-        print(f"# ckptbench record written to {out_path}")
-        print(json.dumps(record), flush=True)
-    else:
-        if not os.path.exists(out_path):
-            check(False, f"--check: no committed {out_path}")
-        else:
-            with open(out_path) as f:
-                committed = json.load(f)
-            if committed.get("device_kind") != record["device_kind"]:
-                print(
-                    f"# ckptbench-check: committed artifact is for "
-                    f"{committed.get('device_kind')!r}, this run is "
-                    f"{record['device_kind']!r} — PASSING with a loud "
-                    "note; re-capture on this device class",
-                    flush=True,
-                )
-            else:
-                band = float(os.environ.get("CKPTBENCH_BAND", "0.75"))
-                for leg, key in (("save", "mean_write_s"),
-                                 ("resume", "time_to_first_step_s")):
-                    was = (committed.get(leg) or {}).get(key)
-                    now = (record.get(leg) or {}).get(key)
-                    if was is None or now is None:
-                        continue
-                    check(
-                        now <= was * (1 + band),
-                        f"--check: {leg}.{key} regressed {was} -> {now} "
-                        f"(> +{band:.0%} band)",
-                    )
-        print(json.dumps({"ckptbench_check": record}), flush=True)
-    if not _failures:
-        shutil.rmtree(plain, ignore_errors=True)
-        shutil.rmtree(ck, ignore_errors=True)
-    return 1 if _failures else 0
-
-
-# ---------------------------------------------------------------------------
 
 
 def main(argv=None) -> int:
@@ -1384,25 +1231,12 @@ def main(argv=None) -> int:
                         "must restore the EF residual state (or cleanly "
                         "zero it with one structured ef_reset event) and "
                         "rejoin the uninterrupted compressed baseline")
-    p.add_argument("--bench", action="store_true",
-                   help="CKPTBENCH: save overhead + time-to-first-step")
-    p.add_argument("--check", action="store_true",
-                   help="with --bench: enforce the committed CKPTBENCH.json")
-    p.add_argument("--out", default=os.path.join(_REPO, "CKPTBENCH.json"))
     p.add_argument("--steps", type=int, default=10,
                    help="target step count for kill legs")
     p.add_argument("--kills-per-phase", type=int, default=4,
                    help="full mode: occurrences per save phase "
                         "(5 phases x 4 = the >= 20-kill schedule)")
     args = p.parse_args(argv)
-
-    if args.bench:
-        rc = run_bench(args.check, args.out)
-        print(json.dumps({
-            "chaos": "ok" if not _failures else "FAIL",
-            "failures": _failures,
-        }), flush=True)
-        return rc
 
     if args.serve:
         run_serve_legs()

@@ -64,7 +64,7 @@ def main(out_dir: str, total_steps: int, die_before_step: int):
         run_training,
     )
     from batchai_retinanet_horovod_coco_tpu.utils import checkpoint as ckpt_lib
-    from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
+    from batchai_retinanet_horovod_coco_tpu.obs.events import EventSink
 
     hw = (64, 64)
     batch_size = 4
@@ -99,7 +99,7 @@ def main(out_dir: str, total_steps: int, die_before_step: int):
             resume=True,
         ),
         mesh=make_mesh(),
-        logger=MetricLogger(os.path.join(out_dir, "logs"), stdout=False),
+        logger=EventSink(os.path.join(out_dir, "logs"), stdout=False),
     )
 
     param_sum = float(

@@ -31,6 +31,7 @@ def main(
     out_dir: str,
     flavor: str = "plain",
 ):
+    from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
     from batchai_retinanet_horovod_coco_tpu.data.pipeline import Batch
     from batchai_retinanet_horovod_coco_tpu.launch import (
         DistributedConfig,
@@ -114,7 +115,7 @@ def main(
         LoopConfig(total_steps=3, log_every=0), mesh=mesh,
         # "quantized": the int8-gather allreduce flavor in a REAL 2-process
         # world (VERDICT r2 missing #3 — it only ever ran single-process).
-        quantized_allreduce=(flavor == "quantized"),
+        comm=CommConfig(compress="int8") if flavor == "quantized" else None,
     )
 
     loss_like = float(

@@ -1,15 +1,13 @@
-"""The deprecated ``quantized_pmean`` shim (parallel/quantize.py) on the
-8-dev CPU mesh.
+"""The stateless int8 reduce (``comm.compress.bucketed_pmean``: no error
+feedback, the plan built from the tree) on the 8-dev CPU mesh, and the
+train step that runs it when the state carries no residuals
+(``make_train_step(comm=CommConfig(compress="int8"))``, the 2-process pod
+worker's "quantized" flavour).
 
-ISSUE 13 subsumed the per-leaf quantized allreduce into the comm/
-subsystem; this file pins the COMPAT surface — the shim (and the
-``make_train_step(quantized_allreduce=True)`` alias the 2-process pod
-worker still uses) must keep the old contract: exact-reduce-then-
-quantize error bound, small leaves exact (now via the undersized-bucket
-rule instead of the per-leaf ``_MIN_QUANTIZE_SIZE`` blind spot), and
-non-finite gradients surfacing as NaN.  The subsystem's own claims
-(bucketing, error feedback, overlap, ZeRO composition, checkpoints)
-live in tests/unit/test_comm.py.
+The contract: exact-reduce-then-quantize error bound, a lone small leaf
+exact (the undersized-bucket rule), zeros exact, non-finite gradients
+surfacing as NaN.  The stateful path's claims (bucketing, error feedback,
+overlap, ZeRO composition, checkpoints) live in tests/unit/test_comm.py.
 """
 
 import jax
@@ -22,24 +20,20 @@ from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
 
-from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
+from batchai_retinanet_horovod_coco_tpu.comm import CommConfig, bucketed_pmean
 from batchai_retinanet_horovod_coco_tpu.models import RetinaNetConfig, build_retinanet
 from batchai_retinanet_horovod_coco_tpu.parallel import make_mesh
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
-from batchai_retinanet_horovod_coco_tpu.parallel.quantize import (
-    quantized_pmean,
-)
 from batchai_retinanet_horovod_coco_tpu.train import create_train_state, make_train_step
 
 N = 8
 
-# The old per-leaf threshold lives on as the bucket-level exactness
-# floor: CommConfig.min_bucket_bytes == 8192 elements * 4 bytes.
+# The bucket-level exactness floor, in float32 elements.
 _MIN_QUANTIZE_ELEMS = CommConfig().min_bucket_bytes // 4
 
 
 def _run_both(tree):
-    """(quantized, exact) pmean of a per-device tree on the 8-dev mesh."""
+    """(int8, exact) pmean of a per-device tree on the 8-dev mesh."""
     mesh = make_mesh(N)
 
     @jax.jit
@@ -49,7 +43,7 @@ def _run_both(tree):
     def both(x):
         per_dev = jax.tree.map(lambda a: a[0], x)  # (1, ...) shard → (...)
         return (
-            quantized_pmean(per_dev, DATA_AXIS, N),
+            bucketed_pmean(per_dev, DATA_AXIS, N),
             jax.tree.map(lambda a: lax.pmean(a, DATA_AXIS), per_dev),
         )
 
@@ -68,8 +62,7 @@ def test_matches_pmean_within_bound():
 
 
 def test_small_single_leaf_stays_exact():
-    """A lone small leaf forms an undersized bucket -> exact path (the
-    successor of the old per-leaf _MIN_QUANTIZE_SIZE skip)."""
+    """A lone small leaf forms an undersized bucket -> exact path."""
     rng = np.random.default_rng(1)
     small = rng.normal(0, 1, (N, _MIN_QUANTIZE_ELEMS // 2)).astype(np.float32)
     q, exact = _run_both({"b": jnp.asarray(small)})
@@ -102,13 +95,12 @@ def test_train_step_learns_with_quantization():
     }
     mesh = make_mesh(N)
 
-    def train_n(quantized, steps=12):
+    def train_n(comm, steps=12):
         state = create_train_state(
             model, optax.adam(1e-3), (1, *hw, 3), jax.random.key(0)
         )
         step = make_train_step(
-            model, hw, 3, mesh=mesh, donate_state=False,
-            quantized_allreduce=quantized,
+            model, hw, 3, mesh=mesh, donate_state=False, comm=comm,
         )
         losses = []
         for _ in range(steps):
@@ -116,9 +108,9 @@ def test_train_step_learns_with_quantization():
             losses.append(float(metrics["loss"]))
         return losses
 
-    q_losses = train_n(True)
-    e_losses = train_n(False)
-    assert q_losses[-1] < q_losses[0], "quantized step failed to learn"
+    q_losses = train_n(CommConfig(compress="int8"))
+    e_losses = train_n(None)
+    assert q_losses[-1] < q_losses[0], "int8 step failed to learn"
     # Step 1 (identical init, loss computed pre-update) must match exactly;
     # trajectories stay close — int8 on reduced grads is a tiny perturbation.
     np.testing.assert_allclose(q_losses[0], e_losses[0], rtol=1e-6)

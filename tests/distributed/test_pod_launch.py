@@ -112,7 +112,7 @@ def _run_bringup_world(tmp_path, flavor: str, nprocs: int) -> list[dict]:
 @pytest.mark.slow
 @pytest.mark.parametrize("flavor", ["plain", "quantized", "spatial"])
 def test_two_process_pod(tmp_path, flavor):
-    """2-host bring-up for the plain, int8-quantized-allreduce, AND
+    """2-host bring-up for the plain, int8-compressed-allreduce, AND
     spatially partitioned step flavors (VERDICT r2 missing #3 /
     r3 missing #2: each had only ever run single-process).  "spatial"
     trains on a 2-D data x space mesh spanning both processes' devices —

@@ -1,8 +1,8 @@
 """Perf doctor tests (ISSUE 8, obs/analyze): committed-fixture golden
 output (bit-for-bit, inline == offline CLI), report schema validation,
 robustness on corrupt/legacy/empty artifacts, the shared percentile
-helper's equivalence pin, the watchdog stall trace marker, bench's span
-attribution, and the tune --from-report consumer.
+helper's equivalence pin, the watchdog stall trace marker, span
+attribution over live rings, and the tune --from-report consumer.
 
 The fixture (tests/fixtures/perf_doctor/) is a real CPU train+eval smoke
 recording: trace.json + metrics.jsonl as `--obs-trace` left them, plus
@@ -184,9 +184,9 @@ class TestRobustness:
         assert report["bottlenecks"]  # still ranks from what it has
 
     def test_events_name_none_skips_a_stale_jsonl(self, tmp_path):
-        """The bench emitters' guard: a shared obs dir can hold a
-        PREVIOUS train run's metrics.jsonl, and events_name=None keeps
-        its header/compile records out of this trace's report."""
+        """A shared obs dir can hold a PREVIOUS train run's
+        metrics.jsonl, and events_name=None keeps its header/compile
+        records out of this trace's report."""
         (tmp_path / "trace.json").write_text(
             json.dumps(
                 {
@@ -274,7 +274,7 @@ class TestCheckMode:
     def test_unreadable_baseline_fails(self, tmp_path, capsys):
         assert cli_main([FIXTURE, "--out", str(tmp_path / "r.json"),
                          "--check", str(tmp_path / "missing.json")]) == 1
-        assert "cannot read committed baseline" in capsys.readouterr().out
+        assert "cannot read baseline" in capsys.readouterr().out
 
 
 class TestPercentileHelper:
@@ -376,8 +376,8 @@ class TestStallMarker:
 
 class TestSpanAttribution:
     def test_bench_style_spans_produce_attribution(self, tmp_path):
-        """The bench.py --trace integration: live in-process rings →
-        compact per-family accounting + overlap ratio."""
+        """Live in-process rings → compact per-family accounting +
+        overlap ratio."""
         trace.configure(str(tmp_path), process_label="bench-eval")
         with trace.span("aot_compile_detect", bucket="64x64"):
             pass
@@ -477,12 +477,6 @@ class TestPeakTable:
         unknown = _mfu_section(cost, steps, "cpu")
         assert unknown["achieved_tflops"] == known["achieved_tflops"]
         assert unknown["mfu"] is None
-
-    def test_bench_uses_the_shared_table(self):
-        """bench.py's MFU peak resolves through obs/analyze (one table)."""
-        import bench
-
-        assert not hasattr(bench, "_PEAK_TFLOPS")
 
 
 class TestAnalyzeEventsUnits:

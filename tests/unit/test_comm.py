@@ -21,11 +21,8 @@ The claims, in dependency order:
 8. with compression off the compiled train step is byte-identical
    (lowered-HLO text + metric key-set) to the comm-free step;
 9. the ef_residual_spike SLO rule fires exactly once on an injected
-   saturation spike, and the CLI alias maps with one structured
-   deprecation warning.
+   saturation spike, and the CLI flags map onto one CommConfig.
 """
-
-import json
 
 import jax
 import jax.numpy as jnp
@@ -742,7 +739,7 @@ class TestCliMapping:
 
         defaults = dict(
             comm_compress="none", comm_overlap=False, comm_bucket_mb=4.0,
-            comm_no_error_feedback=False, quantized_allreduce=False,
+            comm_no_error_feedback=False,
         )
         defaults.update(kw)
         return argparse.Namespace(**defaults)
@@ -766,24 +763,6 @@ class TestCliMapping:
         assert cfg == CommConfig(
             compress="int8", overlap=True, bucket_mb=2.0
         )
-
-    def test_deprecated_alias_maps_with_one_structured_warning(
-        self, capsys
-    ):
-        from batchai_retinanet_horovod_coco_tpu.utils.cli import (
-            make_comm_config,
-        )
-
-        cfg = make_comm_config(self._args(quantized_allreduce=True))
-        assert cfg is not None and cfg.compress == "int8"
-        err = capsys.readouterr().err
-        warnings = [
-            json.loads(l) for l in err.splitlines()
-            if '"deprecated_flag"' in l
-        ]
-        assert len(warnings) == 1
-        assert warnings[0]["flag"] == "--quantized-allreduce"
-        assert "int8" in warnings[0]["mapped_to"]
 
 
 def test_record_comm_feeds_gauges_and_counter():

@@ -655,7 +655,7 @@ class TestCliMapping:
 
         defaults = dict(
             comm_compress="none", comm_overlap=False, comm_bucket_mb=4.0,
-            comm_no_error_feedback=False, quantized_allreduce=False,
+            comm_no_error_feedback=False,
             comm_ici_mode=None, comm_dcn_mode=None, comm_dcn_bucket_mb=None,
             comm_slices=None,
         )

@@ -17,7 +17,7 @@ from batchai_retinanet_horovod_coco_tpu.models import RetinaNetConfig, build_ret
 from batchai_retinanet_horovod_coco_tpu.parallel import make_mesh
 from batchai_retinanet_horovod_coco_tpu.train import create_train_state
 from batchai_retinanet_horovod_coco_tpu.train.loop import LoopConfig, run_training
-from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
+from batchai_retinanet_horovod_coco_tpu.obs.events import EventSink
 
 HW = (64, 64)
 NUM_CLASSES = 3
@@ -62,7 +62,7 @@ def batch_stream(seed=0):
 class TestRunTraining:
     def test_steps_and_jsonl_logging(self, tmp_path):
         model = tiny_model()
-        logger = MetricLogger(str(tmp_path), stdout=False)
+        logger = EventSink(str(tmp_path), stdout=False)
         state = run_training(
             model, fresh_state(model), batch_stream(), NUM_CLASSES,
             LoopConfig(total_steps=4, log_every=2), logger=logger,

@@ -24,7 +24,6 @@ from batchai_retinanet_horovod_coco_tpu.obs.events import (
     scalarize,
     split_runs,
 )
-from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
 
 
 @pytest.fixture(autouse=True)
@@ -429,7 +428,7 @@ class TestWatchdog:
 class TestEventSink:
     def test_run_header_and_split_runs(self, tmp_path):
         for run in range(2):
-            logger = MetricLogger(str(tmp_path), stdout=False)
+            logger = EventSink(str(tmp_path), stdout=False)
             logger.log(1 + run, {"loss": 0.5})
             logger.close()
         runs = split_runs(str(tmp_path / "metrics.jsonl"))
@@ -456,7 +455,7 @@ class TestEventSink:
         assert runs[1]["corrupt"]  # half-written tail kept, not fatal
 
     def test_nan_passes_through_loudly(self, tmp_path, capsys):
-        logger = MetricLogger(str(tmp_path), stdout=True)
+        logger = EventSink(str(tmp_path), stdout=True)
         logger.log(3, {"loss": float("nan"), "ok": 1.0})
         logger.close()
         out = capsys.readouterr().out
@@ -467,7 +466,7 @@ class TestEventSink:
         assert rec["train/ok"] == 1.0
 
     def test_noncastable_metrics_counted_not_silent(self, tmp_path):
-        logger = MetricLogger(str(tmp_path), stdout=False)
+        logger = EventSink(str(tmp_path), stdout=False)
         logger.log(1, {"loss": 1.0, "boxes": np.zeros((3, 4)), "tag": "x"})
         assert logger.dropped_metrics_total == 2
         logger.close()

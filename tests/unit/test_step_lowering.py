@@ -75,6 +75,28 @@ def test_detection_step_flavours_lower_to_the_recorded_text(norm, numerics):
     assert {k: recorded[k] for k in got} == got
 
 
+SIGNATURES = {
+    "make_train_step": ("model", "image_hw", "num_classes", "mesh", "loss_config", "matching_config", "anchor_config",
+                        "donate_state", "shard_weight_update", "comm", "topology", "numerics", "task"),
+    "run_training": ("model", "state", "batches", "num_classes", "config", "mesh", "loss_config", "matching_config",
+                     "anchor_config", "schedule", "eval_fn", "logger", "shard_weight_update", "comm", "topology",
+                     "allow_data_axis_divergence", "task"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SIGNATURES))
+def test_the_train_paths_parameters_are_the_recorded_names(name):
+    """PR 28 took a deprecated alias out of both; the next parameter
+    either gains is a diff of this tuple, read by a reviewer."""
+    import inspect
+
+    from batchai_retinanet_horovod_coco_tpu.train.loop import run_training
+    from batchai_retinanet_horovod_coco_tpu.train.step import make_train_step
+
+    fn = {"make_train_step": make_train_step, "run_training": run_training}[name]
+    assert tuple(inspect.signature(fn).parameters) == SIGNATURES[name]
+
+
 if __name__ == "__main__":  # with the tree to record on PYTHONPATH and 8 CPU devices
     hashes = {}
     for case in CASES:

@@ -18,7 +18,7 @@ The satellite checklist, pinned:
 
 Stub-engine serve tests only (no jax compile in the loop) — the real
 end-to-end scrape runs in scripts/telemetry_smoke.py (make
-telemetry-smoke) and bench.py --mode serve's consistency check.
+telemetry-smoke).
 """
 
 from __future__ import annotations

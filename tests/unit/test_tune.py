@@ -34,7 +34,6 @@ sys.path.insert(
 from batchai_retinanet_horovod_coco_tpu.tune import (  # noqa: E402
     DEFAULT_SCHEDULE,
     ScheduleError,
-    eval_batch_for,
     load_schedule,
     lookup,
     provenance,
@@ -176,8 +175,6 @@ class TestLookupFallback:
             str(tmp_path),
         )
         kind, root = "TPU v5 lite", str(tmp_path)
-        assert eval_batch_for((800, 1344), 8, kind, root) == 16
-        assert eval_batch_for((1344, 800), 8, kind, root) == 8  # untuned
         assert serve_batch_sizes_for((800, 1344), (8,), kind, root) == (1, 16)
         assert serve_batch_sizes_for((1344, 800), (8,), kind, root) == (8,)
 

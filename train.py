@@ -270,15 +270,9 @@ def build_parser() -> argparse.ArgumentParser:
                        help="ZeRO-style weight-update sharding: "
                             "reduce-scatter grads, 1/N optimizer state per "
                             "device, all_gather params (SURVEY.md §2.4)")
-        g.add_argument("--quantized-allreduce", action="store_true",
-                       help="DEPRECATED alias for --comm-compress int8 "
-                            "(ISSUE 13: the per-leaf quantized allreduce "
-                            "was subsumed by the bucketed, error-feedback "
-                            "comm/ subsystem); emits one structured "
-                            "deprecation warning")
         # --comm-compress / --comm-overlap / --comm-bucket-mb /
         # --comm-no-error-feedback: the gradient-communication policy
-        # surface (ISSUE 13, utils/cli.py — shared with chaos/COMMBENCH).
+        # surface (utils/cli.py).
         add_comm_flags(g)
         g.add_argument("--spatial-shards", type=int, default=1,
                        help="shard every image's H axis over this many "
@@ -743,7 +737,7 @@ def _run_lm(args) -> dict[str, float]:
         announce_devices,
         enable_compile_cache,
     )
-    from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
+    from batchai_retinanet_horovod_coco_tpu.obs.events import EventSink
 
     enable_compile_cache()
     announce_devices("train")
@@ -776,7 +770,7 @@ def _run_lm(args) -> dict[str, float]:
         batch_size=args.batch_size, doc_len_median=args.doc_len_median,
         doc_len_min=args.doc_len_min, seed=args.seed,
     ))
-    logger = MetricLogger(log_dir=args.log_dir)
+    logger = EventSink(log_dir=args.log_dir)
     telem_server, slo_monitor = _start_telemetry(args, logger)
     try:
         state = run_training(
@@ -856,7 +850,7 @@ def _run(args) -> dict[str, float]:
         OptimizerConfig,
         make_optimizer,
     )
-    from batchai_retinanet_horovod_coco_tpu.utils.metrics import MetricLogger
+    from batchai_retinanet_horovod_coco_tpu.obs.events import EventSink
 
     initialize_distributed(
         DistributedConfig(
@@ -885,13 +879,12 @@ def _run(args) -> dict[str, float]:
             )
         if (
             getattr(args, "shard_weight_update", False)
-            or getattr(args, "quantized_allreduce", False)
             or getattr(args, "comm_compress", "none") != "none"
             or getattr(args, "comm_overlap", False)
         ):
             raise SystemExit(
                 "--spatial-shards is exclusive with --shard-weight-update "
-                "and --comm-compress/--comm-overlap/--quantized-allreduce"
+                "and --comm-compress/--comm-overlap"
             )
         if not args.f32:
             # The SPMD partitioner miscompiles the bf16 spatial train step
@@ -1061,10 +1054,9 @@ def _run(args) -> dict[str, float]:
     shard_update = bool(getattr(args, "shard_weight_update", False))
     if shard_update and num_devices <= 1:
         raise SystemExit("--shard-weight-update needs --num-devices > 1")
-    # Gradient-communication policy (ISSUE 13): flags (+ the deprecated
-    # --quantized-allreduce alias) resolve to ONE CommConfig; composes
-    # with --shard-weight-update (compressed ZeRO update gather — the
-    # old exclusivity is lifted).
+    # Gradient-communication policy: the flags resolve to ONE CommConfig;
+    # it composes with --shard-weight-update (compressed ZeRO update
+    # gather).
     comm_cfg = make_comm_config(args)
     if comm_cfg is not None and num_devices <= 1:
         raise SystemExit(
@@ -1274,7 +1266,7 @@ def _run(args) -> dict[str, float]:
 
     # run_config feeds the JSONL run-header's config digest: two runs in
     # one log dir are the same experiment iff their digests match.
-    logger = MetricLogger(
+    logger = EventSink(
         args.log_dir, tensorboard=args.tensorboard, run_config=vars(args)
     )
     if getattr(args, "obs_trace", False) or getattr(args, "obs_dir", None):

@@ -14,8 +14,8 @@ Equations (ibm-granite/granite-4.0-h-micro ``config.json``, ``model_type``
   (depthwise, causal, not reaching into the previous document); split into
   ``X`` (heads x head size), ``B``, ``C`` (state size each, one group);
   ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the recurrence of
-  ``ops/ssd.py`` plus ``D * X``; ``RMSNorm(Y * silu(z)) * w`` over all inner
-  channels; ``W_out``.
+  ``ops/ssd.py`` (a kernel pair on a TPU, XLA elsewhere) plus ``D * X``;
+  ``RMSNorm(Y * silu(z)) * w`` over all inner channels; ``W_out``.
 - ``logits = RMSNorm(x_L) E^T / logits_scaling``; the loss is the mean
   cross-entropy of the next token over positions whose next token lies in
   the same document.
@@ -25,8 +25,10 @@ kind of parameter (``embed``, ``mamba``, ``attention``, ``mlp``, ``norms``),
 so that the numerics plane's per-group gradient norms (obs/numerics.py) and
 a reader of a checkpoint see the model's parts.  Parameters are float32;
 activations and matmul operands ``config.dtype`` (bfloat16); norms, softmax,
-the scan's decays and state and the loss reduce in float32.  Every layer is
-recomputed in the backward pass: only the layers' inputs are kept.  Single device: sharding comes with its own issue.
+the scan's decays and state and the loss reduce in float32, in XLA's lowering
+and inside the attention and scan kernels alike (ops/attention.py, ops/ssd.py).
+Every layer is recomputed in the backward pass: only the layers' inputs are
+kept.  Single device: sharding comes with its own issue.
 """
 
 from __future__ import annotations

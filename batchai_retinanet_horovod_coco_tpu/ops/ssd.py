@@ -18,16 +18,41 @@ zero.
 
 Precision: decays, their cumulative sums, the masks and the carried state
 are float32; matrix-multiplication operands are rounded to ``x``'s dtype
-and accumulate in float32.  Left to XLA (no kernel): batched matmuls and
-fused elementwise work.
+and accumulate in float32.
+
+One algorithm, two lowerings; ``lowering`` says which runs, from the backend
+and the shapes alone:
+
+- ``xla``: the body below, batched matmuls and fused elementwise work.  The
+  (chunks, heads, chunk, chunk) masks, decays and weights and every chunk's
+  state go through HBM, and the (tokens, heads x head size) operands change
+  layout between the three einsums.
+- ``kernel``: ops/pallas/ssd.py, a forward and a backward Pallas TPU kernel
+  that take over after the cumulative sums: a block of heads' chunks run in
+  order with the state in VMEM (the product across chunks becomes that
+  order; float32 as here), and decays and weights never leave VMEM.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
+from batchai_retinanet_horovod_coco_tpu.ops.pallas import ssd as ssd_kernel
+
+KERNEL, XLA = "kernel", "xla"
 _NEG_INF = -jnp.inf
+
+
+def lowering(backend: str, seq_len: int, chunk: int, heads: int, head_dim: int, state: int) -> str:
+    """``kernel`` where the scan's kernels can run: a TPU backend, a sequence
+    of whole chunks, and whole tiles (chunks and states of whole lane tiles,
+    heads of whole sublane tiles, whole blocks of heads); ``xla`` everywhere
+    else (the CPU, the tiny preset's chunks of 8, a ragged sequence)."""
+    whole_tiles = (chunk % 128 == 0 and state % 128 == 0 and head_dim % 16 == 0
+                   and heads % ssd_kernel.HEADS_PER_BLOCK == 0)
+    return KERNEL if backend == "tpu" and seq_len > 0 and seq_len % chunk == 0 and whole_tiles else XLA
 
 
 def ssd_chunked(
@@ -48,6 +73,12 @@ def ssd_chunked(
     (T is padded up to a multiple with tokens of no document).
     Returns (batch, T, H, P) float32.
     """
+    _, t, heads, p = x.shape
+    kernel = lowering(jax.default_backend(), t, chunk, heads, p, b.shape[-1]) == KERNEL
+    return _chunked(x, dt, a_log_decay, b, c, segment_ids, chunk, ssd_kernel.chunked_scan if kernel else None)
+
+
+def _chunked(x, dt, a_log_decay, b, c, segment_ids, chunk, scan_kernel):
     batch, t, heads, p = x.shape
     n = b.shape[-1]
     pad = -t % chunk
@@ -66,6 +97,8 @@ def ssd_chunked(
 
     log_a = dt * a_log_decay.astype(jnp.float32)  # (b, c, l, h), <= 0
     cum = jnp.cumsum(log_a, axis=2)  # inclusive: log of a_1 ... a_l
+    if scan_kernel is not None:  # everything below, in one kernel (ops/pallas/ssd.py)
+        return scan_kernel(x, dt, cum, b, c, seg).reshape(batch, nc * chunk, heads, p)[:, :t]
     xdt = (x.astype(jnp.float32) * dt[..., None]).astype(dtype)  # dt_j X_j
 
     # Inside a chunk: i >= j and the same document.

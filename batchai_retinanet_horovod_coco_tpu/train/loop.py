@@ -448,18 +448,18 @@ def _record_step_cost(hw, batch: int) -> None:
         )
 
 
-def _record_run_meta(task, bucket) -> None:
+def _record_run_meta(task, model, bucket) -> None:
     """Run metadata INTO the trace (the perf doctor resolves device peak
     TFLOP/s and process topology from artifacts alone — the events JSONL
     may not exist for this run), with what the task says of the step it is
-    about to build for ``bucket`` (train/task.py::run_meta)."""
+    about to build of ``model`` for ``bucket`` (train/task.py::run_meta)."""
     try:
         trace.instant(
             "run_meta",
             device_kind=jax.devices()[0].device_kind,
             local_device_count=jax.local_device_count(),
             process_count=jax.process_count(),
-            **task.run_meta(bucket),
+            **task.run_meta(model, bucket),
         )
     except Exception:
         pass  # metadata must never block training bring-up
@@ -758,7 +758,7 @@ def run_training(
                 # flagship compile is minutes, far past any stall budget).
                 loop_hb.idle()
                 if not step_fns and trace.enabled():
-                    _record_run_meta(task, hw)
+                    _record_run_meta(task, model, hw)
                 t_compile = monotonic_s()
                 with trace.span(
                     "compile_train_step", bucket=_bucket_name(hw)

@@ -168,10 +168,10 @@ class _Task:
     def host_arrays(self, batch) -> dict[str, Any]:
         return {k: getattr(batch, k) for k in self.batch_fields}
 
-    def run_meta(self, bucket) -> dict[str, Any]:
-        """What the loop's ``run_meta`` instant says of the step for ``bucket``
-        besides the devices."""
-        del bucket
+    def run_meta(self, model, bucket) -> dict[str, Any]:
+        """What the loop's ``run_meta`` instant says of ``model``'s step for
+        ``bucket`` besides the devices."""
+        del model, bucket
         return {}
 
 
@@ -232,11 +232,15 @@ class LMTask(_Task):
         """The bucket is (sequences, tokens per sequence)."""
         return tuple(batch.tokens.shape), batch.tokens.shape[0], batch.sequence_ids
 
-    def run_meta(self, bucket) -> dict[str, Any]:
-        """Which lowering the step's attention layer takes (ops/attention.py)."""
-        from batchai_retinanet_horovod_coco_tpu.ops import attention
+    def run_meta(self, model, bucket) -> dict[str, Any]:
+        """Which lowering the step's attention layer (ops/attention.py) and
+        its mixers' scans (ops/ssd.py) take: static per program."""
+        from batchai_retinanet_horovod_coco_tpu.ops import attention, ssd
 
-        return {"attention_lowering": attention.lowering(jax.default_backend(), bucket[1])}
+        config, backend = model.config, jax.default_backend()
+        return {"attention_lowering": attention.lowering(backend, bucket[1]),
+                "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_n_heads,
+                                             config.mamba_d_head, config.mamba_d_state)}
 
     def loss_fn(self, model, bucket) -> LossFn:
         from batchai_retinanet_horovod_coco_tpu.models.granite_hybrid import next_token_loss

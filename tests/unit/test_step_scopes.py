@@ -375,11 +375,13 @@ def _detection_run():
 
 @pytest.mark.parametrize("run,more", [
     (_detection_run, {}),
-    (_lm_run, {"attention_lowering": "xla"}),  # 64 tokens on the CPU (ops/attention.py::lowering)
+    # 64 tokens in chunks of 8 on the CPU (ops/attention.py::lowering, ops/ssd.py::lowering)
+    (_lm_run, {"attention_lowering": "xla", "ssd_lowering": "xla"}),
 ])
 def test_run_meta_says_which_lowering_the_lm_steps_attention_took(_no_ring, tmp_path, run, more):
     """One ``run_meta`` instant a run, before the step's compile span: the
-    devices, and for the language model what its attention layer lowers to."""
+    devices, and for the language model what its attention layer and its
+    mixers' scans lower to."""
     model, state, batches, num_classes, task = run()
     trace.configure(str(tmp_path), process_label="t")
     loop.run_training(model, state, batches, num_classes, loop.LoopConfig(total_steps=1, log_every=0), task=task)

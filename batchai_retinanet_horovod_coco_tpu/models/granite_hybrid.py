@@ -40,6 +40,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from batchai_retinanet_horovod_coco_tpu.models import lm_layers
+from batchai_retinanet_horovod_coco_tpu.models.lm_layers import next_token_loss
 from batchai_retinanet_horovod_coco_tpu.ops import attention, ssd
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -153,10 +155,7 @@ def init_params(config: GraniteHybridConfig, rng: jax.Array) -> dict:
     return params
 
 
-def _rms_norm(x, w, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
-    return (y * w).astype(x.dtype)
+_rms_norm = lm_layers.rms_norm
 
 
 def _operand(config, x):
@@ -164,8 +163,13 @@ def _operand(config, x):
     return x.astype(config.dtype)
 
 
+def _cast(config):
+    # bound late: the benchmark's control replaces this module's ``_operand``
+    return lambda x: _operand(config, x)
+
+
 def _matmul(config, x, w):
-    return jnp.dot(_operand(config, x), _operand(config, w))
+    return lm_layers.matmul(_cast(config), x, w)
 
 
 def _same_document_shift(x, segment_ids, j: int):
@@ -214,8 +218,7 @@ def _attention_mixer(config, p, u, segment_ids):
 
 
 def _mlp(config, p, u):
-    gate, up = jnp.split(_matmul(config, u, p["gate_up"]), 2, axis=-1)
-    return _matmul(config, jax.nn.silu(gate) * up, p["down"])
+    return lm_layers.gated_mlp(_cast(config), p, u)
 
 
 def _layer(config, kind, mixer_params, mlp_params, norms, x, segment_ids):
@@ -232,7 +235,7 @@ def _layer(config, kind, mixer_params, mlp_params, norms, x, segment_ids):
 def hidden_states(config: GraniteHybridConfig, params: dict, tokens, segment_ids):
     """The last layer's output before the final norm, (batch, T, d)."""
     with jax.named_scope("embed"):
-        x = (config.embedding_multiplier * params["embed"]["embedding"][tokens]).astype(config.dtype)
+        x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype, config.embedding_multiplier)
     for i, kind in enumerate(config.layer_types):
         name = f"layer_{i}"
         layer = jax.checkpoint(_layer, static_argnums=(0, 1))  # only the layer's input is kept
@@ -244,25 +247,15 @@ def logits_of(config: GraniteHybridConfig, params: dict, hidden):
     """float32 logits over the vocabulary held here, from ``hidden_states``."""
     with jax.named_scope("lm_head"):
         x = _rms_norm(hidden, params["norms"]["final"], config.rms_norm_eps)
-        logits = jnp.einsum("btd,vd->btv", _operand(config, x), _operand(config, params["embed"]["embedding"]),
-                            preferred_element_type=jnp.float32)
-        return logits / config.logits_scaling
-
-
-def next_token_loss(logits, tokens, segment_ids):
-    """Mean cross-entropy of the next token over positions whose next token
-    lies in the same document; also the number of such positions."""
-    targets = tokens[:, 1:]
-    counted = (segment_ids[:, 1:] == segment_ids[:, :-1]).astype(jnp.float32)
-    logits = logits[:, :-1].astype(jnp.float32)
-    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    n = jnp.sum(counted)
-    return jnp.sum(nll * counted) / jnp.maximum(n, 1.0), n
+        return lm_layers.head_logits(_cast(config), x, params["embed"]["embedding"]) / config.logits_scaling
 
 
 class GraniteHybrid:
     """The model as the train state and the loop hold it: ``init`` gives
     ``{"params": ...}``, ``apply`` the float32 logits."""
+
+    # the STEP_SCOPES (train/step.py) a step of this model enters
+    scopes = ("embed", MAMBA, ATTENTION, "mlp", "lm_head", "loss")
 
     def __init__(self, config: GraniteHybridConfig):
         self.config = config
@@ -275,3 +268,23 @@ class GraniteHybrid:
         del train  # no dropout, no batch statistics
         params = variables["params"]
         return logits_of(self.config, params, hidden_states(self.config, params, tokens, segment_ids))
+
+    def describe(self) -> str:
+        kinds = self.config.layer_types
+        return f"granite hybrid, {len(kinds)} layers ({kinds.count(MAMBA)} mamba)"
+
+    def loss(self, params: dict, tokens, segment_ids):
+        """``(loss, the step's scalars)`` as the language-model task
+        (train/task.py::LMTask) differentiates and logs them."""
+        logits = self.apply({"params": params}, tokens, segment_ids, train=True)
+        with jax.named_scope("loss"):
+            loss, counted = next_token_loss(logits, tokens, segment_ids)
+        return loss, {"loss": loss, "tokens_counted": counted}
+
+    def run_meta(self, bucket) -> dict[str, Any]:
+        """Which lowering the step's attention layer (ops/attention.py) and
+        its mixers' scans (ops/ssd.py) take: static per program."""
+        config, backend = self.config, jax.default_backend()
+        return {"attention_lowering": attention.lowering(backend, bucket[1]),
+                "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_n_heads,
+                                             config.mamba_d_head, config.mamba_d_state)}

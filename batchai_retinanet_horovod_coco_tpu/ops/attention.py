@@ -3,7 +3,9 @@
     out_i = sum_j softmax_j(scale * q_i . k_j) v_j   over j <= i with seg_j == seg_i
 
 (query head ``h`` reads key/value head ``h // (heads / kv_heads)``; every
-query sees itself, so no row is empty).  One algorithm, two blockings:
+query sees itself, so no row is empty).  The value head may be narrower
+than the query/key head (latent attention: 192 against 128), natively in
+both blockings: nothing is padded.  One algorithm, two blockings:
 
 - ``xla``: the queries in blocks of ``xla_q_block``, block ``i`` against the
   keys ``[0, end of block i)``, each block recomputed in the backward pass.
@@ -68,9 +70,10 @@ def lowering(backend: str, seq_len: int) -> str:
 
 
 def packed_causal_attention(q, k, v, segment_ids, scale: float, xla_q_block: int):
-    """``q`` (batch, T, heads, head size), ``k`` and ``v`` (batch, T,
-    kv_heads, head size), ``segment_ids`` (batch, T) with every document a
-    contiguous run of one id -> (batch, T, heads, head size) in ``q``'s dtype."""
+    """``q`` (batch, T, heads, head size), ``k`` (batch, T, kv_heads, head
+    size), ``v`` (batch, T, kv_heads, value head size), ``segment_ids``
+    (batch, T) with every document a contiguous run of one id ->
+    (batch, T, heads, value head size) in ``q``'s dtype."""
     if lowering(jax.default_backend(), q.shape[1]) == KERNEL:
         return _kernel_path(q, k, v, segment_ids, scale)
     return _xla_path(q, k, v, segment_ids, scale, xla_q_block)
@@ -94,7 +97,7 @@ def _xla_path(q, k, v, segment_ids, scale, q_block):
     out = [block(q[:, s:s + q_block], segment_ids[:, s:s + q_block], s, k[:, :min(s + q_block, t)],
                  v[:, :min(s + q_block, t)], segment_ids[:, :min(s + q_block, t)])
            for s in range(0, t, q_block)]
-    return jnp.concatenate(out, axis=1).reshape(batch, t, heads, hd)
+    return jnp.concatenate(out, axis=1).reshape(batch, t, heads, v.shape[-1])
 
 
 def _kernel_path(q, k, v, segment_ids, scale, interpret: bool = False):

@@ -1,0 +1,245 @@
+"""A routed expert layer for a chip that holds a SHARE of the experts:
+
+    F(u) = sum over e in top_k(u) and in held of  s_e(u) * E_e(u)
+    E_e(u) = W_down,e (silu(W_gate,e u) * W_up,e u)
+
+``route`` scores every token over ALL the experts of the model (the router
+is whole on every chip) and picks its ``k`` largest; ``dispatch`` sorts the
+(token, pick) pairs whose expert is held here by expert, into a buffer of
+static size; ``experts`` runs the gated MLP as grouped matrix products whose
+group sizes are data; ``combine`` weighs each row by its score and adds it
+to its token.  What an absent expert would add is left out: that partial sum
+is the layer's result on this chip (expert parallelism without its exchange;
+nothing here stands in for the other chips).
+
+**Dropless under static shapes.**  The buffer holds the worst case, every
+token sending all ``k`` picks here (tokens x k rows), so no routing can
+overflow it and no pair is ever dropped.  The pairs of held experts come
+first, sorted by expert; the rest (absent experts) lie behind them and are
+never computed: the products' work follows ``sum(group_sizes)``, the rows
+really routed here.
+
+Two lowerings of the grouped products, chosen by ``lowering`` from the
+backend and the shapes alone:
+
+- ``kernel``: the grouped matmul jax ships
+  (``jax.experimental.pallas.ops.tpu.megablox``): the grid runs over the row
+  tiles that hold a routed row (a dynamic grid bound), a tile on a group
+  boundary is visited once per group and stored under a mask.  Its two
+  kernels (``gmm``, and ``tgmm`` for the weights' gradient) are wired into a
+  ``custom_vjp`` HERE and not through the shipped ``ops.gmm``, because that
+  one gives all three products one tiling and ``tgmm`` at the forward's tiles
+  wants 16.8 MB of the 16 MB of scoped VMEM at these widths.
+- ``xla``: ``jax.lax.ragged_dot`` (the CPU tier, and shapes that are not
+  whole tiles).
+
+Rows behind the routed ones are not written by the kernel (whatever was in
+memory stays there), so both lowerings put zeros there, on the way in and
+on the way out: the masks' transposes keep that memory out of every
+gradient too.
+
+Precision: the router is float32 throughout (matmul at ``highest``: top-k is
+discontinuous, and a score rounded to bfloat16 picks other experts); the
+experts' operands are the caller's dtype (bfloat16), accumulated in float32
+and rounded once per product; ``combine`` weighs and adds in float32.
+
+Gathers, not scatters: sorting is a permutation, so ``dispatch``'s backward
+and ``combine``'s forward gather by the inverse permutation and add the
+``k`` rows of a token, where a scatter-add would serialise on a TPU.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+KERNEL, XLA = "kernel", "xla"
+
+# Rows per tile of the grouped products: the granularity at which work
+# follows routing (a group's last tile is partly empty).
+TILE_ROWS = 512
+
+
+class Routing(NamedTuple):
+    scores: jax.Array  # (tokens, experts) float32 softmax over all experts
+    picks: jax.Array  # (tokens, k) int32 expert ids, largest score first
+    weights: jax.Array  # (tokens, k) float32: the picked scores as they are
+    counts: jax.Array  # (experts,) int32 picks of every expert, held or not
+
+
+class Plan(NamedTuple):
+    order: jax.Array  # (tokens x k,) pair index by row: held pairs first, by expert
+    inverse: jax.Array  # (tokens x k,) row by pair index
+    group_sizes: jax.Array  # (held,) int32 rows of every held expert
+    rows: jax.Array  # () int32 their sum: the rows routed here
+
+
+def lowering(backend: str, rows: int, d_model: int, d_expert: int) -> str:
+    """``kernel`` on a TPU where the buffer is whole row tiles and both widths
+    whole lanes; ``xla`` everywhere else."""
+    whole = rows > 0 and rows % TILE_ROWS == 0 and d_model % 128 == 0 and d_expert % 128 == 0
+    return KERNEL if backend == "tpu" and whole else XLA
+
+
+def route(u, w_gate, k: int) -> Routing:
+    """``u`` (tokens, d) in any dtype, ``w_gate`` (d, experts) float32."""
+    logits = jnp.dot(u.astype(jnp.float32), w_gate.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    weights, picks = jax.lax.top_k(scores, k)
+    counts = jnp.sum(jax.nn.one_hot(picks, scores.shape[-1], dtype=jnp.int32), axis=(0, 1))
+    return Routing(scores, picks.astype(jnp.int32), weights, counts)
+
+
+def sequence_balance_loss(scores, picks, k: int):
+    """DeepSeek-V2's sequence-wise balance loss without its coefficient:
+    mean over sequences of ``sum_i f_i P_i`` with ``f_i`` = (picks of expert
+    i in the sequence) x experts / (k T) and ``P_i`` the sequence's mean
+    score of expert i.  ``scores`` (batch, T, experts), ``picks`` (batch, T,
+    k).  The gradient flows through ``P`` alone: ``f`` is a count."""
+    t, experts = scores.shape[1], scores.shape[2]
+    picked = jnp.sum(jax.nn.one_hot(picks, experts, dtype=jnp.float32), axis=(1, 2))  # (batch, experts)
+    f = jax.lax.stop_gradient(picked * (experts / (k * t)))
+    return jnp.mean(jnp.sum(f * jnp.mean(scores, axis=1), axis=-1))
+
+
+def dispatch(picks, held: tuple[int, ...], experts: int) -> Plan:
+    """Sort the (token, pick) pairs by the LOCAL index of their expert; an
+    absent expert's pairs get the index ``len(held)`` and so lie last."""
+    local_of = np.full((experts,), len(held), np.int32)
+    local_of[list(held)] = np.arange(len(held))
+    local = jnp.asarray(local_of)[picks.reshape(-1)]
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    group_sizes = jnp.sum(local[:, None] == jnp.arange(len(held), dtype=jnp.int32), axis=0, dtype=jnp.int32)
+    return Plan(order, inverse, group_sizes, jnp.sum(group_sizes))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of(k, u, order, inverse):
+    """``u[order // k]``: row ``r`` of the buffer is the token of pair ``order[r]``."""
+    return u[order // k]
+
+
+def _rows_of_fwd(k, u, order, inverse):
+    return _rows_of(k, u, order, inverse), inverse
+
+
+def _rows_of_bwd(k, inverse, dy):
+    by_pair = dy[inverse].reshape(-1, k, dy.shape[-1])
+    return jnp.sum(by_pair.astype(jnp.float32), axis=1).astype(dy.dtype), None, None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@jax.custom_vjp
+def _pairs_of(y, order, inverse):
+    """``y[inverse]``: the buffer's rows back in (token, pick) order."""
+    return y[inverse]
+
+
+def _pairs_of_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _pairs_of_bwd(order, dy):
+    return dy[order], None, None
+
+
+_pairs_of.defvjp(_pairs_of_fwd, _pairs_of_bwd)
+
+
+def gather_rows(u, plan: Plan):
+    """(tokens, d) -> the buffer (tokens x k, d): routed rows first, zeros behind."""
+    routed = jnp.arange(plan.order.shape[0]) < plan.rows
+    return jnp.where(routed[:, None], _rows_of(plan.order.shape[0] // u.shape[0], u, plan.order, plan.inverse), 0)
+
+
+def combine(y, plan: Plan, weights):
+    """The buffer's rows weighed by their scores and added to their tokens:
+    (tokens x k, d), (tokens, k) -> (tokens, d) float32."""
+    by_pair = _pairs_of(y, plan.order, plan.inverse).reshape(*weights.shape, y.shape[-1])
+    return jnp.sum(weights[..., None] * by_pair.astype(jnp.float32), axis=1)
+
+
+def experts(xs, gate_up, down, plan: Plan, how: str, interpret: bool = False):
+    """The gated MLP of every held expert on its rows of the buffer.
+    ``xs`` (rows, d), ``gate_up`` (held, d, 2 x width), ``down`` (held,
+    width, d), all in the operand dtype -> (rows, d), zeros behind the routed
+    rows."""
+    product = functools.partial(_kernel_product, interpret=interpret) if how == KERNEL else _xla_product
+    gate, up = jnp.split(product(xs, gate_up, plan.group_sizes), 2, axis=-1)
+    y = product(jax.nn.silu(gate) * up, down, plan.group_sizes)
+    routed = jnp.arange(xs.shape[0]) < plan.rows
+    return jnp.where(routed[:, None], y, 0)
+
+
+def _xla_product(lhs, rhs, group_sizes):
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+# ---- the kernel lowering ------------------------------------------------
+
+
+def _lane_tile(n: int) -> int:
+    """A tile of an axis that lies on lanes: the largest of 1408 (11 x 128:
+    the routed width and its double) and the powers of two that divides it."""
+    return next(t for t in (1408, 1024, 512, 256, 128) if n % t == 0)
+
+
+def _weight_gradient_tiles(k: int, n: int) -> tuple[int, int]:
+    """``tgmm``'s (k, n) tile holds a float32 accumulator and two output
+    buffers: the narrower of the two lane tiles is cut to 512 (1408 x 512 x
+    4 B = 2.9 MB; 1408 x 1024 does not fit the 16 MB of scoped VMEM)."""
+    tk, tn = _lane_tile(k), _lane_tile(n)
+    return (tk, min(tn, 512)) if tk >= tn else (min(tk, 512), tn)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _kernel_product(lhs, rhs, group_sizes, interpret=False):
+    """``lhs`` (rows, k) x ``rhs`` (groups, k, n) -> (rows, n) in ``lhs``'s dtype."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    k, n = rhs.shape[1:]
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, (TILE_ROWS, _lane_tile(k), _lane_tile(n)), interpret=interpret)
+
+
+def _kernel_product_fwd(lhs, rhs, group_sizes, interpret):
+    return _kernel_product(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+
+
+def _kernel_product_bwd(interpret, res, dy):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, group_sizes = res
+    k, n = rhs.shape[1:]
+    d_lhs = gmm(dy, rhs, group_sizes, lhs.dtype, (TILE_ROWS, _lane_tile(n), _lane_tile(k)), transpose_rhs=True,
+                interpret=interpret)
+    d_rhs = tgmm(lhs.swapaxes(0, 1), dy, group_sizes, rhs.dtype, (TILE_ROWS, *_weight_gradient_tiles(k, n)),
+                 num_actual_groups=rhs.shape[0], interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_kernel_product.defvjp(_kernel_product_fwd, _kernel_product_bwd)
+
+
+def expert_layer(u, w_gate, gate_up, down, held: tuple[int, ...], k: int, how: str):
+    """The whole layer on ``u`` (batch, T, d): route, dispatch, experts,
+    combine, each under its named scope.  Returns the held experts' part of
+    the result (batch, T, d) float32, the routing, and the plan."""
+    batch, t, d = u.shape
+    flat = u.reshape(batch * t, d)
+    with jax.named_scope("router"):
+        routing = route(flat, w_gate, k)
+    with jax.named_scope("dispatch"):
+        plan = dispatch(routing.picks, held, w_gate.shape[-1])
+        xs = gather_rows(flat, plan)
+    with jax.named_scope("experts"):
+        y = experts(xs, gate_up, down, plan, how)
+    with jax.named_scope("combine"):
+        out = combine(y, plan, routing.weights)
+    return out.reshape(batch, t, d), routing, plan

@@ -56,12 +56,17 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     "fpn": (),
     "heads": ("cls", "box"),
     "assign": (),  # anchor targets, Pallas or jnp
-    # the language-model task's (LMTask.scopes; models/granite_hybrid.py)
+    # the language-model task's, by model (``model.scopes``):
+    # models/granite_hybrid.py
     "embed": (),
     "mamba": ("in_proj", "conv", "ssd", "gate_norm", "out_proj"),  # with its norm
     "attention": (),
     "mlp": (),
-    "lm_head": (),  # final norm, tied head, 1 / logits_scaling
+    "lm_head": (),  # final norm, head (tied or not), 1 / logits_scaling
+    # models/deepseek_v2.py (``embed`` and ``lm_head`` as above)
+    "mla": ("q_proj", "kv_a", "kv_b", "rope", "core", "o_proj"),  # latent attention with its norm
+    "dense_mlp": (),
+    "moe": ("router", "dispatch", "experts", "combine", "shared", "aux"),  # ops/moe.py, with its norm
     # every task's
     "loss": (),  # focal, smooth-L1, target encoding; next-token cross-entropy
     "optimizer": (),  # clip, decay, momentum, apply, the numerics summary

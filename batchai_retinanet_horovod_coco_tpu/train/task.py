@@ -12,8 +12,8 @@ step's and the loop's, shared by every task.
 
 ``DetectionTask`` is RetinaNet (images, boxes, anchors, focal loss) on every
 mesh; ``LMTask`` is next-token prediction over packed documents
-(models/granite_hybrid.py) on one device: its sharding comes with its own
-issue.
+(models/granite_hybrid.py, models/deepseek_v2.py) on one device: its
+sharding comes with its own issue.
 """
 
 from __future__ import annotations
@@ -160,7 +160,7 @@ class _Task:
     """What the step and the loop ask of a task."""
 
     name: str
-    scopes: tuple[str, ...]  # the STEP_SCOPES (train/step.py) its forward and loss enter
+    scopes: tuple[str, ...]  # the STEP_SCOPES (train/step.py) its forward and loss enter (LMTask: its model's)
     batch_fields: tuple[str, ...]  # of a host batch, as the step's batch dict has them
     example_dtype: Any  # of create_train_state's example input
     supports_mesh: bool
@@ -220,10 +220,18 @@ class DetectionTask(_Task):
 @dataclasses.dataclass(frozen=True)
 class LMTask(_Task):
     """Next-token prediction over packed documents (data/tokens.py): the
-    loss counts the positions whose next token lies in the same document."""
+    loss counts the positions whose next token lies in the same document.
+
+    What differs from one language model to the next is the MODEL's to
+    supply (models/granite_hybrid.py, models/deepseek_v2.py): the scopes its
+    step enters (``model.scopes``), what ``run_meta`` says of its step
+    (``model.run_meta(bucket)``), and ``model.loss(params, tokens,
+    segment_ids) -> (loss, scalars)``: the next-token cross-entropy plus
+    whatever the model adds to it (a mixture of experts' balance loss), and
+    the scalars of the step that the loop logs (``loss``, ``tokens_counted``,
+    a model's routing counters)."""
 
     name = "lm"
-    scopes = ("embed", "mamba", "attention", "mlp", "lm_head", "loss")
     batch_fields = ("tokens", "segment_ids")
     example_dtype = jnp.int32  # token ids
     supports_mesh = False
@@ -233,25 +241,13 @@ class LMTask(_Task):
         return tuple(batch.tokens.shape), batch.tokens.shape[0], batch.sequence_ids
 
     def run_meta(self, model, bucket) -> dict[str, Any]:
-        """Which lowering the step's attention layer (ops/attention.py) and
-        its mixers' scans (ops/ssd.py) take: static per program."""
-        from batchai_retinanet_horovod_coco_tpu.ops import attention, ssd
-
-        config, backend = model.config, jax.default_backend()
-        return {"attention_lowering": attention.lowering(backend, bucket[1]),
-                "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_n_heads,
-                                             config.mamba_d_head, config.mamba_d_state)}
+        return model.run_meta(bucket)
 
     def loss_fn(self, model, bucket) -> LossFn:
-        from batchai_retinanet_horovod_coco_tpu.models.granite_hybrid import next_token_loss
-
         del bucket  # nothing of the program depends on it but the shapes
 
         def loss_of(state, params, batch):
-            tokens, segment_ids = batch["tokens"], batch["segment_ids"]
-            logits = model.apply({"params": params}, tokens, segment_ids, train=True)
-            with jax.named_scope("loss"):
-                loss, counted = next_token_loss(logits, tokens, segment_ids)
-            return loss, ({"loss": loss, "tokens_counted": counted}, state.batch_stats)
+            loss, scalars = model.loss(params, batch["tokens"], batch["segment_ids"])
+            return loss, (scalars, state.batch_stats)
 
         return loss_of

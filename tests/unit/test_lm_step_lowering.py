@@ -1,0 +1,44 @@
+"""The language-model task takes its scopes, ``run_meta`` and loss from the
+model (PR 30), and RMSNorm, the matmul, the gated MLP, the lookup, the head
+and the loss moved to ``models/lm_layers.py``: granite's step still lowers to
+the text it lowered to before, byte for byte.
+``tests/fixtures/granite_step_lowering.json`` holds the sha256 of
+``lowered.as_text()`` as PR 29's tree gave it (this file's ``lowered_hash``
+run with that tree on the path); a later PR that means to change granite's
+step records it again the same way and says so."""
+
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+FIXTURE = os.path.join(os.path.dirname(__file__), "..", "fixtures", "granite_step_lowering.json")
+
+
+def lowered_hash(numerics: bool) -> str:
+    from batchai_retinanet_horovod_coco_tpu.models import granite_hybrid
+    from batchai_retinanet_horovod_coco_tpu.obs.numerics import NumericsConfig
+    from batchai_retinanet_horovod_coco_tpu.train import create_train_state
+    from batchai_retinanet_horovod_coco_tpu.train.optim import OptimizerConfig, make_optimizer
+    from batchai_retinanet_horovod_coco_tpu.train.step import make_train_step
+    from batchai_retinanet_horovod_coco_tpu.train.task import LMTask
+
+    model = granite_hybrid.GraniteHybrid(granite_hybrid.TINY)
+    tx = make_optimizer(OptimizerConfig(optimizer="adamw", schedule="constant", warmup_steps=0))[0]
+    state = create_train_state(model, tx, (1, 8), jax.random.key(0), example_dtype=LMTask.example_dtype)
+    seg = jnp.asarray(np.repeat([[0, 1, 2]], [20, 30, 14], axis=1), jnp.int32)
+    batch = {"tokens": jnp.zeros((1, 64), jnp.int32), "segment_ids": seg}
+    step = make_train_step(model, (1, 64), None, task=LMTask(), donate_state=False,
+                           numerics=NumericsConfig(enabled=numerics))
+    return hashlib.sha256(step.lower(state, batch).as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize("numerics", [False, True])
+def test_granites_step_lowers_to_the_recorded_text(numerics):
+    with open(FIXTURE) as f:
+        recorded = json.load(f)
+    assert lowered_hash(numerics) == recorded[f"numerics={numerics}"]

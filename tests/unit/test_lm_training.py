@@ -139,6 +139,70 @@ def test_train_py_lm_synthetic_trains_the_tiny_preset_and_resumes(tmp_path, caps
         main(["lm-synthetic", "--num-devices", "4"])
 
 
+def test_train_py_lm_synthetic_trains_the_tiny_moe_preset_and_logs_its_counters(tmp_path, capsys):
+    from train import main
+
+    args = ["lm-synthetic", "--platform", "cpu", "--model", "tiny-moe", "--log-every", "2", "--steps", "4",
+            "--log-dir", str(tmp_path / "logs")]
+    assert main(args) == {"final_step": 4.0}
+    out = capsys.readouterr().out
+    assert "deepseek v2, 3 layers (1 dense), 4 of 16 experts held, 3 a token, d=64" in out
+    with open(tmp_path / "logs" / "metrics.jsonl") as f:
+        text = f.read()
+    for name in ("moe/rows_held", "moe/rows_max_expert", "moe/rows_min_expert", "moe/aux_loss", "tokens_counted"):
+        assert name in text, name
+
+
+@pytest.mark.parametrize("model_type,says", [("deepseek_v2", "deepseek v2, 3 layers"),
+                                             ("granitemoehybrid", "granite hybrid, 10 layers (9 mamba)")])
+def test_train_py_lm_synthetic_picks_the_model_by_model_type(tmp_path, capsys, model_type, says):
+    """A ``--model <config.json>`` at toy widths: the benchmark's file of
+    that model_type with the CPU tests' sizes."""
+    import sys
+
+    from train import main
+
+    sys.path.insert(0, os.path.join(_REPO_ROOT, "tests", "benchmark"))
+    from test_benchmark_lm_cell import TINY_MODEL as granite_tiny
+    from test_benchmark_moe_cell import TINY_MODEL as moe_tiny
+
+    name, tiny = {"deepseek_v2": ("deepseek-v2-lite-ep8", moe_tiny),
+                  "granitemoehybrid": ("granite-4.0-h-micro-p1", granite_tiny)}[model_type]
+    with open(os.path.join(_REPO_ROOT, "benchmark", "configs", name + ".json")) as f:
+        config = dict(json.load(f), **tiny)
+    assert config["model_type"] == model_type
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(config))
+    assert main(["lm-synthetic", "--platform", "cpu", "--model", str(path), "--steps", "2", "--log-every", "1"]) == {
+        "final_step": 2.0}
+    assert says in capsys.readouterr().out
+    path.write_text(json.dumps(dict(config, model_type="llama")))
+    with pytest.raises(ValueError, match="model_type 'llama'"):
+        main(["lm-synthetic", "--platform", "cpu", "--model", str(path), "--steps", "1"])
+
+
+def test_run_training_records_the_moe_models_lowerings_in_run_meta(tmp_path):
+    from batchai_retinanet_horovod_coco_tpu.models import deepseek_v2
+
+    model = deepseek_v2.DeepseekV2(deepseek_v2.TINY)
+    trace.reset()
+    trace.configure(str(tmp_path / "obs"), process_label="t")
+    try:
+        sink = _Sink()
+        state = loop.run_training(model, _state(model), packed_token_batches(SOURCE), None,
+                                  loop.LoopConfig(total_steps=2, log_every=1, numerics=True), task=LMTask(), logger=sink)
+        meta = [e for e in trace.snapshot_events() if e["name"] == "run_meta"]
+    finally:
+        trace.reset()
+    assert int(state.step) == 2
+    assert meta and {"attention_lowering": "xla", "moe_lowering": "xla", "experts_held": 4,
+                     "experts_total": 16}.items() <= meta[-1]["args"].items()
+    scalars = sink.rows[-1][1]
+    assert {"moe/rows_held", "moe/aux_loss", "gnorm/router", "gnorm/experts", "gnorm/shared", "gnorm/head"} <= set(scalars)
+    compiled = loop.compiled_step((2, 64))
+    assert {"mla", "moe", "dense_mlp", "lm_head", "optimizer"} <= {s for s, _, _ in scope_table(compiled).values()}
+
+
 def test_train_py_help_names_the_lm_subcommand(capsys):
     from train import build_parser
 
@@ -148,4 +212,5 @@ def test_train_py_help_names_the_lm_subcommand(capsys):
     assert "lm-synthetic" in text and "single-chip" in text and "tiny" in text
     with pytest.raises(SystemExit):
         build_parser().parse_args(["lm-synthetic", "--help"])
-    assert "--model" in capsys.readouterr().out
+    text = " ".join(capsys.readouterr().out.split())
+    assert "--model" in text and "tiny-moe" in text and "deepseek_v2" in text and "granitemoehybrid" in text

@@ -1,0 +1,215 @@
+"""ops/moe.py: the routed expert layer of a chip that holds a share of the
+experts, against a dense loop over every token (``for e in held: y += s_e
+picked_e E_e(u)``); its two lowerings against each other (the grouped kernel
+in interpret mode); no token dropped under any imbalance; and ops/rope.py."""
+
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from batchai_retinanet_horovod_coco_tpu.ops import moe, rope
+
+T, K, D, W, E = 96, 3, 128, 128, 16
+TILE = 32  # of the kernel in these tests: the buffer of 288 rows holds nine
+
+
+def _weights(seed, held, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    u = jnp.asarray(rng.normal(size=(T, D)), dtype)
+    w_gate = jnp.asarray(rng.normal(size=(D, E)) * 0.3, jnp.float32)
+    gate_up = jnp.asarray(rng.normal(size=(len(held), D, 2 * W)) * 0.1, dtype)
+    down = jnp.asarray(rng.normal(size=(len(held), W, D)) * 0.1, dtype)
+    return u, w_gate, gate_up, down
+
+
+def _layer(u, w_gate, gate_up, down, held, how=moe.XLA, interpret=False):
+    r = moe.route(u, w_gate, K)
+    plan = moe.dispatch(r.picks, held, E)
+    y = moe.experts(moe.gather_rows(u, plan), gate_up, down, plan, how, interpret=interpret)
+    return moe.combine(y, plan, r.weights), r, plan
+
+
+def _dense(u, w_gate, gate_up, down, held):
+    """Every held expert on every token, times the score where picked."""
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.softmax(u.astype(jnp.float32) @ w_gate, axis=-1)
+        picks = jnp.argsort(-scores, axis=-1)[:, :K]
+        picked = jnp.sum(jax.nn.one_hot(picks, E), axis=1)
+        y = 0.0
+        for j, e in enumerate(held):
+            gate, up = jnp.split(u @ gate_up[j], 2, axis=-1)
+            y = y + (scores[:, e] * picked[:, e])[:, None] * ((jax.nn.silu(gate) * up) @ down[j])
+        return y
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def test_route_scores_every_expert_and_picks_the_k_largest():
+    u, w_gate, _, _ = _weights(0, (0,))
+    r = moe.route(u, w_gate, K)
+    np.testing.assert_allclose(np.sum(r.scores, axis=-1), 1.0, rtol=1e-5)
+    order = np.argsort(-np.asarray(r.scores), axis=-1)[:, :K]
+    np.testing.assert_array_equal(np.asarray(r.picks), order)
+    np.testing.assert_array_equal(np.asarray(r.weights), np.take_along_axis(np.asarray(r.scores), order, axis=-1))
+    assert r.counts.shape == (E,) and int(r.counts.sum()) == T * K  # of ALL experts, held or not
+    np.testing.assert_array_equal(np.asarray(r.counts), np.bincount(order.reshape(-1), minlength=E))
+
+
+@pytest.mark.parametrize("held", [(0, 1, 2, 3), (1, 5, 6, 9), (15,), tuple(range(E))], ids=str)
+def test_the_layer_matches_a_dense_loop_over_every_token_and_its_gradients(held):
+    args = _weights(1, held)
+    out, r, plan = _layer(*args, held)
+    assert _rel(out, _dense(*args, held)) < 2e-6
+    assert int(plan.rows) == int(sum(r.counts[e] for e in held)) == int(plan.group_sizes.sum())
+    np.testing.assert_array_equal(np.asarray(plan.group_sizes), np.asarray(r.counts)[list(held)])
+    grads = jax.grad(lambda u, g, gu, dn: jnp.sum(jnp.square(_layer(u, g, gu, dn, held)[0])), argnums=(0, 1, 2, 3))(*args)
+    wanted = jax.grad(lambda u, g, gu, dn: jnp.sum(jnp.square(_dense(u, g, gu, dn, held))), argnums=(0, 1, 2, 3))(*args)
+    for name, a, b in zip(("input", "router", "gate_up", "down"), grads, wanted):
+        assert _rel(a, b) < 1e-5, name
+
+
+def test_the_buffer_sorts_held_pairs_first_by_expert_and_zeroes_the_rest():
+    held = (1, 5, 6, 9)
+    u, w_gate, _, _ = _weights(2, held)
+    r = moe.route(u, w_gate, K)
+    plan = moe.dispatch(r.picks, held, E)
+    rows, flat = int(plan.rows), np.asarray(r.picks).reshape(-1)
+    order = np.asarray(plan.order)
+    assert order.shape == (T * K,) and sorted(order) == list(range(T * K))  # a permutation: nothing dropped
+    np.testing.assert_array_equal(np.asarray(plan.inverse)[order], np.arange(T * K))
+    sorted_experts = flat[order[:rows]]
+    assert set(sorted_experts) <= set(held) and list(sorted_experts) == sorted(sorted_experts)
+    assert not set(flat[order[rows:]]) & set(held)
+    xs = np.asarray(moe.gather_rows(u, plan))
+    np.testing.assert_array_equal(xs[:rows], np.asarray(u)[order[:rows] // K])
+    assert not xs[rows:].any()
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 1e-2)], ids=["float32", "bfloat16"])
+def test_the_grouped_kernel_and_the_xla_lowering_agree(dtype, tol):
+    """megablox's gmm / tgmm (interpret mode) under this module's custom_vjp
+    against ``ragged_dot``: the output and the gradients of the input and of
+    both weights."""
+    held = (1, 5, 6, 9)
+    args = _weights(3, held, dtype)
+
+    def loss(how, interpret):
+        return lambda u, gu, dn: jnp.sum(jnp.square(_layer(u, args[1], gu, dn, held, how, interpret)[0]))
+
+    with mock.patch.object(moe, "TILE_ROWS", TILE):
+        out_k = _layer(*args, held, moe.KERNEL, True)[0]
+        out_x = _layer(*args, held, moe.XLA)[0]
+        grads_k = jax.grad(loss(moe.KERNEL, True), argnums=(0, 1, 2))(args[0], args[2], args[3])
+        grads_x = jax.grad(loss(moe.XLA, False), argnums=(0, 1, 2))(args[0], args[2], args[3])
+    assert _rel(out_k, out_x) < tol
+    for name, a, b in zip(("input", "gate_up", "down"), grads_k, grads_x):
+        assert _rel(a, b) < tol, name
+
+
+def _forced_router(to: tuple[int, ...]):
+    """A router whose K largest scores are ``to``'s experts for EVERY token:
+    a bias through a constant input column."""
+    w_gate = np.zeros((D, E), np.float32)
+    w_gate[0, list(to)] = 50.0 + np.arange(len(to))
+    return jnp.asarray(w_gate)
+
+
+@pytest.mark.parametrize("how", [moe.XLA, moe.KERNEL])
+@pytest.mark.parametrize("case", ["every_pick_held", "no_pick_held", "all_on_one_expert"])
+def test_no_token_is_dropped_under_imbalance(case, how):
+    """The buffer holds the worst case: every token sending all its picks
+    here (tokens x k rows on ONE chip, three times an average share), all of
+    them to few experts, or none at all."""
+    held = (2, 3, 4, 5)
+    u, _, gate_up, down = _weights(4, held)
+    u = u.at[:, 0].set(1.0)
+    to = {"every_pick_held": (2, 3, 4), "no_pick_held": (7, 8, 9), "all_on_one_expert": (5, 11, 12)}[case]
+    w_gate = _forced_router(to)
+    with mock.patch.object(moe, "TILE_ROWS", TILE):
+        out, r, plan = _layer(u, w_gate, gate_up, down, held, how, interpret=True)
+    assert set(np.asarray(r.picks).reshape(-1)) == set(to)
+    assert int(plan.rows) == T * len(set(to) & set(held))
+    if case == "all_on_one_expert":
+        assert int(plan.group_sizes.max()) == T  # one expert has every token, 16 times its average load
+    assert _rel(out, _dense(u, w_gate, gate_up, down, held)) < 2e-6 or case == "no_pick_held"
+    if case == "no_pick_held":
+        assert not np.asarray(out).any()
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_the_balance_loss_is_the_sum_of_load_times_mean_score_per_sequence():
+    rng = np.random.default_rng(5)
+    scores = jax.nn.softmax(jnp.asarray(rng.normal(size=(2, 10, 4)), jnp.float32), axis=-1)
+    picks = jnp.asarray(np.argsort(-np.asarray(scores), axis=-1)[..., :2], jnp.int32)
+    by_hand = 0.0
+    for b in range(2):
+        load = np.bincount(np.asarray(picks[b]).reshape(-1), minlength=4) * 4 / (2 * 10)
+        by_hand += float(np.sum(load * np.asarray(scores[b]).mean(axis=0))) / 2
+    assert float(moe.sequence_balance_loss(scores, picks, 2)) == pytest.approx(by_hand, rel=1e-6)
+    # perfectly even routing with uniform scores reads 1
+    even = jnp.full((1, 4, 4), 0.25)
+    assert float(moe.sequence_balance_loss(even, jnp.asarray([[[0, 1], [2, 3], [0, 1], [2, 3]]]), 2)) == pytest.approx(1.0)
+    # the load is a count: the gradient flows through the scores' mean alone
+    g = jax.grad(lambda s: moe.sequence_balance_loss(s, picks, 2))(scores)
+    load0 = np.bincount(np.asarray(picks[0]).reshape(-1), minlength=4) * 4 / 20
+    np.testing.assert_allclose(np.asarray(g[0, 3]), load0 / 10 / 2, rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend,rows,d,w,expected", [
+    ("tpu", 98304, 2048, 1408, moe.KERNEL),  # the cell's: 2 x 8192 tokens x 6 picks
+    ("cpu", 98304, 2048, 1408, moe.XLA),
+    ("tpu", 384, 64, 32, moe.XLA),  # the tiny preset: not whole tiles
+    ("tpu", 98304 + 256, 2048, 1408, moe.XLA),
+    ("tpu", 98304, 2048, 1400, moe.XLA),
+])
+def test_lowering_picks_the_kernel_on_a_tpu_at_whole_tiles(backend, rows, d, w, expected):
+    assert moe.lowering(backend, rows, d, w) == expected
+
+
+# ---- ops/rope.py -------------------------------------------------------------
+
+
+def test_yarn_frequencies_and_mscale_as_written_out_by_hand():
+    """DeepSeek-V2-Lite's rope_scaling: factor 40, original 4096, beta 32 / 1,
+    64 rotary dimensions, theta 10000.  The correction dimensions are
+    64 ln(4096 / (32 x 2 pi)) / (2 ln 10000) = 10.47 -> 10 and
+    64 ln(4096 / (2 pi)) / (2 ln 10000) = 22.51 -> 23: pairs up to 10 keep
+    10000^(-2i/64), pairs from 23 on are divided by 40, between them the
+    blend (i - 10) / 13."""
+    f = np.asarray(rope.yarn_inv_freq(64, 10000.0, 40.0, 4096, 32.0, 1.0), np.float64)
+    plain = lambda i: 10000.0 ** (-2 * i / 64)
+    assert f.shape == (32,)
+    np.testing.assert_allclose(f[:11], [plain(i) for i in range(11)], rtol=1e-6)
+    np.testing.assert_allclose(f[23:], [plain(i) / 40 for i in range(23, 32)], rtol=1e-6)
+    # pair 11: 10^-1.375 = 0.0421697 x (12/13 + 1/(13 x 40)) = 0.0390069; pair 16: 0.01 x (7/13 + 6/520) = 0.0055
+    assert f[11] == pytest.approx(0.0390069, rel=1e-5) and f[16] == pytest.approx(0.0055, rel=1e-5)
+    assert rope.yarn_mscale(40.0, 0.707) == pytest.approx(1.2608038, rel=1e-7)  # 0.1 x 0.707 x ln 40 + 1
+    assert rope.yarn_mscale(1.0, 0.707) == 1.0
+
+
+def test_positions_restart_at_every_document():
+    seg = jnp.asarray([[0, 0, 0, 1, 1, 2, 2, 2, 2], [0, 1, 1, 1, 1, 1, 2, 3, 3]], jnp.int32)
+    np.testing.assert_array_equal(np.asarray(rope.document_positions(seg)),
+                                  [[0, 1, 2, 0, 1, 0, 1, 2, 3], [0, 0, 1, 2, 3, 4, 0, 0, 1]])
+
+
+def test_a_rotated_query_times_a_rotated_key_depends_on_their_distance_alone():
+    rng = np.random.default_rng(6)
+    inv_freq = rope.yarn_inv_freq(8, 10000.0, 40.0, 16, 32.0, 1.0)
+    q = jnp.asarray(rng.normal(size=(1, 1, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, 1, 8)), jnp.float32)
+    dot = lambda i, j: float(jnp.sum(rope.apply_rotary(q, jnp.asarray([[i]]), inv_freq)
+                                     * rope.apply_rotary(k, jnp.asarray([[j]]), inv_freq)))
+    assert dot(7, 3) == pytest.approx(dot(104, 100), rel=1e-4)
+    assert dot(0, 0) == pytest.approx(float(jnp.sum(q * k)), rel=1e-6)
+    assert abs(dot(7, 3) - dot(7, 4)) > 1e-3
+    # pairs (2i, 2i + 1) turn together: (1, 0, ...) by a quarter turn of pair 0 becomes (0, ..., 1 at the second half's 0)
+    e0 = jnp.zeros((1, 1, 8)).at[0, 0, 0].set(1.0)
+    turned = rope.apply_rotary(e0, jnp.asarray([[1]]), jnp.asarray([np.pi / 2, 0, 0, 0], jnp.float32))
+    np.testing.assert_allclose(np.asarray(turned)[0, 0], [0, 0, 0, 0, 1, 0, 0, 0], atol=1e-6)

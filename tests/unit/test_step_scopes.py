@@ -19,6 +19,8 @@ from batchai_retinanet_horovod_coco_tpu.comm import CommConfig
 from batchai_retinanet_horovod_coco_tpu.comm.compress import init_comm_state
 from batchai_retinanet_horovod_coco_tpu.data.pipeline import Batch
 from batchai_retinanet_horovod_coco_tpu.models import RetinaNetConfig, build_retinanet
+from batchai_retinanet_horovod_coco_tpu.models.deepseek_v2 import DeepseekV2
+from batchai_retinanet_horovod_coco_tpu.models.granite_hybrid import GraniteHybrid
 from batchai_retinanet_horovod_coco_tpu.obs import trace
 from batchai_retinanet_horovod_coco_tpu.parallel import make_mesh, zero
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
@@ -36,6 +38,8 @@ from batchai_retinanet_horovod_coco_tpu.train.task import DetectionTask, LMTask
 # The vocabulary is one; a step enters its task's scopes and every step's.
 EVERY_STEPS = ("optimizer", "grad_allreduce")
 DETECTION_SCOPES = (*DetectionTask.scopes, *EVERY_STEPS)
+# the language-model task's are its model's (LMTask: ``model.scopes``)
+LM_SCOPES = (*GraniteHybrid.scopes, *DeepseekV2.scopes)
 
 HW = (64, 64)
 NUM_CLASSES = 3
@@ -172,8 +176,8 @@ def test_every_scope_of_the_vocabulary_reaches_the_compiled_step(compiled_steps,
     table = scope_table(compiled_steps[flavor])
     filed = {(s, d) for s, d, _ in table.values()}
     on_a_mesh = flavor != "single"
-    assert set(STEP_SCOPES) == {*DetectionTask.scopes, *LMTask.scopes, *EVERY_STEPS}
-    assert not set(LMTask.scopes) - {"loss"} & {s for s, _ in filed}
+    assert set(STEP_SCOPES) == {*DetectionTask.scopes, *LM_SCOPES, *EVERY_STEPS}
+    assert not set(LM_SCOPES) - {"loss"} & {s for s, _ in filed}
     for s in DETECTION_SCOPES:
         if s == "grad_allreduce" and not on_a_mesh:
             assert not {d for t, d in filed if t == s}
@@ -206,9 +210,10 @@ def test_the_lm_tasks_scopes_reach_the_compiled_step_through_recomputation():
     compiled = make_train_step(model, (1, 64), None, task=LMTask(), donate_state=False).lower(state, batch).compile()
     table = scope_table(compiled)
     filed = {(s, d) for s, d, _ in table.values()}
-    for s in (*LMTask.scopes, "optimizer"):
+    for s in (*model.scopes, "optimizer"):
         assert (s, "fwd") in filed, s
     assert {s for s, d in filed if d == "bwd"} >= {"embed", "mamba", "attention", "mlp", "lm_head", "loss"}
+    assert not {"mla", "dense_mlp", "moe"} & {s for s, _ in filed}  # the other language model's
     assert not set(DetectionTask.scopes) - {"loss"} & {s for s, _ in filed}
     paths = {p for t, _, p in table.values() if t == "mamba"}
     for name in STEP_SCOPES["mamba"]:

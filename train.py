@@ -20,13 +20,18 @@ The five BASELINE.json configs are runnable by name via ``--preset``:
   eval           on-device batched NMS + mAP@[.5:.95] eval (configs[4])
 
 A second kind of model trains through the same loop (train/task.py):
-``train.py lm-synthetic`` builds a Granite 4.0-H hybrid (Mamba-2 mixers and
-a grouped-query attention layer per period, models/granite_hybrid.py) and
-trains it on seeded packed token sequences (data/tokens.py).  ``--model
-tiny`` (the default: one period of ten layers at width 64) is what the CPU
-tests run; ``--model <config.json>`` takes the published keys, e.g.
-benchmark/configs/granite-4.0-h-micro-p1.json.  The LM task is single-chip
-until an issue brings its sharding: ``--num-devices`` above 1 is refused.
+``train.py lm-synthetic`` builds a language model and trains it on seeded
+packed token sequences (data/tokens.py).  ``--model <config.json>`` takes the
+published keys and picks the model by ``model_type``: ``granitemoehybrid``
+(Granite 4.0-H: Mamba-2 mixers and a grouped-query attention layer per
+period, models/granite_hybrid.py; benchmark/configs/granite-4.0-h-micro-p1.json)
+or ``deepseek_v2`` (latent attention, routed and shared experts of which this
+chip holds a share, models/deepseek_v2.py;
+benchmark/configs/deepseek-v2-lite-ep8.json).  ``--model tiny`` (the default:
+one Granite period of ten layers at width 64) and ``--model tiny-moe`` (one
+dense and two expert layers, 4 of 16 experts held) are what the CPU tests
+run.  The LM task is single-chip until an issue brings its sharding:
+``--num-devices`` above 1 is refused.
 """
 
 from __future__ import annotations
@@ -300,15 +305,21 @@ def _add_lm_parser(sub) -> None:
     eval or mesh flags apply)."""
     lm = sub.add_parser(
         "lm-synthetic", allow_abbrev=False,
-        help="train a Granite 4.0-H hybrid language model on seeded packed "
-             "token sequences (single chip; --model tiny on a CPU)",
+        help="train a language model (Granite 4.0-H hybrid or DeepSeek-V2, "
+             "by the config's model_type) on seeded packed token sequences "
+             "(single chip; --model tiny or tiny-moe on a CPU)",
     )
     g = lm.add_argument_group("model")
     g.add_argument("--model", default="tiny",
-                   help="'tiny' (one period of ten layers at width 64, "
-                        "vocabulary 128: the CPU tests' preset) or a JSON "
-                        "file with the published config.json keys, e.g. "
-                        "benchmark/configs/granite-4.0-h-micro-p1.json")
+                   help="'tiny' (Granite 4.0-H: one period of ten layers "
+                        "at width 64, vocabulary 128), 'tiny-moe' "
+                        "(DeepSeek-V2: a dense and two expert layers at "
+                        "width 64, 4 of 16 experts held, 3 a token) - the "
+                        "CPU tests' presets - or a JSON file with the "
+                        "published config.json keys, whose model_type "
+                        "(granitemoehybrid, deepseek_v2) picks the model: "
+                        "benchmark/configs/granite-4.0-h-micro-p1.json, "
+                        "benchmark/configs/deepseek-v2-lite-ep8.json")
     g = lm.add_argument_group("data")
     g.add_argument("--seq-len", type=int, default=64,
                    help="tokens per packed sequence")
@@ -725,7 +736,7 @@ def _run_lm(args) -> dict[str, float]:
         PackedTokensConfig,
         packed_token_batches,
     )
-    from batchai_retinanet_horovod_coco_tpu.models import granite_hybrid
+    from batchai_retinanet_horovod_coco_tpu.models.language import build_language_model
     from batchai_retinanet_horovod_coco_tpu.train import create_train_state
     from batchai_retinanet_horovod_coco_tpu.train.loop import LoopConfig, run_training
     from batchai_retinanet_horovod_coco_tpu.train.optim import (
@@ -741,12 +752,8 @@ def _run_lm(args) -> dict[str, float]:
 
     enable_compile_cache()
     announce_devices("train")
-    if args.model == "tiny":
-        config = granite_hybrid.TINY
-    else:
-        with open(args.model) as f:
-            config = granite_hybrid.GraniteHybridConfig.from_hf(json.load(f))
-    model, task = granite_hybrid.GraniteHybrid(config), LMTask()
+    model, task = build_language_model(args.model), LMTask()
+    config = model.config
     tx, schedule = make_optimizer(OptimizerConfig(
         optimizer="adamw", schedule="constant", warmup_steps=0,
         base_lr=args.lr, world_size=1, adam_b2=0.95,
@@ -759,8 +766,7 @@ def _run_lm(args) -> dict[str, float]:
     )(jax.random.key(args.seed))
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
     print(
-        f"lm-synthetic: {len(config.layer_types)} layers "
-        f"({config.layer_types.count('mamba')} mamba), d={config.hidden_size}, "
+        f"lm-synthetic: {model.describe()}, d={config.hidden_size}, "
         f"vocabulary {config.vocab_size}, {n_params / 1e6:.1f} M parameters; "
         f"{args.batch_size} x {args.seq_len} tokens per step",
         flush=True,

@@ -319,9 +319,12 @@ class DeepseekV2:
         return hidden_states(self.config, params, tokens, segment_ids)[3]
 
     def run_meta(self, bucket) -> dict[str, Any]:
-        """Which lowering the step's attention (ops/attention.py) and its
-        grouped products (ops/moe.py) take, and the share of the experts held."""
+        """Which lowering the step's attention (ops/attention.py), its grouped
+        products and the row movements around them (ops/moe.py) take, and the
+        share of the experts held."""
         config, backend = self.config, jax.default_backend()
         return {"attention_lowering": attention.lowering(backend, bucket[1]),
                 "moe_lowering": _moe_lowering(config, *bucket),
+                "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,
+                                                       config.hidden_size, config.moe_intermediate_size),
                 "experts_held": len(config.experts_held), "experts_total": config.experts_total}

@@ -38,6 +38,26 @@ memory stays there), so both lowerings put zeros there, on the way in and
 on the way out: the masks' transposes keep that memory out of every
 gradient too.
 
+Two lowerings of the row movements around the products too (``gather_rows``,
+``combine`` and their backward passes), chosen by ``rows_lowering``, which
+follows ``lowering`` and asks for whole token tiles and whole slabs besides:
+
+- ``kernel``: ops/pallas/moe_rows.py.  ``to_buffer`` (``gather_rows``, and
+  ``combine``'s backward with the pair's weight as scale and the weight's
+  gradient as one float a row) and ``to_tokens`` (``combine``, and
+  ``gather_rows``' backward with weight 1) fetch a row from HBM by one
+  asynchronous copy each and weigh and add on the tile in VMEM.  Their work is
+  bounded by ``plan.rows``, read on the device, and by nothing else: a tile
+  of the buffer behind the routed rows starts no copy (``to_buffer`` writes
+  zeros there without reading anything), a pair that is not routed here is
+  never visited (``to_tokens`` walks the held pairs alone, so what lies
+  behind the routed rows of its input is never read: no mask is needed, and
+  a NaN there reaches nothing).  Every routing up to every pick held
+  (tokens x k rows) is computed; there is no capacity and no fallback.
+- ``xla``: the gathers below, over the whole buffer whatever is routed (the
+  CPU tier, and shapes that are not whole tiles), and a float32 ``(tokens, k,
+  d)`` intermediate beside ``combine``'s.
+
 Precision: the router is float32 throughout (matmul at ``highest``: top-k is
 discontinuous, and a score rounded to bfloat16 picks other experts); the
 experts' operands are the caller's dtype (bfloat16), accumulated in float32
@@ -45,7 +65,10 @@ and rounded once per product; ``combine`` weighs and adds in float32.
 
 Gathers, not scatters: sorting is a permutation, so ``dispatch``'s backward
 and ``combine``'s forward gather by the inverse permutation and add the
-``k`` rows of a token, where a scatter-add would serialise on a TPU.
+``k`` rows of a token, where a scatter-add would serialise on a TPU.  The
+row kernels keep that shape (each output row is written once, by the tile
+that owns it), and a permutation of SCALARS (the weights into buffer order,
+their gradients back) is a sort by the permutation, not a gather.
 """
 
 from __future__ import annotations
@@ -57,11 +80,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from batchai_retinanet_horovod_coco_tpu.ops.pallas import moe_rows
+
 KERNEL, XLA = "kernel", "xla"
 
-# Rows per tile of the grouped products: the granularity at which work
-# follows routing (a group's last tile is partly empty).
+# Rows per tile of the grouped products and of the row kernels: the
+# granularity at which work follows routing (a group's last tile is partly empty).
 TILE_ROWS = 512
+# Tokens per tile of ``moe_rows.to_tokens`` (its scratch holds k rows a token).
+TOKEN_TILE = 128
 
 
 class Routing(NamedTuple):
@@ -153,15 +180,19 @@ def _pairs_of_bwd(order, dy):
 _pairs_of.defvjp(_pairs_of_fwd, _pairs_of_bwd)
 
 
-def gather_rows(u, plan: Plan):
+def gather_rows(u, plan: Plan, how: str = XLA, interpret: bool = False):
     """(tokens, d) -> the buffer (tokens x k, d): routed rows first, zeros behind."""
+    if how == KERNEL:
+        return _kernel_rows_of(interpret, u, plan)
     routed = jnp.arange(plan.order.shape[0]) < plan.rows
     return jnp.where(routed[:, None], _rows_of(plan.order.shape[0] // u.shape[0], u, plan.order, plan.inverse), 0)
 
 
-def combine(y, plan: Plan, weights):
+def combine(y, plan: Plan, weights, how: str = XLA, interpret: bool = False):
     """The buffer's rows weighed by their scores and added to their tokens:
     (tokens x k, d), (tokens, k) -> (tokens, d) float32."""
+    if how == KERNEL:
+        return _kernel_combine(interpret, y, plan, weights)
     by_pair = _pairs_of(y, plan.order, plan.inverse).reshape(*weights.shape, y.shape[-1])
     return jnp.sum(weights[..., None] * by_pair.astype(jnp.float32), axis=1)
 
@@ -227,19 +258,89 @@ def _kernel_product_bwd(interpret, res, dy):
 _kernel_product.defvjp(_kernel_product_fwd, _kernel_product_bwd)
 
 
+def _permuted(values, by):
+    """``out[by[i]] = values[i]`` for a permutation ``by``: a sort by it, where
+    XLA's gather of as many scalars (``values[inverse of by]``) takes ten times as long."""
+    return jax.lax.sort_key_val(by, values)[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kernel_rows_of(interpret, u, plan: Plan):
+    """``gather_rows`` by ``moe_rows.to_buffer``: the rows routed here and zeros behind them."""
+    k = plan.order.shape[0] // u.shape[0]
+    return moe_rows.to_buffer(u, plan.order // k, plan.rows, u.dtype, tile=TILE_ROWS, interpret=interpret)
+
+
+def _kernel_rows_of_fwd(interpret, u, plan):
+    return _kernel_rows_of(interpret, u, plan), (plan, u.shape[0])
+
+
+def _kernel_rows_of_bwd(interpret, res, dy):
+    """A token's cotangent is the sum of its routed picks' rows, in float32,
+    rounded once; what lies behind the routed rows of ``dy`` is not read."""
+    plan, tokens = res
+    ones = jnp.ones((tokens, plan.order.shape[0] // tokens), jnp.float32)
+    du = moe_rows.to_tokens(dy, plan.inverse, ones, plan.rows, dy.dtype, tile=TOKEN_TILE, buffer_tile=TILE_ROWS,
+                            interpret=interpret)
+    return du, None
+
+
+_kernel_rows_of.defvjp(_kernel_rows_of_fwd, _kernel_rows_of_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kernel_combine(interpret, y, plan: Plan, weights):
+    """``combine`` by ``moe_rows.to_tokens``; what lies behind the routed rows of ``y`` is not read."""
+    return moe_rows.to_tokens(y, plan.inverse, weights, plan.rows, jnp.float32, tile=TOKEN_TILE, buffer_tile=TILE_ROWS,
+                              interpret=interpret)
+
+
+def _kernel_combine_fwd(interpret, y, plan, weights):
+    return _kernel_combine(interpret, y, plan, weights), (y, plan, weights)
+
+
+def _kernel_combine_bwd(interpret, res, dout):
+    """One pass over the routed rows: ``dy[r] = w[r] dout[token[r]]`` rounded
+    once, and the weight's gradient ``<y[r], dout[token[r]]>`` as one float a
+    row (zeros behind the routed rows: a pair that is not routed here gets
+    none), which goes back to pair order as a permutation of scalars."""
+    y, plan, weights = res
+    k = weights.shape[-1]
+    dy, by_row = moe_rows.to_buffer(dout, plan.order // k, plan.rows, y.dtype, tile=TILE_ROWS,
+                                    scale=_permuted(weights.reshape(-1), plan.inverse), y=y, interpret=interpret)
+    return dy, None, _permuted(by_row, plan.order).reshape(weights.shape).astype(weights.dtype)
+
+
+_kernel_combine.defvjp(_kernel_combine_fwd, _kernel_combine_bwd)
+
+
+def _rows_follow(how: str, tokens: int, d_model: int) -> str:
+    whole = tokens % TOKEN_TILE == 0 and d_model % moe_rows.SLAB_COLUMNS == 0
+    return KERNEL if how == KERNEL and whole else XLA
+
+
+def rows_lowering(backend: str, tokens: int, k: int, d_model: int, d_expert: int) -> str:
+    """What ``gather_rows`` and ``combine`` take: ``kernel`` where the grouped
+    products take it (``lowering``) and the row kernels' own tiles are whole
+    (token tiles, and rows of whole slabs: ops/pallas/moe_rows.py); ``xla``
+    everywhere else."""
+    return _rows_follow(lowering(backend, tokens * k, d_model, d_expert), tokens, d_model)
+
+
 def expert_layer(u, w_gate, gate_up, down, held: tuple[int, ...], k: int, how: str):
     """The whole layer on ``u`` (batch, T, d): route, dispatch, experts,
     combine, each under its named scope.  Returns the held experts' part of
     the result (batch, T, d) float32, the routing, and the plan."""
     batch, t, d = u.shape
     flat = u.reshape(batch * t, d)
+    rows_how = _rows_follow(how, batch * t, d)
     with jax.named_scope("router"):
         routing = route(flat, w_gate, k)
     with jax.named_scope("dispatch"):
         plan = dispatch(routing.picks, held, w_gate.shape[-1])
-        xs = gather_rows(flat, plan)
+        xs = gather_rows(flat, plan, rows_how)
     with jax.named_scope("experts"):
         y = experts(xs, gate_up, down, plan, how)
     with jax.named_scope("combine"):
-        out = combine(y, plan, routing.weights)
+        out = combine(y, plan, routing.weights, rows_how)
     return out.reshape(batch, t, d), routing, plan

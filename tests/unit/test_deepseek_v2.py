@@ -370,4 +370,4 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert int(new_state.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert float(metrics["loss"]) > float(metrics["moe/aux_loss"]) > 0
     assert LMTask().run_meta(model, (2, T)) == {"attention_lowering": "xla", "moe_lowering": "xla",
-                                                "experts_held": 4, "experts_total": 16}
+                                                "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16}

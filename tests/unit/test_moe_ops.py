@@ -1,8 +1,11 @@
 """ops/moe.py: the routed expert layer of a chip that holds a share of the
 experts, against a dense loop over every token (``for e in held: y += s_e
 picked_e E_e(u)``); its two lowerings against each other (the grouped kernel
-in interpret mode); no token dropped under any imbalance; and ops/rope.py."""
+and the row kernels in interpret mode); no token dropped under any imbalance;
+and ops/rope.py."""
 
+import json
+import os
 from unittest import mock
 
 import jax
@@ -13,7 +16,7 @@ import pytest
 from batchai_retinanet_horovod_coco_tpu.ops import moe, rope
 
 T, K, D, W, E = 96, 3, 128, 128, 16
-TILE = 32  # of the kernel in these tests: the buffer of 288 rows holds nine
+TILE = 32  # of the kernels in these tests: the buffer of 288 rows holds nine, the 96 tokens three
 
 
 def _weights(seed, held, dtype=jnp.float32):
@@ -25,11 +28,15 @@ def _weights(seed, held, dtype=jnp.float32):
     return u, w_gate, gate_up, down
 
 
-def _layer(u, w_gate, gate_up, down, held, how=moe.XLA, interpret=False):
+def _layer(u, w_gate, gate_up, down, held, how=moe.XLA, interpret=False, rows_how=moe.XLA):
     r = moe.route(u, w_gate, K)
     plan = moe.dispatch(r.picks, held, E)
-    y = moe.experts(moe.gather_rows(u, plan), gate_up, down, plan, how, interpret=interpret)
-    return moe.combine(y, plan, r.weights), r, plan
+    y = moe.experts(moe.gather_rows(u, plan, rows_how, interpret), gate_up, down, plan, how, interpret=interpret)
+    return moe.combine(y, plan, r.weights, rows_how, interpret), r, plan
+
+
+def _small_tiles():
+    return mock.patch.multiple(moe, TILE_ROWS=TILE, TOKEN_TILE=TILE)
 
 
 def _dense(u, w_gate, gate_up, down, held):
@@ -61,14 +68,19 @@ def test_route_scores_every_expert_and_picks_the_k_largest():
     np.testing.assert_array_equal(np.asarray(r.counts), np.bincount(order.reshape(-1), minlength=E))
 
 
+@pytest.mark.parametrize("rows_how", [moe.XLA, moe.KERNEL], ids=["rows_xla", "rows_kernel"])
 @pytest.mark.parametrize("held", [(0, 1, 2, 3), (1, 5, 6, 9), (15,), tuple(range(E))], ids=str)
-def test_the_layer_matches_a_dense_loop_over_every_token_and_its_gradients(held):
+def test_the_layer_matches_a_dense_loop_over_every_token_and_its_gradients(held, rows_how):
+    """With the row kernels (interpret mode) the router's gradient comes
+    through ``to_buffer``'s float per row, the input's through ``to_tokens``."""
     args = _weights(1, held)
-    out, r, plan = _layer(*args, held)
+    layer = lambda *a: _layer(*a, held, interpret=True, rows_how=rows_how)
+    with _small_tiles():
+        out, r, plan = layer(*args)
+        grads = jax.grad(lambda *a: jnp.sum(jnp.square(layer(*a)[0])), argnums=(0, 1, 2, 3))(*args)
     assert _rel(out, _dense(*args, held)) < 2e-6
     assert int(plan.rows) == int(sum(r.counts[e] for e in held)) == int(plan.group_sizes.sum())
     np.testing.assert_array_equal(np.asarray(plan.group_sizes), np.asarray(r.counts)[list(held)])
-    grads = jax.grad(lambda u, g, gu, dn: jnp.sum(jnp.square(_layer(u, g, gu, dn, held)[0])), argnums=(0, 1, 2, 3))(*args)
     wanted = jax.grad(lambda u, g, gu, dn: jnp.sum(jnp.square(_dense(u, g, gu, dn, held))), argnums=(0, 1, 2, 3))(*args)
     for name, a, b in zip(("input", "router", "gate_up", "down"), grads, wanted):
         assert _rel(a, b) < 1e-5, name
@@ -120,19 +132,21 @@ def _forced_router(to: tuple[int, ...]):
     return jnp.asarray(w_gate)
 
 
-@pytest.mark.parametrize("how", [moe.XLA, moe.KERNEL])
+@pytest.mark.parametrize("how,rows_how", [(moe.XLA, moe.XLA), (moe.KERNEL, moe.XLA), (moe.KERNEL, moe.KERNEL)],
+                         ids=["xla", "kernel", "kernel_and_row_kernels"])
 @pytest.mark.parametrize("case", ["every_pick_held", "no_pick_held", "all_on_one_expert"])
-def test_no_token_is_dropped_under_imbalance(case, how):
+def test_no_token_is_dropped_under_imbalance(case, how, rows_how):
     """The buffer holds the worst case: every token sending all its picks
     here (tokens x k rows on ONE chip, three times an average share), all of
-    them to few experts, or none at all."""
+    them to few experts, or none at all.  The row kernels' bound is the rows
+    routed here and nothing else: every row of the buffer when every pick is held."""
     held = (2, 3, 4, 5)
     u, _, gate_up, down = _weights(4, held)
     u = u.at[:, 0].set(1.0)
     to = {"every_pick_held": (2, 3, 4), "no_pick_held": (7, 8, 9), "all_on_one_expert": (5, 11, 12)}[case]
     w_gate = _forced_router(to)
-    with mock.patch.object(moe, "TILE_ROWS", TILE):
-        out, r, plan = _layer(u, w_gate, gate_up, down, held, how, interpret=True)
+    with _small_tiles():
+        out, r, plan = _layer(u, w_gate, gate_up, down, held, how, interpret=True, rows_how=rows_how)
     assert set(np.asarray(r.picks).reshape(-1)) == set(to)
     assert int(plan.rows) == T * len(set(to) & set(held))
     if case == "all_on_one_expert":
@@ -161,15 +175,37 @@ def test_the_balance_loss_is_the_sum_of_load_times_mean_score_per_sequence():
     np.testing.assert_allclose(np.asarray(g[0, 3]), load0 / 10 / 2, rtol=1e-5)
 
 
-@pytest.mark.parametrize("backend,rows,d,w,expected", [
-    ("tpu", 98304, 2048, 1408, moe.KERNEL),  # the cell's: 2 x 8192 tokens x 6 picks
-    ("cpu", 98304, 2048, 1408, moe.XLA),
-    ("tpu", 384, 64, 32, moe.XLA),  # the tiny preset: not whole tiles
-    ("tpu", 98304 + 256, 2048, 1408, moe.XLA),
-    ("tpu", 98304, 2048, 1400, moe.XLA),
+@pytest.mark.parametrize("backend,tokens,k,d,w,expected,rows_expected", [
+    ("tpu", 16384, 6, 2048, 1408, moe.KERNEL, moe.KERNEL),  # the cell's: 2 x 8192 tokens x 6 picks = 98 304 rows
+    ("cpu", 16384, 6, 2048, 1408, moe.XLA, moe.XLA),
+    ("tpu", 64, 6, 64, 32, moe.XLA, moe.XLA),  # the tiny preset: not whole tiles
+    ("tpu", 98304 + 256, 1, 2048, 1408, moe.XLA, moe.XLA),
+    ("tpu", 16384, 6, 2048, 1400, moe.XLA, moe.XLA),
+    # the row kernels follow the products and ask for their own whole tiles besides
+    ("tpu", 16384, 6, 4096, 1408, moe.KERNEL, moe.KERNEL),
+    ("tpu", 16384, 6, 1024, 1408, moe.KERNEL, moe.XLA),  # a bfloat16 row of 1024 is half a slab
+    ("tpu", 64, 8, 2048, 1408, moe.KERNEL, moe.XLA),  # 512 rows are a tile of the buffer, 64 tokens no tile of tokens
 ])
-def test_lowering_picks_the_kernel_on_a_tpu_at_whole_tiles(backend, rows, d, w, expected):
-    assert moe.lowering(backend, rows, d, w) == expected
+def test_lowering_picks_the_kernel_on_a_tpu_at_whole_tiles(backend, tokens, k, d, w, expected, rows_expected):
+    assert moe.lowering(backend, tokens * k, d, w) == expected
+    assert moe.rows_lowering(backend, tokens, k, d, w) == rows_expected
+
+
+def test_run_meta_says_which_lowering_the_row_movements_take():
+    """``moe_rows_lowering`` beside ``moe_lowering``: the kernels at the
+    published widths on a TPU (2 sequences of 8192), XLA on the CPU and at the
+    tiny preset's shapes on any backend."""
+    from batchai_retinanet_horovod_coco_tpu.models import deepseek_v2
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark", "configs", "deepseek-v2-lite-ep8.json")
+    with open(path) as f:
+        published = deepseek_v2.DeepseekV2(deepseek_v2.DeepseekV2Config.from_hf(json.load(f)))
+    tiny = deepseek_v2.DeepseekV2(deepseek_v2.TINY)
+    assert published.run_meta((2, 8192))["moe_rows_lowering"] == moe.XLA  # this process's backend is the CPU
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        meta = published.run_meta((2, 8192))
+        assert (meta["moe_lowering"], meta["moe_rows_lowering"]) == (moe.KERNEL, moe.KERNEL)
+        assert tiny.run_meta((2, 64))["moe_rows_lowering"] == moe.XLA
 
 
 # ---- ops/rope.py -------------------------------------------------------------

@@ -172,27 +172,13 @@ def _matmul(config, x, w):
     return lm_layers.matmul(_cast(config), x, w)
 
 
-def _same_document_shift(x, segment_ids, j: int):
-    """``x`` delayed by ``j`` tokens, zero where that token is before the
-    sequence or in another document."""
-    if j == 0:
-        return x
-    moved = jnp.pad(x[:, :-j], [(0, 0), (j, 0), (0, 0)])
-    same = jnp.pad(segment_ids[:, :-j], [(0, 0), (j, 0)], constant_values=-1) == segment_ids
-    return jnp.where(same[..., None], moved, 0)
-
-
 def _mamba_mixer(config, p, u, segment_ids):
     inner, n, heads = config.mamba_d_inner, config.mamba_d_state, config.mamba_n_heads
     batch, t, _ = u.shape
     with jax.named_scope("in_proj"):
         z, xbc, dt = jnp.split(_matmul(config, u, p["in_proj"]), [inner, 2 * inner + 2 * n], axis=-1)
     with jax.named_scope("conv"):
-        k = config.mamba_d_conv
-        xbc32 = xbc.astype(jnp.float32)
-        conv = p["conv_b"] + sum(p["conv_w"][k - 1 - j] * _same_document_shift(xbc32, segment_ids, j)
-                                 for j in range(k))
-        xbc = jax.nn.silu(conv).astype(config.dtype)
+        xbc = lm_layers.document_conv_silu(xbc, p["conv_w"], p["conv_b"], segment_ids).astype(config.dtype)
         x, b, c = jnp.split(xbc, [inner, inner + n], axis=-1)
         x = x.reshape(batch, t, heads, config.mamba_d_head)
     with jax.named_scope("ssd"):

@@ -1,31 +1,38 @@
 """The language models ``train.py lm-synthetic`` and the benchmark build,
 picked by a preset's name or by the ``model_type`` of a published
-``config.json``'s keys."""
+``config.json``'s keys.  A model's module is imported when it is asked for."""
 
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import json
+
+# preset or ``model_type`` -> (module under models/, the model's class, its config: a class or the preset)
+PRESETS = {"tiny": ("granite_hybrid", "GraniteHybrid", "TINY"),
+           "tiny-moe": ("deepseek_v2", "DeepseekV2", "TINY"),
+           "tiny-nemotron": ("nemotron_h", "NemotronH", "TINY")}
+BY_TYPE = {"granitemoehybrid": ("granite_hybrid", "GraniteHybrid", "GraniteHybridConfig"),
+           "deepseek_v2": ("deepseek_v2", "DeepseekV2", "DeepseekV2Config"),
+           "nemotron_h": ("nemotron_h", "NemotronH", "NemotronHConfig")}
+
+
+def _load(entry):
+    module = importlib.import_module(f"batchai_retinanet_horovod_coco_tpu.models.{entry[0]}")
+    return getattr(module, entry[1]), getattr(module, entry[2])
 
 
 def build_language_model(spec: str | dict, **overrides):
-    """``spec``: ``tiny`` / ``tiny-moe`` (the CPU tests' presets), the path of
-    a JSON file with the published keys, or those keys as a dict.
-    ``overrides`` replace fields of the model's config (``dtype``)."""
-    import dataclasses
-
-    from batchai_retinanet_horovod_coco_tpu.models import deepseek_v2, granite_hybrid
-
-    presets = {"tiny": (granite_hybrid.GraniteHybrid, granite_hybrid.TINY),
-               "tiny-moe": (deepseek_v2.DeepseekV2, deepseek_v2.TINY)}
-    if isinstance(spec, str) and spec in presets:
-        model, config = presets[spec]
+    """``spec``: ``tiny`` / ``tiny-moe`` / ``tiny-nemotron`` (the CPU tests'
+    presets), the path of a JSON file with the published keys, or those keys
+    as a dict.  ``overrides`` replace fields of the model's config (``dtype``)."""
+    if isinstance(spec, str) and spec in PRESETS:
+        model, config = _load(PRESETS[spec])
         return model(dataclasses.replace(config, **overrides))
     if isinstance(spec, str):
         with open(spec) as f:
             spec = json.load(f)
-    by_type = {"granitemoehybrid": (granite_hybrid.GraniteHybrid, granite_hybrid.GraniteHybridConfig),
-               "deepseek_v2": (deepseek_v2.DeepseekV2, deepseek_v2.DeepseekV2Config)}
-    if spec.get("model_type") not in by_type:
-        raise ValueError(f"model_type {spec.get('model_type')!r}: lm-synthetic trains {sorted(by_type)}")
-    model, config = by_type[spec["model_type"]]
+    if spec.get("model_type") not in BY_TYPE:
+        raise ValueError(f"model_type {spec.get('model_type')!r}: lm-synthetic trains {sorted(BY_TYPE)}")
+    model, config = _load(BY_TYPE[spec["model_type"]])
     return model(config.from_hf(spec, **overrides))

@@ -1,6 +1,7 @@
 """What the language models share (models/granite_hybrid.py,
-models/deepseek_v2.py): RMSNorm, the matmul with a weight, the gated SiLU
-MLP, the embedding lookup, the head and the next-token loss.
+models/deepseek_v2.py, models/nemotron_h.py): RMSNorm, the matmul with a
+weight, the gated SiLU MLP and the squared-ReLU MLP, a Mamba-2 mixer's
+depthwise convolution, the embedding lookup, the head and the next-token loss.
 
 A matmul's operands are rounded by the CALLER's ``cast`` (its module's
 ``_operand`` bound to its config): the benchmark's precision controls patch
@@ -28,6 +29,30 @@ def gated_mlp(cast, p, u):
     """``W_down (silu(W_g u) * W_u u)`` with ``p = {gate_up, down}``."""
     gate, up = jnp.split(matmul(cast, u, p["gate_up"]), 2, axis=-1)
     return matmul(cast, jax.nn.silu(gate) * up, p["down"])
+
+
+def relu2_mlp(cast, p, u):
+    """``W_down relu(W_up u)^2`` with ``p = {up, down}``: no gate."""
+    return matmul(cast, jnp.square(jax.nn.relu(matmul(cast, u, p["up"]))), p["down"])
+
+
+def _same_document_shift(x, segment_ids, j: int):
+    """``x`` delayed by ``j`` tokens, zero where that token is before the
+    sequence or in another document."""
+    if j == 0:
+        return x
+    moved = jnp.pad(x[:, :-j], [(0, 0), (j, 0), (0, 0)])
+    same = jnp.pad(segment_ids[:, :-j], [(0, 0), (j, 0)], constant_values=-1) == segment_ids
+    return jnp.where(same[..., None], moved, 0)
+
+
+def document_conv_silu(x, w, b, segment_ids):
+    """``silu(conv1d(x) + b)`` float32: depthwise over ``x`` (batch, T,
+    channels), causal, ``w`` (taps, channels) with the last tap on the token
+    itself, not reaching into the previous document."""
+    k = w.shape[0]
+    x32 = x.astype(jnp.float32)
+    return jax.nn.silu(b + sum(w[k - 1 - j] * _same_document_shift(x32, segment_ids, j) for j in range(k)))
 
 
 def embed_lookup(table, tokens, dtype, multiplier=None):

@@ -1,14 +1,18 @@
 """A routed expert layer for a chip that holds a SHARE of the experts:
 
-    F(u) = sum over e in top_k(u) and in held of  s_e(u) * E_e(u)
-    E_e(u) = W_down,e (silu(W_gate,e u) * W_up,e u)
+    F(u) = sum over e in top_k(u) and in held of  w_e(u) * E_e(u)
+    E_e(u) = W_down,e (silu(W_gate,e u) * W_up,e u)       (``experts``: DeepSeek-V2's)
+          or W_down,e relu(W_up,e u)^2                    (``experts_relu2``: Nemotron-H's)
 
-``route`` scores every token over ALL the experts of the model (the router
-is whole on every chip) and picks its ``k`` largest; ``dispatch`` sorts the
-(token, pick) pairs whose expert is held here by expert, into a buffer of
-static size; ``experts`` runs the gated MLP as grouped matrix products whose
-group sizes are data; ``combine`` weighs each row by its score and adds it
-to its token.  What an absent expert would add is left out: that partial sum
+A router scores every token over ALL the experts of the model (it is whole
+on every chip) and picks ``k`` of them: ``route`` is a softmax whose ``k``
+largest scores are the weights as they are; ``route_sigmoid`` scores by a
+sigmoid, picks the ``k`` largest of score + a selection bias, and weighs by
+the picked scores (without the bias) normalised to sum to a scale.
+``dispatch`` sorts the (token, pick) pairs whose expert is held here by
+expert, into a buffer of static size; ``experts`` / ``experts_relu2`` run the
+MLP as grouped matrix products whose group sizes are data; ``combine`` weighs
+each row by its weight and adds it to its token.  What an absent expert would add is left out: that partial sum
 is the layer's result on this chip (expert parallelism without its exchange;
 nothing here stands in for the other chips).
 
@@ -32,6 +36,12 @@ backend and the shapes alone:
   wants 16.8 MB of the 16 MB of scoped VMEM at these widths.
 - ``xla``: ``jax.lax.ragged_dot`` (the CPU tier, and shapes that are not
   whole tiles).
+
+An axis on lanes is tiled by its largest divisor of whole lanes up to 1408; an
+expert's width that is NOT whole lanes (Nemotron-H's 1856 = 14.5 x 128) is one
+tile, the whole width (a block may be the whole axis whatever its size; the
+compiler pads the last lanes in VMEM, nothing is padded in HBM or in the
+parameters).
 
 Rows behind the routed ones are not written by the kernel (whatever was in
 memory stays there), so both lowerings put zeros there, on the way in and
@@ -92,9 +102,9 @@ TOKEN_TILE = 128
 
 
 class Routing(NamedTuple):
-    scores: jax.Array  # (tokens, experts) float32 softmax over all experts
-    picks: jax.Array  # (tokens, k) int32 expert ids, largest score first
-    weights: jax.Array  # (tokens, k) float32: the picked scores as they are
+    scores: jax.Array  # (tokens, experts) float32, of all experts (``route``: softmax; ``route_sigmoid``: sigmoid)
+    picks: jax.Array  # (tokens, k) int32 expert ids, largest first
+    weights: jax.Array  # (tokens, k) float32: what ``combine`` weighs the picks by
     counts: jax.Array  # (experts,) int32 picks of every expert, held or not
 
 
@@ -105,10 +115,16 @@ class Plan(NamedTuple):
     rows: jax.Array  # () int32 their sum: the rows routed here
 
 
+# The widest expert whose width may be ONE tile of the grouped products (a width that is not whole lanes).
+WHOLE_WIDTH_MOST = 2048
+
+
 def lowering(backend: str, rows: int, d_model: int, d_expert: int) -> str:
-    """``kernel`` on a TPU where the buffer is whole row tiles and both widths
-    whole lanes; ``xla`` everywhere else."""
-    whole = rows > 0 and rows % TILE_ROWS == 0 and d_model % 128 == 0 and d_expert % 128 == 0
+    """``kernel`` on a TPU where the buffer is whole row tiles, the model's
+    width whole lanes and the expert's width whole lanes or one tile (whole
+    packed sublanes of 16, at most ``WHOLE_WIDTH_MOST``); ``xla`` everywhere else."""
+    expert_tiles = d_expert % 128 == 0 or (d_expert % 16 == 0 and 128 < d_expert <= WHOLE_WIDTH_MOST)
+    whole = rows > 0 and rows % TILE_ROWS == 0 and d_model % 128 == 0 and expert_tiles
     return KERNEL if backend == "tpu" and whole else XLA
 
 
@@ -117,6 +133,20 @@ def route(u, w_gate, k: int) -> Routing:
     logits = jnp.dot(u.astype(jnp.float32), w_gate.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.softmax(logits, axis=-1)
     weights, picks = jax.lax.top_k(scores, k)
+    counts = jnp.sum(jax.nn.one_hot(picks, scores.shape[-1], dtype=jnp.int32), axis=(0, 1))
+    return Routing(scores, picks.astype(jnp.int32), weights, counts)
+
+
+def route_sigmoid(u, w_gate, k: int, bias, scale: float) -> Routing:
+    """``u`` (tokens, d), ``w_gate`` (d, experts) float32, ``bias`` (experts,)
+    float32.  Scores are sigmoids; the picks are the ``k`` largest of score +
+    ``bias`` (the bias moves the choice and nothing else: no gradient reaches
+    it); the weights are the picked SCORES over their sum, times ``scale``."""
+    logits = jnp.dot(u.astype(jnp.float32), w_gate.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, picks = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+    picked = jnp.take_along_axis(scores, picks, axis=-1)
+    weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
     counts = jnp.sum(jax.nn.one_hot(picks, scores.shape[-1], dtype=jnp.int32), axis=(0, 1))
     return Routing(scores, picks.astype(jnp.int32), weights, counts)
 
@@ -197,16 +227,30 @@ def combine(y, plan: Plan, weights, how: str = XLA, interpret: bool = False):
     return jnp.sum(weights[..., None] * by_pair.astype(jnp.float32), axis=1)
 
 
-def experts(xs, gate_up, down, plan: Plan, how: str, interpret: bool = False):
+def _gated_silu(h):
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def experts(xs, gate_up, down, plan: Plan, how: str, interpret: bool = False, activation=_gated_silu):
     """The gated MLP of every held expert on its rows of the buffer.
     ``xs`` (rows, d), ``gate_up`` (held, d, 2 x width), ``down`` (held,
     width, d), all in the operand dtype -> (rows, d), zeros behind the routed
     rows."""
     product = functools.partial(_kernel_product, interpret=interpret) if how == KERNEL else _xla_product
-    gate, up = jnp.split(product(xs, gate_up, plan.group_sizes), 2, axis=-1)
-    y = product(jax.nn.silu(gate) * up, down, plan.group_sizes)
+    y = product(activation(product(xs, gate_up, plan.group_sizes)), down, plan.group_sizes)
     routed = jnp.arange(xs.shape[0]) < plan.rows
     return jnp.where(routed[:, None], y, 0)
+
+
+def experts_relu2(xs, up, down, plan: Plan, how: str, interpret: bool = False):
+    """``experts`` for the two-matrix expert ``W_down relu(W_up u)^2``:
+    ``up`` (held, d, width), ``down`` (held, width, d)."""
+    return experts(xs, up, down, plan, how, interpret, _relu2)
 
 
 def _xla_product(lhs, rhs, group_sizes):
@@ -216,18 +260,29 @@ def _xla_product(lhs, rhs, group_sizes):
 # ---- the kernel lowering ------------------------------------------------
 
 
-def _lane_tile(n: int) -> int:
-    """A tile of an axis that lies on lanes: the largest of 1408 (11 x 128:
-    the routed width and its double) and the powers of two that divides it."""
-    return next(t for t in (1408, 1024, 512, 256, 128) if n % t == 0)
+def _lane_tile(n: int, most: int = 1408) -> int:
+    """A tile of an axis that lies on lanes: its largest divisor of whole
+    lanes up to ``most`` (1408 = 11 x 128 for DeepSeek-V2-Lite's routed width
+    and its double, 1024 for 2048, 896 = 7 x 128 for 2688), or the whole axis
+    where it is not whole lanes (``lowering`` admits one such width)."""
+    if n % 128:
+        return n
+    return max(t for t in range(128, min(n, most) + 1, 128) if n % t == 0)
 
 
 def _weight_gradient_tiles(k: int, n: int) -> tuple[int, int]:
     """``tgmm``'s (k, n) tile holds a float32 accumulator and two output
-    buffers: the narrower of the two lane tiles is cut to 512 (1408 x 512 x
-    4 B = 2.9 MB; 1408 x 1024 does not fit the 16 MB of scoped VMEM)."""
+    buffers: the narrower of the two lane tiles is cut to 512 at most (1408 x
+    512 x 4 B = 2.9 MB; 1408 x 1024 does not fit the 16 MB of scoped VMEM)."""
     tk, tn = _lane_tile(k), _lane_tile(n)
-    return (tk, min(tn, 512)) if tk >= tn else (min(tk, 512), tn)
+    return (tk, _lane_tile(n, 512)) if tk >= tn else (_lane_tile(k, 512), tn)
+
+
+def _product_tiles(k: int, n: int) -> tuple[int, int]:
+    """``gmm``'s (k, n) tiles: the two lane tiles; beside a whole width that
+    is not whole lanes the other is cut as ``tgmm``'s is (512 rows x 896 x
+    1856 wants 16.6 MB of the 16 MB of scoped VMEM; x 384 x 1856 11.5)."""
+    return _weight_gradient_tiles(k, n) if k % 128 or n % 128 else (_lane_tile(k), _lane_tile(n))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -236,7 +291,7 @@ def _kernel_product(lhs, rhs, group_sizes, interpret=False):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     k, n = rhs.shape[1:]
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, (TILE_ROWS, _lane_tile(k), _lane_tile(n)), interpret=interpret)
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, (TILE_ROWS, *_product_tiles(k, n)), interpret=interpret)
 
 
 def _kernel_product_fwd(lhs, rhs, group_sizes, interpret):
@@ -248,7 +303,7 @@ def _kernel_product_bwd(interpret, res, dy):
 
     lhs, rhs, group_sizes = res
     k, n = rhs.shape[1:]
-    d_lhs = gmm(dy, rhs, group_sizes, lhs.dtype, (TILE_ROWS, _lane_tile(n), _lane_tile(k)), transpose_rhs=True,
+    d_lhs = gmm(dy, rhs, group_sizes, lhs.dtype, (TILE_ROWS, *_product_tiles(n, k)), transpose_rhs=True,
                 interpret=interpret)
     d_rhs = tgmm(lhs.swapaxes(0, 1), dy, group_sizes, rhs.dtype, (TILE_ROWS, *_weight_gradient_tiles(k, n)),
                  num_actual_groups=rhs.shape[0], interpret=interpret)
@@ -327,20 +382,24 @@ def rows_lowering(backend: str, tokens: int, k: int, d_model: int, d_expert: int
     return _rows_follow(lowering(backend, tokens * k, d_model, d_expert), tokens, d_model)
 
 
-def expert_layer(u, w_gate, gate_up, down, held: tuple[int, ...], k: int, how: str):
+def expert_layer(u, w_gate, gate_up, down, held: tuple[int, ...], k: int, how: str, router=None, mlp=None):
     """The whole layer on ``u`` (batch, T, d): route, dispatch, experts,
-    combine, each under its named scope.  Returns the held experts' part of
-    the result (batch, T, d) float32, the routing, and the plan."""
+    combine, each under its named scope.  ``router(flat, w_gate, k)`` is
+    ``route`` (None) or ``route_sigmoid`` with its bias and scale bound;
+    ``mlp`` is ``experts`` (None) or ``experts_relu2`` (``gate_up`` then the
+    up matrix alone).  The defaults are looked up when the layer is traced.
+    Returns the held experts' part of the result (batch, T, d) float32, the
+    routing, and the plan."""
     batch, t, d = u.shape
     flat = u.reshape(batch * t, d)
     rows_how = _rows_follow(how, batch * t, d)
     with jax.named_scope("router"):
-        routing = route(flat, w_gate, k)
+        routing = (router or route)(flat, w_gate, k)
     with jax.named_scope("dispatch"):
         plan = dispatch(routing.picks, held, w_gate.shape[-1])
         xs = gather_rows(flat, plan, rows_how)
     with jax.named_scope("experts"):
-        y = experts(xs, gate_up, down, plan, how)
+        y = (mlp or experts)(xs, gate_up, down, plan, how)
     with jax.named_scope("combine"):
         out = combine(y, plan, routing.weights, rows_how)
     return out.reshape(batch, t, d), routing, plan

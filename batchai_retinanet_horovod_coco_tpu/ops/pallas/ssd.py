@@ -18,6 +18,12 @@ with the state's cotangent in VMEM, recomputes decays and weights, and reads
 the states the forward saved (one (heads x head size, state) float32 block a
 chunk).
 
+Groups.  ``B`` and ``C`` come in ``G`` groups of consecutive heads (ops/ssd.py).
+A block of heads holds whole groups, and then carries their ``B``, ``C`` and one
+``C B^T`` a group (eight groups of eight heads under blocks of 32: four), or lies
+inside one group (one group of 64 heads: the block's ``B`` and ``C`` are the
+group's, and the blocks' gradients of them are added outside).
+
 Layout.  The tokens are last, (channels, tokens), which is how XLA holds the
 mixer's activations around the depthwise convolution: a head is ``head size``
 sublanes by ``chunk`` lanes, per-token scalars (dt, decays) are rows, and
@@ -70,6 +76,17 @@ _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 HEADS_PER_BLOCK = 32
 
 
+def groups_per_block(heads: int, groups: int, hb: int) -> int | None:
+    """How many of the ``groups`` of B and C a block of ``hb`` heads carries: whole
+    groups, or 1 where the block lies inside a group; nothing where it would cut one."""
+    if heads % groups:
+        return None
+    per_group = heads // groups
+    if hb % per_group == 0:
+        return hb // per_group
+    return 1 if per_group % hb == 0 else None
+
+
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
@@ -79,9 +96,10 @@ class _Chunk:
     the masks and ``C B^T``, transposed: rows are the source token j, the lanes
     the token i it reaches."""
 
-    def __init__(self, bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref):
-        self.bt, self.ct = bt_ref[0], ct_ref[0]  # (n, l)
-        chunk = self.bt.shape[-1]
+    def __init__(self, bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref, n):
+        groups = bt_ref.shape[1] // n  # those this block of heads carries
+        self.bt, self.ct = ([ref[0, n * g:n * (g + 1), :] for g in range(groups)] for ref in (bt_ref, ct_ref))
+        chunk = bt_ref.shape[-1]
         self.last = slice(chunk - 1, chunk)
         seg_row, seg_col = seg_row_ref[0, 0], seg_col_ref[0, 0]  # (1, l), (l, 1)
         seg_last, seg_prev = seg_row[:, self.last], seg_prev_ref[0, 0][:, self.last]  # (1, 1)
@@ -91,42 +109,45 @@ class _Chunk:
         self.reached = seg_row == seg_prev  # (1, l): still the document the carried state belongs to
         self.to_end = seg_row == seg_last  # (1, l): the last token's document
         self.carried = seg_last == seg_prev  # (1, 1)
-        self.cbt = _dot(self.bt, self.ct, _TN)  # (j, i) float32
+        self.cbt = [_dot(bt, ct, _TN) for bt, ct in zip(self.bt, self.ct)]  # (j, i) float32
 
-    def head(self, cum_row, cum_col):
-        """(decay, weights) (j, i), and the rows ``reach``, ``to_end`` (1, l), ``keep`` (1, 1)."""
+    def head(self, cum_row, cum_col, g):
+        """Of a head of the block's group ``g``: (decay, weights) (j, i), and
+        the rows ``reach``, ``to_end`` (1, l), ``keep`` (1, 1)."""
         cum_last = cum_row[:, self.last]
         decay = jnp.exp(jnp.where(self.within, cum_row - cum_col, _NEG_INF))  # cum_i - cum_j <= 0 under the mask
         reach = jnp.exp(jnp.where(self.reached, cum_row, _NEG_INF))
         to_end = jnp.exp(jnp.where(self.to_end, cum_last - cum_row, _NEG_INF))
         keep = jnp.where(self.carried, jnp.exp(cum_last), 0.0)
-        return decay, self.cbt * decay, reach, to_end, keep
+        return decay, self.cbt[g] * decay, reach, to_end, keep
 
 
 def _fwd_kernel(x_ref, dt_ref, cum_row_ref, cum_col_ref, bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref,
-                y_ref, *rest, heads, p):
+                y_ref, *rest, heads, p, n):
     start_ref, state = rest if len(rest) == 2 else (None, rest[0])
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
-    chunk = _Chunk(bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref)
+    chunk = _Chunk(bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref, n)
     dtype = x_ref.dtype
+    per_group = heads // len(chunk.bt)
     for k in range(heads):
-        rows = slice(p * k, p * (k + 1))
-        _, w, reach, to_end, keep = chunk.head(cum_row_ref[0, 0, k:k + 1, :], cum_col_ref[0, 0, 0, :, k:k + 1])
+        rows, g = slice(p * k, p * (k + 1)), k // per_group
+        _, w, reach, to_end, keep = chunk.head(
+            cum_row_ref[0, 0, k:k + 1, :], cum_col_ref[0, 0, 0, :, k:k + 1], g)
         xdt = (x_ref[0, rows, :].astype(jnp.float32) * dt_ref[0, 0, k:k + 1, :]).astype(dtype)  # (p, j)
         s = state[rows, :]  # (p, n): where this chunk starts
-        y_ref[0, rows, :] = _dot(xdt, w.astype(dtype)) + _dot(s.astype(dtype), chunk.ct) * reach
+        y_ref[0, rows, :] = _dot(xdt, w.astype(dtype)) + _dot(s.astype(dtype), chunk.ct[g]) * reach
         if start_ref is not None:
             start_ref[0, 0, rows, :] = s
         xdt_end = (xdt.astype(jnp.float32) * to_end).astype(dtype)
-        state[rows, :] = keep * s + _dot(xdt_end, chunk.bt, _NT)
+        state[rows, :] = keep * s + _dot(xdt_end, chunk.bt[g], _NT)
 
 
 def _bwd_kernel(x_ref, dt_ref, cum_row_ref, cum_col_ref, bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref,
-                start_ref, dy_ref, dx_ref, ddt_ref, dcum_row_ref, dcum_col_ref, dbt_ref, dct_ref, dstate, *, heads, p):
+                start_ref, dy_ref, dx_ref, ddt_ref, dcum_row_ref, dcum_col_ref, dbt_ref, dct_ref, dstate, *, heads, p, n):
     """The chunks of a block of heads in reverse; ``dstate`` is the cotangent
     of the state the chunk ends with."""
 
@@ -134,19 +155,23 @@ def _bwd_kernel(x_ref, dt_ref, cum_row_ref, cum_col_ref, bt_ref, ct_ref, seg_row
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
-    chunk = _Chunk(bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref)
+    chunk = _Chunk(bt_ref, ct_ref, seg_row_ref, seg_col_ref, seg_prev_ref, n)
     dtype = x_ref.dtype
-    size = chunk.bt.shape[-1]
+    size = bt_ref.shape[-1]
+    groups = len(chunk.bt)
+    per_group = heads // groups
     last_lane = jax.lax.broadcasted_iota(jnp.int32, (1, size), 1) == size - 1
     head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
-    dcbt = jnp.zeros((size, size), jnp.float32)
     dcum_col = jnp.zeros((size, heads), jnp.float32)
-    dbt = jnp.zeros(chunk.bt.shape, jnp.float32)
-    dct = jnp.zeros(chunk.ct.shape, jnp.float32)
     for k in range(heads):
-        rows = slice(p * k, p * (k + 1))
+        rows, group = slice(p * k, p * (k + 1)), k // per_group
+        if k % per_group == 0:  # the first head of a group
+            dcbt = jnp.zeros((size, size), jnp.float32)
+            dbt = jnp.zeros((n, size), jnp.float32)
+            dct = jnp.zeros((n, size), jnp.float32)
         dt = dt_ref[0, 0, k:k + 1, :]
-        decay, w, reach, to_end, keep = chunk.head(cum_row_ref[0, 0, k:k + 1, :], cum_col_ref[0, 0, 0, :, k:k + 1])
+        decay, w, reach, to_end, keep = chunk.head(
+            cum_row_ref[0, 0, k:k + 1, :], cum_col_ref[0, 0, 0, :, k:k + 1], group)
         x = x_ref[0, rows, :].astype(jnp.float32)
         xdt = (x * dt).astype(dtype)  # (p, j)
         xdt32 = xdt.astype(jnp.float32)
@@ -162,14 +187,14 @@ def _bwd_kernel(x_ref, dt_ref, cum_row_ref, cum_col_ref, bt_ref, ct_ref, seg_row
         dcum_row = jnp.sum(g, axis=0, keepdims=True)  # to cum_i
         dcum_col = jnp.where(head_lane == k, -jnp.sum(g, axis=1, keepdims=True), dcum_col)  # to cum_j
         # from the state the chunk starts with: y += (s @ C^T) * reach
-        dcum_row += jnp.sum(dy * _dot(s_op, chunk.ct), axis=0, keepdims=True) * reach
+        dcum_row += jnp.sum(dy * _dot(s_op, chunk.ct[group]), axis=0, keepdims=True) * reach
         dy_reach = (dy * reach).astype(dtype)
-        ds = _dot(dy_reach, chunk.ct, _NT)  # (p, n)
+        ds = _dot(dy_reach, chunk.ct[group], _NT)  # (p, n)
         dct = dct + _dot(s_op, dy_reach, _TN)
         # the state the chunk ends with: keep * s + (xdt decayed to the end) @ B
         de = dstate[rows, :]
         de_op = de.astype(dtype)
-        dxdt_end = _dot(de_op, chunk.bt)  # (p, j)
+        dxdt_end = _dot(de_op, chunk.bt[group])  # (p, j)
         dbt = dbt + _dot(de_op, (xdt32 * to_end).astype(dtype), _TN)
         dxdt += dxdt_end * to_end
         d_to_end = jnp.sum(dxdt_end * xdt32, axis=0, keepdims=True) * to_end  # to cum_last - cum_j
@@ -178,19 +203,21 @@ def _bwd_kernel(x_ref, dt_ref, cum_row_ref, cum_col_ref, bt_ref, ct_ref, seg_row
         dcum_row_ref[0, 0, k:k + 1, :] = dcum_row - d_to_end + jnp.where(last_lane, dcum_last, 0.0)
         dx_ref[0, rows, :] = (dxdt * dt).astype(dx_ref.dtype)
         ddt_ref[0, 0, k:k + 1, :] = jnp.sum(dxdt * x, axis=0, keepdims=True)
+        if (k + 1) % per_group == 0:  # the last head of a group
+            # The cotangent of C B^T in two pieces of the operands' dtype: four small products a group, and
+            # B's and C's gradients lie nearer the float32 recurrence than with XLA's one rounding.
+            hi = dcbt.astype(dtype)
+            lo = (dcbt - hi.astype(jnp.float32)).astype(dtype)
+            of_group = slice(n * group, n * (group + 1))
+            dbt_ref[0, 0, of_group, :] = dbt + _dot(chunk.ct[group], hi, _NT) + _dot(chunk.ct[group], lo, _NT)
+            dct_ref[0, 0, of_group, :] = dct + _dot(chunk.bt[group], hi) + _dot(chunk.bt[group], lo)
     dcum_col_ref[0, 0, 0] = dcum_col
-    # The cotangent of C B^T in two pieces of the operands' dtype: four small products a block of
-    # heads, and B's and C's gradients lie nearer the float32 recurrence than with XLA's one rounding.
-    hi = dcbt.astype(dtype)
-    lo = (dcbt - hi.astype(jnp.float32)).astype(dtype)
-    dbt_ref[0, 0] = dbt + _dot(chunk.ct, hi, _NT) + _dot(chunk.ct, lo, _NT)
-    dct_ref[0, 0] = dct + _dot(chunk.bt, hi) + _dot(chunk.bt, lo)
 
 
 def _operands(x, dt, cum, b, c, seg, hb):
     """The kernels' layouts of ``x`` (batch, chunks, chunk, heads, head size),
     ``dt`` and ``cum`` (batch, chunks, chunk, heads), ``b`` and ``c`` (batch,
-    chunks, chunk, state), ``seg`` (batch, chunks, chunk)."""
+    chunks, chunk, [groups,] state), ``seg`` (batch, chunks, chunk)."""
     batch, nc, chunk, heads, p = x.shape
     tokens_last = lambda a: jnp.moveaxis(a.reshape(batch, nc * chunk, -1), 1, 2)  # (batch, channels, tokens)
     rows = lambda a: jnp.moveaxis(a, 3, 2)  # (batch, chunks, heads, chunk)
@@ -199,22 +226,36 @@ def _operands(x, dt, cum, b, c, seg, hb):
             seg[:, :, None, :], seg[..., None])
 
 
-def _specs(nc, chunk, hb, p, n, reverse):
+def _specs(nc, chunk, hb, p, n, gb, blocks_per_group, reverse):
     """Block specs over the grid (batch, block of heads, chunk); ``reverse``
-    walks the chunks from the last."""
+    walks the chunks from the last.  A block carries ``gb`` groups of B and C,
+    or ``blocks_per_group`` blocks share one."""
     at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
     before = lambda c: jnp.maximum(at(c) - 1, 0)
     return dict(
         wide=pl.BlockSpec((1, hb * p, chunk), lambda b, g, c: (b, g, at(c))),  # of (batch, heads x head size, tokens)
         rows=pl.BlockSpec((1, 1, hb, chunk), lambda b, g, c: (b, at(c), g, 0)),  # of (batch, chunks, heads, chunk)
         cols=pl.BlockSpec((1, 1, 1, chunk, hb), lambda b, g, c: (b, at(c), g, 0, 0)),
-        state_dim=pl.BlockSpec((1, n, chunk), lambda b, g, c: (b, 0, at(c))),  # of (batch, state, tokens)
+        # of (batch, groups x state, tokens)
+        state_dim=pl.BlockSpec((1, gb * n, chunk), lambda b, g, c: (b, g // blocks_per_group, at(c))),
         seg_row=pl.BlockSpec((1, 1, 1, chunk), lambda b, g, c: (b, at(c), 0, 0)),
         seg_col=pl.BlockSpec((1, 1, chunk, 1), lambda b, g, c: (b, at(c), 0, 0)),
         seg_prev=pl.BlockSpec((1, 1, 1, chunk), lambda b, g, c: (b, before(c), 0, 0)),
         start=pl.BlockSpec((1, 1, hb * p, n), lambda b, g, c: (b, at(c), g, 0)),  # of (batch, chunks, heads x head size, state)
-        per_block=pl.BlockSpec((1, 1, n, chunk), lambda b, g, c: (b, g, 0, at(c))),  # of (batch, blocks, state, tokens)
+        # of (batch, blocks, groups x state, tokens)
+        per_block=pl.BlockSpec((1, 1, gb * n, chunk), lambda b, g, c: (b, g, 0, at(c))),
     )
+
+
+def _groups(b) -> int:
+    return 1 if b.ndim == 4 else b.shape[3]
+
+
+def _groups_per_block(x, b, hb) -> int:
+    gb = groups_per_block(x.shape[3], _groups(b), hb)
+    if gb is None:
+        raise ValueError(f"a block of {hb} heads cuts a group: {x.shape[3]} heads in {_groups(b)} groups")
+    return gb
 
 
 def _call(kernel, x, dt, cum, b, c, seg, more, hb, interpret, reverse, *, name, out_specs, out_shape):
@@ -222,15 +263,15 @@ def _call(kernel, x, dt, cum, b, c, seg, more, hb, interpret, reverse, *, name, 
     operands both kernels read and ``more`` ``(spec name, array)`` pairs;
     ``out_specs`` by name."""
     batch, nc, chunk, heads, p = x.shape
-    n = b.shape[-1]
-    specs = _specs(nc, chunk, hb, p, n, reverse)
+    n, gb = b.shape[-1], _groups_per_block(x, b, hb)
+    specs = _specs(nc, chunk, hb, p, n, gb, max(1, heads // _groups(b) // hb), reverse)
     operands = _operands(x, dt, cum, b, c, seg, hb)
     names = ("wide", "rows", "rows", "cols", "state_dim", "state_dim", "seg_row", "seg_col", "seg_prev")
     # A block of heads' chunks run in order: the state is carried in VMEM.
     params = None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=64 * 1024 * 1024)
     return pl.pallas_call(
-        functools.partial(kernel, heads=hb, p=p), grid=(batch, heads // hb, nc),
+        functools.partial(kernel, heads=hb, p=p, n=n), grid=(batch, heads // hb, nc),
         in_specs=[specs[k] for k in (*names, *(k for k, _ in more))],
         out_specs=[specs[k] for k in out_specs], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hb * p, n), jnp.float32)],
@@ -254,7 +295,8 @@ def _backward(x, dt, cum, b, c, seg, start, dy, hb, interpret):
     batch, nc, chunk, heads, p = x.shape
     tokens = nc * chunk
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    per_block = f32(batch, heads // hb, b.shape[-1], tokens)
+    n, groups, gb = b.shape[-1], _groups(b), _groups_per_block(x, b, hb)
+    per_block = f32(batch, heads // hb, gb * n, tokens)
     dy_t = jnp.moveaxis(dy.reshape(batch, tokens, heads * p), 1, 2)
     dx, ddt, dcum_row, dcum_col, dbt, dct = _call(
         _bwd_kernel, x, dt, cum, b, c, seg, (("start", start), ("wide", dy_t)), hb, interpret, True,
@@ -264,7 +306,9 @@ def _backward(x, dt, cum, b, c, seg, start, dy, hb, interpret):
     dx = jnp.moveaxis(dx, 1, 2).reshape(x.shape)
     rows_back = lambda a: jnp.moveaxis(a, 2, 3)
     dcum = rows_back(dcum_row) + jnp.moveaxis(dcum_col, 2, 3).reshape(cum.shape)
-    tokens_first = lambda a: jnp.moveaxis(jnp.sum(a, axis=1), 1, 2).reshape(b.shape).astype(b.dtype)
+    # a group's gradient is its blocks' added (one block where a block carries whole groups)
+    tokens_first = lambda a: jnp.moveaxis(jnp.sum(a.reshape(batch, groups, -1, n, tokens), axis=2).reshape(
+        batch, groups * n, tokens), 1, 2).reshape(b.shape).astype(b.dtype)
     return dx, rows_back(ddt), dcum, tokens_first(dbt), tokens_first(dct)
 
 
@@ -272,7 +316,8 @@ def _backward(x, dt, cum, b, c, seg, start, dy, hb, interpret):
 def chunked_scan(x, dt, cum, b, c, seg, heads_per_block=None, interpret=False):
     """``x`` (batch, chunks, chunk, heads, head size), ``dt`` and ``cum``
     (batch, chunks, chunk, heads) float32 (``cum`` the inclusive sum of ``dt *
-    A`` inside a chunk), ``b`` and ``c`` (batch, chunks, chunk, state), ``seg``
+    A`` inside a chunk), ``b`` and ``c`` (batch, chunks, chunk, state) or, in groups
+    of consecutive heads, (batch, chunks, chunk, groups, state), ``seg``
     (batch, chunks, chunk) int -> ``Y`` of ops/ssd.py's recurrence, (batch,
     chunks, chunk, heads, head size) float32.  ``heads_per_block`` None is
     ``HEADS_PER_BLOCK``; ``interpret`` runs the kernels in Pallas's

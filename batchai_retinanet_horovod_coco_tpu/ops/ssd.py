@@ -1,8 +1,10 @@
 """The chunked state-space scan of a Mamba-2 mixer, with state reset at
 document boundaries.
 
-The recurrence, per head (``X_t`` of size P, ``B_t``/``C_t`` of size N, one
-group shared by all heads, ``a_t = exp(dt_t * A)``):
+The recurrence, per head (``X_t`` of size P, ``B_t``/``C_t`` of size N,
+``a_t = exp(dt_t * A)``; the heads share ``B`` and ``C`` in ``G`` groups of
+consecutive heads, head ``h`` reading group ``h // (H / G)``; Granite 4.0-H
+has one group, Nemotron-H eight):
 
     S_t = a_t * S_{t-1} + dt_t * X_t (x) B_t,   S = 0 at a document's first token
     Y_t = S_t C_t
@@ -45,13 +47,15 @@ KERNEL, XLA = "kernel", "xla"
 _NEG_INF = -jnp.inf
 
 
-def lowering(backend: str, seq_len: int, chunk: int, heads: int, head_dim: int, state: int) -> str:
+def lowering(backend: str, seq_len: int, chunk: int, heads: int, head_dim: int, state: int, groups: int = 1) -> str:
     """``kernel`` where the scan's kernels can run: a TPU backend, a sequence
     of whole chunks, and whole tiles (chunks and states of whole lane tiles,
-    heads of whole sublane tiles, whole blocks of heads); ``xla`` everywhere
-    else (the CPU, the tiny preset's chunks of 8, a ragged sequence)."""
+    heads of whole sublane tiles, whole blocks of heads, each holding whole
+    groups or lying inside one); ``xla`` everywhere else (the CPU, the tiny
+    presets' chunks of 8, a ragged sequence)."""
     whole_tiles = (chunk % 128 == 0 and state % 128 == 0 and head_dim % 16 == 0
-                   and heads % ssd_kernel.HEADS_PER_BLOCK == 0)
+                   and heads % ssd_kernel.HEADS_PER_BLOCK == 0
+                   and ssd_kernel.groups_per_block(heads, groups, ssd_kernel.HEADS_PER_BLOCK) is not None)
     return KERNEL if backend == "tpu" and seq_len > 0 and seq_len % chunk == 0 and whole_tiles else XLA
 
 
@@ -67,20 +71,30 @@ def ssd_chunked(
     """``Y`` of the recurrence above (without the ``D`` skip).
 
     x: (batch, T, H, P); dt: (batch, T, H) float32, after softplus;
-    a_log_decay: (H,) float32, ``A = -exp(A_log)``; b, c: (batch, T, N);
+    a_log_decay: (H,) float32, ``A = -exp(A_log)``; b, c: (batch, T, N), one
+    group, or (batch, T, G, N);
     segment_ids: (batch, T) int, constant over a document and different in
     neighbouring documents; chunk: tokens per chunk, any positive number
     (T is padded up to a multiple with tokens of no document).
     Returns (batch, T, H, P) float32.
     """
     _, t, heads, p = x.shape
-    kernel = lowering(jax.default_backend(), t, chunk, heads, p, b.shape[-1]) == KERNEL
+    groups = 1 if b.ndim == 3 else b.shape[2]
+    kernel = lowering(jax.default_backend(), t, chunk, heads, p, b.shape[-1], groups) == KERNEL
     return _chunked(x, dt, a_log_decay, b, c, segment_ids, chunk, ssd_kernel.chunked_scan if kernel else None)
 
 
 def _chunked(x, dt, a_log_decay, b, c, segment_ids, chunk, scan_kernel):
     batch, t, heads, p = x.shape
     n = b.shape[-1]
+    if b.ndim == 4 and scan_kernel is None:
+        # XLA: each group's heads are a scan of their own over that group's B and C.
+        groups = b.shape[2]
+        by_group = lambda a: a.reshape(*a.shape[:2], groups, heads // groups, *a.shape[3:])
+        y = jax.vmap(lambda x, dt, a, b, c: _chunked(x, dt, a, b, c, segment_ids, chunk, None),
+                     in_axes=(2, 2, 0, 2, 2), out_axes=2)(
+            by_group(x), by_group(dt), a_log_decay.reshape(groups, -1), b, c)
+        return y.reshape(batch, t, heads, p)
     pad = -t % chunk
     if pad:
         # Padding: a document of its own that contributes nothing (dt = 0).
@@ -91,8 +105,8 @@ def _chunked(x, dt, a_log_decay, b, c, segment_ids, chunk, scan_kernel):
     dtype = x.dtype
     x = x.reshape(batch, nc, chunk, heads, p)
     dt = dt.astype(jnp.float32).reshape(batch, nc, chunk, heads)
-    b = b.reshape(batch, nc, chunk, n)
-    c = c.reshape(batch, nc, chunk, n)
+    b = b.reshape(batch, nc, chunk, *b.shape[2:])  # (..., n), or (..., groups, n) for the kernels
+    c = c.reshape(batch, nc, chunk, *c.shape[2:])
     seg = segment_ids.reshape(batch, nc, chunk)
 
     log_a = dt * a_log_decay.astype(jnp.float32)  # (b, c, l, h), <= 0

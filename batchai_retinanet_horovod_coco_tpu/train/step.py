@@ -67,6 +67,8 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     "mla": ("q_proj", "kv_a", "kv_b", "rope", "core", "o_proj"),  # latent attention with its norm
     "dense_mlp": (),
     "moe": ("router", "dispatch", "experts", "combine", "shared", "aux"),  # ops/moe.py, with its norm
+    # models/nemotron_h.py enters ``embed``, ``mamba``, ``attention``, ``moe`` (without ``aux``), ``lm_head``:
+    # every layer ONE of the three mixers with its norm, and no ``mlp``
     # every task's
     "loss": (),  # focal, smooth-L1, target encoding; next-token cross-entropy
     "optimizer": (),  # clip, decay, momentum, apply, the numerics summary

@@ -223,7 +223,8 @@ class LMTask(_Task):
     loss counts the positions whose next token lies in the same document.
 
     What differs from one language model to the next is the MODEL's to
-    supply (models/granite_hybrid.py, models/deepseek_v2.py): the scopes its
+    supply (models/granite_hybrid.py, models/deepseek_v2.py,
+    models/nemotron_h.py): the scopes its
     step enters (``model.scopes``), what ``run_meta`` says of its step
     (``model.run_meta(bucket)``), and ``model.loss(params, tokens,
     segment_ids) -> (loss, scalars)``: the next-token cross-entropy plus

@@ -153,8 +153,54 @@ def test_train_py_lm_synthetic_trains_the_tiny_moe_preset_and_logs_its_counters(
         assert name in text, name
 
 
+def test_train_py_lm_synthetic_trains_the_tiny_nemotron_preset_and_logs_its_counters(tmp_path, capsys):
+    from train import main
+
+    args = ["lm-synthetic", "--platform", "cpu", "--model", "tiny-nemotron", "--log-every", "2", "--steps", "4",
+            "--log-dir", str(tmp_path / "logs")]
+    assert main(args) == {"final_step": 4.0}
+    out = capsys.readouterr().out
+    assert "nemotron-h, 5 layers (2 mamba, 2 moe, 1 attention), 2 of 8 experts held, 3 a token, d=64" in out
+    with open(tmp_path / "logs" / "metrics.jsonl") as f:
+        text = f.read()
+    for name in ("moe/rows_held", "moe/rows_max_expert", "moe/rows_min_expert", "tokens_counted"):
+        assert name in text, name
+    assert "moe/aux_loss" not in text
+
+
+def test_a_few_steps_of_run_training_lower_the_tiny_nemotrons_loss_and_log_its_scalars(tmp_path):
+    from batchai_retinanet_horovod_coco_tpu.models import nemotron_h
+
+    model = nemotron_h.NemotronH(nemotron_h.TINY)
+    batch = next(packed_token_batches(SOURCE))
+    trace.reset()
+    trace.configure(str(tmp_path / "obs"), process_label="t")
+    try:
+        sink = _Sink()
+        state = loop.run_training(model, _state(model), itertools.repeat(batch), None,
+                                  loop.LoopConfig(total_steps=30, log_every=10, numerics=True), task=LMTask(), logger=sink)
+        events = trace.snapshot_events()
+    finally:
+        trace.reset()
+    assert int(state.step) == 30
+    losses = [s["loss"] for _, s in sink.rows]
+    assert losses[-1] < losses[0] - 0.05, losses
+    scalars = sink.rows[-1][1]
+    assert {"loss", "tokens_counted", "moe/rows_held", "moe/rows_max_expert", "moe/rows_min_expert", "grad_norm",
+            *(f"gnorm/{g}" for g in ("embed", "mamba", "attention", "router", "experts", "shared", "norms", "head"))
+            } <= set(scalars)
+    meta = [e for e in events if e["name"] == "run_meta"]
+    assert meta and {"attention_lowering": "xla", "ssd_lowering": "xla", "ssd_groups": 2, "moe_lowering": "xla",
+                     "moe_rows_lowering": "xla", "experts_held": 2, "experts_total": 8}.items() <= meta[-1]["args"].items()
+    (built,) = [e for e in events if e["name"] == "compile_train_step"]
+    assert built["args"]["bucket"] == "2x64"
+    compiled = loop.compiled_step((2, 64))
+    assert {"mamba", "attention", "moe", "lm_head", "optimizer"} <= {s for s, _, _ in scope_table(compiled).values()}
+
+
 @pytest.mark.parametrize("model_type,says", [("deepseek_v2", "deepseek v2, 3 layers"),
-                                             ("granitemoehybrid", "granite hybrid, 10 layers (9 mamba)")])
+                                             ("granitemoehybrid", "granite hybrid, 10 layers (9 mamba)"),
+                                             ("nemotron_h", "nemotron-h, 5 layers (2 mamba, 2 moe, 1 attention)")])
 def test_train_py_lm_synthetic_picks_the_model_by_model_type(tmp_path, capsys, model_type, says):
     """A ``--model <config.json>`` at toy widths: the benchmark's file of
     that model_type with the CPU tests' sizes."""
@@ -165,9 +211,11 @@ def test_train_py_lm_synthetic_picks_the_model_by_model_type(tmp_path, capsys, m
     sys.path.insert(0, os.path.join(_REPO_ROOT, "tests", "benchmark"))
     from test_benchmark_lm_cell import TINY_MODEL as granite_tiny
     from test_benchmark_moe_cell import TINY_MODEL as moe_tiny
+    from test_benchmark_nemotron_cell import TINY_MODEL as nemotron_tiny
 
     name, tiny = {"deepseek_v2": ("deepseek-v2-lite-ep8", moe_tiny),
-                  "granitemoehybrid": ("granite-4.0-h-micro-p1", granite_tiny)}[model_type]
+                  "granitemoehybrid": ("granite-4.0-h-micro-p1", granite_tiny),
+                  "nemotron_h": ("nemotron-3-nano-30b-ep16", nemotron_tiny)}[model_type]
     with open(os.path.join(_REPO_ROOT, "benchmark", "configs", name + ".json")) as f:
         config = dict(json.load(f), **tiny)
     assert config["model_type"] == model_type
@@ -214,3 +262,4 @@ def test_train_py_help_names_the_lm_subcommand(capsys):
         build_parser().parse_args(["lm-synthetic", "--help"])
     text = " ".join(capsys.readouterr().out.split())
     assert "--model" in text and "tiny-moe" in text and "deepseek_v2" in text and "granitemoehybrid" in text
+    assert "tiny-nemotron" in text and "nemotron_h" in text and "nemotron-3-nano-30b-ep16.json" in text

@@ -124,6 +124,121 @@ def test_the_grouped_kernel_and_the_xla_lowering_agree(dtype, tol):
         assert _rel(a, b) < tol, name
 
 
+# ---- the two-matrix relu^2 expert, the sigmoid router, a width that is not whole lanes -----
+
+SCALE = 2.5
+RAGGED = 208  # 13 x 16: whole packed sublanes, 1.625 lane tiles (Nemotron-H's 1856 is 14.5)
+
+
+def _relu2_weights(seed, held, width, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    u = jnp.asarray(rng.normal(size=(T, D)), dtype)
+    w_gate = jnp.asarray(rng.normal(size=(D, E)) * 0.3, jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(E,)) * 0.2, jnp.float32)
+    up = jnp.asarray(rng.normal(size=(len(held), D, width)) * 0.1, dtype)
+    down = jnp.asarray(rng.normal(size=(len(held), width, D)) * 0.1, dtype)
+    return u, w_gate, bias, up, down
+
+
+def _relu2_layer(u, w_gate, bias, up, down, held, how=moe.XLA, interpret=False):
+    r = moe.route_sigmoid(u, w_gate, K, bias, SCALE)
+    plan = moe.dispatch(r.picks, held, E)
+    y = moe.experts_relu2(moe.gather_rows(u, plan), up, down, plan, how, interpret=interpret)
+    return moe.combine(y, plan, r.weights), r, plan
+
+
+def _relu2_dense(u, w_gate, bias, up, down, held):
+    """Every held expert on every token; the weight of a picked expert is its
+    sigmoid over the sum of the picked sigmoids, times the scale."""
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.sigmoid(u.astype(jnp.float32) @ w_gate)
+        picks = jnp.argsort(-(scores + bias), axis=-1)[:, :K]
+        picked = jnp.sum(jax.nn.one_hot(picks, E), axis=1)
+        weights = SCALE * scores * picked / jnp.sum(scores * picked, axis=-1, keepdims=True)
+        y = 0.0
+        for j, e in enumerate(held):
+            y = y + weights[:, e, None] * (jnp.square(jnp.maximum(u @ up[j], 0.0)) @ down[j])
+        return y
+
+
+def test_the_sigmoid_routers_bias_moves_picks_and_never_weights():
+    u, w_gate, bias, _, _ = _relu2_weights(7, (0,), W)
+    r = moe.route_sigmoid(u, w_gate, K, bias, SCALE)
+    scores = np.asarray(jax.nn.sigmoid(jnp.dot(u, w_gate, precision="highest")))
+    np.testing.assert_allclose(np.asarray(r.scores), scores, rtol=1e-6)
+    order = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :K]
+    np.testing.assert_array_equal(np.asarray(r.picks), order)
+    picked = np.take_along_axis(scores, order, axis=-1)
+    np.testing.assert_allclose(np.asarray(r.weights), SCALE * picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(r.weights).sum(-1), SCALE, rtol=1e-6)  # norm_topk_prob x the scale
+    # without the bias other experts are picked ...
+    plain = moe.route_sigmoid(u, w_gate, K, jnp.zeros((E,)), SCALE)
+    assert (np.sort(np.asarray(plain.picks), -1) != np.sort(order, -1)).any()
+    # ... a bias that is the same for every expert changes nothing at all ...
+    shifted = moe.route_sigmoid(u, w_gate, K, jnp.full((E,), 3.0), SCALE)
+    np.testing.assert_array_equal(np.asarray(shifted.picks), np.asarray(plain.picks))
+    np.testing.assert_array_equal(np.asarray(shifted.weights), np.asarray(plain.weights))
+    # ... and no gradient reaches it
+    g = jax.grad(lambda b: jnp.sum(jnp.square(moe.route_sigmoid(u, w_gate, K, b, SCALE).weights)))(bias)
+    assert not np.asarray(g).any()
+    np.testing.assert_array_equal(np.asarray(r.counts), np.bincount(order.reshape(-1), minlength=E))
+
+
+@pytest.mark.parametrize("width", [W, RAGGED], ids=["whole_lanes", "ragged_width"])
+@pytest.mark.parametrize("held", [(0, 1, 2, 3), (1, 5, 6, 9), tuple(range(E))], ids=str)
+def test_the_relu2_layer_matches_a_dense_loop_and_its_gradients(held, width):
+    args = _relu2_weights(8, held, width)
+    out, r, plan = _relu2_layer(*args, held)
+    assert _rel(out, _relu2_dense(*args, held)) < 2e-6
+    np.testing.assert_array_equal(np.asarray(plan.group_sizes), np.asarray(r.counts)[list(held)])
+    got = jax.grad(lambda u, g, b, up, dn: jnp.sum(jnp.square(_relu2_layer(u, g, b, up, dn, held)[0])),
+                   argnums=(0, 1, 3, 4))(*args)
+    wanted = jax.grad(lambda u, g, b, up, dn: jnp.sum(jnp.square(_relu2_dense(u, g, b, up, dn, held))),
+                      argnums=(0, 1, 3, 4))(*args)
+    for name, a, b in zip(("input", "router", "up", "down"), got, wanted):
+        assert _rel(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 1e-2)], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("expert", ["gated_silu", "relu2"])
+def test_the_grouped_kernel_at_a_width_that_is_not_whole_lanes_is_ragged_dot(expert, dtype, tol):
+    """The expert's width is ONE tile of gmm / tgmm (interpret mode), the
+    whole axis, as the kernel lowering takes Nemotron-H's 1856: the output and
+    the gradients of the input and of both weights against ``ragged_dot``."""
+    held = (1, 5, 6, 9)
+    u, w_gate, bias, up, down = _relu2_weights(9, held, RAGGED, dtype)
+    if expert == "gated_silu":
+        up = jnp.concatenate([up, up[..., ::-1]], axis=-1)  # (held, D, 2 x RAGGED)
+    mlp = moe.experts if expert == "gated_silu" else moe.experts_relu2
+    r = moe.route_sigmoid(u, w_gate, K, bias, SCALE)
+    plan = moe.dispatch(r.picks, held, E)
+    assert moe._product_tiles(D, up.shape[-1]) == (D, up.shape[-1]) and moe._product_tiles(RAGGED, D) == (RAGGED, D)
+
+    def run(how, interpret):
+        f = lambda u, up, down: moe.combine(
+            mlp(moe.gather_rows(u, plan), up, down, plan, how, interpret=interpret), plan, r.weights)
+        return f(u, up, down), jax.grad(lambda *a: jnp.sum(jnp.square(f(*a))), argnums=(0, 1, 2))(u, up, down)
+
+    with mock.patch.object(moe, "TILE_ROWS", TILE):
+        out_k, grads_k = run(moe.KERNEL, True)
+    out_x, grads_x = run(moe.XLA, False)
+    assert _rel(out_k, out_x) < tol
+    for name, a, b in zip(("input", "up", "down"), grads_k, grads_x):
+        assert a.shape == b.shape and _rel(a, b) < tol, name
+
+
+@pytest.mark.parametrize("k,n,tiles,gradient_tiles", [
+    (2048, 2816, (1024, 1408), (512, 1408)),  # DeepSeek-V2-Lite's gate and up: as PR 30 measured them
+    (1408, 2048, (1408, 1024), (1408, 512)),
+    (2688, 1856, (384, 1856), (384, 1856)),   # Nemotron-H's up: the width whole, the other cut to fit VMEM
+    (1856, 2688, (1856, 384), (1856, 384)),
+    (2688, 3712, (896, 128), (896, 128)),     # 3712 = 29 x 128: prime in lane tiles (the shared expert is no grouped product)
+])
+def test_the_products_tiles_divide_their_axes(k, n, tiles, gradient_tiles):
+    assert moe._product_tiles(k, n) == tiles and moe._weight_gradient_tiles(k, n) == gradient_tiles
+    assert all(axis % tile == 0 for axis, tile in zip((k, n), tiles))
+
+
 def _forced_router(to: tuple[int, ...]):
     """A router whose K largest scores are ``to``'s experts for EVERY token:
     a bias through a constant input column."""
@@ -181,6 +296,10 @@ def test_the_balance_loss_is_the_sum_of_load_times_mean_score_per_sequence():
     ("tpu", 64, 6, 64, 32, moe.XLA, moe.XLA),  # the tiny preset: not whole tiles
     ("tpu", 98304 + 256, 1, 2048, 1408, moe.XLA, moe.XLA),
     ("tpu", 16384, 6, 2048, 1400, moe.XLA, moe.XLA),
+    # Nemotron-H: 1856 = 14.5 lane tiles is one tile of the products; a bfloat16 row of 2688 is 1.3 slabs
+    ("tpu", 16384, 6, 2688, 1856, moe.KERNEL, moe.XLA),
+    ("cpu", 16384, 6, 2688, 1856, moe.XLA, moe.XLA),
+    ("tpu", 16384, 6, 2688, 4160, moe.XLA, moe.XLA),  # too wide to be one tile
     # the row kernels follow the products and ask for their own whole tiles besides
     ("tpu", 16384, 6, 4096, 1408, moe.KERNEL, moe.KERNEL),
     ("tpu", 16384, 6, 1024, 1408, moe.KERNEL, moe.XLA),  # a bfloat16 row of 1024 is half a slab

@@ -35,14 +35,17 @@ def _segments(lengths):
     return seg[None]
 
 
-def _operands(seed, dtype, seg):
+def _operands(seed, dtype, seg, groups=None):
+    """``groups`` None: B and C (1, T, STATE), one group as Granite's mixer
+    passes them; a number: (1, T, groups, STATE)."""
     keys = jax.random.split(jax.random.key(seed), 6)
+    bc_shape = (1, T, STATE) if groups is None else (1, T, groups, STATE)
     x = jax.random.normal(keys[0], (1, T, HEADS, HEAD), jnp.float32).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(keys[1], (1, T, HEADS), jnp.float32) - 2.0)
     dt = jnp.where(seg[..., None] < 0, 0.0, dt)  # padding contributes nothing
     a = -jnp.exp(jax.random.uniform(keys[2], (HEADS,), jnp.float32, 0.0, 2.7))  # A in (-15, -1)
-    b = jax.random.normal(keys[3], (1, T, STATE), jnp.float32).astype(dtype)
-    c = jax.random.normal(keys[4], (1, T, STATE), jnp.float32).astype(dtype)
+    b = jax.random.normal(keys[3], bc_shape, jnp.float32).astype(dtype)
+    c = jax.random.normal(keys[4], bc_shape, jnp.float32).astype(dtype)
     g = jax.random.normal(keys[5], (1, T, HEADS, HEAD), jnp.float32)
     return (x, dt, a, b, c), g
 
@@ -60,15 +63,20 @@ def _xla(x, dt, a, b, c, seg):
 
 def _recurrence(x, dt, a, b, c, seg):
     """Token by token in float32 at the highest matmul precision, as
-    benchmark/reference/granite_hybrid.py::mamba runs it."""
+    benchmark/reference/granite_hybrid.py::mamba runs it; head ``h`` reads
+    the B and C of group ``h // (heads / groups)``."""
     x, b, c = (v[0].astype(jnp.float32) for v in (x, b, c))
+    if b.ndim == 3:  # (T, groups, STATE) -> a B and a C for every head
+        b, c = (jnp.repeat(v, HEADS // v.shape[1], axis=1) for v in (b, c))
+    else:
+        b, c = (jnp.broadcast_to(v[:, None, :], (T, HEADS, STATE)) for v in (b, c))
     first = jnp.concatenate([jnp.ones((1,), bool), seg[0, 1:] != seg[0, :-1]])
 
     def token(state, inp):
         x_t, b_t, c_t, dt_t, first_t = inp
         state = jnp.where(first_t, 0.0, state)
-        state = jnp.exp(dt_t * a)[:, None, None] * state + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
-        return state, jnp.einsum("hpn,n->hp", state, c_t, precision="highest")
+        state = jnp.exp(dt_t * a)[:, None, None] * state + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
+        return state, jnp.einsum("hpn,hn->hp", state, c_t, precision="highest")
 
     _, y = jax.lax.scan(token, jnp.zeros((HEADS, HEAD, STATE), jnp.float32), (x, b, c, dt[0], first))
     return y[None]
@@ -120,6 +128,61 @@ def test_bfloat16_kernel_is_no_further_from_the_float32_recurrence_than_the_xla_
         assert _rel(gk, gr) <= 1.05 * _rel(gx, gr), name
 
 
+GROUPED = {  # groups of B and C, heads in a block of the kernel
+    "one_group_as_a_fourth_axis": (1, HEADS),
+    "two_groups_in_one_block": (2, HEADS),
+    "a_group_a_head": (8, HEADS),
+    "two_blocks_inside_each_of_two_groups": (2, 2),
+    "a_block_a_group": (4, 2),
+}
+
+
+@pytest.mark.parametrize("layout", ["a_boundary_inside_a_chunk", "a_document_over_many_chunks"])
+@pytest.mark.parametrize("case", GROUPED)
+def test_grouped_scan_xla_body_and_kernel_are_the_recurrence_by_group(case, layout):
+    """B and C in groups of consecutive heads (Nemotron-H's mixer: 8): the XLA
+    body against the token-by-token recurrence in which head h reads group
+    h // (heads / groups), and the kernels (whole groups in a block, or a block
+    inside a group) against the XLA body, forward and backward."""
+    groups, heads_per_block = GROUPED[case]
+    seg = jnp.asarray(_segments(LAYOUTS[layout]))
+    operands, g = _operands(4, jnp.float32, seg, groups)
+    scan = functools.partial(ssd_kernel.chunked_scan, heads_per_block=heads_per_block, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        out_k, grads_k = _out_and_grads(lambda *o: ssd._chunked(*o, CHUNK, scan), operands, g, seg)
+        out_x, grads_x = _out_and_grads(_xla, operands, g, seg)
+    out_r, grads_r = _out_and_grads(_recurrence, operands, g, seg)
+    assert out_x.shape == (1, T, HEADS, HEAD)
+    assert _rel(out_x, out_r) < 1e-5 and _rel(out_k, out_x) < 1e-6
+    for name, gk, gx, gr in zip(NAMES, grads_k, grads_x, grads_r, strict=True):
+        assert gk.shape == gx.shape == gr.shape and gk.dtype == gx.dtype, name
+        assert _rel(gx, gr) < 1e-4, name
+        assert _rel(gk, gx) < 2e-5, name
+
+
+@pytest.mark.parametrize("lowered", ["xla", "kernel"])
+def test_a_head_reads_its_own_group_and_no_other(lowered):
+    """Four groups of two heads: changing group 2's B and C moves heads 4 and 5 alone."""
+    seg = jnp.asarray(_segments(LAYOUTS["a_boundary_inside_a_chunk"]))
+    (x, dt, a, b, c), _ = _operands(5, jnp.float32, seg, 4)
+    fn = _kernel if lowered == "kernel" else _xla
+    other = fn(x, dt, a, b.at[:, :, 2].multiply(-1.5), c.at[:, :, 2].add(0.5), seg)
+    moved = np.abs(np.asarray(fn(x, dt, a, b, c, seg) - other)).max(axis=(0, 1, 3))
+    assert (moved[[4, 5]] > 1e-2).all() and (np.delete(moved, [4, 5]) == 0).all()
+
+
+def test_grouped_bfloat16_kernel_is_no_further_from_the_float32_recurrence_than_the_xla_body():
+    seg = jnp.asarray(_segments(LAYOUTS["a_document_over_many_chunks"]))
+    operands, g = _operands(6, jnp.bfloat16, seg, 8)
+    out_k, grads_k = _out_and_grads(_kernel, operands, g, seg)
+    out_x, grads_x = _out_and_grads(_xla, operands, g, seg)
+    out_r, grads_r = _out_and_grads(_recurrence, operands, g, seg)
+    assert _rel(out_k, out_r) < 4e-3 and _rel(out_k, out_r) <= 1.05 * _rel(out_x, out_r)
+    for name, gk, gx, gr in zip(NAMES, grads_k, grads_x, grads_r, strict=True):
+        assert gk.dtype == gx.dtype, name
+        assert _rel(gk, gr) < 1e-2 and _rel(gk, gr) <= 1.05 * _rel(gx, gr), name
+
+
 def test_a_large_dt_gives_no_nan():
     """``cum_i - cum_j`` above the diagonal is then hundreds: exponentiated
     before the mask it would be inf, and inf times the mask's zero a NaN."""
@@ -163,6 +226,19 @@ def test_lowering_follows_backend_and_shapes(backend, seq_len, chunk, heads, hea
     assert ssd.lowering(backend, seq_len, chunk, heads, head, state) == want
 
 
+@pytest.mark.parametrize("heads,groups,want", [
+    (64, 8, ssd.KERNEL),   # nemo3-nano-train-pack8k: a block of 32 heads carries four groups
+    (64, 1, ssd.KERNEL),   # granite's, said aloud
+    (64, 2, ssd.KERNEL),   # a block is a group
+    (64, 64, ssd.KERNEL),  # a group a head
+    (64, 3, ssd.XLA),      # the heads are not whole groups
+    (96, 2, ssd.XLA),      # groups of 48 heads: a block of 32 would cut one
+])
+def test_lowering_follows_the_groups(heads, groups, want):
+    assert ssd.lowering("tpu", 8192, 256, heads, 64, 128, groups) == want
+    assert ssd.lowering("cpu", 8192, 256, heads, 64, 128, groups) == ssd.XLA
+
+
 def test_mixer_takes_the_xla_path_on_the_cpu(monkeypatch):
     monkeypatch.setattr(ssd_kernel, "chunked_scan", lambda *a, **k: pytest.fail("the kernels on the CPU"))
     config = granite_hybrid.TINY
@@ -176,11 +252,13 @@ def _calls(text):
     return len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text))
 
 
-def test_kernels_lower_for_tpu_at_the_cells_shapes_with_the_committed_block():
+@pytest.mark.parametrize("groups", [None, 8], ids=["granite_one_group", "nemotron_eight_groups"])
+def test_kernels_lower_for_tpu_at_the_cells_shapes_with_the_committed_block(groups):
     """JAX-level lowering only (what Mosaic says of it is the chip's to tell):
     64 heads of 64, state 128, 8192 tokens in chunks of 256; the forward that
     saves the states and the backward."""
     spec = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    bc = spec(1, 8192, 128) if groups is None else spec(1, 8192, groups, 128)
 
     def fn(x, dt, a, b, c, seg):
         out, vjp = jax.vjp(lambda *o: ssd._chunked(*o, seg, 256, ssd_kernel.chunked_scan), x, dt, a, b, c)
@@ -188,10 +266,10 @@ def test_kernels_lower_for_tpu_at_the_cells_shapes_with_the_committed_block():
 
     text = jax.jit(fn).trace(
         spec(1, 8192, 64, 64), spec(1, 8192, 64, dtype=jnp.float32), spec(64, dtype=jnp.float32),
-        spec(1, 8192, 128), spec(1, 8192, 128), spec(1, 8192, dtype=jnp.int32),
+        bc, bc, spec(1, 8192, dtype=jnp.int32),
     ).lower(lowering_platforms=("tpu",)).as_text()
     assert _calls(text) == 2
-    assert ssd.lowering("tpu", 8192, 256, 64, 64, 128) == ssd.KERNEL
+    assert ssd.lowering("tpu", 8192, 256, 64, 64, 128, groups or 1) == ssd.KERNEL
 
 
 def test_the_kernels_calls_sit_under_mamba_ssd_in_all_three_passes(monkeypatch):

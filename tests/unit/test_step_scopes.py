@@ -223,6 +223,42 @@ def test_the_lm_tasks_scopes_reach_the_compiled_step_through_recomputation():
     assert {s for s, _, _ in dots} >= {"mamba", "attention", "mlp", "lm_head"}
 
 
+def test_the_hybrid_expert_models_step_files_every_operation_it_can_under_a_scope_of_the_model():
+    """models/nemotron_h.py through the shared step: a layer is ONE mixer, so
+    the model enters ``mamba``, ``attention`` and ``moe`` and neither ``mlp``
+    nor the other mixture of experts' ``mla`` / ``dense_mlp`` / ``moe/aux``;
+    forward, recomputed forward and backward keep the scope; every matrix
+    product and every sort of the compiled step lies under one of the
+    model's scopes or the optimizer's, none is unscoped."""
+    from batchai_retinanet_horovod_coco_tpu.models import nemotron_h
+    from batchai_retinanet_horovod_coco_tpu.train.step import UNSCOPED
+
+    model = nemotron_h.NemotronH(nemotron_h.TINY)
+    tx = make_optimizer(OptimizerConfig(optimizer="adamw", schedule="constant", warmup_steps=0))[0]
+    state = create_train_state(model, tx, (1, 8), jax.random.key(0), example_dtype=LMTask.example_dtype)
+    seg = jnp.asarray(np.repeat([[0, 1, 2], [0, 1, 1]], [20, 30, 14], axis=1), jnp.int32)
+    batch = {"tokens": jnp.zeros((2, 64), jnp.int32), "segment_ids": seg}
+    compiled = make_train_step(model, (2, 64), None, task=LMTask(), donate_state=False).lower(state, batch).compile()
+    table = scope_table(compiled)
+    filed = {(s, d) for s, d, _ in table.values()}
+    assert model.scopes == ("embed", "mamba", "attention", "moe", "lm_head", "loss")
+    assert set(model.scopes) <= set(STEP_SCOPES)
+    for s in (*model.scopes, "optimizer"):
+        assert (s, "fwd") in filed, s
+    assert {s for s, d in filed if d == "bwd"} >= set(model.scopes)
+    assert not {"mla", "dense_mlp", "mlp"} & {s for s, _ in filed}
+    assert not set(DetectionTask.scopes) - {"loss"} & {s for s, _ in filed}
+    for slice_, beneath in (("mamba", STEP_SCOPES["mamba"]), ("moe", ("router", "dispatch", "experts", "combine", "shared"))):
+        paths = {p for t, _, p in table.values() if t == slice_}
+        for name in beneath:
+            assert any(f"/{name}/" in p or p.endswith("/" + name) for p in paths), (slice_, name)
+    assert not any("/aux" in p for t, _, p in table.values() if t == "moe")  # no balance loss
+    work = {n: table[n] for n, op in _instructions(compiled).items() if op in ("dot", "sort", "convolution")}
+    assert work and not [n for n, (s, _, _) in work.items() if s == UNSCOPED]
+    assert {s for s, _, _ in work.values()} >= {"mamba", "attention", "moe", "lm_head"}
+    assert {s for s, _, _ in work.values()} <= {*model.scopes, "optimizer"}
+
+
 def test_a_step_built_for_an_explicit_detection_task_is_the_default_step():
     model = _model()
     state = create_train_state(model, _optimizer(), (1, *HW, 3), jax.random.key(0))

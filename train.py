@@ -27,10 +27,14 @@ published keys and picks the model by ``model_type``: ``granitemoehybrid``
 period, models/granite_hybrid.py; benchmark/configs/granite-4.0-h-micro-p1.json)
 or ``deepseek_v2`` (latent attention, routed and shared experts of which this
 chip holds a share, models/deepseek_v2.py;
-benchmark/configs/deepseek-v2-lite-ep8.json).  ``--model tiny`` (the default:
-one Granite period of ten layers at width 64) and ``--model tiny-moe`` (one
-dense and two expert layers, 4 of 16 experts held) are what the CPU tests
-run.  The LM task is single-chip until an issue brings its sharding:
+benchmark/configs/deepseek-v2-lite-ep8.json) or ``nemotron_h`` (Nemotron-H:
+every layer ONE mixer, a Mamba-2 scan with grouped B and C, squared-ReLU
+routed and shared experts behind a sigmoid router, or grouped-query attention,
+models/nemotron_h.py; benchmark/configs/nemotron-3-nano-30b-ep16.json).
+``--model tiny`` (the default: one Granite period of ten layers at width 64),
+``--model tiny-moe`` (one dense and two expert layers, 4 of 16 experts held)
+and ``--model tiny-nemotron`` (the pattern MEM*E, 2 groups, 2 of 8 experts
+held) are what the CPU tests run.  The LM task is single-chip until an issue brings its sharding:
 ``--num-devices`` above 1 is refused.
 """
 
@@ -305,21 +309,26 @@ def _add_lm_parser(sub) -> None:
     eval or mesh flags apply)."""
     lm = sub.add_parser(
         "lm-synthetic", allow_abbrev=False,
-        help="train a language model (Granite 4.0-H hybrid or DeepSeek-V2, "
-             "by the config's model_type) on seeded packed token sequences "
-             "(single chip; --model tiny or tiny-moe on a CPU)",
+        help="train a language model (Granite 4.0-H hybrid, DeepSeek-V2 or "
+             "Nemotron-H, by the config's model_type) on seeded packed token "
+             "sequences (single chip; --model tiny, tiny-moe or tiny-nemotron "
+             "on a CPU)",
     )
     g = lm.add_argument_group("model")
     g.add_argument("--model", default="tiny",
                    help="'tiny' (Granite 4.0-H: one period of ten layers "
                         "at width 64, vocabulary 128), 'tiny-moe' "
                         "(DeepSeek-V2: a dense and two expert layers at "
-                        "width 64, 4 of 16 experts held, 3 a token) - the "
-                        "CPU tests' presets - or a JSON file with the "
+                        "width 64, 4 of 16 experts held, 3 a token), "
+                        "'tiny-nemotron' (Nemotron-H: the pattern MEM*E at "
+                        "width 64, 2 groups, 2 of 8 experts held, 3 a token) "
+                        "- the CPU tests' presets - or a JSON file with the "
                         "published config.json keys, whose model_type "
-                        "(granitemoehybrid, deepseek_v2) picks the model: "
+                        "(granitemoehybrid, deepseek_v2, nemotron_h) picks "
+                        "the model: "
                         "benchmark/configs/granite-4.0-h-micro-p1.json, "
-                        "benchmark/configs/deepseek-v2-lite-ep8.json")
+                        "benchmark/configs/deepseek-v2-lite-ep8.json, "
+                        "benchmark/configs/nemotron-3-nano-30b-ep16.json")
     g = lm.add_argument_group("data")
     g.add_argument("--seq-len", type=int, default=64,
                    help="tokens per packed sequence")

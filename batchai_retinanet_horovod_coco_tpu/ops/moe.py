@@ -50,7 +50,8 @@ gradient too.
 
 Two lowerings of the row movements around the products too (``gather_rows``,
 ``combine`` and their backward passes), chosen by ``rows_lowering``, which
-follows ``lowering`` and asks for whole token tiles and whole slabs besides:
+follows ``lowering`` and asks for whole token tiles besides (a row of whole
+lanes is all the kernels need: a slab may be wider than its row):
 
 - ``kernel``: ops/pallas/moe_rows.py.  ``to_buffer`` (``gather_rows``, and
   ``combine``'s backward with the pair's weight as scale and the weight's
@@ -370,15 +371,15 @@ _kernel_combine.defvjp(_kernel_combine_fwd, _kernel_combine_bwd)
 
 
 def _rows_follow(how: str, tokens: int, d_model: int) -> str:
-    whole = tokens % TOKEN_TILE == 0 and d_model % moe_rows.SLAB_COLUMNS == 0
+    whole = tokens % TOKEN_TILE == 0 and d_model % moe_rows.LANES == 0
     return KERNEL if how == KERNEL and whole else XLA
 
 
 def rows_lowering(backend: str, tokens: int, k: int, d_model: int, d_expert: int) -> str:
     """What ``gather_rows`` and ``combine`` take: ``kernel`` where the grouped
-    products take it (``lowering``) and the row kernels' own tiles are whole
-    (token tiles, and rows of whole slabs: ops/pallas/moe_rows.py); ``xla``
-    everywhere else."""
+    products take it (``lowering``, which asks for rows of whole lanes: all
+    that ops/pallas/moe_rows.py asks of a width) and the tokens are whole
+    tiles of ``TOKEN_TILE``; ``xla`` everywhere else."""
     return _rows_follow(lowering(backend, tokens * k, d_model, d_expert), tokens, d_model)
 
 

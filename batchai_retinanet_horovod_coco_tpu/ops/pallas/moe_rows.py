@@ -18,12 +18,24 @@ pick order: no ``(tokens, k, d)`` array exists in HBM.
 **Slabs.**  Mosaic slices an HBM array only at whole (8, 128) tiles of its
 last two dimensions, so a single row of a ``(n, d)`` array cannot be copied.
 Rows are therefore gathered from a *slab* copy ``(n, S, 128)`` uint32 in which
-a row is ``S`` whole sublanes and contiguous: a 32-bit row as it is (``S = d /
-128``), a 16-bit row with column ``c`` in the low and column ``c + d / 2`` in
-the high half of a word (``S = d / 256``; unpacking bfloat16 is then a shift
-or a mask, and both halves stay on their lanes).  ``pack`` writes the slabs of
-the first ``live`` rows and touches nothing behind them.  The way back to rows
-by columns is a strided load: sublane ``c`` of every slab of a tile.
+a row is ``S`` whole sublanes and contiguous, ``S`` the row's words rounded UP
+to whole tiles of eight sublanes: a 32-bit row as it is (block ``c`` of 128
+columns on sublane ``c``), a 16-bit row with block ``c`` in the low and block
+``S + c`` in the high half of a word (unpacking bfloat16 is then a shift or a
+mask, and both halves stay on their lanes).  **A slab may be wider than its
+row**: 2048 columns are 8 sublanes of bfloat16 and 16 of float32 and fill
+their slab, Nemotron-H's 2688 are 10.5 and 21 and lie in slabs of 16 (blocks
+0-15 low, 16-20 high, the eleven behind them do not exist) and 24.  ``pack``
+writes zeros where a block does not exist, the other kernels neither store
+such a block nor add it to the weight's gradient: what lies behind column
+``d`` of a slab reaches no result.  All of that is Python on static shapes; a
+width that fills its slabs traces to what it traced to before slabs could be
+wider (a test holds the lowered text at 2048 columns).  A width need only be
+whole lanes; a narrow row pays for its padding (1024 columns of bfloat16 are
+4 sublanes in a slab of 8: ``pack`` writes, and a copy moves, twice the
+bytes; not measured).  ``pack`` writes the slabs of the first ``live`` rows
+and touches nothing behind them.  The way back to rows by columns is a
+strided load: sublane ``c`` of every slab of a tile.
 
 **Walking the held pairs alone.**  ``to_tokens`` sorts the pairs of each tile
 of tokens, held ones first and in their order (one XLA sort of (tiles, tile x
@@ -55,6 +67,14 @@ here; XLA's gathers they stand in for take 2.0 / 7.6 / 7.1 / 17.6 at any share):
   two lowerings take 4.14 + 1.57 s with XLA's row path, 5.70 + 2.22 with no
   unrolling, 6.43 + 2.52 at four, 7.07 + 3.02 at eight (it is ``setup_s``).  The
   three entry points are jitted for the same reason: traced once a process.
+MEASURED (v5e-1, PR 33; 2688 bfloat16 columns, slabs of 16 and 24 sublanes, beside 2048
+in the same call; ms a call with its packing, the host's clock over ten calls, at 6 /
+18 / 50 / 100% of the buffer routed here; XLA's expression at any share behind it):
+  to_buffer            1.34 / 1.59 / 2.24 / 3.18   (2048: 1.00 / 1.17 / 1.63 / 2.35)   2.50
+  to_tokens, w = 1     0.81 / 1.25 / 2.62 / 4.77   (2048: 0.57 / 0.91 / 2.03 / 3.80)   9.0
+  to_tokens            0.95 / 1.46 / 2.65 / 4.83   (2048: 0.68 / 0.98 / 2.05 / 3.80)   9.5
+  to_buffer, weighted  1.92 / 2.27 / 3.22 / 4.67   (2048: 1.55 / 1.83 / 2.67 / 3.94)   22.3
+  1.18-1.49 x a call at 2048 for 1.31 x the columns in slabs of 2 x and 1.5 x the words.
 
 The names of the compiled kernels (``moe_rows_pack``, ``moe_rows_to_buffer``,
 ``moe_rows_to_tokens``) are what a trace shows.
@@ -70,21 +90,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-# Columns of a row whose slab is whole (8, 128) tiles in 16 bits as in 32: what ops/moe.py asks of a width.
-SLAB_COLUMNS = 2048
 # Rows per trip of a kernel's loops over rows (Mosaic unrolls a loop whole or not at all).
 _UNROLL = 4
 _HIGH_HALF = 0xFFFF0000
 
 
 def slab_sublanes(d: int, dtype) -> int:
-    """Sublanes of one row's slab; raises where a row is not whole words of whole lanes."""
+    """Sublanes ``S`` of one row's slab: the row's words in whole tiles of
+    eight sublanes (the blocks of 128 columns behind column ``d`` do not
+    exist); raises where a row is not whole lanes of 16- or 32-bit columns."""
     itemsize = jnp.dtype(dtype).itemsize
-    if itemsize not in (2, 4) or (d * itemsize) % (4 * LANES):
-        raise ValueError(f"a row of {d} x {jnp.dtype(dtype).name} is not whole lanes of 32-bit words")
+    if itemsize not in (2, 4) or d % LANES:
+        raise ValueError(f"a row of {d} x {jnp.dtype(dtype).name} is not whole lanes of 16- or 32-bit columns")
     if itemsize == 2 and jnp.dtype(dtype) != jnp.bfloat16:
         raise ValueError("the only 16-bit rows are bfloat16")
-    return d * itemsize // (4 * LANES)
+    return pl.cdiv(d * itemsize, 4 * LANES * 8) * 8
 
 
 def _bits(x):
@@ -93,7 +113,7 @@ def _bits(x):
 
 def _unpacked(words, dtype):
     """The float32 values that slab words hold: one array of a 32-bit dtype,
-    of bfloat16 the low halves (columns before d / 2) and the high halves."""
+    of bfloat16 the low halves (the first S blocks of 128 columns) and the high halves."""
     if jnp.dtype(dtype).itemsize == 4:
         return (pltpu.bitcast(words, jnp.float32),)
     return pltpu.bitcast(words << 16, jnp.float32), pltpu.bitcast(words & jnp.uint32(_HIGH_HALF), jnp.float32)
@@ -112,15 +132,20 @@ _COMPILER_PARAMS = pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem
 
 
 def _pack_kernel(live_ref, x_ref, out_ref, *, tile, sublanes):
+    d = x_ref.shape[1]
+
     @pl.when(pl.program_id(0) * tile < live_ref[0])
     def _():
         for c in range(sublanes):
-            if x_ref.dtype.itemsize == 4:
+            if c * LANES >= d:  # a block that does not exist, and in 16 bits the one above it
+                words = jnp.zeros((tile, LANES), jnp.uint32)
+            elif x_ref.dtype.itemsize == 4:
                 words = pltpu.bitcast(x_ref[:, c * LANES:(c + 1) * LANES], jnp.uint32)
             else:
-                low = _bits(x_ref[:, c * LANES:(c + 1) * LANES]) >> 16
-                high = _bits(x_ref[:, (sublanes + c) * LANES:(sublanes + c + 1) * LANES]) & jnp.uint32(_HIGH_HALF)
-                words = high | low
+                words = _bits(x_ref[:, c * LANES:(c + 1) * LANES]) >> 16
+                if (sublanes + c) * LANES < d:
+                    high = _bits(x_ref[:, (sublanes + c) * LANES:(sublanes + c + 1) * LANES]) & jnp.uint32(_HIGH_HALF)
+                    words = high | words
             out_ref[pl.ds(c, tile, stride=sublanes), :] = words
 
 
@@ -191,9 +216,12 @@ def _to_buffer_kernel(rows_ref, token_ref, src, *rest, tile, sublanes, src_dtype
             # (1, tile) on lanes -> the same numbers down the sublanes, on every lane
             scale = jnp.broadcast_to(scale_ref[0], (LANES, tile)).T
             partial = jnp.zeros((tile, LANES), jnp.float32)
-        for c in range(sublanes):  # sublane c of every slab: columns c x 128.. and, of bfloat16, d / 2 + c x 128..
+        d = out_ref.shape[1]
+        for c in range(min(sublanes, d // LANES)):  # sublane c of every slab: block c and, of bfloat16, block S + c
             for half, value in enumerate(_unpacked(scratch[pl.ds(c, tile, stride=sublanes), :], src_dtype)):
                 first = (half * sublanes + c) * LANES
+                if first >= d:  # a block that does not exist
+                    continue
                 if weighted:
                     partial = partial + y_ref[:, first:first + LANES].astype(jnp.float32) * value
                     value = scale * value
@@ -278,7 +306,7 @@ def _to_tokens_kernel(held_ref, row_ref, token_ref, weight_ref, buf, out_ref, sc
 
     zero = jnp.zeros((sublanes, LANES), jnp.float32)
     _each(held, add, (jnp.int32(-1), (zero,) * (wide // sublanes)))
-    for c in range(wide):
+    for c in range(out_ref.shape[1] // LANES):  # the blocks that exist
         out_ref[:, c * LANES:(c + 1) * LANES] = acc[pl.ds(c, tile, stride=wide), :].astype(out_ref.dtype)
 
 
@@ -291,6 +319,7 @@ def to_tokens(buf, inverse, weights, rows, out_dtype, *, tile: int, buffer_tile:
     and in their order, so the kernel walks the held pairs alone."""
     (total, d), (n, k) = buf.shape, weights.shape
     sublanes = slab_sublanes(d, buf.dtype)
+    wide = sublanes * 4 // buf.dtype.itemsize  # sublanes of a token's float32 sum: a slab's halves on end
     slabs = pack(buf, rows, tile=buffer_tile, interpret=interpret)
     tiles, pairs = n // tile, tile * k
     by_tile = inverse.reshape(tiles, pairs)
@@ -308,6 +337,6 @@ def to_tokens(buf, inverse, weights, rows, out_dtype, *, tile: int, buffer_tile:
             in_specs=[in_smem, in_smem, in_smem, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((tile, d), lambda i, held: (i, 0)),
             scratch_shapes=[pltpu.VMEM((pairs * sublanes, LANES), jnp.uint32),
-                            pltpu.VMEM((tile * d // LANES, LANES), jnp.float32), pltpu.SemaphoreType.DMA(())]),
+                            pltpu.VMEM((tile * wide, LANES), jnp.float32), pltpu.SemaphoreType.DMA(())]),
         compiler_params=_COMPILER_PARAMS, interpret=interpret, name="moe_rows_to_tokens",
     )(held, row.reshape(tiles, 1, pairs), token.reshape(tiles, 1, pairs), weight.reshape(tiles, 1, pairs), slabs)

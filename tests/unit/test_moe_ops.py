@@ -296,13 +296,13 @@ def test_the_balance_loss_is_the_sum_of_load_times_mean_score_per_sequence():
     ("tpu", 64, 6, 64, 32, moe.XLA, moe.XLA),  # the tiny preset: not whole tiles
     ("tpu", 98304 + 256, 1, 2048, 1408, moe.XLA, moe.XLA),
     ("tpu", 16384, 6, 2048, 1400, moe.XLA, moe.XLA),
-    # Nemotron-H: 1856 = 14.5 lane tiles is one tile of the products; a bfloat16 row of 2688 is 1.3 slabs
-    ("tpu", 16384, 6, 2688, 1856, moe.KERNEL, moe.XLA),
+    # Nemotron-H: 1856 = 14.5 lane tiles is one tile of the products; a bfloat16 row of 2688 is 10.5 sublanes in a slab of 16
+    ("tpu", 16384, 6, 2688, 1856, moe.KERNEL, moe.KERNEL),
     ("cpu", 16384, 6, 2688, 1856, moe.XLA, moe.XLA),
     ("tpu", 16384, 6, 2688, 4160, moe.XLA, moe.XLA),  # too wide to be one tile
-    # the row kernels follow the products and ask for their own whole tiles besides
+    # the row kernels follow the products and ask for whole tiles of tokens besides
     ("tpu", 16384, 6, 4096, 1408, moe.KERNEL, moe.KERNEL),
-    ("tpu", 16384, 6, 1024, 1408, moe.KERNEL, moe.XLA),  # a bfloat16 row of 1024 is half a slab
+    ("tpu", 16384, 6, 1024, 1408, moe.KERNEL, moe.KERNEL),  # a bfloat16 row of 1024 is 4 sublanes in a slab of 8
     ("tpu", 64, 8, 2048, 1408, moe.KERNEL, moe.XLA),  # 512 rows are a tile of the buffer, 64 tokens no tile of tokens
 ])
 def test_lowering_picks_the_kernel_on_a_tpu_at_whole_tiles(backend, tokens, k, d, w, expected, rows_expected):

@@ -20,7 +20,9 @@ linted tree), collects every emit site across the whole tree, and flags:
 
 Emit sites are calls whose attribute is ``event`` / ``instant`` /
 ``counter`` / ``gauge`` / ``histogram`` (or an ``emit``/``_emit_event``
-helper) with a string-literal first argument.  Dynamic names
+helper) with a string-literal first argument; and, as attribute calls only
+(``trace.phase(...)``, not a script's own ``phase()`` helper), ``phase`` /
+``record_phase``: the set-up record of obs/trace.py (ISSUE 34).  Dynamic names
 (``sink.event(name, ...)``) are invisible to the rule and should be
 funnelled through a registered prefix helper or suppressed with rationale.
 """
@@ -50,6 +52,12 @@ _EMIT_ATTRS = {
     "emit": "event",
     "_emit_event": "event",
     "emit_event": "event",
+}
+
+#: emit kinds that count only as ``<object>.<attr>(...)`` calls
+_ATTRIBUTE_ONLY = {
+    "phase": "phase",
+    "record_phase": "phase",
 }
 
 #: files whose string literals are never emit sites: the registry itself
@@ -106,11 +114,11 @@ def _emit_sites(pctx: ProjectContext):
                 continue
             if isinstance(node.func, ast.Attribute):
                 attr = node.func.attr
+                kind = _EMIT_ATTRS.get(attr) or _ATTRIBUTE_ONLY.get(attr)
             elif isinstance(node.func, ast.Name):
-                attr = node.func.id
+                kind = _EMIT_ATTRS.get(node.func.id)
             else:
                 continue
-            kind = _EMIT_ATTRS.get(attr)
             if kind is None:
                 continue
             if not (node.args and isinstance(node.args[0], ast.Constant)

@@ -45,6 +45,13 @@ def _summary_line(report: dict, path: str | None) -> str:
             "steps": steps.get("count"),
             "decomposition": steps.get("decomposition"),
             "mfu": mfu.get("mfu"),
+            # where the start went: self seconds by set-up phase
+            "setup_self_s": {
+                name: total["self_s"]
+                for name, total in (
+                    (report.get("setup") or {}).get("by_phase") or {}
+                ).items()
+            },
             "top_bottlenecks": top,
         },
         sort_keys=True,

@@ -64,6 +64,7 @@ import os
 import sys
 from typing import Any, Iterable
 
+from batchai_retinanet_horovod_coco_tpu.obs import trace
 from batchai_retinanet_horovod_coco_tpu.obs.events import (
     latency_percentiles,
     split_runs,
@@ -299,6 +300,73 @@ def _steps_section(spans: dict[str, list[dict]]) -> dict | None:
         "decomposition": decomposition,
         "fractions_sum": _r(sum(decomposition.values())),
         "totals_s": {k: _r(v, 4) for k, v in attributed.items()},
+    }
+
+
+# Rows of the set-up table: the phases that cost most, by self time.
+_SETUP_ROWS = 30
+
+
+def _setup_section(events: list[dict]) -> dict:
+    """Where the start went: the phases of ``obs/trace.py`` (``cat``
+    ``obs.phase``: ``backend_init``, ``init_state``, ``ckpt_restore``,
+    ``place_state``, ``compile_train_step`` and, beneath them, JAX's
+    ``jit_trace`` / ``jit_lower`` / ``xla_compile_or_load`` of every program
+    built), each with its self time (its duration less what its children
+    cover) and, for a program, whether the persistent cache held it."""
+    phases = [
+        e for e in events
+        if e.get("ph") == "X" and e.get("cat") == "obs.phase"
+        and (e.get("args") or {}).get("phase") is not None
+    ]
+    if not phases:
+        return {"available": False, "by_phase": {}, "rows": []}
+    # One id space a process: a merged trace carries several.
+    def key(e: dict, field: str):
+        value = (e.get("args") or {}).get(field)
+        return None if value is None else (e.get("pid"), value)
+
+    self_s = trace.self_times(
+        [
+            trace.Phase(
+                key(e, "phase"), key(e, "parent"), e.get("name"),
+                _start_s(e), _dur_s(e), None, None,
+            )
+            for e in phases
+        ]
+    )
+    t_first = min(_start_s(e) for e in phases)
+    rows = []
+    by_phase: dict[str, dict] = {}
+    for e in phases:
+        args = e.get("args") or {}
+        own_s = self_s[key(e, "phase")]
+        rows.append(
+            {
+                "phase": e.get("name", "?"),
+                "what": args.get("fun") or args.get("bucket"),
+                "start_s": _r(_start_s(e) - t_first, 3),
+                "dur_s": _r(_dur_s(e), 4),
+                "self_s": _r(own_s, 4),
+                "cache": args.get("cache"),
+            }
+        )
+        total = by_phase.setdefault(
+            e.get("name", "?"),
+            {"count": 0, "self_s": 0.0, "hits": 0, "misses": 0},
+        )
+        total["count"] += 1
+        total["self_s"] += own_s
+        total["hits"] += args.get("cache") == "hit"
+        total["misses"] += args.get("cache") == "miss"
+    for total in by_phase.values():
+        total["self_s"] = _r(total["self_s"], 4)
+    rows.sort(key=lambda r: -r["self_s"])
+    return {
+        "available": True,
+        "by_phase": dict(sorted(by_phase.items())),
+        "rows": sorted(rows[:_SETUP_ROWS], key=lambda r: r["start_s"]),
+        "rows_left_out": max(0, len(rows) - _SETUP_ROWS),
     }
 
 
@@ -1067,6 +1135,7 @@ def analyze_events(
         "numerics": numerics,
         "events": events_section,
         "span_stats": _span_stats(spans),
+        "setup": _setup_section(events),
         "bottlenecks": _bottlenecks(
             steps, pipeline, spans, queues, violations, numerics
         ),

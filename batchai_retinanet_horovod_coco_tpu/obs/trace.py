@@ -50,11 +50,24 @@ session runs, so whoever starts one (``--profile-dir``, the benchmark's
 tracer, ``jax.profiler.start_server``) finds the program's spans on the
 device trace's clock with no switch to flip.  A process that never
 installs a factory (the decode workers) keeps the shared null span.
+
+Phases (ISSUE 34): a phase is a span that is ALWAYS kept and names its
+parent.  ``phase()`` is for the boundaries of set-up, a few dozen a process
+(backend start, state initialisation, placement, the build of a step with
+its first call); ``record_phase()`` files an interval somebody else timed
+(JAX's own trace, lowering and compile events, ``utils/backend.py``).  They
+go to one bounded process-wide list whether or not ``configure()`` ran, so
+"where did this start go" has an answer in every process: ``phases()`` hands
+out a snapshot, ``self_times()`` gives each phase's duration less what its
+children cover, and ``export()`` writes them as ``X`` events with
+``args.phase`` / ``args.parent`` beside the ring's.  The per-step spans stay
+spans.
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import threading
@@ -269,6 +282,169 @@ def span(name: str, **args: Any):
     return _Span(name, args or None, annotation)
 
 
+# ---- phases: the set-up record -------------------------------------------
+
+# Bound on the process-wide phase list.  Set-up comes FIRST in a process,
+# so a full list refuses what comes later (and counts it) where the rings
+# drop their oldest: a server that compiles a new bucket a day must not
+# push its own start out of the record.
+MAX_PHASES = 8192
+
+Phase = collections.namedtuple("Phase", "id parent name t0 dur args thread")
+
+
+class _PhaseRec:
+    """One filed phase; mutable because an interval filed after the fact
+    adopts the earlier ones it contains (``record_phase``)."""
+
+    __slots__ = ("id", "parent", "name", "t0", "dur", "args", "thread", "tid")
+
+    def __init__(self, id, parent, name, t0, dur, args):
+        self.id, self.parent, self.name = id, parent, name
+        self.t0, self.dur, self.args = t0, dur, args
+        # The thread's ring gives its track in the export, and tells this
+        # thread's phases from another's (one ring a thread lifetime).
+        self.thread, self.tid = threading.current_thread().name, _ring().tid
+
+
+_phase_lock = threading.Lock()
+_phase_list: list[_PhaseRec] = []
+_phases_dropped = 0
+_phase_ids = itertools.count(1)  # next() is atomic
+
+
+def _phase_stack() -> list[int]:
+    stack = getattr(_tls, "phases", None)
+    if stack is None or getattr(_tls, "phases_gen", None) != _generation:
+        stack = _tls.phases = []
+        _tls.phases_gen = _generation
+    return stack
+
+
+def _file_phase(rec: _PhaseRec, adopt: bool) -> None:
+    global _phases_dropped
+    with _phase_lock:
+        if len(_phase_list) >= MAX_PHASES:
+            _phases_dropped += 1
+            return
+        if adopt:
+            # What this thread filed under the same parent since rec began
+            # happened INSIDE rec (a listener hears of an inner interval
+            # before the outer one closes): rec is its parent.
+            for other in reversed(_phase_list):
+                if other.tid != rec.tid:
+                    continue
+                if other.t0 < rec.t0:
+                    break
+                if other.parent == rec.parent:
+                    other.parent = rec.id
+        _phase_list.append(rec)
+
+
+class _OpenPhase:
+    """``phase()``'s context manager.  After the block ``dur`` is the
+    phase's length in seconds (``None`` while it is open)."""
+
+    __slots__ = ("name", "args", "annotation", "id", "parent", "t0", "dur")
+
+    def __init__(self, name: str, args: dict | None, annotation):
+        self.name, self.args, self.annotation = name, args, annotation
+        self.dur = None
+
+    def __enter__(self):
+        stack = _phase_stack()
+        self.parent = stack[-1] if stack else None
+        self.id = next(_phase_ids)
+        stack.append(self.id)
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        self.t0 = monotonic_s()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur = monotonic_s() - self.t0
+        stack = _phase_stack()
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        _file_phase(
+            _PhaseRec(self.id, self.parent, self.name, self.t0, self.dur, self.args),
+            adopt=False,
+        )
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        return False
+
+
+def phase(name: str, **args: Any) -> _OpenPhase:
+    """A span that is always kept: the region is filed on the phase list
+    with the innermost phase open on this thread as its parent, is a
+    profiler annotation ``rn.<name>`` where a factory is installed, and is
+    exported with the ring's events.  For set-up boundaries only; its cost
+    (two clock reads, a lock, an object) is a span's with the ring on."""
+    annotation = None
+    if _annotation_factory is not None:
+        annotation = _annotation_factory(ANNOTATION_PREFIX + name, **args)
+    return _OpenPhase(name, args or None, annotation)
+
+
+def record_phase(name: str, t0: float, dur: float, **args: Any) -> None:
+    """File ``[t0, t0 + dur]`` (``monotonic_s()`` seconds) as a phase that
+    ended just now on this thread: its parent is the phase open here, and
+    the phases this thread filed under that parent since ``t0`` become its
+    children.  For a listener that is told of an interval after the fact."""
+    stack = _phase_stack()
+    parent = stack[-1] if stack else None
+    _file_phase(
+        _PhaseRec(next(_phase_ids), parent, name, t0, max(0.0, dur), args or None),
+        adopt=True,
+    )
+
+
+def _clear_phases() -> None:
+    global _phases_dropped
+    with _phase_lock:
+        _phase_list.clear()
+        _phases_dropped = 0
+
+
+def phases() -> list[Phase]:
+    """A snapshot of the filed phases, in the order they ENDED (a parent
+    after its children; find one by ``id``, never by position)."""
+    with _phase_lock:
+        return [Phase(r.id, r.parent, r.name, r.t0, r.dur, r.args, r.thread) for r in _phase_list]
+
+
+def phases_dropped() -> int:
+    return _phases_dropped
+
+
+def self_times(snapshot: list[Phase] | None = None) -> dict[int, float]:
+    """``{id: seconds}``: each phase's duration less the part of it that its
+    children cover (their union, clipped to the parent)."""
+    snapshot = phases() if snapshot is None else snapshot
+    children: dict[int, list[Phase]] = {}
+    for p in snapshot:
+        if p.parent is not None:
+            children.setdefault(p.parent, []).append(p)
+    out = {}
+    for p in snapshot:
+        covered, until = 0.0, p.t0
+        for c in sorted(children.get(p.id, ()), key=lambda c: c.t0):
+            start, stop = max(c.t0, until), min(c.t0 + c.dur, p.t0 + p.dur)
+            if stop > start:
+                covered += stop - start
+                until = stop
+        out[p.id] = max(0.0, p.dur - covered)
+    return out
+
+
+def from_wall(t_wall: float) -> float:
+    """The inverse of ``to_wall``: a ``time.time()`` stamp (JAX's monitoring
+    events carry those) on the ``monotonic_s()`` clock."""
+    return _PERF_ANCHOR + (t_wall - _WALL_ANCHOR)
+
+
+
 def begin(name: str, **args: Any):
     """Explicit begin half of a cross-thread span: the returned handle may
     be ``end()``-ed by ANY thread; the ring event lands on the beginning
@@ -396,6 +572,7 @@ def maybe_configure_from_env(process_label: str) -> bool:
         with _registry_lock:
             _rings.clear()  # the parent owns those events, not this child
             _generation += 1
+        _clear_phases()
     trace_dir = os.environ.get(OBS_DIR_ENV)
     if not trace_dir:
         return False
@@ -488,6 +665,17 @@ def _chrome_events() -> Iterator[dict]:
                 if args:
                     ev["args"] = args
             yield ev
+    # The phases, on the track of the thread that filed them: the set-up
+    # record from before configure() ran is on the timeline too.
+    with _phase_lock:
+        filed = list(_phase_list)
+    for r in filed:
+        yield {
+            "ph": "X", "cat": "obs.phase", "name": r.name,
+            "ts": int(to_wall(r.t0) * 1e6), "dur": max(0, int(r.dur * 1e6)),
+            "pid": pid, "tid": r.tid,
+            "args": {**(r.args or {}), "phase": r.id, "parent": r.parent},
+        }
 
 
 def snapshot_events() -> list[dict]:
@@ -521,6 +709,7 @@ def export(path: str | None = None) -> str | None:
             "process_label": _process_label,
             "pid": os.getpid(),
             "events_dropped_by_ring": dropped,
+            "phases_dropped": _phases_dropped,
             "wall_anchor_s": _WALL_ANCHOR,
         },
     }
@@ -603,3 +792,4 @@ def reset() -> None:
         # caller's): a live thread's next event re-registers a fresh ring
         # instead of appending to an orphaned one.
         _generation += 1
+    _clear_phases()

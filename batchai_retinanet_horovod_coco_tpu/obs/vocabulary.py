@@ -22,7 +22,16 @@ literal — no comprehensions, no computed keys).  Entry shape:
 
 - ``kinds`` — any of ``"event"`` (EventSink.event / emit_event JSONL),
   ``"instant"`` (trace.instant), ``"series"`` (telemetry counter/gauge/
-  histogram constructors and trace.counter samples).
+  histogram constructors and trace.counter samples), ``"phase"``
+  (trace.phase / trace.record_phase: the set-up record, ISSUE 34).  The
+  benchmark's readers of the phases (``benchmark/harness/setup_phases.py``
+  and the eight ``benchmark/layer_metrics/setup.*.py``) lie outside the
+  scanned tree and cannot be listed: ``setup.step_builds`` and
+  ``setup.step_build_s`` read ``compile_train_step``, ``setup.init_state_s``
+  ``init_state``, ``setup.place_state_s`` ``place_state``,
+  ``setup.trace_lower_s`` ``jit_trace`` and ``jit_lower``, and
+  ``setup.cache_load_s``, ``setup.backend_compile_s`` and
+  ``setup.cache_misses`` ``xla_compile_or_load``.
 - ``consumers`` — repo-relative paths of the files that READ the name
   (report sections, SLO rules, smoke drivers).  Empty means
   "emitted for ad-hoc analysis"; the rule only checks listed paths.
@@ -63,6 +72,10 @@ VOCABULARY: dict[str, dict] = {
             "scripts/chaos.py",
         ),
     },
+    "backend_init": {
+        "kinds": ("phase",),
+        "consumers": (),
+    },
     "canary_promoted": {
         "kinds": ("event",),
         "consumers": (
@@ -82,10 +95,20 @@ VOCABULARY: dict[str, dict] = {
             "batchai_retinanet_horovod_coco_tpu/obs/analyze/report.py",
         ),
     },
+    "ckpt_restore": {
+        "kinds": ("phase",),
+        "consumers": (),
+    },
     "ckpt_saved": {
         "kinds": ("event",),
         "consumers": (
             "scripts/chaos.py",
+        ),
+    },
+    "compile_train_step": {
+        "kinds": ("phase",),
+        "consumers": (
+            "batchai_retinanet_horovod_coco_tpu/obs/analyze/report.py",
         ),
     },
     "cost_analysis": {
@@ -186,6 +209,22 @@ VOCABULARY: dict[str, dict] = {
         "kinds": ("event",),
         "consumers": (),
     },
+    "init_state": {
+        "kinds": ("phase",),
+        "consumers": (),
+    },
+    "jit_lower": {
+        "kinds": ("phase",),
+        "consumers": (
+            "batchai_retinanet_horovod_coco_tpu/utils/backend.py",
+        ),
+    },
+    "jit_trace": {
+        "kinds": ("phase",),
+        "consumers": (
+            "batchai_retinanet_horovod_coco_tpu/utils/backend.py",
+        ),
+    },
     "numerics_trip": {
         "kinds": ("instant",),
         "consumers": (
@@ -199,6 +238,10 @@ VOCABULARY: dict[str, dict] = {
             "batchai_retinanet_horovod_coco_tpu/obs/analyze/report.py",
             "train.py",
         ),
+    },
+    "place_state": {
+        "kinds": ("phase",),
+        "consumers": (),
     },
     "respawn_budget_exhausted": {
         "kinds": ("event",),
@@ -378,6 +421,12 @@ VOCABULARY: dict[str, dict] = {
         "kinds": ("event",),
         "consumers": (
             "batchai_retinanet_horovod_coco_tpu/obs/analyze/report.py",
+        ),
+    },
+    "xla_compile_or_load": {
+        "kinds": ("phase",),
+        "consumers": (
+            "batchai_retinanet_horovod_coco_tpu/utils/backend.py",
         ),
     },
 }

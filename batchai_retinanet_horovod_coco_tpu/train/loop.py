@@ -387,6 +387,9 @@ class _AsyncEvalRunner:
 # functions, and so their loaded executables, live until the next call
 # replaces them.
 _built_steps: dict[tuple[int, ...], tuple[Callable, tuple]] = {}
+# Builds of a train step so far in this process, by bucket name
+# (``compile_train_step``'s ``call=``).
+_step_builds: dict[str, int] = {}
 
 
 def _bucket_name(bucket: tuple[int, ...]) -> str:
@@ -521,6 +524,61 @@ def _compile_barrier(step_fn, state, device_arrays, hw) -> None:
     client.wait_at_barrier(f"train_step_compiled_{_bucket_name(hw)}", 600_000)
 
 
+def _place_state(state: TrainState, mesh: Mesh, shard_weight_update: bool) -> TrainState:
+    """Replicate ``state`` over ``mesh`` (restored arrays land committed to
+    a single device, which conflicts with the shard_map'd step).  In
+    weight-update-sharded mode the opt_state leaves keep their 1/N layout
+    on the data axis instead (parallel/zero.py storage format)."""
+
+    def _place_comm_state(comm_state):
+        # Comm EF residuals (ISSUE 13) keep their 1/N data-axis
+        # layout, exactly like ZeRO optimizer state.
+        from jax.sharding import NamedSharding
+
+        from batchai_retinanet_horovod_coco_tpu.comm.compress import (
+            state_partition_specs,
+        )
+
+        return jax.tree.map(
+            lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
+            comm_state,
+            state_partition_specs(comm_state),
+        )
+
+    if shard_weight_update:
+        from jax.sharding import NamedSharding
+
+        from batchai_retinanet_horovod_coco_tpu.parallel.zero import (
+            opt_state_partition_specs,
+        )
+
+        rep = replicated_sharding(mesh)
+        opt_state = jax.tree.map(
+            lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
+            state.opt_state,
+            opt_state_partition_specs(state.opt_state),
+        )
+        state = state.replace(
+            step=jax.device_put(state.step, rep),
+            params=jax.device_put(state.params, rep),
+            batch_stats=jax.device_put(state.batch_stats, rep),
+            opt_state=opt_state,
+            comm_state=_place_comm_state(state.comm_state),
+        )
+    elif getattr(state, "comm_state", ()):
+        rep = replicated_sharding(mesh)
+        state = state.replace(
+            step=jax.device_put(state.step, rep),
+            params=jax.device_put(state.params, rep),
+            batch_stats=jax.device_put(state.batch_stats, rep),
+            opt_state=jax.device_put(state.opt_state, rep),
+            comm_state=_place_comm_state(state.comm_state),
+        )
+    else:
+        state = jax.device_put(state, replicated_sharding(mesh))
+    return state
+
+
 def run_training(
     model,
     state: TrainState,
@@ -588,7 +646,7 @@ def run_training(
             t_restore = monotonic_s()
             template = state
             try:
-                with trace.span("ckpt_restore"):
+                with trace.phase("ckpt_restore"):
                     state = ckpt.restore(template)
             except Exception as e:
                 raise RuntimeError(
@@ -642,56 +700,11 @@ def run_training(
                 state = jax.tree.map(_replace, template, state)
 
     if mesh is not None:
-        # Replicate state over the mesh (restored arrays land committed to a
-        # single device, which conflicts with the shard_map'd step).  In
-        # weight-update-sharded mode the opt_state leaves keep their 1/N
-        # layout on the data axis instead (parallel/zero.py storage format).
-        def _place_comm_state(comm_state):
-            # Comm EF residuals (ISSUE 13) keep their 1/N data-axis
-            # layout, exactly like ZeRO optimizer state.
-            from jax.sharding import NamedSharding
-
-            from batchai_retinanet_horovod_coco_tpu.comm.compress import (
-                state_partition_specs,
-            )
-
-            return jax.tree.map(
-                lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
-                comm_state,
-                state_partition_specs(comm_state),
-            )
-
-        if shard_weight_update:
-            from jax.sharding import NamedSharding
-
-            from batchai_retinanet_horovod_coco_tpu.parallel.zero import (
-                opt_state_partition_specs,
-            )
-
-            rep = replicated_sharding(mesh)
-            opt_state = jax.tree.map(
-                lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
-                state.opt_state,
-                opt_state_partition_specs(state.opt_state),
-            )
-            state = state.replace(
-                step=jax.device_put(state.step, rep),
-                params=jax.device_put(state.params, rep),
-                batch_stats=jax.device_put(state.batch_stats, rep),
-                opt_state=opt_state,
-                comm_state=_place_comm_state(state.comm_state),
-            )
-        elif getattr(state, "comm_state", ()):
-            rep = replicated_sharding(mesh)
-            state = state.replace(
-                step=jax.device_put(state.step, rep),
-                params=jax.device_put(state.params, rep),
-                batch_stats=jax.device_put(state.batch_stats, rep),
-                opt_state=jax.device_put(state.opt_state, rep),
-                comm_state=_place_comm_state(state.comm_state),
-            )
-        else:
-            state = jax.device_put(state, replicated_sharding(mesh))
+        # A phase of the set-up record: the host's time to ISSUE the
+        # placement (a device_put per leaf); the copies themselves finish
+        # under whatever runs next.
+        with trace.phase("place_state", devices=int(mesh.devices.size)):
+            state = _place_state(state, mesh, shard_weight_update)
 
     step_fns: dict[tuple[int, ...], Callable] = {}
     _built_steps.clear()
@@ -725,6 +738,12 @@ def run_training(
     # provenance context for a tripped finite-check.
     numerics_config = NumericsConfig(enabled=config.numerics)
 
+    def start_profile_if_due(step: int) -> None:
+        if config.profile_dir and step == prof_start:
+            jax.profiler.start_trace(
+                config.profile_dir, profiler_options=_profile_options()
+            )
+
     it = _prefetch_to_device(batches, mesh, config.device_prefetch, task)
     # The loop's own heartbeat: one beat per step.  Long legitimate gaps
     # (sync eval, final epilogue) are bracketed with idle() so only a
@@ -751,18 +770,24 @@ def run_training(
             step_fn = step_fns.get(hw)
             new_step = step_fn is None
             if new_step:
-                # AOT point: build + (multi-process) compile-and-barrier.
-                # The span/event turn each bucket's one-time multi-minute
-                # gap into an attributed compile, not an apparent stall —
-                # and the heartbeat goes idle for the same reason (a cold
-                # flagship compile is minutes, far past any stall budget).
+                # AOT point: build + (multi-process) compile-and-barrier +
+                # the new step's FIRST call, where a one-process run traces,
+                # lowers and loads or compiles it.  The phase/event turn
+                # each bucket's one-time multi-minute gap into an attributed
+                # compile, not an apparent stall — and the heartbeat goes
+                # idle for the same reason (a cold flagship compile is
+                # minutes, far past any stall budget).
                 loop_hb.idle()
                 if not step_fns and trace.enabled():
                     _record_run_meta(task, model, hw)
-                t_compile = monotonic_s()
-                with trace.span(
-                    "compile_train_step", bucket=_bucket_name(hw)
-                ):
+                bucket = _bucket_name(hw)
+                # The n-th build of this bucket in this process: a caller
+                # that runs the loop twice (warm-up, then the real call)
+                # sees what the second build still costs.
+                call = _step_builds[bucket] = _step_builds.get(bucket, 0) + 1
+                with trace.phase(
+                    "compile_train_step", bucket=bucket, call=call
+                ) as built:
                     if spatial:
                         step_fn = step_fns[hw] = make_train_step_spatial(
                             model,
@@ -797,28 +822,26 @@ def run_training(
                     _built_steps[hw] = (
                         step_fn, _abstract((state, device_arrays))
                     )
+                    start_profile_if_due(step)
+                    state, metrics = step_fn(state, device_arrays)
                 loop_hb.beat()
                 # Live-telemetry record site (one bool check while off):
                 # the status server's train_compiles_total/last_compile.
-                telemetry.record_compile(
-                    _bucket_name(hw), monotonic_s() - t_compile
-                )
+                telemetry.record_compile(bucket, built.dur)
                 # Duck-typed: tests pass bare .log-only logger fakes.
                 log_event = getattr(logger, "event", None)
                 if log_event is not None:
                     log_event(
                         "compile",
                         target="train_step",
-                        bucket=_bucket_name(hw),
+                        bucket=bucket,
                         step=step,
-                        build_s=round(monotonic_s() - t_compile, 3),
+                        build_s=round(built.dur, 3),
                     )
-            if config.profile_dir and step == prof_start:
-                jax.profiler.start_trace(
-                    config.profile_dir, profiler_options=_profile_options()
-                )
-            with trace.span("step"):
-                state, metrics = step_fn(state, device_arrays)
+            else:
+                start_profile_if_due(step)
+                with trace.span("step"):
+                    state, metrics = step_fn(state, device_arrays)
             if new_step and trace.enabled():
                 # Obs runs record the step's XLA-counted FLOPs so
                 # PERF_REPORT.json can carry an MFU estimate: from the

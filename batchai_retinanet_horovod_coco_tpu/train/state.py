@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from batchai_retinanet_horovod_coco_tpu.obs import trace
+
 
 def model_variables(state: "TrainState") -> dict[str, Any]:
     """Flax variables dict for ``model.apply`` from a TrainState.
@@ -116,13 +118,17 @@ def create_train_state(
     sharded mode (parallel/zero.py) initializes its 1/N layout directly and
     must not pay the peak memory of a throwaway replicated ``tx.init``.
     """
-    variables = jax.jit(model.init)(rng, jnp.zeros(example_image_shape, example_dtype))
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params=params,
-        batch_stats=batch_stats,
-        opt_state=tx.init(params) if init_opt_state else (),
-        tx=tx,
-    )
+    # A phase of the set-up record (obs/trace.py): the jitted init's trace,
+    # lowering and load or compile land beneath it.  Under a caller's own
+    # jit it covers the trace alone, and lies beneath that jit's.
+    with trace.phase("init_state"):
+        variables = jax.jit(model.init)(rng, jnp.zeros(example_image_shape, example_dtype))
+        params = variables["params"]
+        batch_stats = variables.get("batch_stats", {})
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=params,
+            batch_stats=batch_stats,
+            opt_state=tx.init(params) if init_opt_state else (),
+            tx=tx,
+        )

@@ -111,63 +111,31 @@ def _check(cond: bool, message: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-class CompileCounter:
-    """Counts this process's compile requests through jax.monitoring.
-
-    ``requests`` go through the persistent cache; ``hits`` were loaded
-    from it; ``written`` were compiled and were big enough (≥ 1 s of
-    compile) to be stored.  requests − hits = programs compiled now.
-    """
-
-    _EVENTS = {
-        "/jax/compilation_cache/compile_requests_use_cache": "requests",
-        "/jax/compilation_cache/cache_hits": "hits",
-        "/jax/compilation_cache/cache_misses": "written",
-    }
-    _COMPILE_SECONDS = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self) -> None:
-        import jax.monitoring
-
-        self.counts = {"requests": 0, "hits": 0, "written": 0}
-        self.compile_s = 0.0
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration
-        )
-
-    def _on_event(self, event: str, **_kw) -> None:
-        name = self._EVENTS.get(event)
-        if name is not None:
-            self.counts[name] += 1
-
-    def _on_duration(self, event: str, duration: float, **_kw) -> None:
-        if event == self._COMPILE_SECONDS:
-            self.compile_s += duration
-
-    def snapshot(self) -> dict:
-        return {**self.counts, "compile_s": self.compile_s}
-
-
 @contextlib.contextmanager
-def phase(name: str, counter: CompileCounter, record: list):
-    """Time one phase and attribute the compiles inside it.  An exception
-    propagates — a failed phase fails the run."""
+def phase(name: str, record: list):
+    """Time one phase and attribute the compiles inside it, from the
+    program's own set-up record (``utils/backend.py::compile_stats``: JAX's
+    compile events as phases of ``obs/trace.py``).  ``compile_requests`` go
+    through the persistent cache; ``loaded_from_cache`` were found in it;
+    ``compiled`` were not, and ``written_to_cache`` of them were stored for
+    the next run.  An exception propagates — a failed phase fails the run."""
+    from batchai_retinanet_horovod_coco_tpu.utils.backend import compile_stats
+
     print(f"--- phase {name} ---", flush=True)
-    before = counter.snapshot()
+    before = compile_stats()
     t0 = time.monotonic()
     yield
     wall = time.monotonic() - t0
-    after = counter.snapshot()
+    after = compile_stats()
     facts = {
         "phase": name,
         "wall_s": round(wall, 1),
         "compile_requests": after["requests"] - before["requests"],
         "loaded_from_cache": after["hits"] - before["hits"],
+        "compiled": after["misses"] - before["misses"],
         "written_to_cache": after["written"] - before["written"],
         "backend_compile_s": round(after["compile_s"] - before["compile_s"], 1),
     }
-    facts["compiled"] = facts["compile_requests"] - facts["loaded_from_cache"]
     record.append(facts)
     print(
         f"phase {name}: ok, {facts['wall_s']} s wall; programs compiled "
@@ -787,14 +755,13 @@ def main(argv: list[str] | None = None) -> int:
 
     shutil.rmtree(OUT_DIR, ignore_errors=True)
     os.makedirs(OUT_DIR)
-    counter = CompileCounter()
     phases: list[dict] = []
     size = FLAGSHIP
     t0 = time.monotonic()
     try:
-        with phase("kernels", counter, phases):
+        with phase("kernels", phases):
             kernels = phase_kernels(size)
-        with phase("train", counter, phases):
+        with phase("train", phases):
             trained = phase_train(size, OUT_DIR)
             from batchai_retinanet_horovod_coco_tpu.native.build import (
                 library_origin,
@@ -812,14 +779,14 @@ def main(argv: list[str] | None = None) -> int:
                 }[library_origin()],
                 flush=True,
             )
-        with phase("step", counter, phases):
+        with phase("step", phases):
             phase_step_program(size)
-        with phase("export", counter, phases):
+        with phase("export", phases):
             export_dir = phase_export(size, OUT_DIR, trained["snapshot"])
-        with phase("serve", counter, phases):
+        with phase("serve", phases):
             phase_serve(size, OUT_DIR, export_dir)
         if args.chips > 1:
-            with phase("multichip", counter, phases):
+            with phase("multichip", phases):
                 phase_multichip(size, OUT_DIR, args.chips)
     finally:
         for name in _BULKY:

@@ -393,8 +393,11 @@ def test_cost_analysis_is_recorded_after_the_first_execution_from_the_cache(_no_
     cost = [e for e in events if e["name"] == "cost_analysis"]
     assert len(cost) == 1 and cost[0]["args"]["flops"] > 0
     assert cost[0]["args"] == dict(cost[0]["args"], target="train_step", bucket="64x64", batch=BATCH)
-    first_step = min(e["ts"] for e in events if e["name"] == "step")
-    assert cost[0]["ts"] >= first_step
+    # The step's first call is the tail of its compile phase (ISSUE 34), no ``step`` span.
+    # Phases are kept with the ring off too, so the warming run's build is exported beside this one's.
+    built = max((e for e in events if e["name"] == "compile_train_step"), key=lambda e: e["ts"])
+    assert cost[0]["ts"] >= built["ts"] + built["dur"]
+    assert len([e for e in events if e["name"] == "step"]) == 1  # steps 2 and 3 ran; 2 was the build
 
 
 def _lm_run():

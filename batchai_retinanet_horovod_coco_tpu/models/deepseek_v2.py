@@ -311,7 +311,8 @@ class DeepseekV2:
             aux = config.aux_loss_alpha * balance
             loss = cross_entropy + aux
         return loss, {"loss": loss, "tokens_counted": counted, "moe/aux_loss": aux, "moe/rows_held": jnp.sum(rows),
-                      "moe/rows_max_expert": jnp.max(rows), "moe/rows_min_expert": jnp.min(rows)}
+                      "moe/rows_max_expert": jnp.max(rows), "moe/rows_min_expert": jnp.min(rows),
+                      **attention.step_counters(segment_ids)}
 
     def picks(self, params: dict, tokens, segment_ids):
         """The experts every token picked, (expert layers, batch, T, k): what
@@ -323,7 +324,7 @@ class DeepseekV2:
         products and the row movements around them (ops/moe.py) take, and the
         share of the experts held."""
         config, backend = self.config, jax.default_backend()
-        return {"attention_lowering": attention.lowering(backend, bucket[1]),
+        return {**attention.run_meta(backend, bucket[1]),
                 "moe_lowering": _moe_lowering(config, *bucket),
                 "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,
                                                        config.hidden_size, config.moe_intermediate_size),

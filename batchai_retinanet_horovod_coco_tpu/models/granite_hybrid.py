@@ -265,12 +265,12 @@ class GraniteHybrid:
         logits = self.apply({"params": params}, tokens, segment_ids, train=True)
         with jax.named_scope("loss"):
             loss, counted = next_token_loss(logits, tokens, segment_ids)
-        return loss, {"loss": loss, "tokens_counted": counted}
+        return loss, {"loss": loss, "tokens_counted": counted, **attention.step_counters(segment_ids)}
 
     def run_meta(self, bucket) -> dict[str, Any]:
         """Which lowering the step's attention layer (ops/attention.py) and
         its mixers' scans (ops/ssd.py) take: static per program."""
         config, backend = self.config, jax.default_backend()
-        return {"attention_lowering": attention.lowering(backend, bucket[1]),
+        return {**attention.run_meta(backend, bucket[1]),
                 "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_n_heads,
                                              config.mamba_d_head, config.mamba_d_state)}

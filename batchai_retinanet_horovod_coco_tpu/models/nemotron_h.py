@@ -352,7 +352,8 @@ class NemotronH:
         with jax.named_scope("loss"):
             loss, counted = lm_layers.next_token_loss(logits, tokens, segment_ids)
         return loss, {"loss": loss, "tokens_counted": counted, "moe/rows_held": jnp.sum(rows),
-                      "moe/rows_max_expert": jnp.max(rows), "moe/rows_min_expert": jnp.min(rows)}
+                      "moe/rows_max_expert": jnp.max(rows), "moe/rows_min_expert": jnp.min(rows),
+                      **attention.step_counters(segment_ids)}
 
     def picks(self, params: dict, tokens, segment_ids):
         """The experts every token picked, (expert layers, batch, T, k): what
@@ -364,7 +365,7 @@ class NemotronH:
         (ops/ssd.py), its grouped products and the row movements around them
         (ops/moe.py) take, the scan's groups and the share of the experts held."""
         config, backend = self.config, jax.default_backend()
-        return {"attention_lowering": attention.lowering(backend, bucket[1]),
+        return {**attention.run_meta(backend, bucket[1]),
                 "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_num_heads,
                                              config.mamba_head_dim, config.ssm_state_size, config.n_groups),
                 "ssd_groups": config.n_groups,

@@ -15,9 +15,18 @@ both blockings: nothing is padded.  One algorithm, two blockings:
 - ``kernel``: the blocked TPU kernel jax ships
   (``jax.experimental.pallas.ops.tpu.splash_attention``; causal mask, segment
   ids, grouped heads without repeating k and v, its own forward, dq and dk/dv
-  kernels).  A block of scores lives in VMEM only, the softmax is the online
-  one (running maximum and sum), and the block pairs above the diagonal are
-  skipped when the kernel is built.
+  kernels).  A block of scores lives in VMEM only and the softmax is the
+  online one (running maximum and sum).  Which (query block, key block)
+  pairs run is decided twice: those above the diagonal are left out when the
+  kernel is built (the static block lists of the mask that is causal inside
+  each of the batch's sequences, laid end to end for one call), and those that
+  hold no query and key of one document when the step runs: the three
+  kernels' block lists are scalar-prefetch operands, computed from the
+  step's ``segment_ids`` on the device (``_follow_documents``).  A pair left
+  out is a pair whose every score is masked: it adds nothing to the online
+  softmax (every query sees itself on the diagonal, which always runs), so
+  the outputs and the three gradients are the static lists' numbers, bit
+  for bit.  A sequence that is one document runs the causal lists.
 
 Which one runs is ``lowering``'s answer, from the backend and the shapes
 alone.
@@ -37,11 +46,14 @@ tiny preset's 0.25), one more rounding of ``q`` for any other value.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 KERNEL, XLA = "kernel", "xla"
+RUN_SHARE = "attn/block_pairs_run_share"
 
 # The kernel's blocks of queries and of keys (forward, dk/dv kernel, dq kernel) and
 # the forward's sub-block of keys per pass of the online softmax.
@@ -57,6 +69,26 @@ KERNEL, XLA = "kernel", "xla"
 #   2048 queries: the dq kernel wants 17.45 MB of 16 MB scoped VMEM; forward 2048x512 5.69
 # The kernel's fused backward (dq inside the dk/dv kernel) reads 11.18 but keeps
 # dq's partial sums per key block in bfloat16: not the precision promised above.
+#
+# MEASURED (v5e-1, PR 35: the block lists follow the documents; T = 8192, the cells'
+# packings of seeds 905418237 / 2100415840, ms of device time per forward +
+# backward of a whole batch, forward / dq / dk/dv kernels; "static" = the causal lists):
+#   16 / 16 heads of 192 / 128, 2 sequences (dsv2); pairs run 0.535 / 0.504 at 1024, 0.383 / 0.385 at 512
+#     static 1024             6.39 / 9.17 / 10.69
+#     documents, all 1024     3.58 / 5.14 / 6.27 and 3.41 / 4.89 / 6.02  <- taken
+#     documents, all 512      3.94 / 5.05 / 6.33 and 3.95 / 5.06 / 6.33
+#     all 1024, a step left out keeps its own block in data_next: 5.01 / 6.38 / 7.44 (it pays the copies)
+#     the batch, all operations of a call: static under vmap 30.3; the lists as batched scalar-prefetch
+#       operands under vmap (Pallas loops over the batch, slicing and updating whole arrays) 40.6; a Python
+#       loop over the sequences 19.5, but XLA fuses no slice or concatenation into a kernel call: dsv2's
+#       compiled step writes 4.85 GB a step around the kernels against 2.23 static; the sequences laid end
+#       to end, one call a batch (the kernels' rows above were read in the loop): 2.24 GB  <- taken
+#   32 / 8 heads of 64, 1 sequence (granite); pairs run 0.542 / 0.569 at 1024, 0.386 / 0.472 at 512
+#     static 1024 4.10 / 5.91 / 7.38; documents 1024 2.48 / 3.50 / 4.01 and 2.59 / 3.63 / 4.18;
+#     documents 512 2.59 / 3.64 / 4.29 and 2.94 / 4.08 / 4.85
+#   32 / 2 heads of 128, 2 sequences (nemo3): static 1024 8.56 / 11.30 / 14.21; documents 1024
+#     4.97 / 7.28 / 7.97 and 4.71 / 6.97 / 7.57; documents 512 5.32 / 7.63 / 8.29 and 5.32 / 7.65 / 8.31
+# At 512 fewer pairs run and each costs more than a quarter of a 1024 pair: nowhere faster.
 BLOCK_SIZES = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
                    block_q_dkv=1024, block_kv_dkv=1024, block_q_dq=1024, block_kv_dq=1024)
 
@@ -67,6 +99,33 @@ def lowering(backend: str, seq_len: int) -> str:
     ragged sequence)."""
     whole_blocks = seq_len > 0 and all(seq_len % b == 0 for b in BLOCK_SIZES.values())
     return KERNEL if backend == "tpu" and whole_blocks else XLA
+
+
+def run_meta(backend: str, seq_len: int) -> dict[str, str]:
+    """What a model's ``run_meta`` says of its attention: the lowering, and
+    on the kernel path that the block lists follow the documents."""
+    path = lowering(backend, seq_len)
+    return {"attention_lowering": path, **({"attention_block_skip": "documents"} if path == KERNEL else {})}
+
+
+def step_counters(segment_ids) -> dict:
+    """The scalars a step's attention layers add to the model's (a model's
+    ``loss`` merges them into what the loop logs): on the kernel path
+    ``attn/block_pairs_run_share``, the share of the causal (query block,
+    key block) pairs of the forward kernel that run, mean over the step's
+    sequences (1.0 = nothing skipped; every attention layer of a step sees
+    the same ``segment_ids`` and blocks, so one layer's share is the
+    mean over the layers); nothing on the xla path."""
+    if lowering(jax.default_backend(), segment_ids.shape[1]) != KERNEL:
+        return {}
+    return {RUN_SHARE: block_pairs_run_share(segment_ids, BLOCK_SIZES["block_q"], BLOCK_SIZES["block_kv"])}
+
+
+def block_pairs_run_share(segment_ids, block_q: int, block_kv: int):
+    """On the device, for ``segment_ids`` (batch, T): the causal block pairs
+    that hold a pair of one document over all of them -> float32 scalar."""
+    causal, shares = _block_pairs(segment_ids, block_q, block_kv)
+    return jnp.sum(shares, dtype=jnp.float32) / (segment_ids.shape[0] * int(causal.sum()))
 
 
 def packed_causal_attention(q, k, v, segment_ids, scale: float, xla_q_block: int):
@@ -101,33 +160,122 @@ def _xla_path(q, k, v, segment_ids, scale, q_block):
 
 
 def _kernel_path(q, k, v, segment_ids, scale, interpret: bool = False):
+    """The shipped kernel over the batch's sequences laid end to end (one
+    call of each of the three kernels a batch, one transpose an array),
+    built from the mask that is causal inside each sequence, with its three
+    block lists cut to the documents of the step (``_document_block_lists``)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-    t, heads = q.shape[1], q.shape[2]
-    kernel = splash.make_splash_mha(
-        splash.MultiHeadMask([splash.CausalMask((t, t))] * heads),
-        block_sizes=splash.BlockSizes(**BLOCK_SIZES), head_shards=1, q_seq_shards=1, interpret=interpret)
+    batch, t, heads, _ = q.shape
+    kernel = _causal_kernel(batch, t, heads, tuple(BLOCK_SIZES.items()), interpret)
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    heads_first = lambda x: x.transpose(0, 2, 1, 3)
-    out = jax.vmap(lambda q1, k1, v1, seg: kernel(q1, k1, v1, splash.SegmentIds(q=seg, kv=seg)))(
-        heads_first(q), heads_first(k), heads_first(v), segment_ids)
-    return heads_first(out)
+    end_to_end = lambda x: x.transpose(2, 0, 1, 3).reshape(x.shape[2], batch * t, x.shape[3])
+    seg = segment_ids.reshape(batch * t)
+    out = _document_block_lists(kernel, seg, heads)(
+        end_to_end(q), end_to_end(k), end_to_end(v), splash.SegmentIds(q=seg, kv=seg))
+    return out.reshape(heads, batch, t, -1).transpose(1, 2, 0, 3)
 
 
-def block_pair_counts(segment_ids, block_q: int, block_kv: int) -> tuple[int, int]:
-    """On the host, for a batch's ``segment_ids`` (batch, T): the (query
-    block, key block) pairs the causal kernel computes (those holding a pair
-    ``j <= i``), and how many of them hold at least one pair of the same
-    document.  The difference is what skipping by document would leave out.
+@functools.lru_cache(maxsize=None)
+def _causal_kernel(sequences: int, t: int, heads: int, block_sizes: tuple, interpret: bool):
+    """The shipped kernel for ``sequences`` of ``t`` tokens laid end to end,
+    with the static block lists of the mask that is causal inside each: a
+    block pair of two sequences is in no list, and the library shrinks each
+    grid to one sequence's width.  Built once a shape and outside any trace,
+    so that its lists stay arrays that ``_follow_documents`` can read while a
+    step is traced."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    class SequencesCausalMask(splash.CausalMask):
+        """``k <= q`` and both of one sequence, for the block lists; inside
+        the kernel the causal rule alone (``mask_function``), which is the
+        same wherever a block runs: a sequence is whole blocks."""
+
+        def __getitem__(self, idx):
+            rows, cols = self.q_sequence[idx[0]][:, None], self.q_sequence[idx[1]][None, :]
+            return (rows >= cols) & (rows // t == cols // t)
+
+    assert all(t % b == 0 for b in dict(block_sizes).values())
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(
+            splash.MultiHeadMask([SequencesCausalMask((sequences * t, sequences * t))] * heads),
+            block_sizes=splash.BlockSizes(**dict(block_sizes)), head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def _block_pairs(seg, block_q: int, block_kv: int):
+    """For ``segment_ids`` (..., T), on the host (numpy) or on the device:
+    ``causal``, numpy bool (query blocks, key blocks), the pairs that hold a
+    pair ``k <= q``, and ``shares`` (..., query blocks, key blocks), those of
+    them that hold such a pair of one document.
 
     Documents are contiguous runs, so a key block that ends before a query
     block begins shares a document with it exactly when its last token and
     the query block's first token do."""
-    seg = np.asarray(segment_ids)
-    t = seg.shape[1]
+    t = seg.shape[-1]
     q_first, k_first = np.arange(0, t, block_q), np.arange(0, t, block_kv)
     q_last, k_last = q_first + block_q - 1, k_first + block_kv - 1
-    computed = k_first[None, :] <= q_last[:, None]
-    meets_itself = computed & (k_last[None, :] >= q_first[:, None])
-    same = seg[:, q_first][:, :, None] == seg[:, k_last][:, None, :]
-    return seg.shape[0] * int(computed.sum()), int((computed & (meets_itself | same)).sum())
+    causal = k_first[None, :] <= q_last[:, None]
+    meets_itself = causal & (k_last[None, :] >= q_first[:, None])
+    same = seg[..., q_first][..., :, None] == seg[..., k_last][..., None, :]
+    return causal, causal & (meets_itself | same)
+
+
+def _document_block_lists(kernel, seg, heads: int):
+    """``kernel`` (a ``SplashAttentionKernel`` built from a static mask) with
+    the block lists of its forward, dq and dk/dv kernels cut to the blocks
+    that share a document in ``seg`` (tokens,): a step of the grid whose two
+    blocks share none is not run and fetches nothing.  ``seg`` may be
+    sequences laid end to end whose ids begin again: what two blocks of two
+    sequences share is never asked, the static lists do not hold them."""
+    blocks = kernel.kwargs["block_sizes"]
+    lists = [_follow_documents(info, _block_pairs(seg, bq, bkv)[1], heads, is_dkv) for info, bq, bkv, is_dkv in (
+        (kernel.fwd_mask_info, blocks.block_q, blocks.block_kv, False),
+        (kernel.dq_mask_info, blocks.block_q_dq, blocks.block_kv_dq, False),
+        (kernel.dkv_mask_info, blocks.block_q_dkv, blocks.block_kv_dkv, True))]
+    return type(kernel)(*lists, **kernel.kwargs)
+
+
+def _follow_documents(info, shares, heads: int, is_dkv: bool):
+    """One ``MaskInfo`` of the library (one list for all heads), in the
+    layout the library built it (entry ``[0, i, j]`` is read by the grid
+    step whose inner index is ``j``, or ``i`` in the dk/dv kernel; that axis
+    may be shrunk to the steps that run, so where a step runs ``data_next``
+    names the key block, or the query block in the dk/dv kernel, that it
+    stands for and fetches), with ``block_mask`` zeroed where ``shares``
+    (query blocks, key blocks) says the step's two blocks share no document,
+    and ``data_next`` of every step that does not run naming the block of the
+    next step that does, in the order the grid visits them, so that a step
+    left out starts no copy.  Forward and dq visit (head, query block, key
+    block): one list serves every head, and after a head's last step comes
+    the next head's first.  The dk/dv kernel visits (key block, head, query
+    block): after a head's last step of a key block comes the next head's
+    first step of the SAME key block, and only after the last head's the
+    next key block's, so its lists are by head."""
+    static_mask, static_next = np.asarray(info.block_mask)[0], np.asarray(info.data_next)[0]
+    rows, cols = np.indices(static_mask.shape)
+    q_block, k_block = (static_next, cols) if is_dkv else (rows, static_next)
+    runs = jnp.asarray(static_mask > 0) & shares[q_block, k_block]
+    block_mask = jnp.where(runs, static_mask, 0).astype(static_mask.dtype)
+    in_grid_order = (lambda x: x.T.reshape(-1)) if is_dkv else (lambda x: x.reshape(-1))  # of one head's steps
+    n = static_mask.size
+    next_run = jax.lax.cummin(jnp.where(in_grid_order(runs), jnp.arange(n), n), reverse=True)
+    next_run = jnp.where(next_run == n, next_run[0], next_run)  # after the last: the first
+    data_next = jnp.asarray(in_grid_order(static_next))[next_run]
+    if not is_dkv:
+        return info._replace(block_mask=block_mask[None], data_next=data_next.reshape(1, *static_mask.shape))
+    data_next = data_next.reshape(static_mask.shape[::-1]).T
+    none_later = jax.lax.cummax(runs.astype(jnp.int8), axis=0, reverse=True) == 0  # in this key block's column
+    columns_first = jnp.min(jnp.where(runs, static_next, np.iinfo(static_next.dtype).max), axis=0)  # its diagonal runs
+    not_last_head = (np.arange(heads) < heads - 1)[:, None, None]
+    data_next = jnp.where(none_later & not_last_head, columns_first, data_next)
+    return info._replace(block_mask=jnp.broadcast_to(block_mask, data_next.shape), data_next=data_next)
+
+
+def block_pair_counts(segment_ids, block_q: int, block_kv: int) -> tuple[int, int]:
+    """On the host, for a batch's ``segment_ids`` (batch, T): the (query
+    block, key block) pairs the causal lists run, and how many of them hold
+    at least one pair of the same document: what the lists that follow the
+    documents run."""
+    seg = np.asarray(segment_ids)
+    causal, shares = _block_pairs(seg, block_q, block_kv)
+    return seg.shape[0] * int(causal.sum()), int(shares.sum())

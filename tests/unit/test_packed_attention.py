@@ -1,6 +1,7 @@
 """ops/attention.py: the blocked kernel (interpret mode on the CPU) against
 the XLA path and against the float32 reference's dense masked softmax; the
-choice between the two; the count of block pairs a batch's documents need."""
+choice between the two; the count of block pairs a batch's documents need,
+and the kernel's block lists that follow them."""
 
 from unittest import mock
 
@@ -9,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from batchai_retinanet_horovod_coco_tpu.data.tokens import PackedTokensConfig, packed_token_batches
 from batchai_retinanet_horovod_coco_tpu.models import granite_hybrid
 from batchai_retinanet_horovod_coco_tpu.ops import attention
 from benchmark.reference import granite_hybrid as reference
@@ -31,12 +33,12 @@ LAYOUTS = {
 }
 
 
-def _qkv(seed, dtype=jnp.bfloat16):
+def _qkv(seed, dtype=jnp.bfloat16, batch=1):
     keys = jax.random.split(jax.random.key(seed), 4)
-    q = jax.random.normal(keys[0], (1, T, HEADS, HEAD), jnp.float32)
-    k = jax.random.normal(keys[1], (1, T, KV_HEADS, HEAD), jnp.float32)
-    v = jax.random.normal(keys[2], (1, T, KV_HEADS, HEAD), jnp.float32)
-    g = jax.random.normal(keys[3], (1, T, HEADS, HEAD), jnp.float32)
+    q = jax.random.normal(keys[0], (batch, T, HEADS, HEAD), jnp.float32)
+    k = jax.random.normal(keys[1], (batch, T, KV_HEADS, HEAD), jnp.float32)
+    v = jax.random.normal(keys[2], (batch, T, KV_HEADS, HEAD), jnp.float32)
+    g = jax.random.normal(keys[3], (batch, T, HEADS, HEAD), jnp.float32)
     return tuple(x.astype(dtype) for x in (q, k, v)), g
 
 
@@ -166,15 +168,126 @@ def test_block_pair_counts_match_the_dense_mask(blocks):
     assert attention.block_pair_counts(seg, *blocks) == _dense_counts(seg, *blocks)
 
 
-def test_kernel_lowers_for_tpu_at_the_cells_shapes_with_the_committed_blocks():
+@pytest.mark.parametrize("sequences", [1, 2])
+@pytest.mark.parametrize("heads,kv_heads,head,value_head", [
+    (32, 8, 64, 64),      # granite-h-train-pack8k
+    (16, 16, 192, 128),   # dsv2-lite-train-pack8k: latent attention's narrower value head
+    (32, 2, 128, 128),    # nemo3-nano-train-pack8k
+])
+def test_kernel_lowers_for_tpu_at_the_cells_shapes_with_the_committed_blocks(heads, kv_heads, head, value_head,
+                                                                             sequences):
     """JAX-level lowering only (what Mosaic says of it is the chip's to tell):
-    32 query / 8 key-value heads of 64, 8192 tokens, forward and both backward kernels."""
-    spec = lambda heads: jax.ShapeDtypeStruct((1, 8192, heads, 64), jnp.bfloat16)
+    8192 tokens, forward and both backward kernels once a sequence, their
+    block lists computed from the segment ids inside the program."""
+    spec = lambda h, d: jax.ShapeDtypeStruct((sequences, 8192, h, d), jnp.bfloat16)
 
-    def fn(q, k, v, seg):
+    def fn(q, k, v, g, seg):
         out, vjp = jax.vjp(lambda q, k, v: attention._kernel_path(q, k, v, seg, 0.015625), q, k, v)
-        return vjp(out)
+        return out, vjp(g)
 
-    text = jax.jit(fn).trace(spec(32), spec(8), spec(8), jax.ShapeDtypeStruct((1, 8192), jnp.int32)).lower(
+    text = jax.jit(fn).trace(spec(heads, head), spec(kv_heads, head), spec(kv_heads, value_head), spec(heads, value_head),
+                             jax.ShapeDtypeStruct((sequences, 8192), jnp.int32)).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") >= 3
+
+
+# ---- the block lists follow the documents ----------------------------------
+
+PACKINGS = {**{name: [lengths] for name, lengths in LAYOUTS.items()},
+            "a_document_over_three_blocks": [[100, 250, 162]],
+            "two_sequences_packed_differently": [[T], [60, 100, 40, 90, 30, 110, 82]],
+            "two_sequences_with_documents": [[100, 250, 162], [BLOCK, 2 * BLOCK, BLOCK]]}
+
+
+def _static_lists():
+    """The same kernel with the causal mask's static lists, as the library built them."""
+    return mock.patch.object(attention, "_follow_documents", lambda info, *_: info)
+
+
+@pytest.mark.parametrize("packing", PACKINGS)
+def test_document_block_lists_give_the_static_lists_arrays_bit_for_bit(packing):
+    seg = jnp.asarray(np.concatenate([_segments(lengths) for lengths in PACKINGS[packing]]))
+    qkv, g = _qkv(3, batch=seg.shape[0])
+    followed = _out_and_grads(_kernel, qkv, g, seg)
+    with _static_lists():
+        static = _out_and_grads(_kernel, qkv, g, seg)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), jax.tree.leaves(followed), jax.tree.leaves(static)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
+
+
+def _lists_of(seg, heads=2):
+    """(name, is_dkv, blocks, static info, followed info) of the three kernels at the committed blocks,
+    for a batch's ``seg`` (sequences, T)."""
+    kernel = attention._causal_kernel(*seg.shape, heads, tuple(attention.BLOCK_SIZES.items()), False)
+    followed = attention._document_block_lists(kernel, jnp.asarray(seg.reshape(-1)), heads)
+    b = attention.BLOCK_SIZES
+    return [("forward", False, (b["block_q"], b["block_kv"]), kernel.fwd_mask_info, followed.fwd_mask_info),
+            ("dq", False, (b["block_q_dq"], b["block_kv_dq"]), kernel.dq_mask_info, followed.dq_mask_info),
+            ("dkv", True, (b["block_q_dkv"], b["block_kv_dkv"]), kernel.dkv_mask_info, followed.dkv_mask_info)]
+
+
+def _cell_packings(seed):
+    source = packed_token_batches(PackedTokensConfig(vocab_size=64, seq_len=8192, batch_size=2, seed=seed))
+    return next(source).segment_ids
+
+
+@pytest.mark.parametrize("seed", [0, 1, 7, 905418237, 1732050807])
+def test_lists_run_the_pairs_block_pair_counts_needs_at_the_cells_sequence_length(seed):
+    both = _cell_packings(seed)
+    for seg in (both, both[:1]):  # two sequences a step (dsv2, nemo3) and one (granite)
+        for name, is_dkv, blocks, static, followed in _lists_of(seg):
+            computed, needed = attention.block_pair_counts(seg, *blocks)
+            if seed == 0:  # the oracle's own oracle, at this length too
+                assert _dense_counts(seg, *blocks) == (computed, needed)
+            mask, static_mask = np.asarray(followed.block_mask), np.asarray(static.block_mask)
+            # the library shrank each grid to one sequence's width: no step for a pair of two sequences
+            assert static_mask.shape[1 if is_dkv else 2] == 8192 // blocks[is_dkv]
+            assert mask.shape == (2 if is_dkv else 1, *static_mask.shape[1:]), name  # dk/dv: a list a head
+            assert (mask == mask[:1]).all(), name
+            assert int((static_mask > 0).sum()) == computed, name
+            assert int((mask[0] > 0).sum()) == needed, name
+            np.testing.assert_array_equal(mask[0], np.where(mask[0] > 0, static_mask[0], 0))  # 1 and 2 keep their meaning
+
+
+@pytest.mark.parametrize("seed", [0, 905418237])
+def test_a_step_left_out_names_the_block_of_the_next_step_that_runs(seed):
+    for name, is_dkv, _, static, followed in _lists_of(_cell_packings(seed)):
+        mask, nxt = np.asarray(followed.block_mask), np.asarray(followed.data_next)
+        own = np.broadcast_to(np.asarray(static.data_next), nxt.shape)
+        heads, n_i, n_j = mask.shape
+        # the grid's order: forward and dq (head, query block, key block), where one list serves every head and
+        # after a head's last step comes the next head's first; dk/dv (key block, head, query block)
+        steps = ([(h, i, j) for j in range(n_j) for h in range(heads) for i in range(n_i)] if is_dkv else
+                 [(h, i, j) for h in range(heads) for i in range(n_i) for j in range(n_j)])
+        running = [s for s in steps if mask[s] > 0]
+        assert running and len(running) < len(steps), name
+        for at, step in enumerate(steps):
+            later = next((s for s in steps[at:] if mask[s] > 0), running[0])
+            assert nxt[step] == own[later], (name, step, later)
+
+
+def test_run_share_is_one_for_one_document_and_the_hosts_count_otherwise():
+    one = np.zeros((2, 8192), np.int32)
+    assert float(attention.block_pairs_run_share(jnp.asarray(one), 1024, 1024)) == 1.0
+    for seed in (0, 905418237):
+        seg = _cell_packings(seed)
+        for blocks in ((1024, 1024), (512, 512), (1024, 512)):
+            computed, needed = attention.block_pair_counts(seg, *blocks)
+            assert 0.3 < needed / computed < 0.9
+            np.testing.assert_allclose(float(attention.block_pairs_run_share(jnp.asarray(seg), *blocks)),
+                                       needed / computed, rtol=1e-6)
+
+
+def test_the_counter_and_run_meta_speak_of_skipping_on_the_kernel_path_alone():
+    seg = jnp.asarray(_cell_packings(0))
+    assert attention.step_counters(seg) == {}  # the CPU: the xla path skips nothing by block
+    assert attention.run_meta("cpu", 8192) == {"attention_lowering": "xla"}
+    assert attention.run_meta("tpu", 8192) == {"attention_lowering": "kernel", "attention_block_skip": "documents"}
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        (name, share), = attention.step_counters(seg).items()
+        assert attention.step_counters(seg[:, :64]) == {}  # a short sequence: the xla path
+    b = attention.BLOCK_SIZES
+    computed, needed = attention.block_pair_counts(np.asarray(seg), b["block_q"], b["block_kv"])
+    assert name == "attn/block_pairs_run_share"
+    np.testing.assert_allclose(float(share), needed / computed, rtol=1e-6)

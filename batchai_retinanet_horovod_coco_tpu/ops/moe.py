@@ -6,7 +6,8 @@
 
 A router scores every token over ALL the experts of the model (it is whole
 on every chip) and picks ``k`` of them: ``route`` is a softmax whose ``k``
-largest scores are the weights as they are; ``route_sigmoid`` scores by a
+largest scores are the weights as they are (``route_renormalised``: divided
+by their sum, Qwen3-MoE's ``norm_topk_prob``); ``route_sigmoid`` scores by a
 sigmoid, picks the ``k`` largest of score + a selection bias, and weighs by
 the picked scores (without the bias) normalised to sum to a scale.
 ``dispatch`` sorts the (token, pick) pairs whose expert is held here by
@@ -138,6 +139,13 @@ def route(u, w_gate, k: int) -> Routing:
     return Routing(scores, picks.astype(jnp.int32), weights, counts)
 
 
+def route_renormalised(u, w_gate, k: int) -> Routing:
+    """``route`` with the picked scores divided by their sum (``norm_topk_prob``):
+    a token's ``k`` weights add up to 1."""
+    routing = route(u, w_gate, k)
+    return routing._replace(weights=routing.weights / jnp.sum(routing.weights, axis=-1, keepdims=True))
+
+
 def route_sigmoid(u, w_gate, k: int, bias, scale: float) -> Routing:
     """``u`` (tokens, d), ``w_gate`` (d, experts) float32, ``bias`` (experts,)
     float32.  Scores are sigmoids; the picks are the ``k`` largest of score +
@@ -150,6 +158,19 @@ def route_sigmoid(u, w_gate, k: int, bias, scale: float) -> Routing:
     weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
     counts = jnp.sum(jax.nn.one_hot(picks, scores.shape[-1], dtype=jnp.int32), axis=(0, 1))
     return Routing(scores, picks.astype(jnp.int32), weights, counts)
+
+
+def global_balance_loss(counts, mean_scores, tokens: int):
+    """Qwen3-MoE's ``load_balancing_loss_func`` without its coefficient, over
+    the tokens of ALL the step's expert layers taken together (the published
+    code concatenates the layers' router logits before it takes its means):
+    ``experts x sum_i f_i P_i`` with ``f_i`` the picks of expert i a token and
+    ``P_i`` the mean score of expert i, both over layers and tokens.
+    ``counts`` (layers, experts) picks, ``mean_scores`` (layers, experts)
+    float32 means over a layer's ``tokens`` tokens.  The gradient flows
+    through ``P`` alone: ``f`` is a count."""
+    f = jax.lax.stop_gradient(jnp.mean(counts.astype(jnp.float32), axis=0) / tokens)
+    return counts.shape[-1] * jnp.sum(f * jnp.mean(mean_scores, axis=0))
 
 
 def sequence_balance_loss(scores, picks, k: int):

@@ -15,6 +15,15 @@
   all second elements).  Only the product of a rotated query with a rotated
   key is ever used, and that does not depend on the layout.
 
+- ``plain_inv_freq``, ``sectioned_angles``, ``apply_rotary_halves``: rotary
+  positions in SECTIONS (Qwen2-VL's multimodal rotary embedding, which
+  Keye-VL-2.0's language model takes over: ``rope_scaling.mrope_section``).  A
+  token has three position ids (temporal, height, width); the ``dim / 2``
+  frequency pairs are cut into three runs of ``sections`` pairs and run ``a``
+  turns by id ``a``.  Text tokens have three equal ids, and the result is the
+  plain rotation.  Pair ``i`` is elements ``(i, i + dim / 2)`` (the published
+  ``rotate_half``), and the layout is kept.
+
 Angles, cosines and sines are float32; the rotated vector goes back to the
 input's dtype.
 """
@@ -61,4 +70,28 @@ def apply_rotary(x, positions, inv_freq, scale: float = 1.0):
     cos, sin = scale * jnp.cos(angles), scale * jnp.sin(angles)
     x32 = x.astype(jnp.float32)
     a, b = x32[..., 0::2], x32[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def plain_inv_freq(dim: int, theta: float):
+    """(dim / 2,) float32: ``theta^(-2i / dim)``."""
+    return 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+
+
+def sectioned_angles(position_ids, inv_freq, sections: tuple[int, ...]):
+    """``position_ids`` (sections, batch, T) int32, ``inv_freq`` (pairs,) with
+    ``sum(sections) == pairs`` -> (batch, T, pairs) float32 angles: the pairs
+    of run ``a`` turn by ``position_ids[a]``."""
+    assert sum(sections) == inv_freq.shape[0] and len(sections) == position_ids.shape[0]
+    edges = [sum(sections[:a]) for a in range(len(sections) + 1)]
+    return jnp.concatenate([position_ids[a].astype(jnp.float32)[..., None] * inv_freq[lo:hi]
+                            for a, (lo, hi) in enumerate(zip(edges, edges[1:]))], axis=-1)
+
+
+def apply_rotary_halves(x, angles):
+    """``x`` (batch, T, ..., dim) with ``angles`` (batch, T, dim / 2): elements
+    ``(i, i + dim / 2)`` turn together by ``angles[..., i]``."""
+    angles = angles.reshape(*angles.shape[:2], *([1] * (x.ndim - 3)), angles.shape[-1])
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)

@@ -60,7 +60,7 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     # models/granite_hybrid.py
     "embed": (),
     "mamba": ("in_proj", "conv", "ssd", "gate_norm", "out_proj"),  # with its norm
-    "attention": (),
+    "attention": ("indexer", "select", "attention_core", "indexer_loss"),  # beneath it in models/keye_vl2.py alone
     "mlp": (),
     "lm_head": (),  # final norm, head (tied or not), 1 / logits_scaling
     # models/deepseek_v2.py (``embed`` and ``lm_head`` as above)
@@ -69,6 +69,8 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     "moe": ("router", "dispatch", "experts", "combine", "shared", "aux"),  # ops/moe.py, with its norm
     # models/nemotron_h.py enters ``embed``, ``mamba``, ``attention``, ``moe`` (without ``aux``), ``lm_head``:
     # every layer ONE of the three mixers with its norm, and no ``mlp``
+    # models/keye_vl2.py enters ``embed``, ``attention`` (with the indexer, the selection, the selected attention and
+    # the indexer's loss beneath it: ops/sparse_attention.py), ``moe`` (without ``shared``), ``lm_head``
     # every task's
     "loss": (),  # focal, smooth-L1, target encoding; next-token cross-entropy
     "optimizer": (),  # clip, decay, momentum, apply, the numerics summary

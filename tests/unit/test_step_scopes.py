@@ -434,3 +434,47 @@ def test_run_meta_says_which_lowering_the_lm_steps_attention_took(_no_ring, tmp_
     assert meta["args"] == {"device_kind": jax.devices()[0].device_kind, "process_count": 1,
                             "local_device_count": jax.local_device_count(), **more}
     assert meta["ts"] <= min(e["ts"] for e in events if e["name"] == "compile_train_step")
+
+
+def test_the_sparse_attention_models_step_files_every_operation_it_can_under_a_scope_of_the_model():
+    """models/keye_vl2.py through the shared step: the model enters ``attention``
+    (with ``indexer``, ``select``, ``attention_core`` and ``indexer_loss`` beneath
+    it) and ``moe`` (without ``shared``), and none of the other models' scopes;
+    forward, recomputed forward and backward keep the scope; every matrix
+    product and every sort of the compiled step lies under one of the model's
+    scopes or the optimizer's, none is unscoped."""
+    from batchai_retinanet_horovod_coco_tpu.models import keye_vl2
+    from batchai_retinanet_horovod_coco_tpu.train.step import UNSCOPED
+
+    model = keye_vl2.KeyeVL2(keye_vl2.TINY)
+    tx = make_optimizer(OptimizerConfig(optimizer="adamw", schedule="constant", warmup_steps=0))[0]
+    state = create_train_state(model, tx, (1, 8), jax.random.key(0), example_dtype=LMTask.example_dtype)
+    seg = jnp.asarray(np.repeat([[0, 1, 2], [0, 1, 1]], [20, 30, 14], axis=1), jnp.int32)
+    batch = {"tokens": jnp.zeros((2, 64), jnp.int32), "segment_ids": seg}
+    compiled = make_train_step(model, (2, 64), None, task=LMTask(), donate_state=False).lower(state, batch).compile()
+    table = scope_table(compiled)
+    filed = {(s, d) for s, d, _ in table.values()}
+    assert model.scopes == ("embed", "attention", "moe", "lm_head", "loss")
+    assert set(model.scopes) <= set(STEP_SCOPES)
+    for s in (*model.scopes, "optimizer"):
+        assert (s, "fwd") in filed, s
+    assert {s for s, d in filed if d == "bwd"} >= set(model.scopes)
+    assert not {"mla", "dense_mlp", "mlp", "mamba"} & {s for s, _ in filed}
+    assert not set(DetectionTask.scopes) - {"loss"} & {s for s, _ in filed}
+    assert STEP_SCOPES["attention"] == ("indexer", "select", "attention_core", "indexer_loss")
+    for slice_, beneath in (("attention", STEP_SCOPES["attention"]), ("moe", ("router", "dispatch", "experts", "combine", "aux"))):
+        paths = {p for t, _, p in table.values() if t == slice_}
+        for name in beneath:
+            assert any(f"/{name}/" in p or p.endswith("/" + name) for p in paths), (slice_, name)
+    assert not any("/shared" in p for t, _, p in table.values() if t == "moe")  # no shared expert
+    # the selection has no backward of its own, the indexer and its loss have
+    second = lambda p: next((n for n in p.split("/")[1:] if n in STEP_SCOPES["attention"]), "-")
+    directions = {}
+    for t, d, p in table.values():
+        if t == "attention":
+            directions.setdefault(second(p), set()).add(d)
+    assert directions["indexer"] == directions["attention_core"] == directions["indexer_loss"] == {"fwd", "bwd"}
+    work = {n: table[n] for n, op in _instructions(compiled).items() if op in ("dot", "sort", "convolution")}
+    assert work and not [n for n, (s, _, _) in work.items() if s == UNSCOPED]
+    assert {s for s, _, _ in work.values()} >= {"attention", "moe", "lm_head"}
+    assert {s for s, _, _ in work.values()} <= {*model.scopes, "optimizer"}

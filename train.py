@@ -30,10 +30,15 @@ chip holds a share, models/deepseek_v2.py;
 benchmark/configs/deepseek-v2-lite-ep8.json) or ``nemotron_h`` (Nemotron-H:
 every layer ONE mixer, a Mamba-2 scan with grouped B and C, squared-ReLU
 routed and shared experts behind a sigmoid router, or grouped-query attention,
-models/nemotron_h.py; benchmark/configs/nemotron-3-nano-30b-ep16.json).
+models/nemotron_h.py; benchmark/configs/nemotron-3-nano-30b-ep16.json) or
+``KeyeVL2`` (Keye-VL-2.0's language model: grouped-query attention over the
+keys a learned indexer selects, exactly the top 2048 a query, and softmax-routed
+experts without a shared one, models/keye_vl2.py;
+benchmark/configs/keye-vl2-30b-a3b-ep8.json).
 ``--model tiny`` (the default: one Granite period of ten layers at width 64),
-``--model tiny-moe`` (one dense and two expert layers, 4 of 16 experts held)
-and ``--model tiny-nemotron`` (the pattern MEM*E, 2 groups, 2 of 8 experts
+``--model tiny-moe`` (one dense and two expert layers, 4 of 16 experts held),
+``--model tiny-nemotron`` (the pattern MEM*E, 2 groups, 2 of 8 experts held)
+and ``--model tiny-keye`` (three layers, a query keeps 24 keys, 4 of 16 experts
 held) are what the CPU tests run.  The LM task is single-chip until an issue brings its sharding:
 ``--num-devices`` above 1 is refused.
 """
@@ -309,10 +314,10 @@ def _add_lm_parser(sub) -> None:
     eval or mesh flags apply)."""
     lm = sub.add_parser(
         "lm-synthetic", allow_abbrev=False,
-        help="train a language model (Granite 4.0-H hybrid, DeepSeek-V2 or "
-             "Nemotron-H, by the config's model_type) on seeded packed token "
-             "sequences (single chip; --model tiny, tiny-moe or tiny-nemotron "
-             "on a CPU)",
+        help="train a language model (Granite 4.0-H hybrid, DeepSeek-V2, "
+             "Nemotron-H or Keye-VL-2.0's, by the config's model_type) on "
+             "seeded packed token sequences (single chip; --model tiny, "
+             "tiny-moe, tiny-nemotron or tiny-keye on a CPU)",
     )
     g = lm.add_argument_group("model")
     g.add_argument("--model", default="tiny",
@@ -321,14 +326,18 @@ def _add_lm_parser(sub) -> None:
                         "(DeepSeek-V2: a dense and two expert layers at "
                         "width 64, 4 of 16 experts held, 3 a token), "
                         "'tiny-nemotron' (Nemotron-H: the pattern MEM*E at "
-                        "width 64, 2 groups, 2 of 8 experts held, 3 a token) "
+                        "width 64, 2 groups, 2 of 8 experts held, 3 a token), "
+                        "'tiny-keye' (Keye-VL-2.0's language model: three "
+                        "layers at width 64, an indexer that keeps 24 keys a "
+                        "query, 4 of 16 experts held, 3 a token) "
                         "- the CPU tests' presets - or a JSON file with the "
                         "published config.json keys, whose model_type "
-                        "(granitemoehybrid, deepseek_v2, nemotron_h) picks "
-                        "the model: "
+                        "(granitemoehybrid, deepseek_v2, nemotron_h, KeyeVL2) "
+                        "picks the model: "
                         "benchmark/configs/granite-4.0-h-micro-p1.json, "
                         "benchmark/configs/deepseek-v2-lite-ep8.json, "
-                        "benchmark/configs/nemotron-3-nano-30b-ep16.json")
+                        "benchmark/configs/nemotron-3-nano-30b-ep16.json, "
+                        "benchmark/configs/keye-vl2-30b-a3b-ep8.json")
     g = lm.add_argument_group("data")
     g.add_argument("--seq-len", type=int, default=64,
                    help="tokens per packed sequence")

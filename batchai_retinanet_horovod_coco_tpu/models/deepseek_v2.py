@@ -37,7 +37,9 @@ level is the kind of parameter (``embed``, ``attention``, ``dense_mlp``,
 ``router``, ``experts``, ``shared``, ``norms``, ``head``).  float32
 parameters; ``config.dtype`` (bfloat16) activations and matmul operands;
 float32 norms, router, softmax, rotary angles and loss.  Every layer is
-recomputed in the backward pass.  Single device.
+recomputed in the backward pass from its input; where the attention kernels
+run their output and log-sum-exp are kept too (``lm_layers.LAYER_KEEPS``).
+Single device.
 """
 
 from __future__ import annotations
@@ -260,7 +262,7 @@ def hidden_states(config: DeepseekV2Config, params: dict, tokens, segment_ids):
         name, dense = f"layer_{i}", _is_dense(config, i)
         mlp_p = params["dense_mlp"][name] if dense else (
             params["router"][name], params["experts"][name], params["shared"][name])
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1))  # only the layer's input is kept
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
         x, r = layer(config, dense, params["attention"][name], mlp_p, params["norms"][name], x, segment_ids, positions)
         if not dense:
             routed.append(r)

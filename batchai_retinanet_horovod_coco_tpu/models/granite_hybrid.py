@@ -27,8 +27,9 @@ a reader of a checkpoint see the model's parts.  Parameters are float32;
 activations and matmul operands ``config.dtype`` (bfloat16); norms, softmax,
 the scan's decays and state and the loss reduce in float32, in XLA's lowering
 and inside the attention and scan kernels alike (ops/attention.py, ops/ssd.py).
-Every layer is recomputed in the backward pass: only the layers' inputs are
-kept.  Single device: sharding comes with its own issue.
+Every layer is recomputed in the backward pass: the layers' inputs are kept,
+and where the attention kernels run their output and log-sum-exp
+(``lm_layers.LAYER_KEEPS``).  Single device: sharding comes with its own issue.
 """
 
 from __future__ import annotations
@@ -224,7 +225,7 @@ def hidden_states(config: GraniteHybridConfig, params: dict, tokens, segment_ids
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype, config.embedding_multiplier)
     for i, kind in enumerate(config.layer_types):
         name = f"layer_{i}"
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1))  # only the layer's input is kept
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
         x = layer(config, kind, params[kind][name], params["mlp"][name], params["norms"][name], x, segment_ids)
     return x
 

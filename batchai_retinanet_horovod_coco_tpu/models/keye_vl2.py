@@ -44,8 +44,9 @@ is the kind of parameter (``embed``, ``attention``, ``indexer``, ``router``,
 (bfloat16) activations and matmul operands, the indexer's projections and the
 operands of its scores too; float32 norms, router, softmaxes, rotary angles,
 index scores, thresholds, ``P``, the KL and the loss.  Every layer is recomputed
-in the backward pass; of its inside only the selection's thresholds (two
-integers a query) are kept.  Single device.
+in the backward pass; of its inside the selection's thresholds (two integers a
+query) are kept, and where the attention kernels run their output and
+log-sum-exp (``lm_layers.LAYER_KEEPS``).  Single device.
 """
 
 from __future__ import annotations
@@ -285,9 +286,7 @@ def hidden_states(config: KeyeVL2Config, params: dict, tokens, segment_ids, posi
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype)
         if position_ids is None:
             position_ids = text_positions(segment_ids)
-    # of a layer only its input and the selection's thresholds are kept (ops/sparse_attention.py::THRESHOLD)
-    layer = jax.checkpoint(_layer, static_argnums=(0, 1),
-                           policy=jax.checkpoint_policies.save_only_these_names(sparse.THRESHOLD))
+    layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
     by_layer = []
     for i in range(config.num_hidden_layers):
         name = f"layer_{i}"
@@ -369,9 +368,12 @@ class KeyeVL2:
     def run_meta(self, bucket) -> dict[str, Any]:
         """Which lowering the step's sparse attention (ops/sparse_attention.py),
         its grouped products and the row movements around them (ops/moe.py)
-        take, the keys a query keeps and the share of the experts held."""
+        take (on the kernels: a recomputed layer keeps their output and
+        log-sum-exp), the keys a query keeps and the share of the experts held."""
         config, backend = self.config, jax.default_backend()
-        return {"attention_lowering": _lowering(bucket[1]), "attention_block_skip": "causal",
+        how = _lowering(bucket[1])
+        return {"attention_lowering": how, "attention_block_skip": "causal",
+                **({"attention_residuals": "kept"} if how == sparse.KERNEL else {}),
                 "dsa_topk": config.indexer_topk,
                 "moe_lowering": _moe_lowering(config, *bucket),
                 "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,

@@ -1,7 +1,8 @@
 """What the language models share (models/granite_hybrid.py,
-models/deepseek_v2.py, models/nemotron_h.py): RMSNorm, the matmul with a
-weight, the gated SiLU MLP and the squared-ReLU MLP, a Mamba-2 mixer's
-depthwise convolution, the embedding lookup, the head and the next-token loss.
+models/deepseek_v2.py, models/nemotron_h.py, models/keye_vl2.py): RMSNorm, the
+matmul with a weight, the gated SiLU MLP and the squared-ReLU MLP, a Mamba-2
+mixer's depthwise convolution, the embedding lookup, the head, the next-token
+loss, and what a layer recomputed in the backward pass keeps (``LAYER_KEEPS``).
 
 A matmul's operands are rounded by the CALLER's ``cast`` (its module's
 ``_operand`` bound to its config): the benchmark's precision controls patch
@@ -13,6 +14,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from batchai_retinanet_horovod_coco_tpu.ops import attention
+from batchai_retinanet_horovod_coco_tpu.ops import sparse_attention as sparse
+
+# The policy of every model's ``jax.checkpoint(_layer)``: of a layer its input is kept, and of its inside
+# what carries one of these names - the attention kernels' output and log-sum-exp (a few hundred MB a step
+# against a second run of the forward kernel a layer) and the sparse attention's thresholds.  Where the
+# xla lowerings run nothing carries the first name: the input (and the thresholds) alone, as before.
+LAYER_KEEPS = jax.checkpoint_policies.save_only_these_names(attention.RESIDUALS, sparse.THRESHOLD)
 
 
 def rms_norm(x, w, eps):

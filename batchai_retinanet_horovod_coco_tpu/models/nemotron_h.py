@@ -58,7 +58,8 @@ parameter (``embed``, ``mamba``, ``attention``, ``router``, ``experts``,
 ``shared``, ``norms``, ``head``).  float32 parameters; ``config.dtype``
 (bfloat16) activations and matmul operands; float32 norms, router, softmax,
 the scan's decays and state, and loss.  Every layer is recomputed in the
-backward pass.  Single device.
+backward pass from its input; where the attention kernels run their output and
+log-sum-exp are kept too (``lm_layers.LAYER_KEEPS``).  Single device.
 """
 
 from __future__ import annotations
@@ -303,7 +304,7 @@ def hidden_states(config: NemotronHConfig, params: dict, tokens, segment_ids):
         name = f"layer_{i}"
         p = (params["router"][name], params["experts"][name], params["shared"][name]) if kind == EXPERTS else (
             params[SCOPE[kind]][name])
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1, 2))  # only the layer's input is kept
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1, 2), policy=lm_layers.LAYER_KEEPS)
         x, r = layer(config, kind, len(routed), p, params["norms"][name], x, segment_ids)
         if kind == EXPERTS:
             routed.append(r)

@@ -21,7 +21,14 @@ passes over the query's scores (``_kth_largest``).  The selection is then
 that fill the ``topk`` (``cut``: the position of the last tie taken) - what
 ``lax.top_k`` picks.  ``tau`` and ``cut`` are (batch, T) integers: they carry
 the checkpoint name ``THRESHOLD`` so that a layer recomputed in the backward
-pass may keep them and form the selection again by comparison alone.
+pass may keep them and form the selection again by comparison alone.  The
+kernel lowering's attention gives its output and log-sum-exp, the residuals
+of its dq and dk/dv kernels, the name ``attention.RESIDUALS`` INSIDE its
+forward rule (a residual is the rule's own value: a name given outside the
+``custom_vjp`` names another), so that such a layer keeps them too and the
+forward kernel is not run again in the backward pass: 136 MB a layer at
+T = 16 384, 0.3 ms to write and read back against 27 ms to form again.
+``lm_layers.LAYER_KEEPS`` is the policy that keeps both names.
 
 Two lowerings, chosen by ``lowering`` from the backend and the shapes alone:
 
@@ -321,7 +328,8 @@ def _masked_attention(interpret, tiles, q, k, v, mask):
 
 
 def _masked_attention_fwd(interpret, tiles, q, k, v, mask):
-    out, lse = dsa.masked_attention(q, k, v, mask, interpret, tiles)
+    # named here, before they are the rule's outputs and residuals: a recomputed layer keeps them
+    out, lse = (checkpoint_name(x, attention.RESIDUALS) for x in dsa.masked_attention(q, k, v, mask, interpret, tiles))
     return (out, lse), (q, k, v, mask, out, lse)
 
 

@@ -371,3 +371,8 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert float(metrics["loss"]) > float(metrics["moe/aux_loss"]) > 0
     assert LMTask().run_meta(model, (2, T)) == {"attention_lowering": "xla", "moe_lowering": "xla",
                                                 "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16}
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):  # the cell's model and bucket: the kernels
+        meta = build_language_model(CONFIG_FILE).run_meta((2, 8192))
+    assert meta == {"attention_lowering": "kernel", "attention_block_skip": "documents",
+                    "attention_residuals": "kept", "moe_lowering": "kernel", "moe_rows_lowering": "kernel",
+                    "experts_held": 8, "experts_total": 64}

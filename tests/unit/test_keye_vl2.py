@@ -9,6 +9,7 @@ import dataclasses
 import functools
 import json
 import os
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -421,3 +422,11 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert LMTask().run_meta(model, (2, T)) == {
         "attention_lowering": "xla", "attention_block_skip": "causal", "dsa_topk": 24, "moe_lowering": "xla",
         "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16}
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):  # the cell's model and bucket: the kernels
+        published = build_language_model(CONFIG_FILE)
+        meta = published.run_meta((1, 16384))
+        assert "attention_residuals" not in published.run_meta((1, 16384 + 1024))  # no whole runs: the xla lowering
+    assert meta == {
+        "attention_lowering": "kernel", "attention_block_skip": "causal", "attention_residuals": "kept",
+        "dsa_topk": 2048, "moe_lowering": "kernel", "moe_rows_lowering": "kernel", "experts_held": 16,
+        "experts_total": 128}

@@ -283,7 +283,9 @@ def test_the_counter_and_run_meta_speak_of_skipping_on_the_kernel_path_alone():
     seg = jnp.asarray(_cell_packings(0))
     assert attention.step_counters(seg) == {}  # the CPU: the xla path skips nothing by block
     assert attention.run_meta("cpu", 8192) == {"attention_lowering": "xla"}
-    assert attention.run_meta("tpu", 8192) == {"attention_lowering": "kernel", "attention_block_skip": "documents"}
+    assert attention.run_meta("tpu", 8192) == {"attention_lowering": "kernel", "attention_block_skip": "documents",
+                                               "attention_residuals": "kept"}
+    assert attention.run_meta("tpu", 8192 + 64) == {"attention_lowering": "xla"}  # a ragged sequence keeps none
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         (name, share), = attention.step_counters(seg).items()
         assert attention.step_counters(seg[:, :64]) == {}  # a short sequence: the xla path

@@ -41,11 +41,16 @@ class PackedTokensConfig:
     doc_len_sigma: float = 1.3  # of the logarithm
     doc_len_min: int = 16  # and at most seq_len
     seed: int = 0
+    # None: ``seed`` draws the documents' lengths and then the token ids, from one generator.  A number: the
+    # lengths come from a generator of their own with this seed, so ``segment_ids`` are the same for every
+    # ``seed``, which then moves the token ids alone (a benchmark cell whose work follows the packing).
+    layout_seed: int | None = None
 
 
 def packed_token_batches(config: PackedTokensConfig) -> Iterator[TokenBatch]:
     """The endless stream of batches; the same config gives the same stream."""
     rng = np.random.default_rng(config.seed)
+    lengths = rng if config.layout_seed is None else np.random.default_rng(config.layout_seed)
     t = config.seq_len
     left = 0  # tokens of the current document still to place
     row = 0
@@ -56,7 +61,7 @@ def packed_token_batches(config: PackedTokensConfig) -> Iterator[TokenBatch]:
                 at = doc = 0
                 while at < t:
                     if left == 0:
-                        drawn = rng.lognormal(math.log(config.doc_len_median), config.doc_len_sigma)
+                        drawn = lengths.lognormal(math.log(config.doc_len_median), config.doc_len_sigma)
                         left = int(np.clip(round(drawn), config.doc_len_min, t))
                     n = min(left, t - at)
                     segment_ids[r, at:at + n] = doc

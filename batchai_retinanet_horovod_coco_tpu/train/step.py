@@ -71,6 +71,9 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     # every layer ONE of the three mixers with its norm, and no ``mlp``
     # models/keye_vl2.py enters ``embed``, ``attention`` (with the indexer, the selection, the selected attention and
     # the indexer's loss beneath it: ops/sparse_attention.py), ``moe`` (without ``shared``), ``lm_head``
+    # models/olmo_hybrid.py enters ``embed``, ``gdn``, ``attention``, ``mlp``, ``lm_head``: a layer's norm is on its
+    # sublayer's OUTPUT and counts with the sublayer
+    "gdn": ("in_proj", "conv", "delta_rule", "gate_norm", "out_proj"),  # gated delta rule (ops/delta_rule.py)
     # every task's
     "loss": (),  # focal, smooth-L1, target encoding; next-token cross-entropy
     "optimizer": (),  # clip, decay, momentum, apply, the numerics summary

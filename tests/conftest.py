@@ -83,6 +83,11 @@ def pytest_configure(config):
 # `workloads` lists; PR 38 appended keye-vl2-train-doc16k.  The rest of their
 # bodies is kept alive, by name, in
 # tests/benchmark/test_benchmark_nemotron_cell_after_pr38.py.
+#
+# One more since PR 40: the Keye cell's "still there word for word" test pins
+# keye-vl2-train-doc16k as the LAST name of four `workloads` lists; PR 40
+# appended olmo-hybrid-train-pack8k.  The rest of its body is kept alive, by
+# name, in tests/benchmark/test_benchmark_keye_cell_after_pr40.py.
 _PINNED_TO_THE_LAST_ENTRIES = {
     ("test_benchmark_lm_cell.py", "test_the_cell_and_its_configuration_as_the_issue_set_them"): (
         "asserts granite's entries are the last of BENCHMARK.json's lists; "
@@ -95,6 +100,10 @@ _PINNED_TO_THE_LAST_ENTRIES = {
     ("test_benchmark_nemotron_cell.py", "test_what_the_benchmark_had_is_still_there_word_for_word"): (
         "asserts nemo3-nano-train-pack8k is the last cell of four workloads "
         "lists; PR 38 appended keye-vl2-train-doc16k after it (ROADMAP S0c)"
+    ),
+    ("test_benchmark_keye_cell.py", "test_what_the_benchmark_had_is_still_there_word_for_word"): (
+        "asserts keye-vl2-train-doc16k is the last cell of four workloads "
+        "lists; PR 40 appended olmo-hybrid-train-pack8k after it (ROADMAP S0c)"
     ),
 }
 

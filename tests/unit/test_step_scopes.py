@@ -21,6 +21,7 @@ from batchai_retinanet_horovod_coco_tpu.data.pipeline import Batch
 from batchai_retinanet_horovod_coco_tpu.models import RetinaNetConfig, build_retinanet
 from batchai_retinanet_horovod_coco_tpu.models.deepseek_v2 import DeepseekV2
 from batchai_retinanet_horovod_coco_tpu.models.granite_hybrid import GraniteHybrid
+from batchai_retinanet_horovod_coco_tpu.models.olmo_hybrid import OlmoHybrid
 from batchai_retinanet_horovod_coco_tpu.obs import trace
 from batchai_retinanet_horovod_coco_tpu.parallel import make_mesh, zero
 from batchai_retinanet_horovod_coco_tpu.parallel.mesh import DATA_AXIS
@@ -39,7 +40,7 @@ from batchai_retinanet_horovod_coco_tpu.train.task import DetectionTask, LMTask
 EVERY_STEPS = ("optimizer", "grad_allreduce")
 DETECTION_SCOPES = (*DetectionTask.scopes, *EVERY_STEPS)
 # the language-model task's are its model's (LMTask: ``model.scopes``)
-LM_SCOPES = (*GraniteHybrid.scopes, *DeepseekV2.scopes)
+LM_SCOPES = (*GraniteHybrid.scopes, *DeepseekV2.scopes, *OlmoHybrid.scopes)
 
 HW = (64, 64)
 NUM_CLASSES = 3

@@ -315,9 +315,10 @@ def _add_lm_parser(sub) -> None:
     lm = sub.add_parser(
         "lm-synthetic", allow_abbrev=False,
         help="train a language model (Granite 4.0-H hybrid, DeepSeek-V2, "
-             "Nemotron-H or Keye-VL-2.0's, by the config's model_type) on "
-             "seeded packed token sequences (single chip; --model tiny, "
-             "tiny-moe, tiny-nemotron or tiny-keye on a CPU)",
+             "Nemotron-H, Keye-VL-2.0's or Olmo-Hybrid, by the config's "
+             "model_type) on seeded packed token sequences (single chip; "
+             "--model tiny, tiny-moe, tiny-nemotron, tiny-keye or tiny-olmo "
+             "on a CPU)",
     )
     g = lm.add_argument_group("model")
     g.add_argument("--model", default="tiny",
@@ -329,15 +330,19 @@ def _add_lm_parser(sub) -> None:
                         "width 64, 2 groups, 2 of 8 experts held, 3 a token), "
                         "'tiny-keye' (Keye-VL-2.0's language model: three "
                         "layers at width 64, an indexer that keeps 24 keys a "
-                        "query, 4 of 16 experts held, 3 a token) "
+                        "query, 4 of 16 experts held, 3 a token), "
+                        "'tiny-olmo' (Olmo-Hybrid: one period of three gated "
+                        "delta rule layers and a full attention layer at "
+                        "width 64) "
                         "- the CPU tests' presets - or a JSON file with the "
                         "published config.json keys, whose model_type "
-                        "(granitemoehybrid, deepseek_v2, nemotron_h, KeyeVL2) "
-                        "picks the model: "
+                        "(granitemoehybrid, deepseek_v2, nemotron_h, KeyeVL2, "
+                        "olmo_hybrid) picks the model: "
                         "benchmark/configs/granite-4.0-h-micro-p1.json, "
                         "benchmark/configs/deepseek-v2-lite-ep8.json, "
                         "benchmark/configs/nemotron-3-nano-30b-ep16.json, "
-                        "benchmark/configs/keye-vl2-30b-a3b-ep8.json")
+                        "benchmark/configs/keye-vl2-30b-a3b-ep8.json, "
+                        "benchmark/configs/olmo-hybrid-7b-p1.json")
     g = lm.add_argument_group("data")
     g.add_argument("--seq-len", type=int, default=64,
                    help="tokens per packed sequence")
@@ -348,6 +353,10 @@ def _add_lm_parser(sub) -> None:
                         "clipped to [--doc-len-min, --seq-len]); the "
                         "benchmark's mix uses 512 at --seq-len 8192")
     g.add_argument("--doc-len-min", type=int, default=4)
+    g.add_argument("--layout-seed", type=int, default=None,
+                   help="draw the documents' lengths from a generator of "
+                        "their own: the packing is then the same for every "
+                        "--seed, which moves token ids and weights alone")
     g = lm.add_argument_group("optimization")
     g.add_argument("--steps", type=int, default=20)
     g.add_argument("--lr", type=float, default=3e-4,
@@ -793,6 +802,7 @@ def _run_lm(args) -> dict[str, float]:
         vocab_size=config.vocab_size, seq_len=args.seq_len,
         batch_size=args.batch_size, doc_len_median=args.doc_len_median,
         doc_len_min=args.doc_len_min, seed=args.seed,
+        layout_seed=args.layout_seed,
     ))
     logger = EventSink(log_dir=args.log_dir)
     telem_server, slo_monitor = _start_telemetry(args, logger)

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from batchai_retinanet_horovod_coco_tpu.models import lm_layers
 from batchai_retinanet_horovod_coco_tpu.models.lm_layers import next_token_loss
-from batchai_retinanet_horovod_coco_tpu.ops import attention, ssd
+from batchai_retinanet_horovod_coco_tpu.ops import attention, document_conv, ssd
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -269,9 +269,12 @@ class GraniteHybrid:
         return loss, {"loss": loss, "tokens_counted": counted, **attention.step_counters(segment_ids)}
 
     def run_meta(self, bucket) -> dict[str, Any]:
-        """Which lowering the step's attention layer (ops/attention.py) and
-        its mixers' scans (ops/ssd.py) take: static per program."""
+        """Which lowering the step's attention layer (ops/attention.py), its
+        mixers' scans (ops/ssd.py) and the convolutions before them
+        (ops/document_conv.py) take: static per program."""
         config, backend = self.config, jax.default_backend()
         return {**attention.run_meta(backend, bucket[1]),
                 "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_n_heads,
-                                             config.mamba_d_head, config.mamba_d_state)}
+                                             config.mamba_d_head, config.mamba_d_state),
+                "conv_lowering": document_conv.lowering(
+                    backend, bucket[1], config.mamba_d_inner + 2 * config.mamba_d_state, config.mamba_d_conv)}

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from batchai_retinanet_horovod_coco_tpu.ops import attention
+from batchai_retinanet_horovod_coco_tpu.ops import attention, document_conv
 from batchai_retinanet_horovod_coco_tpu.ops import sparse_attention as sparse
 
 # The policy of every model's ``jax.checkpoint(_layer)``: of a layer its input is kept, and of its inside
@@ -46,23 +46,10 @@ def relu2_mlp(cast, p, u):
     return matmul(cast, jnp.square(jax.nn.relu(matmul(cast, u, p["up"]))), p["down"])
 
 
-def _same_document_shift(x, segment_ids, j: int):
-    """``x`` delayed by ``j`` tokens, zero where that token is before the
-    sequence or in another document."""
-    if j == 0:
-        return x
-    moved = jnp.pad(x[:, :-j], [(0, 0), (j, 0), (0, 0)])
-    same = jnp.pad(segment_ids[:, :-j], [(0, 0), (j, 0)], constant_values=-1) == segment_ids
-    return jnp.where(same[..., None], moved, 0)
-
-
-def document_conv_silu(x, w, b, segment_ids):
-    """``silu(conv1d(x) + b)`` float32: depthwise over ``x`` (batch, T,
-    channels), causal, ``w`` (taps, channels) with the last tap on the token
-    itself, not reaching into the previous document."""
-    k = w.shape[0]
-    x32 = x.astype(jnp.float32)
-    return jax.nn.silu(b + sum(w[k - 1 - j] * _same_document_shift(x32, segment_ids, j) for j in range(k)))
+# The mixers' depthwise convolution, ``document_conv_silu(x, w, b, segment_ids)`` -> float32: the models look
+# it up HERE at call time (the benchmark's mutations patch this name); ops/document_conv.py says which
+# lowering runs.
+document_conv_silu = document_conv.document_conv_silu
 
 
 def embed_lookup(table, tokens, dtype, multiplier=None):

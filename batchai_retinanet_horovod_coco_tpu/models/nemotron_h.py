@@ -73,7 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from batchai_retinanet_horovod_coco_tpu.models import lm_layers
-from batchai_retinanet_horovod_coco_tpu.ops import attention, moe, rope, ssd
+from batchai_retinanet_horovod_coco_tpu.ops import attention, document_conv, moe, rope, ssd
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 SCOPE = {MAMBA: "mamba", EXPERTS: "moe", ATTENTION: "attention"}
@@ -363,13 +363,17 @@ class NemotronH:
 
     def run_meta(self, bucket) -> dict[str, Any]:
         """Which lowering the step's attention (ops/attention.py), its scans
-        (ops/ssd.py), its grouped products and the row movements around them
-        (ops/moe.py) take, the scan's groups and the share of the experts held."""
+        (ops/ssd.py), the convolutions before them (ops/document_conv.py), its
+        grouped products and the row movements around them (ops/moe.py) take, the
+        scan's groups and the share of the experts held."""
         config, backend = self.config, jax.default_backend()
         return {**attention.run_meta(backend, bucket[1]),
                 "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_num_heads,
                                              config.mamba_head_dim, config.ssm_state_size, config.n_groups),
                 "ssd_groups": config.n_groups,
+                "conv_lowering": document_conv.lowering(
+                    backend, bucket[1], config.mamba_d_inner + 2 * config.n_groups * config.ssm_state_size,
+                    config.conv_kernel),
                 "moe_lowering": _moe_lowering(config, *bucket),
                 "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,
                                                        config.hidden_size, config.moe_intermediate_size),

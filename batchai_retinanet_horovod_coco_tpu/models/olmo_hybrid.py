@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from batchai_retinanet_horovod_coco_tpu.models import lm_layers
-from batchai_retinanet_horovod_coco_tpu.ops import attention, delta_rule, rope
+from batchai_retinanet_horovod_coco_tpu.ops import attention, delta_rule, document_conv, rope
 
 LINEAR, FULL = "linear_attention", "full_attention"
 SCOPE = {LINEAR: "gdn", FULL: "attention"}  # a kind's scope (train/step.py::STEP_SCOPES) and parameter group
@@ -310,12 +310,15 @@ class OlmoHybrid:
         return loss, {"loss": loss, "tokens_counted": counted, **counters, **attention.step_counters(segment_ids)}
 
     def run_meta(self, bucket) -> dict[str, Any]:
-        """Which lowering the step's attention layers (ops/attention.py) and its
-        delta rules (ops/delta_rule.py) take, and the delta rule's chunk: static per
-        program."""
+        """Which lowering the step's attention layers (ops/attention.py), its delta
+        rules (ops/delta_rule.py) and the convolutions before them
+        (ops/document_conv.py) take, and the delta rule's chunk: static per program."""
         config, backend = self.config, jax.default_backend()
         return {**attention.run_meta(backend, bucket[1]),
                 "delta_rule_lowering": delta_rule.lowering(
                     backend, bucket[1], config.delta_rule_chunk, config.linear_num_value_heads,
                     config.linear_key_head_dim, config.linear_value_head_dim),
-                "delta_rule_chunk": config.delta_rule_chunk}
+                "delta_rule_chunk": config.delta_rule_chunk,
+                "conv_lowering": document_conv.lowering(
+                    backend, bucket[1], 2 * config.linear_key_dim + config.linear_value_dim,
+                    config.linear_conv_kernel_dim)}

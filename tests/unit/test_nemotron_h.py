@@ -430,11 +430,11 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert "moe/aux_loss" not in metrics  # the configuration has no balance loss
     assert int(new_state.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert LMTask().run_meta(model, (2, T)) == {
-        "attention_lowering": "xla", "ssd_lowering": "xla", "ssd_groups": 2, "moe_lowering": "xla",
-        "moe_rows_lowering": "xla", "experts_held": 2, "experts_total": 8}
+        "attention_lowering": "xla", "ssd_lowering": "xla", "ssd_groups": 2, "conv_lowering": "xla",
+        "moe_lowering": "xla", "moe_rows_lowering": "xla", "experts_held": 2, "experts_total": 8}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         meta = build_language_model(CONFIG_FILE).run_meta((2, 8192))
     assert meta == {"attention_lowering": "kernel", "attention_block_skip": "documents",
                     "attention_residuals": "kept", "ssd_lowering": "kernel",
-                    "ssd_groups": 8, "moe_lowering": "kernel",
+                    "ssd_groups": 8, "conv_lowering": "kernel", "moe_lowering": "kernel",
                     "moe_rows_lowering": "kernel", "experts_held": 8, "experts_total": 128}

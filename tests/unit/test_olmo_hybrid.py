@@ -255,7 +255,8 @@ def test_the_language_models_registry_builds_it_by_preset_and_by_model_type():
     assert isinstance(model, oh.OlmoHybrid) and model.config.dtype == jnp.float32
     assert model.scopes == ("embed", "gdn", "attention", "mlp", "lm_head", "loss")
     meta = model.run_meta((1, 8192))
-    assert meta == {"attention_lowering": "xla", "delta_rule_lowering": "xla", "delta_rule_chunk": 128}
+    assert meta == {"attention_lowering": "xla", "delta_rule_lowering": "xla", "delta_rule_chunk": 128,
+                    "conv_lowering": "xla"}
 
 
 def test_the_lm_task_trains_on_one_device():

@@ -420,8 +420,9 @@ def _detection_run():
 
 @pytest.mark.parametrize("run,more", [
     (_detection_run, {}),
-    # 64 tokens in chunks of 8 on the CPU (ops/attention.py::lowering, ops/ssd.py::lowering)
-    (_lm_run, {"attention_lowering": "xla", "ssd_lowering": "xla"}),
+    # 64 tokens in chunks of 8 on the CPU (ops/attention.py::lowering, ops/ssd.py::lowering,
+    # ops/document_conv.py::lowering)
+    (_lm_run, {"attention_lowering": "xla", "ssd_lowering": "xla", "conv_lowering": "xla"}),
 ])
 def test_run_meta_says_which_lowering_the_lm_steps_attention_took(_no_ring, tmp_path, run, more):
     """One ``run_meta`` instant a run, before the step's compile span: the

@@ -38,7 +38,10 @@ level is the kind of parameter (``embed``, ``attention``, ``dense_mlp``,
 parameters; ``config.dtype`` (bfloat16) activations and matmul operands;
 float32 norms, router, softmax, rotary angles and loss.  Every layer is
 recomputed in the backward pass from its input; where the attention kernels
-run their output and log-sum-exp are kept too (``lm_layers.LAYER_KEEPS``).
+run their output and log-sum-exp are kept too, and where the device has the
+room the dense and the shared MLPs' products with ``gate_up``
+(``lm_layers.layer_keeps`` decides, ``lm_layers.MLP_GATE_UP`` is their name;
+``run_meta`` says what was kept).
 Single device.
 """
 
@@ -249,6 +252,14 @@ def _layer(config, dense: bool, attn_p, mlp_p, norms, x, segment_ids, positions)
         return h + f, routed
 
 
+def _keeps(config, params, bucket) -> lm_layers.Keeps:
+    """What the recomputed layers of a step over ``bucket`` (sequences, tokens) keep: a dense layer's
+    gated MLP, an expert layer's shared one (``lm_layers.layer_keeps``)."""
+    widths = [config.intermediate_size if _is_dense(config, i) else config.n_shared_experts * config.moe_intermediate_size
+              for i in range(config.num_hidden_layers)]
+    return lm_layers.keeps_of(widths, params, bucket, config.hidden_size, config.dtype)
+
+
 def hidden_states(config: DeepseekV2Config, params: dict, tokens, segment_ids):
     """``(x, balance, rows, picks)``: the last layer's output before the
     final norm (batch, T, d); the expert layers' balance losses summed,
@@ -257,12 +268,13 @@ def hidden_states(config: DeepseekV2Config, params: dict, tokens, segment_ids):
     with jax.named_scope("embed"):
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype)
         positions = rope.document_positions(segment_ids)
+    policy = lm_layers.policy(_keeps(config, params, tokens.shape))
     routed = []
     for i in range(config.num_hidden_layers):
         name, dense = f"layer_{i}", _is_dense(config, i)
         mlp_p = params["dense_mlp"][name] if dense else (
             params["router"][name], params["experts"][name], params["shared"][name])
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=policy)
         x, r = layer(config, dense, params["attention"][name], mlp_p, params["norms"][name], x, segment_ids, positions)
         if not dense:
             routed.append(r)
@@ -323,10 +335,11 @@ class DeepseekV2:
 
     def run_meta(self, bucket) -> dict[str, Any]:
         """Which lowering the step's attention (ops/attention.py), its grouped
-        products and the row movements around them (ops/moe.py) take, and the
-        share of the experts held."""
+        products and the row movements around them (ops/moe.py) take, what its
+        recomputed layers keep, and the share of the experts held."""
         config, backend = self.config, jax.default_backend()
-        return {**attention.run_meta(backend, bucket[1]),
+        params = lm_layers.param_shapes(init_params, config)
+        return {**attention.run_meta(backend, bucket[1]), **lm_layers.run_meta(_keeps(config, params, bucket)),
                 "moe_lowering": _moe_lowering(config, *bucket),
                 "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,
                                                        config.hidden_size, config.moe_intermediate_size),

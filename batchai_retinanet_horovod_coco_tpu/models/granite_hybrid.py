@@ -28,8 +28,11 @@ activations and matmul operands ``config.dtype`` (bfloat16); norms, softmax,
 the scan's decays and state and the loss reduce in float32, in XLA's lowering
 and inside the attention and scan kernels alike (ops/attention.py, ops/ssd.py).
 Every layer is recomputed in the backward pass: the layers' inputs are kept,
-and where the attention kernels run their output and log-sum-exp
-(``lm_layers.LAYER_KEEPS``).  Single device: sharding comes with its own issue.
+where the attention kernels run their output and log-sum-exp, and where the
+device has the room the MLPs' products with ``gate_up``
+(``lm_layers.layer_keeps`` decides, ``lm_layers.MLP_GATE_UP`` is their name;
+``run_meta`` says what was kept).  Single device: sharding comes with its own
+issue.
 """
 
 from __future__ import annotations
@@ -219,13 +222,21 @@ def _layer(config, kind, mixer_params, mlp_params, norms, x, segment_ids):
         return h + (r * _mlp(config, mlp_params, u)).astype(x.dtype)
 
 
+def _keeps(config, params, bucket) -> lm_layers.Keeps:
+    """What the recomputed layers of a step over ``bucket`` (sequences, tokens) keep: every layer ends
+    in one gated MLP (``lm_layers.layer_keeps``)."""
+    widths = [config.intermediate_size] * len(config.layer_types)
+    return lm_layers.keeps_of(widths, params, bucket, config.hidden_size, config.dtype)
+
+
 def hidden_states(config: GraniteHybridConfig, params: dict, tokens, segment_ids):
     """The last layer's output before the final norm, (batch, T, d)."""
+    policy = lm_layers.policy(_keeps(config, params, tokens.shape))
     with jax.named_scope("embed"):
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype, config.embedding_multiplier)
     for i, kind in enumerate(config.layer_types):
         name = f"layer_{i}"
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=policy)
         x = layer(config, kind, params[kind][name], params["mlp"][name], params["norms"][name], x, segment_ids)
     return x
 
@@ -271,9 +282,10 @@ class GraniteHybrid:
     def run_meta(self, bucket) -> dict[str, Any]:
         """Which lowering the step's attention layer (ops/attention.py), its
         mixers' scans (ops/ssd.py) and the convolutions before them
-        (ops/document_conv.py) take: static per program."""
+        (ops/document_conv.py) take, and what its recomputed layers keep: static per program."""
         config, backend = self.config, jax.default_backend()
-        return {**attention.run_meta(backend, bucket[1]),
+        params = lm_layers.param_shapes(init_params, config)
+        return {**attention.run_meta(backend, bucket[1]), **lm_layers.run_meta(_keeps(config, params, bucket)),
                 "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_n_heads,
                                              config.mamba_d_head, config.mamba_d_state),
                 "conv_lowering": document_conv.lowering(

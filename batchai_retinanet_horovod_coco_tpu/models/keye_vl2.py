@@ -46,7 +46,8 @@ operands of its scores too; float32 norms, router, softmaxes, rotary angles,
 index scores, thresholds, ``P``, the KL and the loss.  Every layer is recomputed
 in the backward pass; of its inside the selection's thresholds (two integers a
 query) are kept, and where the attention kernels run their output and
-log-sum-exp (``lm_layers.LAYER_KEEPS``).  Single device.
+log-sum-exp (``lm_layers.layer_keeps``; no layer here calls ``gated_mlp``, so
+nothing carries ``lm_layers.MLP_GATE_UP``).  Single device.
 """
 
 from __future__ import annotations
@@ -286,7 +287,7 @@ def hidden_states(config: KeyeVL2Config, params: dict, tokens, segment_ids, posi
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype)
         if position_ids is None:
             position_ids = text_positions(segment_ids)
-    layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
+    layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.policy(lm_layers.NO_PRODUCT))
     by_layer = []
     for i in range(config.num_hidden_layers):
         name = f"layer_{i}"
@@ -374,6 +375,7 @@ class KeyeVL2:
         how = _lowering(bucket[1])
         return {"attention_lowering": how, "attention_block_skip": "causal",
                 **({"attention_residuals": "kept"} if how == sparse.KERNEL else {}),
+                **lm_layers.run_meta(lm_layers.NO_PRODUCT),
                 "dsa_topk": config.indexer_topk,
                 "moe_lowering": _moe_lowering(config, *bucket),
                 "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,

@@ -1,8 +1,11 @@
 """What the language models share (models/granite_hybrid.py,
-models/deepseek_v2.py, models/nemotron_h.py, models/keye_vl2.py): RMSNorm, the
-matmul with a weight, the gated SiLU MLP and the squared-ReLU MLP, a Mamba-2
-mixer's depthwise convolution, the embedding lookup, the head, the next-token
-loss, and what a layer recomputed in the backward pass keeps (``LAYER_KEEPS``).
+models/deepseek_v2.py, models/nemotron_h.py, models/keye_vl2.py,
+models/olmo_hybrid.py): RMSNorm, the matmul with a weight, the gated SiLU MLP
+and the squared-ReLU MLP, a Mamba-2 mixer's depthwise convolution, the
+embedding lookup, the head, the next-token loss, and what a layer recomputed in
+the backward pass keeps (``layer_keeps``: the attention kernels' residuals, the
+sparse attention's thresholds and, where the device has the room, the gated
+MLPs' products with ``gate_up``, named ``MLP_GATE_UP``).
 
 A matmul's operands are rounded by the CALLER's ``cast`` (its module's
 ``_operand`` bound to its config): the benchmark's precision controls patch
@@ -12,17 +15,110 @@ reduce in float32.
 
 from __future__ import annotations
 
+import collections
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from batchai_retinanet_horovod_coco_tpu.ops import attention, document_conv
 from batchai_retinanet_horovod_coco_tpu.ops import sparse_attention as sparse
 
-# The policy of every model's ``jax.checkpoint(_layer)``: of a layer its input is kept, and of its inside
-# what carries one of these names - the attention kernels' output and log-sum-exp (a few hundred MB a step
-# against a second run of the forward kernel a layer) and the sparse attention's thresholds.  Where the
-# xla lowerings run nothing carries the first name: the input (and the thresholds) alone, as before.
-LAYER_KEEPS = jax.checkpoint_policies.save_only_these_names(attention.RESIDUALS, sparse.THRESHOLD)
+# What a model's ``jax.checkpoint(_layer)`` keeps of a layer beside its input: whatever carries one of ``names``
+# (``policy`` below); of these ``MLP_GATE_UP`` is carried by ``gate_up_layers`` products of ``gate_up_bytes`` in all.
+Keeps = collections.namedtuple("Keeps", "names gate_up_layers gate_up_bytes")
+
+MLP_GATE_UP = "mlp_gate_up"  # ``gated_mlp``'s product with ``gate_up``, (batch, T, 2 x intermediate size)
+
+# ``layer_keeps``' memory model, in bytes of the device: a step holds STATE_AND_GRADIENTS x the parameters' bytes
+# (float32 parameters, Adam's two moments, and the gradients, most of them products of bfloat16 operands that XLA holds
+# unconverted: half the parameters' bytes) and WORKING_SET x one layer's input (the float32 logits and what is live
+# while one layer is recomputed and differentiated: both grow with tokens x width); what is kept besides has to leave
+# MARGIN of the limit free.
+#
+# MEASURED (``memory_analysis()`` of the five cells' train steps compiled for a described v5e, ``jax.default_backend``
+# patched to say ``tpu``, before any chip call; PR 44; GB = 1e9 bytes; the limit a v5e states is 16.909):
+#   cell's model                   granite      dsv2        olmo        nemo3       keye
+#   parameters P                   3.089        2.542       3.715       2.668       2.637
+#   arguments (= 3 P)              9.266        7.626       11.147      8.004       7.910
+#   temporaries, today's names     3.000        4.589       4.621       5.847       6.091
+#     less P / 2, over one layer's
+#     input (tokens x d x 2 bytes) 43.4         49.4        43.9        51.2        71.1    <- WORKING_SET 50
+#   + code = the step              12.471       12.581      15.809      14.106      14.716
+#   the model's estimate of it     12.488       12.252      16.149      13.742      12.584
+#   named products: layers, bytes  10, 2.684    6, 1.640    4, 1.443    none        none
+#   temporaries, products kept     5.382        5.740       4.500 (!)   -           -
+#   + code = the step              14.842       13.728      15.687      -           -
+#   the estimate + the products    15.172       13.892      17.592      -           -
+#   of the limit                   89.7%        82.2%       104.0%      -           -
+#   ``layer_keeps`` at 94%         KEEPS        KEEPS       nothing more
+# (keye's working set, the index scores of 16 384 keys a query and the widest vocabulary slice, reads 71 inputs and
+# enters no decision: it has no such product.)
+# What is kept costs less than its bytes (granite 2.38 of 2.68 GB, dsv2 1.15 of 1.64) because it takes the room of
+# temporaries that died earlier (PR 39 found the same), and in olmo's step the compiler's count FALLS by 0.12 GB: by
+# that count olmo's products would fit at 92.8%.  The model cannot see that (it would take a compilation to find out,
+# and a step that does not fit fails to compile), so it answers from what the step needs before any reuse.
+STATE_AND_GRADIENTS, WORKING_SET, MARGIN = 3.5, 50, 0.06
+
+
+def param_shapes(init_params, config):
+    """The shapes of ``init_params(config, key)``'s tree, nothing computed: a ``run_meta(bucket)`` has no
+    parameters, and asks ``keeps_of`` what the traced step asks with them."""
+    return jax.eval_shape(functools.partial(init_params, config), jax.random.key(0))
+
+
+def device_memory_limit() -> int | None:
+    """The bytes a program may take of the first local device; ``None`` where the backend does not say
+    (the CPU)."""
+    return (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def layer_keeps(products, param_bytes: int, layer_input_bytes: int, memory_limit: int | None) -> Keeps:
+    """What every recomputed layer of a step keeps: the attention kernels' residuals and the sparse attention's
+    thresholds (a few hundred MB a step against a second run of a forward kernel a layer; the xla lowerings carry
+    neither name), and ``MLP_GATE_UP`` where the gated MLPs' named products fit the device beside the rest of the
+    step.  Selective recomputation under a memory budget, decided from what the program sees when it is traced and
+    from nothing else: ``products`` the bytes of each such product (one a layer that calls ``gated_mlp``),
+    ``param_bytes`` the parameter tree's, ``layer_input_bytes`` one layer's input's (batch x T x d in the
+    activations' dtype), ``memory_limit`` the device's.  All products or none; none where the device states no limit
+    (the CPU: its programs are what they were)."""
+    names, kept = (attention.RESIDUALS, sparse.THRESHOLD), sum(products)
+    if not kept or memory_limit is None:
+        return Keeps(names, 0, 0)
+    step = STATE_AND_GRADIENTS * param_bytes + WORKING_SET * layer_input_bytes
+    if step + kept > (1 - MARGIN) * memory_limit:
+        return Keeps(names, 0, 0)
+    return Keeps((*names, MLP_GATE_UP), len(products), kept)
+
+
+NO_PRODUCT = layer_keeps((), 0, 0, None)  # of a model without a gated MLP: there is nothing to decide
+
+
+def keeps_of(widths, params, bucket, hidden_size: int, dtype) -> Keeps:
+    """``layer_keeps`` for a step over ``bucket`` (sequences, tokens) on this process's first device: ``widths``
+    the intermediate size of every gated MLP the step runs (a product is tokens x 2 x that, in ``dtype``),
+    ``params`` the parameter tree (arrays, tracers or shapes), ``hidden_size`` and ``dtype`` the activations'."""
+    tokens, itemsize = bucket[0] * bucket[1], jnp.dtype(dtype).itemsize
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize for x in jax.tree.leaves(params))
+    return layer_keeps(tuple(tokens * 2 * w * itemsize for w in widths), param_bytes, tokens * hidden_size * itemsize,
+                       device_memory_limit())
+
+
+@functools.lru_cache(maxsize=None)
+def policy(keeps: Keeps):
+    """The ``policy`` of a model's ``jax.checkpoint(_layer)``: ONE object for one answer.  JAX keys its caches of
+    a checkpointed layer's traces on the policy's identity, so a fresh closure a trace would make a step built a
+    second time in a process (the benchmark's measured call) trace every layer anew: 3.9 s for 1.1 in granite's
+    cell (my chip run, PR 44)."""
+    return jax.checkpoint_policies.save_only_these_names(*keeps.names)
+
+
+def run_meta(keeps: Keeps) -> dict:
+    """What a model's ``run_meta`` says of what its recomputed layers keep."""
+    return {"layer_keeps": ",".join(keeps.names), "mlp_gate_up_layers": keeps.gate_up_layers,
+            "mlp_gate_up_bytes": keeps.gate_up_bytes}
 
 
 def rms_norm(x, w, eps):
@@ -36,8 +132,9 @@ def matmul(cast, x, w):
 
 
 def gated_mlp(cast, p, u):
-    """``W_down (silu(W_g u) * W_u u)`` with ``p = {gate_up, down}``."""
-    gate, up = jnp.split(matmul(cast, u, p["gate_up"]), 2, axis=-1)
+    """``W_down (silu(W_g u) * W_u u)`` with ``p = {gate_up, down}``; the product with ``gate_up`` carries
+    the checkpoint name ``MLP_GATE_UP``."""
+    gate, up = jnp.split(checkpoint_name(matmul(cast, u, p["gate_up"]), MLP_GATE_UP), 2, axis=-1)
     return matmul(cast, jax.nn.silu(gate) * up, p["down"])
 
 

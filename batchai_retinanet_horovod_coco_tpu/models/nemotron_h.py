@@ -59,7 +59,8 @@ parameter (``embed``, ``mamba``, ``attention``, ``router``, ``experts``,
 (bfloat16) activations and matmul operands; float32 norms, router, softmax,
 the scan's decays and state, and loss.  Every layer is recomputed in the
 backward pass from its input; where the attention kernels run their output and
-log-sum-exp are kept too (``lm_layers.LAYER_KEEPS``).  Single device.
+log-sum-exp are kept too (``lm_layers.layer_keeps``; no layer here calls
+``gated_mlp``, so nothing carries ``lm_layers.MLP_GATE_UP``).  Single device.
 """
 
 from __future__ import annotations
@@ -299,12 +300,13 @@ def hidden_states(config: NemotronHConfig, params: dict, tokens, segment_ids):
     every token picked (expert layers, batch, T, k)."""
     with jax.named_scope("embed"):
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype)
+    policy = lm_layers.policy(lm_layers.NO_PRODUCT)
     routed = []
     for i, kind in enumerate(config.pattern):
         name = f"layer_{i}"
         p = (params["router"][name], params["experts"][name], params["shared"][name]) if kind == EXPERTS else (
             params[SCOPE[kind]][name])
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1, 2), policy=lm_layers.LAYER_KEEPS)
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1, 2), policy=policy)
         x, r = layer(config, kind, len(routed), p, params["norms"][name], x, segment_ids)
         if kind == EXPERTS:
             routed.append(r)
@@ -365,9 +367,9 @@ class NemotronH:
         """Which lowering the step's attention (ops/attention.py), its scans
         (ops/ssd.py), the convolutions before them (ops/document_conv.py), its
         grouped products and the row movements around them (ops/moe.py) take, the
-        scan's groups and the share of the experts held."""
+        scan's groups, what its recomputed layers keep and the share of the experts held."""
         config, backend = self.config, jax.default_backend()
-        return {**attention.run_meta(backend, bucket[1]),
+        return {**attention.run_meta(backend, bucket[1]), **lm_layers.run_meta(lm_layers.NO_PRODUCT),
                 "ssd_lowering": ssd.lowering(backend, bucket[1], config.mamba_chunk_size, config.mamba_num_heads,
                                              config.mamba_head_dim, config.ssm_state_size, config.n_groups),
                 "ssd_groups": config.n_groups,

@@ -36,9 +36,11 @@ is the kind of parameter (``embed``, ``gdn``, ``attention``, ``mlp``, ``norms``,
 ``head``).  float32 parameters; ``config.dtype`` (bfloat16) activations and matmul
 operands; float32 norms, softmax, the L2 norms, ``a``, ``b``, the cumulative
 log-decays, the triangular system's solution, the carried state, logits and loss.
-Every layer is recomputed in the backward pass: the layers' inputs are kept, and
-where the attention kernels run their output and log-sum-exp
-(``lm_layers.LAYER_KEEPS``); the delta rule's kernels name nothing, so their forward
+Every layer is recomputed in the backward pass: the layers' inputs are kept,
+where the attention kernels run their output and log-sum-exp, and where the device
+has the room the MLPs' products with ``gate_up`` (``lm_layers.layer_keeps`` decides,
+``lm_layers.MLP_GATE_UP`` is their name, ``run_meta`` says what was kept: at the
+published widths a v5e has not); the delta rule's kernels name nothing, so their forward
 runs again (the states they save live inside one layer's backward pass).
 Single device.
 """
@@ -250,15 +252,23 @@ def _layer(config, kind, mixer_params, mlp_params, norms, x, segment_ids):
         return h + _rms_norm(out, norms["mlp"], config.rms_norm_eps).astype(x.dtype), counters
 
 
+def _keeps(config, params, bucket) -> lm_layers.Keeps:
+    """What the recomputed layers of a step over ``bucket`` (sequences, tokens) keep: every layer ends
+    in one gated MLP (``lm_layers.layer_keeps``)."""
+    widths = [config.intermediate_size] * len(config.layer_types)
+    return lm_layers.keeps_of(widths, params, bucket, config.hidden_size, config.dtype)
+
+
 def hidden_states(config: OlmoHybridConfig, params: dict, tokens, segment_ids):
     """``(x, counters)``: the last layer's output before the final norm (batch, T,
     d), and the delta rule's three counters over the linear-attention layers."""
     with jax.named_scope("embed"):
         x = lm_layers.embed_lookup(params["embed"]["embedding"], tokens, config.dtype)
+    policy = lm_layers.policy(_keeps(config, params, tokens.shape))
     per_layer = []
     for i, kind in enumerate(config.layer_types):
         name = f"layer_{i}"
-        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=lm_layers.LAYER_KEEPS)
+        layer = jax.checkpoint(_layer, static_argnums=(0, 1), policy=policy)
         x, counters = layer(config, kind, params[SCOPE[kind]][name], params["mlp"][name], params["norms"][name], x,
                             segment_ids)
         if kind == LINEAR:
@@ -312,9 +322,11 @@ class OlmoHybrid:
     def run_meta(self, bucket) -> dict[str, Any]:
         """Which lowering the step's attention layers (ops/attention.py), its delta
         rules (ops/delta_rule.py) and the convolutions before them
-        (ops/document_conv.py) take, and the delta rule's chunk: static per program."""
+        (ops/document_conv.py) take, the delta rule's chunk, and what its recomputed layers
+        keep: static per program."""
         config, backend = self.config, jax.default_backend()
-        return {**attention.run_meta(backend, bucket[1]),
+        params = lm_layers.param_shapes(init_params, config)
+        return {**attention.run_meta(backend, bucket[1]), **lm_layers.run_meta(_keeps(config, params, bucket)),
                 "delta_rule_lowering": delta_rule.lowering(
                     backend, bucket[1], config.delta_rule_chunk, config.linear_num_value_heads,
                     config.linear_key_head_dim, config.linear_value_head_dim),

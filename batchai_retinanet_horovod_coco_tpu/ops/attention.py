@@ -31,10 +31,11 @@ both blockings: nothing is padded.  One algorithm, two blockings:
   residuals, and the library gives both the checkpoint name it is built
   with (``residual_checkpoint_name``: ``RESIDUALS``) inside its forward
   rule - a name given to the output outside the ``custom_vjp`` would name
-  another value.  A layer recomputed under ``lm_layers.LAYER_KEEPS`` keeps
-  them, so the forward kernel runs once a layer and step, not again in the
-  backward pass: 67 MB a layer of dsv2's cell written and read back in
-  0.2 ms against 3.4-3.6 ms to form again.  The xla blocking names nothing.
+  another value.  A layer recomputed under ``lm_layers.layer_keeps``' policy
+  (these residuals, the sparse attention's thresholds and, where the device has
+  the room, ``lm_layers.MLP_GATE_UP``) keeps them, so the forward kernel runs
+  once a layer and step, not again in the backward pass: 67 MB a layer of
+  dsv2's cell written and read back in 0.2 ms against 3.4-3.6 ms to form again.  The xla blocking names nothing.
 
 Which one runs is ``lowering``'s answer, from the backend and the shapes
 alone.

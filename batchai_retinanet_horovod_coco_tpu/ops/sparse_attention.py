@@ -28,7 +28,8 @@ forward rule (a residual is the rule's own value: a name given outside the
 ``custom_vjp`` names another), so that such a layer keeps them too and the
 forward kernel is not run again in the backward pass: 136 MB a layer at
 T = 16 384, 0.3 ms to write and read back against 27 ms to form again.
-``lm_layers.LAYER_KEEPS`` is the policy that keeps both names.
+``lm_layers.layer_keeps`` gives the policy that keeps both names (and, where the
+device has the room, ``lm_layers.MLP_GATE_UP``).
 
 Two lowerings, chosen by ``lowering`` from the backend and the shapes alone:
 

@@ -1,5 +1,6 @@
 """What a recomputed layer keeps of its attention (models/lm_layers.py::
-LAYER_KEEPS): the two kernel lowerings (ops/attention.py's splash kernels,
+layer_keeps, here its answer for layers without a gated MLP: LAYER_KEEPS;
+tests/unit/test_layer_keeps.py has the product): the two kernel lowerings (ops/attention.py's splash kernels,
 ops/sparse_attention.py's own; interpret mode on the CPU) name their forward's
 output and log-sum-exp inside their forward rule, so that under the models'
 policy the forward kernel runs ONCE a layer in a gradient, and twice under a
@@ -24,6 +25,7 @@ T, D, HEADS, KV, SIZE, INDEX_HEADS, INDEX_SIZE = 256, 64, 4, 2, 16, 4, 8
 BLOCK = 128  # of the splash kernel here: T holds two
 TILES = dict(rows=128, scores=64, thresholds=dict(query_tile=32, columns=128), attention=(32, 64), probs=(32, 64))
 SEG = jnp.asarray(np.r_[np.zeros(100), np.ones(156)].astype(np.int32)[None])
+LAYER_KEEPS = lm_layers.policy(lm_layers.NO_PRODUCT)  # these layers hold no gated MLP
 WITHOUT_RESIDUALS = jax.checkpoint_policies.save_only_these_names(sparse.THRESHOLD)  # the parent's policy
 
 
@@ -92,7 +94,7 @@ def test_a_gradient_runs_the_forward_kernel_once_a_layer_and_gives_the_same_bits
     layer, fwd, dq, dkv = LOWERINGS[lowering]
     operands = _operands()
     grads = {}
-    for policy, forward_calls in ((lm_layers.LAYER_KEEPS, 2), (WITHOUT_RESIDUALS, 4)):  # of two layers
+    for policy, forward_calls in ((LAYER_KEEPS, 2), (WITHOUT_RESIDUALS, 4)):  # of two layers
         grad = jax.grad(_loss(layer, policy), argnums=(0, 1))
         calls = _kernel_calls(jax.make_jaxpr(grad)(*operands).jaxpr)
         # by the first part of the name: the library's splash kernels' names go on (``_segmented_residuals``)
@@ -123,7 +125,7 @@ def test_a_layer_keeps_its_input_the_thresholds_the_output_and_the_log_sum_exp(l
     thresholds = [((TILES["rows"],), "int32")] * 4 if lowering == "dsa" else []
     residuals = [((HEADS, T, SIZE), "float32"), ((HEADS, T), "float32")]
     layer_input = ((1, T, D), "float32")
-    assert _kept(layer, lm_layers.LAYER_KEEPS, weights[0], x) == sorted([layer_input, *residuals, *thresholds])
+    assert _kept(layer, LAYER_KEEPS, weights[0], x) == sorted([layer_input, *residuals, *thresholds])
     assert _kept(layer, WITHOUT_RESIDUALS, weights[0], x) == sorted([layer_input, *thresholds])
 
 
@@ -136,7 +138,7 @@ def test_the_xla_lowerings_name_nothing():
         return x + attention.packed_causal_attention(*_qkv(w, x), SEG, 0.25, 64).reshape(1, T, -1) @ w["o"]
 
     layer = lambda w, x: (xla_layer(w, x), 0.0)
-    kept = [_kept(layer, policy, weights[0], x) for policy in (lm_layers.LAYER_KEEPS, None)]
+    kept = [_kept(layer, policy, weights[0], x) for policy in (LAYER_KEEPS, None)]
     assert kept[0] == kept[1] == [((1, T, D), "float32")]
 
 

@@ -27,6 +27,8 @@ from benchmark.reference import deepseek_v2 as reference
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 T = 64
+# what ``run_meta`` says of a recomputed layer's keeps where no device states a memory limit (the CPU)
+NOTHING_MORE = {"layer_keeps": "attention_residuals,dsa_threshold", "mlp_gate_up_layers": 0, "mlp_gate_up_bytes": 0}
 F32 = dataclasses.replace(ds.TINY, dtype=jnp.float32)
 DOCS = ([20, 30, 14], [7, 57], [64], [5, 9, 21, 17, 12])  # documents per sequence
 GROUPS = ("embed", "attention", "dense_mlp", "router", "experts", "shared", "norms", "head")
@@ -370,9 +372,10 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert int(new_state.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert float(metrics["loss"]) > float(metrics["moe/aux_loss"]) > 0
     assert LMTask().run_meta(model, (2, T)) == {"attention_lowering": "xla", "moe_lowering": "xla",
-                                                "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16}
+                                                "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16,
+                                                **NOTHING_MORE}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):  # the cell's model and bucket: the kernels
         meta = build_language_model(CONFIG_FILE).run_meta((2, 8192))
     assert meta == {"attention_lowering": "kernel", "attention_block_skip": "documents",
                     "attention_residuals": "kept", "moe_lowering": "kernel", "moe_rows_lowering": "kernel",
-                    "experts_held": 8, "experts_total": 64}
+                    "experts_held": 8, "experts_total": 64, **NOTHING_MORE}

@@ -29,6 +29,8 @@ from benchmark.reference import keye_vl2 as reference
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CONFIG_FILE = os.path.join(REPO, "benchmark", "configs", "keye-vl2-30b-a3b-ep8.json")
 T = 64
+# what ``run_meta`` says of a recomputed layer's keeps where no device states a memory limit (the CPU)
+NOTHING_MORE = {"layer_keeps": "attention_residuals,dsa_threshold", "mlp_gate_up_layers": 0, "mlp_gate_up_bytes": 0}
 F32 = dataclasses.replace(kv.TINY, dtype=jnp.float32)
 DOCS = ([20, 30, 14], [7, 57], [64], [5, 9, 21, 17, 12])  # documents per sequence
 GROUPS = ("embed", "attention", "indexer", "router", "experts", "norms", "head")
@@ -421,7 +423,7 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert 0 < float(metrics["dsa/selected_share"]) < 1 and float(metrics["gnorm/indexer"]) > 0
     assert LMTask().run_meta(model, (2, T)) == {
         "attention_lowering": "xla", "attention_block_skip": "causal", "dsa_topk": 24, "moe_lowering": "xla",
-        "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16}
+        "moe_rows_lowering": "xla", "experts_held": 4, "experts_total": 16, **NOTHING_MORE}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):  # the cell's model and bucket: the kernels
         published = build_language_model(CONFIG_FILE)
         meta = published.run_meta((1, 16384))
@@ -429,4 +431,4 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert meta == {
         "attention_lowering": "kernel", "attention_block_skip": "causal", "attention_residuals": "kept",
         "dsa_topk": 2048, "moe_lowering": "kernel", "moe_rows_lowering": "kernel", "experts_held": 16,
-        "experts_total": 128}
+        "experts_total": 128, **NOTHING_MORE}

@@ -11,7 +11,13 @@ step records it again the same way and says so.
 mixture-of-experts models' tiny steps (``tiny-moe``: DeepSeek-V2, ``tiny-nemotron``:
 Nemotron-H) as PR 35's tree gave them, recorded by PR 38, which added a fourth
 language model beside them, functions to ``ops/moe.py`` and ``ops/rope.py`` and
-names beneath ``attention`` in ``STEP_SCOPES``: none of that may reach their steps."""
+names beneath ``attention`` in ``STEP_SCOPES``: none of that may reach their steps.
+PR 44 recorded ``tiny-moe``'s two again: ``gated_mlp`` names its product with
+``gate_up`` (``lm_layers.MLP_GATE_UP``; the CPU's policy does not list the name), and
+the text of DeepSeek-V2's step is the old one but for the NUMBERS MLIR gives to
+repeated private functions from the first shared expert on (``@silu_208`` is
+``@silu_209``, and so on: no line differs once ``_<n>`` is cut from the symbols);
+granite's and Nemotron-H's texts hold byte for byte."""
 
 import hashlib
 import json

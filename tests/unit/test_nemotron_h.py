@@ -29,6 +29,8 @@ from benchmark.reference import nemotron_h as reference
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 T = 64
+# what ``run_meta`` says of a recomputed layer's keeps where no device states a memory limit (the CPU)
+NOTHING_MORE = {"layer_keeps": "attention_residuals,dsa_threshold", "mlp_gate_up_layers": 0, "mlp_gate_up_bytes": 0}
 BIAS = ((0.06, -0.04, 0.0, 0.05, -0.06, 0.02, 0.04, -0.02), (-0.05, 0.06, 0.03, -0.02, 0.0, 0.04, -0.06, 0.02))
 F32 = dataclasses.replace(nh.TINY, dtype=jnp.float32, router_bias=BIAS)
 DOCS = ([20, 30, 14], [7, 57], [64], [5, 9, 21, 17, 12])  # documents per sequence
@@ -431,10 +433,10 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert int(new_state.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert LMTask().run_meta(model, (2, T)) == {
         "attention_lowering": "xla", "ssd_lowering": "xla", "ssd_groups": 2, "conv_lowering": "xla",
-        "moe_lowering": "xla", "moe_rows_lowering": "xla", "experts_held": 2, "experts_total": 8}
+        "moe_lowering": "xla", "moe_rows_lowering": "xla", "experts_held": 2, "experts_total": 8, **NOTHING_MORE}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         meta = build_language_model(CONFIG_FILE).run_meta((2, 8192))
     assert meta == {"attention_lowering": "kernel", "attention_block_skip": "documents",
                     "attention_residuals": "kept", "ssd_lowering": "kernel",
                     "ssd_groups": 8, "conv_lowering": "kernel", "moe_lowering": "kernel",
-                    "moe_rows_lowering": "kernel", "experts_held": 8, "experts_total": 128}
+                    "moe_rows_lowering": "kernel", "experts_held": 8, "experts_total": 128, **NOTHING_MORE}

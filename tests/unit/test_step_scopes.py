@@ -421,13 +421,14 @@ def _detection_run():
 @pytest.mark.parametrize("run,more", [
     (_detection_run, {}),
     # 64 tokens in chunks of 8 on the CPU (ops/attention.py::lowering, ops/ssd.py::lowering,
-    # ops/document_conv.py::lowering)
-    (_lm_run, {"attention_lowering": "xla", "ssd_lowering": "xla", "conv_lowering": "xla"}),
+    # ops/document_conv.py::lowering; the CPU states no memory limit: models/lm_layers.py::layer_keeps)
+    (_lm_run, {"attention_lowering": "xla", "ssd_lowering": "xla", "conv_lowering": "xla",
+               "layer_keeps": "attention_residuals,dsa_threshold", "mlp_gate_up_layers": 0, "mlp_gate_up_bytes": 0}),
 ])
 def test_run_meta_says_which_lowering_the_lm_steps_attention_took(_no_ring, tmp_path, run, more):
     """One ``run_meta`` instant a run, before the step's compile span: the
     devices, and for the language model what its attention layer and its
-    mixers' scans lower to."""
+    mixers' scans lower to and what its recomputed layers keep."""
     model, state, batches, num_classes, task = run()
     trace.configure(str(tmp_path), process_label="t")
     loop.run_training(model, state, batches, num_classes, loop.LoopConfig(total_steps=1, log_every=0), task=task)

@@ -19,7 +19,7 @@ DRYFLAG = $(if $(DRY),--dry-run,)
 CLUSTER = python -m batchai_retinanet_horovod_coco_tpu.launch.cluster
 
 .PHONY: create submit status delete test test-timings smoke chip-smoke \
-	canaries convergence-full lint lint-obs check-static tune-smoke tune \
+	canaries convergence-full lint lint-obs check-static \
 	perf-report telemetry-smoke numerics-smoke chaos chaos-smoke \
 	chaos-comm fleet-smoke fleet-obs-smoke stream-smoke scale-smoke
 
@@ -38,17 +38,16 @@ delete:
 test:
 	python -m pytest tests/ -q
 
-# Regenerate the committed per-test timing snapshot (budget mechanism,
-# tests/conftest.py): run the fast tier, write TEST_TIMINGS.md.  Timings
-# depend on how warm tests/.jax_cache is (see conftest.py): a cold run
-# pays each unique program's compile once.  Six workers by file: the
-# shape of the driver's own run of the tier.
-# bash + pipefail: a failing tier must NOT regenerate/bless the snapshot.
+# Regenerate the committed timing snapshot (budget mechanism,
+# tests/conftest.py): run the fast tier, write TEST_TIMINGS.md (seconds by
+# file and the slowest tests, from the junit file).  Six workers by file:
+# the shape of the driver's own run of the tier.  A failing tier stops
+# make before the snapshot is written.
 test-timings:
-	bash -o pipefail -c 'python -m pytest tests/ -q -m "not slow" \
+	python -m pytest tests/ -q -m "not slow" \
 	  -p xdist -n 6 --dist loadfile \
-	  --durations=40 | tee /tmp/fast_tier_timings.log'
-	python scripts/update_test_timings.py /tmp/fast_tier_timings.log
+	  --junitxml=/tmp/fast_tier_timings.xml
+	python scripts/update_test_timings.py /tmp/fast_tier_timings.xml
 
 # End-to-end synthetic smoke on a virtual CPU mesh (no data, no TPU needed).
 smoke:
@@ -189,25 +188,6 @@ check-static: lint telemetry-smoke numerics-smoke chaos-smoke fleet-smoke fleet-
 # in tier-1 (tests/unit/test_obs.py::test_audit_threads_clean).
 lint-obs:
 	python scripts/audit_threads.py
-
-# Schedule autotuner (ISSUE 6, tune/): measured search over the tunable
-# hot-path parameters — Pallas tile/block shapes (focal, matching, NMS),
-# pre_nms_size, per-bucket batch sizes — per device_kind; winners land in
-# artifacts/schedules/<device_kind>.json, which train/eval/serve/export
-# resolve at compile time (RUNBOOK "Autotuning schedules").
-#
-# tune-smoke: CPU-sized end-to-end proof (tiny bucket, xla winners,
-# pallas candidates recorded as skipped) into a throwaway registry dir —
-# CI-safe, never mutates the committed registry.
-tune-smoke:
-	python -m batchai_retinanet_horovod_coco_tpu.tune --smoke \
-	  --ops nms,focal,matching --batch-axis \
-	  --out-root /tmp/tune_smoke_schedules
-
-# tune: the real search on THIS device — writes the device's registry
-# artifact (artifacts/schedules/<device_kind>.json).
-tune:
-	python -m batchai_retinanet_horovod_coco_tpu.tune --batch-axis
 
 # Perf doctor (ISSUE 8, obs/analyze): turn an obs dir's own artifacts
 # (merged trace.json + metrics.jsonl) into one machine-readable

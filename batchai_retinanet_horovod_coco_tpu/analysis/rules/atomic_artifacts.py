@@ -1,8 +1,8 @@
 """atomic-artifacts: package artifact writes must commit via rename.
 
 The durability subsystem's restore path (utils/checkpoint.py) SCANS
-directories and trusts what it finds; so do the export loader, the tune
-schedule registry, the lint baseline, and the obs trace merger.  A plain
+directories and trusts what it finds; so do the export loader, the lint
+baseline, and the obs trace merger.  A plain
 ``open(path, "w")`` publishes the file name BEFORE the bytes: a reader
 racing the write — or a process SIGKILLed mid-write, the exact fault
 ``scripts/chaos.py`` injects — observes a truncated artifact that either

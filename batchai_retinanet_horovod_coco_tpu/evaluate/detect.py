@@ -60,92 +60,38 @@ from batchai_retinanet_horovod_coco_tpu.train.state import model_variables
 
 @dataclasses.dataclass(frozen=True)
 class DetectConfig:
-    """FilterDetections-equivalent knobs (reference defaults, SURVEY.md M6).
-
-    Since ISSUE 6 the performance knobs — ``pre_nms_size``, the NMS
-    backend, and its block shape — are SCHEDULE-RESOLVED: ``None`` means
-    "look the winner up in the per-device schedule registry"
-    (tune/schedule.py; the built-in defaults reproduce the hand-picked
-    values every consumer shipped with).  An explicit value pins the knob
-    regardless of the registry.  Resolution happens once per compile in
-    :func:`resolve_detect_config` — the registry lookup is cached and
-    stable for the process lifetime, so serve/eval never recompile at
-    request time.
-    """
+    """FilterDetections-equivalent knobs (reference defaults, SURVEY.md M6)."""
 
     score_threshold: float = 0.05
     iou_threshold: float = 0.5
-    # None = schedule-resolved (built-in default 1000).  NOTE: unlike the
-    # backend knobs below, this one CHANGES DETECTION SEMANTICS (fewer
-    # candidates survive to NMS) — see tune/candidates.py.
-    pre_nms_size: int | None = None
+    # Candidates that survive to NMS (the reference's 1000): unlike the two
+    # backend fields below this one changes what is detected.
+    pre_nms_size: int = 1000
     max_detections: int = 300
-    # NMS suppression backend: None = schedule-resolved ("xla" unless the
-    # device's committed schedule names "pallas"); "xla" | "pallas" pins.
-    nms_impl: str | None = None
-    # (K, K) IoU tile width of the Pallas kernel: None = schedule-resolved.
-    nms_block_k: int | None = None
+    # NMS suppression backend: "xla" | "pallas" (ops/pallas/nms.py, exact
+    # and bit-identical; untimed on the chip, so not the default).
+    nms_impl: str = "xla"
+    # (K, K) IoU tile width of the Pallas kernel (ops/pallas/nms.DEFAULT_BLOCK_K).
+    nms_block_k: int = 256
     # Interpreter-mode Pallas (CPU tests of the fused suppression path).
     nms_interpret: bool = False
     codec: boxes_lib.BoxCodecConfig = boxes_lib.BoxCodecConfig()
     anchor: anchors_lib.AnchorConfig = anchors_lib.AnchorConfig()
 
-
-def resolve_detect_config(
-    config: DetectConfig, device_kind: str | None = None
-) -> DetectConfig:
-    """Fill every schedule-resolved field; returns a fully concrete config.
-
-    The consumer entrypoint for the tune/ registry on the detect side:
-    ``_detect_body`` calls it at trace time (host-side, once per bucket
-    compile), so the executable bakes the winning ``pre_nms_size`` /
-    backend / block shape in.  Unknown ``device_kind`` falls back to the
-    built-in defaults with one loud ``schedule_fallback`` event
-    (tune/schedule.py), never a crash.
-    """
-    if config.nms_impl is not None and config.nms_impl not in ("xla", "pallas"):
-        # Validate BEFORE the fully-pinned early return: a typo'd impl on
-        # a fully concrete config must raise here, not silently take the
-        # XLA branch in nms_fn_for's == "pallas" comparison.
-        raise ValueError(
-            f"nms_impl must be 'xla' or 'pallas', got {config.nms_impl!r}"
-        )
-    if (
-        config.pre_nms_size is not None
-        and config.nms_impl is not None
-        and config.nms_block_k is not None
-    ):
-        return config
-    from batchai_retinanet_horovod_coco_tpu.tune import schedule as schedule_lib
-
-    entry = schedule_lib.lookup(device_kind)["nms"]
-    impl = config.nms_impl or str(entry.get("impl", "xla"))
-    if impl == "auto":  # NMS has no backend-conditional default: auto = xla
-        impl = "xla"
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"nms_impl must be 'xla' or 'pallas', got {impl!r}")
-    return dataclasses.replace(
-        config,
-        pre_nms_size=(
-            config.pre_nms_size
-            if config.pre_nms_size is not None
-            else int(entry.get("pre_nms_size", 1000))
-        ),
-        nms_impl=impl,
-        nms_block_k=(
-            config.nms_block_k
-            if config.nms_block_k is not None
-            else int(entry.get("block_k", 256))
-        ),
-    )
+    def __post_init__(self):
+        # A typo must raise, not take the XLA branch of nms_fn_for's
+        # == "pallas" comparison in silence.
+        if self.nms_impl not in ("xla", "pallas"):
+            raise ValueError(
+                f"nms_impl must be 'xla' or 'pallas', got {self.nms_impl!r}"
+            )
 
 
 def nms_fn_for(
     config: DetectConfig,
 ) -> Callable[[jnp.ndarray, jnp.ndarray], nms_lib.Detections]:
-    """``(boxes (B, A, 4), scores (B, A, K)) → Detections`` for a RESOLVED
-    config — the one place the XLA-vs-Pallas suppression dispatch lives."""
-    config = resolve_detect_config(config)
+    """``(boxes (B, A, 4), scores (B, A, K)) → Detections`` — the one
+    place the XLA-vs-Pallas suppression dispatch lives."""
     if config.nms_impl == "pallas":
         from batchai_retinanet_horovod_coco_tpu.ops.pallas import (
             nms as pallas_nms,
@@ -184,12 +130,11 @@ def _detect_body(
     sigmoid → decode → clip → batched NMS.  Shared so the batch-sharded and
     spatially-sharded paths can never drift from the single-device one.
 
-    The NMS backend dispatch lives here too (schedule-resolved, see
-    :func:`resolve_detect_config`): ``impl == "pallas"`` swaps the
-    suppression stage for the fused blocked kernel (ops/pallas/nms.py),
-    which shares candidate selection and compaction with the XLA path and
-    is bit-identical to it (tests/unit/test_pallas_nms.py)."""
-    config = resolve_detect_config(config)
+    The NMS backend dispatch lives here too (:func:`nms_fn_for`):
+    ``nms_impl == "pallas"`` swaps the suppression stage for the fused
+    blocked kernel (ops/pallas/nms.py), which shares candidate selection
+    and compaction with the XLA path and is bit-identical to it
+    (tests/unit/test_pallas_nms.py)."""
     anchors = jnp.asarray(
         anchors_lib.anchors_for_image_shape(image_hw, config.anchor)
     )

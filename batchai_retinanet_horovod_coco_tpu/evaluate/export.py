@@ -101,17 +101,7 @@ def export_model(
     own defaults (manifest-driven routing, same discipline as the anchor
     config).  Returns the manifest path.
     """
-    from batchai_retinanet_horovod_coco_tpu.evaluate.detect import (
-        resolve_detect_config,
-    )
-    from batchai_retinanet_horovod_coco_tpu.tune import schedule as schedule_lib
-
     os.makedirs(output_dir, exist_ok=True)
-    # Resolve the schedule-dependent knobs ONCE, here: the manifest must
-    # record the concrete values the artifacts were exported with (a None
-    # pre_nms_size in the manifest would describe nothing), and every
-    # per-bucket export below must bake in the same resolution.
-    config = resolve_detect_config(config)
     batch_sizes = (
         (batch_size,) if isinstance(batch_size, int) else tuple(batch_size)
     )
@@ -142,11 +132,6 @@ def export_model(
             "nms_impl": config.nms_impl,
             "nms_block_k": config.nms_block_k,
         },
-        # Where the schedule-dependent knobs above came from (ROADMAP:
-        # winners are "recorded next to the export manifests"): the
-        # per-device registry artifact, or the built-in defaults when the
-        # exporting device is untuned.
-        "schedule": schedule_lib.provenance(),
         # Anchors parameterize box decoding INSIDE the artifact; recorded so
         # the artifact is self-describing (a consumer regenerating anchors,
         # e.g. for target assignment, must use these, not the defaults).

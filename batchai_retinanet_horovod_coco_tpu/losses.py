@@ -61,23 +61,19 @@ class LossConfig:
     focal_gamma: float = 2.0
     smooth_l1_beta: float = 1.0 / 9.0  # sigma=3 in the reference parametrization
     box_loss_weight: float = 1.0
-    # Fused Pallas focal kernel (ops/pallas/focal.py).  None = resolved
-    # from the per-device schedule registry by the train step
-    # (train/step.py resolve_kernel_schedule; the built-in default is the
-    # XLA path) and treated as OFF by direct loss calls.  The hand kernel
+    # Fused Pallas focal kernel (ops/pallas/focal.py).  The hand kernel
     # measured ~2.8x SLOWER than XLA's lowering of the exp-form jnp path
     # at K=80 on v5e (3.6 vs 7.9 ms fwd; the K=80 minor dim wastes 37% of
-    # the 128-lane VPU tiles), so only a measured schedule winner — or an
-    # explicit True — turns it on.  It stays bit-validated for K>=128
-    # workloads.
-    pallas_focal: bool | None = None
+    # the 128-lane VPU tiles), so only an explicit True turns it on.  It
+    # stays bit-validated for K>=128 workloads.
+    pallas_focal: bool = False
     # Run the Pallas kernel in interpreter mode (CPU tests of the wiring).
     pallas_interpret: bool = False
-    # Anchor-tile widths for the fused kernel: None = the schedule-resolved
-    # or module defaults (ops/pallas/focal.FWD_TILE_A/BWD_TILE_A).
-    # Searched schedule parameters (tune/candidates.py).
-    focal_fwd_tile_a: int | None = None
-    focal_bwd_tile_a: int | None = None
+    # Anchor-tile widths for the fused kernel: the values of
+    # ops/pallas/focal.FWD_TILE_A / BWD_TILE_A (not imported: this module
+    # stays free of Pallas; tests/unit/test_kernel_constants.py holds them equal).
+    focal_fwd_tile_a: int = 8192
+    focal_bwd_tile_a: int = 4096
 
 
 def _focal_elementwise(

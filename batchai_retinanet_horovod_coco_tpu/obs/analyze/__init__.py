@@ -5,7 +5,7 @@ JSONL via ``split_runs``, watchdog markers) into one machine-readable
 ``PERF_REPORT.json`` — step-time decomposition, pipeline overlap
 efficiency, queue/stall correlation, memory trend, an MFU estimate from
 the recorded XLA cost-analysis FLOPs, and a ranked top-3 bottleneck
-verdict naming the spans and ``tune/`` problems to attack next.
+verdict naming the spans to attack next.
 
 Three entrypoints:
 

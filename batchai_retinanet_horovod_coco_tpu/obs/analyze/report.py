@@ -25,8 +25,7 @@ function from a run's own artifacts to
   unoptimized lowering — no second backend compile) against the device's
   peak TFLOP/s, so the number exists per RUN;
 - **a ranked top-3 bottleneck verdict** — each entry names the spans to
-  stare at in Perfetto and the ``tune/`` problems (``nms``, ``focal``,
-  ``matching``, ``batch``) the next optimization PR should search;
+  stare at in Perfetto and suggests where to look next;
 - **a numerics section** (ISSUE 10, schema v3) — the numerics flight
   recorder's read-back: per-log-window grad-norm/update-ratio/
   replica-agreement series from the ``numerics`` JSONL records, tripped
@@ -40,8 +39,7 @@ function from a run's own artifacts to
   aggregated per rule.  A violated SLO is a breach someone *declared*
   they care about, so it outranks every inferred bottleneck: each
   violated rule contributes a ``slo:<rule>`` verdict at the head of the
-  ranking (score 1.0), with tune ops mapped from the breached metric so
-  ``tune --from-report`` still closes the loop.
+  ranking (score 1.0).
 
 Determinism contract: the report is a pure function of the artifact
 files — no wall clocks, no environment probes (the peak-TFLOPs env
@@ -814,32 +812,6 @@ def _stalls_section(events: list[dict], events_section: dict) -> dict:
     }
 
 
-# Bottleneck → the tune/ problems that attack it (tune CLI --from-report
-# consumes these names directly: python -m ...tune --from-report).
-_TUNE_OPS = {
-    "device_step": ["focal", "matching", "nms"],
-    "eval_pipeline": ["nms", "batch"],
-    "eval_fetch_blocking": ["nms", "batch"],
-    "serve_fetch_blocking": ["nms", "batch"],
-    "host_input_pipeline": ["batch"],
-}
-
-
-def _slo_tune_ops(metric: str | None) -> list[str]:
-    """Breached metric → the tune/ problems that attack it, so an SLO
-    verdict at rank 1 still gives ``tune --from-report`` something to
-    search (a stall/shed rule maps to nothing — those are capacity or
-    wedge problems, not kernel-schedule problems)."""
-    m = (metric or "").lower()
-    if "latency" in m or "p99" in m or "p50" in m:
-        return ["nms", "batch"]
-    if "step_time" in m or "images_per_sec" in m:
-        return ["focal", "matching", "nms"]
-    if "data_wait" in m:
-        return ["batch"]
-    return []
-
-
 def _bottlenecks(
     steps: dict | None,
     pipeline: dict,
@@ -910,8 +882,9 @@ def _bottlenecks(
                 "spans": ["step"],
                 "evidence": f"device step {d['step']:.1%} of the window",
                 "suggestion": (
-                    "the roofline lever: fused Pallas kernels for focal/"
-                    "matching/NMS + a tune/ search on this device_kind"
+                    "the roofline lever: the step's device time by named "
+                    "scope (benchmark/README.md, --trace 1) says which "
+                    "kernel to fuse or re-tile"
                 ),
             }
         )
@@ -922,8 +895,8 @@ def _bottlenecks(
                 "spans": ["eval", "final_eval", "detect_dispatch"],
                 "evidence": f"in-loop eval {d['eval']:.1%} of the window",
                 "suggestion": (
-                    "--async-eval overlaps eval with the step stream; "
-                    "tune/ batch axis raises detect throughput"
+                    "--async-eval overlaps eval with the step stream; a "
+                    "larger eval batch raises detect throughput"
                 ),
             }
         )
@@ -941,22 +914,22 @@ def _bottlenecks(
         )
     # Pipeline fetch-blocking verdicts exist with or WITHOUT a train loop
     # (an eval/serve trace has no `step` spans, but its fetch
-    # blocking IS the detect-ceiling evidence tune/ exists to attack):
-    # normalized by the loop window when one exists, else by the
+    # blocking IS the detect-ceiling evidence): normalized by the loop window when one exists, else by the
     # pipeline's own wall.
     for key, name, span_list, suggestion in (
         (
             "eval",
             "eval_fetch_blocking",
             ["detect_fetch", "eval_put_wait"],
-            "one-behind overlap is losing to device NMS time: tune/ nms "
-            "+ per-bucket batch",
+            "one-behind overlap is losing to device NMS time: the NMS "
+            "backend (DetectConfig.nms_impl) and the eval batch",
         ),
         (
             "serve",
             "serve_fetch_blocking",
             ["serve_fetch"],
-            "tune/ nms + serve batch sizes",
+            "the NMS backend (DetectConfig.nms_impl) and the serve "
+            "batch sizes",
         ),
     ):
         sec = pipeline.get(key)
@@ -981,7 +954,7 @@ def _bottlenecks(
             }
         )
     if steps is None:
-        # No train loop in this trace (eval/serve/tune artifacts): also
+        # No train loop in this trace (eval/serve artifacts): also
         # rank raw span families by their share of the span-covered
         # wall, skipping families a pipeline verdict already claims.
         claimed = {s for c in cands for s in c["spans"]}
@@ -1008,8 +981,6 @@ def _bottlenecks(
     cands = [c for c in cands if (c["score"] or 0) > 0]
     cands.sort(key=lambda c: (-c["score"], c["name"]))
     top = cands[:3]
-    for c in top:
-        c["tune_ops"] = _TUNE_OPS.get(c["name"], [])
     vio_cands: list[dict] = []
     for name, info in sorted(
         ((violations or {}).get("rules") or {}).items()
@@ -1031,7 +1002,6 @@ def _bottlenecks(
                     "bottlenecks: attack the breached metric first "
                     "(RUNBOOK 'Live telemetry')"
                 ),
-                "tune_ops": _slo_tune_ops(info.get("metric")),
             }
         )
     num_cands: list[dict] = []
@@ -1071,7 +1041,6 @@ def _bottlenecks(
                     "--debug-nans rerun needed (RUNBOOK 'Numerics "
                     "triage')"
                 ),
-                "tune_ops": [],
             }
         )
     top = num_cands + vio_cands + top
@@ -1395,8 +1364,7 @@ def _fleet_section(
 
 def _fleet_bottlenecks(fleet: dict) -> list[dict]:
     """Fleet verdicts, same shape as every other bottleneck entry so the
-    schema-v3 machinery (``tune --from-report``, the checks) consumes
-    them unchanged: the UNAVAILABLE replica first (a lost replica has no
+    schema-v3 checks consume them unchanged: the UNAVAILABLE replica first (a lost replica has no
     performance question left at fleet scope), then the most-shed and
     the slowest replica."""
     cands: list[dict] = []
@@ -1430,7 +1398,6 @@ def _fleet_bottlenecks(fleet: dict) -> list[dict]:
                     "around the breaker-open instants; the re-dispatch "
                     "markers carry the affected trace ids"
                 ),
-                "tune_ops": [],
             }
         )
     # Underprovisioned fleet (ISSUE 19): scale-up breaches the policy
@@ -1467,7 +1434,6 @@ def _fleet_bottlenecks(fleet: dict) -> list[dict]:
                     "autoscale_decision on the timeline carries the "
                     "breached signal values"
                 ),
-                "tune_ops": [],
             }
         )
     replicas = fleet.get("replicas") or {}
@@ -1495,7 +1461,6 @@ def _fleet_bottlenecks(fleet: dict) -> list[dict]:
                     "routed share; a shedding replica under a healthy "
                     "fleet is a capacity mismatch, not a kernel problem"
                 ),
-                "tune_ops": [],
             }
         )
     p99s = {
@@ -1525,10 +1490,8 @@ def _fleet_bottlenecks(fleet: dict) -> list[dict]:
                     ),
                     "suggestion": (
                         "compare this replica's serve stage spans "
-                        "against a healthy track; tune/ nms + batch on "
-                        "its device_kind if device-bound"
+                        "against a healthy track"
                     ),
-                    "tune_ops": ["nms", "batch"],
                 }
             )
     cands = [c for c in cands if (c["score"] or 0) > 0]

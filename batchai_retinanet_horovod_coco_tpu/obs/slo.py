@@ -10,8 +10,7 @@ the verdict landing everywhere the post-hoc tooling already reads:
 - one ``slo_violation`` trace instant (visible ON the Perfetto timeline
   at the moment of the breach, like the watchdog's stall markers),
 - the ``violations`` section of PERF_REPORT.json (obs/analyze ranks a
-  sustained violation ABOVE inferred bottlenecks, and ``tune
-  --from-report`` consumes the mapped ops).
+  sustained violation ABOVE inferred bottlenecks).
 
 Rule shapes (all evaluated on ``Registry.snapshot()`` keys):
 

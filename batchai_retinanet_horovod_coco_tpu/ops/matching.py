@@ -54,11 +54,10 @@ class MatchingConfig:
     fused_pallas: bool | None = None
     # Interpreter-mode pallas (CPU tests of the fused path).
     pallas_interpret: bool = False
-    # Anchor-tile width for the fused kernel: None = the schedule-resolved
-    # or module default (ops/pallas/matching.TILE_A).  A searched schedule
-    # parameter — train/step.py fills it from the per-device registry
-    # (tune/schedule.py) when left None.
-    pallas_tile_a: int | None = None
+    # Anchor-tile width for the fused kernel: the value of
+    # ops/pallas/matching.TILE_A (not imported: this module stays free of
+    # Pallas; tests/unit/test_kernel_constants.py holds the two equal).
+    pallas_tile_a: int = 8192
 
 
 class AnchorAssignment(NamedTuple):

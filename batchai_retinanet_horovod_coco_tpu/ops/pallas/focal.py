@@ -238,10 +238,9 @@ def focal_loss_per_image_sums(
       anchor_state: (B, A) int32 in {-1 ignore, 0 negative, 1 positive}.
       interpret: run the kernel in interpreter mode (CPU testing).
       fwd_tile_a / bwd_tile_a: anchor-tile widths (None = the module
-        defaults FWD_TILE_A/BWD_TILE_A).  Searched schedule parameters
-        (tune/candidates.FOCAL_FWD_TILES/FOCAL_BWD_TILES) — must be
-        positive multiples of 128; the backward ceiling is lower because
-        it holds more live temps (see the constants' note above).
+        defaults FWD_TILE_A/BWD_TILE_A) — must be positive multiples of
+        128; the backward ceiling is lower because it holds more live
+        temps (see the constants' note above).
 
     Gradients flow to ``cls_logits`` only.
     """

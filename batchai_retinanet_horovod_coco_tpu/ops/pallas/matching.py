@@ -139,8 +139,7 @@ def assign_fused(
         slice of the kernel's transposed output, where the default (B, A, 4)
         form costs a moveaxis copy of a 32x-lane-padded tensor (~206 MB of
         tiles at the flagship bucket; see ops.boxes.encode_boxes_planar).
-      tile_a: anchor-tile width (None = module default TILE_A).  A searched
-        schedule parameter (tune/candidates.MATCHING_TILES); must be a
+      tile_a: anchor-tile width (None = module default TILE_A); must be a
         positive multiple of 128.
 
     Returns:

@@ -33,15 +33,12 @@ the next reduce) is done with an exact 0/1 identity matmul
 (``Precision.HIGHEST``) — Mosaic has no cheap vector transpose, and the
 dot is exact on {0, 1} values.
 
-``block_k`` is a SEARCHED parameter (tune/): candidate values are
-generated by ``tune.candidates.nms_candidates`` and the measured winner
-per device_kind is recorded in the schedule registry
-(artifacts/schedules/<device_kind>.json), which ``DetectConfig``
-resolution consumes (evaluate/detect.py).  The jnp reference
-(``use_kernel=False``) is the vmapped ``ops.nms.greedy_keep``.
+``block_k`` defaults to ``DEFAULT_BLOCK_K`` (``DetectConfig.nms_block_k``
+states the same value).  The jnp reference (``use_kernel=False``) is the
+vmapped ``ops.nms.greedy_keep``.
 
 Compiled by Mosaic (libtpu 0.0.34) and bit-identical to the XLA path on a
-v5e at B=8, K=1000, every ``block_k`` tune/ offers: chip_smoke.py's
+v5e at B=8, K=1000, ``block_k`` 128, 256 and 512: chip_smoke.py's
 ``kernels`` phase repeats that on every run.  The rows and class ids of
 a block are read through the refs (``ref[0, pl.ds(start, block_k), :]``):
 Mosaic has no ``dynamic_slice`` of a loaded value.
@@ -60,8 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 from batchai_retinanet_horovod_coco_tpu.ops import nms as nms_lib
 
 # Default (K, K) IoU tile width: 256 keeps every live tile (iou, masks,
-# eye) under ~1 MB of VMEM while amortizing the per-block fixed point;
-# searched per device by tune/ (candidates 128/256/512).
+# eye) under ~1 MB of VMEM while amortizing the per-block fixed point.
+# Untimed on the chip against 128 and 512 (ROADMAP S6).
 DEFAULT_BLOCK_K = 256
 
 

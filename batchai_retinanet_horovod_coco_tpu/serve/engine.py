@@ -185,7 +185,7 @@ class DetectEngine:
         model,
         state,
         buckets: tuple[tuple[int, int], ...] | None = None,
-        batch_sizes: tuple[int, ...] | None = None,
+        batch_sizes: tuple[int, ...] = (8,),
         config=None,
         min_side: int = 800,
         max_side: int = 1333,
@@ -195,15 +195,8 @@ class DetectEngine:
         """Engine over live params, AOT-compiled via the shared
         ``compile_detect_fn`` path (one executable per bucket × batch).
 
-        ``batch_sizes=None`` resolves each bucket's executable table from
-        the per-device schedule registry (tune/schedule.py ``serve.
-        batch_sizes``; built-in default ``(8,)`` for untuned buckets — an
-        unknown device falls back with one loud structured event).  The
-        NMS impl/block/``pre_nms_size`` knobs resolve the same way inside
-        ``compile_detect_fn`` (evaluate/detect.resolve_detect_config).
-        The registry lookup is cached for the process lifetime, so every
-        program is compiled at startup and no request ever recompiles.
-        An explicit tuple pins every bucket to those sizes.
+        Every bucket gets one executable per entry of ``batch_sizes``;
+        all are compiled here, so no request ever compiles.
         """
         from batchai_retinanet_horovod_coco_tpu.data.pipeline import (
             default_buckets,
@@ -212,9 +205,6 @@ class DetectEngine:
             DetectConfig,
             compile_detect_fn,
         )
-        from batchai_retinanet_horovod_coco_tpu.tune import (
-            serve_batch_sizes_for,
-        )
 
         if buckets is None:
             buckets = default_buckets(min_side, max_side)
@@ -222,14 +212,9 @@ class DetectEngine:
             config = DetectConfig()
         fns: dict[tuple[int, int], dict[int, Callable]] = {}
         for hw in buckets:
-            sizes = (
-                serve_batch_sizes_for(hw, (8,))
-                if batch_sizes is None
-                else batch_sizes
-            )
             fns[hw] = {
                 b: compile_detect_fn(model, state, hw, b, config, mesh=mesh)
-                for b in sorted(set(sizes))
+                for b in sorted(set(batch_sizes))
             }
         return cls(fns, min_side, max_side, label_to_cat_id, source="live")
 

@@ -40,7 +40,6 @@ from batchai_retinanet_horovod_coco_tpu.train.task import (  # noqa: F401  (re-e
     DetectionTask,
     LossFn,
     _forward_and_loss,
-    resolve_kernel_schedule,
 )
 
 # The step program's slices, written down once: every ``jax.named_scope`` a
@@ -898,14 +897,7 @@ def make_train_step_spatial(
             "pallas_call is opaque to GSPMD, so the head outputs would be "
             "replicated instead of sharded — use the default XLA focal path"
         )
-    # Resolve the schedule first (tile fields), then FORCE the GSPMD-opaque
-    # kernels off: a per-device schedule winner must not re-enable what
-    # spatial partitioning cannot shard (only an EXPLICIT pallas_focal=True
-    # reaches the raise above).
-    loss_config, matching_config = resolve_kernel_schedule(
-        loss_config, matching_config
-    )
-    loss_config = _dc.replace(loss_config, pallas_focal=False)
+    # fused_pallas=None would pick the GSPMD-opaque kernel on a TPU.
     matching_config = _dc.replace(matching_config, fused_pallas=False)
     # Numerics summary rides the global-math body (grads are global under
     # GSPMD); the per-replica agreement probe needs a named axis shard_map

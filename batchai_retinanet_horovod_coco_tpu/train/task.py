@@ -110,52 +110,6 @@ def _forward_and_loss(
     return metrics["loss"], (metrics, new_batch_stats)
 
 
-def resolve_kernel_schedule(
-    loss_config: losses_lib.LossConfig,
-    matching_config: matching_lib.MatchingConfig,
-    device_kind: str | None = None,
-) -> tuple[losses_lib.LossConfig, matching_lib.MatchingConfig]:
-    """Fill schedule-resolved kernel params (the train-side consumer of
-    the tune/ registry): focal impl + fwd/bwd tiles, matching impl + tile.
-
-    ``None`` fields mean "look the measured winner up in the per-device
-    schedule" (tune/schedule.py; built-in defaults reproduce the
-    hand-picked values, so an untuned device behaves exactly as before
-    ISSUE 6).  Explicit values always win — a CLI/test override must not
-    be silently re-tuned.  ``matching.impl == "auto"`` preserves the
-    backend-conditional dispatch (fused on TPU, jnp elsewhere).
-    """
-    import dataclasses as _dc
-
-    from batchai_retinanet_horovod_coco_tpu.tune import (
-        schedule as schedule_lib,
-    )
-
-    sched = schedule_lib.lookup(device_kind)
-    m, f = sched["matching"], sched["focal"]
-    if matching_config.pallas_tile_a is None:
-        matching_config = _dc.replace(
-            matching_config, pallas_tile_a=int(m["tile_a"])
-        )
-    if matching_config.fused_pallas is None and m["impl"] != "auto":
-        matching_config = _dc.replace(
-            matching_config, fused_pallas=m["impl"] == "pallas"
-        )
-    if loss_config.pallas_focal is None and f["impl"] != "auto":
-        loss_config = _dc.replace(
-            loss_config, pallas_focal=f["impl"] == "pallas"
-        )
-    if loss_config.focal_fwd_tile_a is None:
-        loss_config = _dc.replace(
-            loss_config, focal_fwd_tile_a=int(f["fwd_tile_a"])
-        )
-    if loss_config.focal_bwd_tile_a is None:
-        loss_config = _dc.replace(
-            loss_config, focal_bwd_tile_a=int(f["bwd_tile_a"])
-        )
-    return loss_config, matching_config
-
-
 class _Task:
     """What the step and the loop ask of a task."""
 
@@ -198,20 +152,15 @@ class DetectionTask(_Task):
         return tuple(batch.images.shape[1:3]), batch.images.shape[0], batch.image_ids
 
     def loss_fn(self, model, bucket) -> LossFn:
-        """Schedule-resolved kernel params (tune/): tile shapes + impl
-        choices come from the per-device registry unless explicitly pinned
-        (explicit values always win: the spatial step pins Pallas off)."""
         anchors = jnp.asarray(anchors_lib.anchors_for_image_shape(
             bucket, self.anchor_config or anchors_lib.AnchorConfig()))
-        loss_config, matching_config = resolve_kernel_schedule(
-            self.loss_config, self.matching_config)
 
         def loss_of(state, params, batch):
             return _forward_and_loss(
                 model, state, params,
                 batch["images"], batch["gt_boxes"], batch["gt_labels"],
-                batch["gt_mask"], anchors, loss_config,
-                matching_config, train=True,
+                batch["gt_mask"], anchors, self.loss_config,
+                self.matching_config, train=True,
             )
 
         return loss_of

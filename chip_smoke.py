@@ -54,6 +54,14 @@ OUT_DIR = os.path.join(REPO_ROOT, "chiprun_out", "chip_smoke")
 # what the chip tool copies back.
 _BULKY = ("data", "snapshot", "export")
 
+# The tiles the kernels phase checks each Pallas kernel at, beside its
+# module's default (VMEM bounds them; the focal backward holds more live
+# temporaries than the forward, hence its lower ceiling).
+MATCHING_TILES = (4096, 8192, 16384)
+NMS_BLOCKS = (128, 256, 512)
+FOCAL_FWD_TILES = (4096, 8192, 16384)
+FOCAL_BWD_TILES = (2048, 4096)
+
 
 @dataclasses.dataclass(frozen=True)
 class SmokeSize:
@@ -177,7 +185,6 @@ def _kernel_matching(size: SmokeSize, anchors, rng) -> list[str]:
 
     from batchai_retinanet_horovod_coco_tpu.ops import matching as M
     from batchai_retinanet_horovod_coco_tpu.ops.pallas.matching import TILE_A
-    from batchai_retinanet_horovod_coco_tpu.tune import candidates
 
     done = []
     jnp_path = M.MatchingConfig(fused_pallas=False)
@@ -197,8 +204,7 @@ def _kernel_matching(size: SmokeSize, anchors, rng) -> list[str]:
             )(boxes, labels, mask)
 
         want = jax.device_get(assign(jnp_path))
-        # The module default tile and every tile tune/ offers.
-        for tile in sorted({TILE_A, *candidates.MATCHING_TILES}):
+        for tile in sorted({TILE_A, *MATCHING_TILES}):
             got = jax.device_get(
                 assign(
                     M.MatchingConfig(
@@ -238,7 +244,6 @@ def _kernel_nms(size: SmokeSize, anchors, rng) -> list[str]:
     from batchai_retinanet_horovod_coco_tpu.ops.pallas.nms import (
         DEFAULT_BLOCK_K,
     )
-    from batchai_retinanet_horovod_coco_tpu.tune import candidates
 
     # Decoded anchors with small deltas: neighbours overlap heavily, so
     # the suppression chains are long; sigmoid(-4 ± 1) gives far more
@@ -270,7 +275,7 @@ def _kernel_nms(size: SmokeSize, anchors, rng) -> list[str]:
         "nms: the reference kept almost nothing; the scene is degenerate",
     )
     done = []
-    for block_k in sorted({DEFAULT_BLOCK_K, *candidates.NMS_BLOCKS}):
+    for block_k in sorted({DEFAULT_BLOCK_K, *NMS_BLOCKS}):
         got = post(
             dc.replace(
                 base,
@@ -294,7 +299,6 @@ def _kernel_focal(size: SmokeSize, num_anchors: int, rng) -> list[str]:
 
     from batchai_retinanet_horovod_coco_tpu import losses as L
     from batchai_retinanet_horovod_coco_tpu.ops.pallas import focal
-    from batchai_retinanet_horovod_coco_tpu.tune import candidates
 
     batch = size.per_chip_batch
     logits = jnp.asarray(
@@ -321,10 +325,10 @@ def _kernel_focal(size: SmokeSize, num_anchors: int, rng) -> list[str]:
         )
 
     want, want_grad = value_and_grad(L.LossConfig(pallas_focal=False))
-    # The module default tiles and every tile tune/ offers (the fwd and
-    # bwd menus are independent: pair them off, cycling the shorter one).
-    fwd = sorted({focal.FWD_TILE_A, *candidates.FOCAL_FWD_TILES})
-    bwd = sorted({focal.BWD_TILE_A, *candidates.FOCAL_BWD_TILES})
+    # The fwd and bwd lists are independent: pair them off, cycling the
+    # shorter one.
+    fwd = sorted({focal.FWD_TILE_A, *FOCAL_FWD_TILES})
+    bwd = sorted({focal.BWD_TILE_A, *FOCAL_BWD_TILES})
     done = []
     for i in range(max(len(fwd), len(bwd))):
         fwd_tile, bwd_tile = fwd[i % len(fwd)], bwd[i % len(bwd)]

@@ -2,7 +2,7 @@
 output (bit-for-bit, inline == offline CLI), report schema validation,
 robustness on corrupt/legacy/empty artifacts, the shared percentile
 helper's equivalence pin, the watchdog stall trace marker, span
-attribution over live rings, and the tune --from-report consumer.
+attribution over live rings.
 
 The fixture (tests/fixtures/perf_doctor/) is a real CPU train+eval smoke
 recording: trace.json + metrics.jsonl as `--obs-trace` left them, plus
@@ -409,48 +409,6 @@ class TestSpanAttribution:
         assert d is not None and abs(sum(d.values()) - 1.0) < 0.02
 
 
-class TestTuneFromReport:
-    def test_golden_report_maps_to_tune_ops(self):
-        from batchai_retinanet_horovod_coco_tpu.tune.__main__ import (
-            _ops_from_report,
-        )
-
-        ops, batch_axis = _ops_from_report(GOLDEN)
-        # The fixture's #1 verdict is device_step → kernel families in
-        # rank order; eval_pipeline contributes the batch axis.
-        assert ops[0] == "focal"
-        assert set(ops) <= {"focal", "matching", "nms"}
-        assert batch_axis is True
-
-    def test_empty_verdict_refuses_loudly(self, tmp_path):
-        from batchai_retinanet_horovod_coco_tpu.tune.__main__ import (
-            _ops_from_report,
-        )
-
-        p = tmp_path / "r.json"
-        p.write_text(json.dumps({"bottlenecks": [
-            {"name": "compilation", "tune_ops": []}
-        ]}))
-        with pytest.raises(SystemExit, match="names no tunable ops"):
-            _ops_from_report(str(p))
-        with pytest.raises(SystemExit, match="cannot read"):
-            _ops_from_report(str(tmp_path / "missing.json"))
-
-    def test_structurally_wrong_reports_exit_cleanly(self, tmp_path):
-        from batchai_retinanet_horovod_coco_tpu.tune.__main__ import (
-            _ops_from_report,
-        )
-
-        arr = tmp_path / "array.json"
-        arr.write_text("[1, 2, 3]")  # top-level array
-        with pytest.raises(SystemExit, match="cannot read"):
-            _ops_from_report(str(arr))
-        strings = tmp_path / "strings.json"
-        strings.write_text(json.dumps({"bottlenecks": ["not-a-dict"]}))
-        with pytest.raises(SystemExit, match="cannot read"):
-            _ops_from_report(str(strings))
-
-
 class TestPeakTable:
     def test_known_kinds_and_fallbacks(self, monkeypatch):
         assert device_peak_tflops("TPU v5 lite") == (197.0, "spec")
@@ -503,8 +461,7 @@ class TestAnalyzeEventsUnits:
 
     def test_fetch_blocking_verdict_without_train_loop(self):
         """A bench eval/serve trace (no `step` spans) still gets a
-        fetch-blocking verdict with tune_ops — the detect-ceiling
-        evidence `tune --from-report` exists to consume."""
+        fetch-blocking verdict: the detect-ceiling evidence."""
         def mk(name, ts, dur):
             return {"ph": "X", "name": name, "ts": ts, "dur": dur,
                     "pid": 1, "tid": 1}
@@ -516,7 +473,6 @@ class TestAnalyzeEventsUnits:
         )
         top = rep["bottlenecks"][0]
         assert top["name"] == "eval_fetch_blocking"
-        assert top["tune_ops"] == ["nms", "batch"]
         # The generic fallback does not duplicate the claimed spans.
         assert not any(
             b["name"] == "span:detect_fetch" for b in rep["bottlenecks"]

@@ -213,12 +213,11 @@ class TestPallasKernelsLowerForTpu:
             _spec((b, num_gt), jnp.int32), _spec((b, num_gt), jnp.bool_),
         )
 
-    def test_nms_every_block_tune_offers(self):
+    def test_nms_every_block_the_smoke_checks(self):
         from batchai_retinanet_horovod_coco_tpu.ops.pallas import nms
-        from batchai_retinanet_horovod_coco_tpu.tune import candidates
 
         b, k = self.B, 1000
-        for block_k in candidates.NMS_BLOCKS:
+        for block_k in chip_smoke.NMS_BLOCKS:
             _lowers_for_tpu(
                 lambda bx, sc, cl, bk=block_k: nms.nms_keep_mask(
                     bx, sc, cl, 0.5, block_k=bk

@@ -132,6 +132,46 @@ def test_unknown_shape_rejected(tiny_model_and_state, tmp_path):
         loaded(np.zeros((1, 64, 64, 3), dtype=np.uint8))
 
 
+@pytest.mark.parametrize("written_by", ["this tree", "PR 44 and before"])
+def test_manifest_loads_with_or_without_the_schedule_key(
+    tiny_model_and_state, tmp_path, written_by
+):
+    """Until PR 45 a manifest carried ``schedule``: where the registry of
+    kernel parameters had its values from.  Nothing ever read the key; an
+    export of that age must still serve."""
+    import json
+
+    from batchai_retinanet_horovod_coco_tpu.serve import DetectEngine
+
+    model, state = tiny_model_and_state
+    path = export_model(
+        state, model, str(tmp_path / "exp"), buckets=((64, 64),),
+        batch_size=2, config=CONFIG,
+    )
+    with open(path) as f:
+        manifest = json.load(f)
+    assert "schedule" not in manifest
+    assert manifest["detect_config"] == {
+        "score_threshold": 0.05, "iou_threshold": 0.5, "pre_nms_size": 64,
+        "max_detections": 10, "nms_impl": "xla", "nms_block_k": 256,
+    }
+    if written_by != "this tree":
+        manifest["schedule"] = {
+            "device_kind": "cpu", "found": True,
+            "source": "artifacts/schedules/cpu.json",
+        }
+        with open(path, "w") as f:
+            json.dump(manifest, f, indent=2)
+    engine = DetectEngine.from_export(str(tmp_path / "exp"))
+    assert engine.buckets == ((64, 64),)
+    assert engine.batch_sizes((64, 64)) == [2]
+    images = np.zeros((2, 64, 64, 3), dtype=np.uint8)
+    got = engine.fetch(engine.dispatch((64, 64), images))
+    want = make_detect_fn(model, (64, 64), CONFIG)(state, images)
+    np.testing.assert_array_equal(got.valid, np.asarray(want.valid))
+    np.testing.assert_array_equal(got.scores, np.asarray(want.scores))
+
+
 def test_convert_model_cli_roundtrip_to_server(tmp_path):
     """ISSUE 4 satellite: checkpoint → ``convert_model.py`` (with bucket /
     batch-size / platform flags) → export dir → serve engine answers a

@@ -6,6 +6,7 @@
 import itertools
 import json
 import os
+import re
 import sys
 
 import jax
@@ -260,6 +261,10 @@ def test_train_py_help_names_the_lm_subcommand(capsys):
     assert "lm-synthetic" in text and "single-chip" in text and "tiny" in text
     with pytest.raises(SystemExit):
         build_parser().parse_args(["lm-synthetic", "--help"])
-    text = " ".join(capsys.readouterr().out.split())
-    assert "--model" in text and "tiny-moe" in text and "deepseek_v2" in text and "granitemoehybrid" in text
-    assert "tiny-nemotron" in text and "nemotron_h" in text and "nemotron-3-nano-30b-ep16.json" in text
+    from batchai_retinanet_horovod_coco_tpu.models.language import BY_TYPE, PRESETS
+
+    text = re.sub(r"-\n\s*", "-", capsys.readouterr().out)  # argparse breaks a line after a hyphen
+    text = " ".join(text.split())
+    # built from models/language.py: a sixth model edits no help text
+    assert "--model" in text and "benchmark/configs/" in text
+    assert all(name in text for name in (*PRESETS, *BY_TYPE))

@@ -312,37 +312,22 @@ def build_parser() -> argparse.ArgumentParser:
 def _add_lm_parser(sub) -> None:
     """``lm-synthetic``: its own, short flag surface (no image, anchor,
     eval or mesh flags apply)."""
+    from batchai_retinanet_horovod_coco_tpu.models.language import BY_TYPE, PRESETS
+
+    presets = ", ".join(PRESETS)
     lm = sub.add_parser(
         "lm-synthetic", allow_abbrev=False,
-        help="train a language model (Granite 4.0-H hybrid, DeepSeek-V2, "
-             "Nemotron-H, Keye-VL-2.0's or Olmo-Hybrid, by the config's "
-             "model_type) on seeded packed token sequences (single chip; "
-             "--model tiny, tiny-moe, tiny-nemotron, tiny-keye or tiny-olmo "
-             "on a CPU)",
+        help=f"train a language model ({', '.join(e[1] for e in BY_TYPE.values())}, "
+             "by the config's model_type) on seeded packed token sequences "
+             f"(single chip; --model {presets} on a CPU)",
     )
     g = lm.add_argument_group("model")
     g.add_argument("--model", default="tiny",
-                   help="'tiny' (Granite 4.0-H: one period of ten layers "
-                        "at width 64, vocabulary 128), 'tiny-moe' "
-                        "(DeepSeek-V2: a dense and two expert layers at "
-                        "width 64, 4 of 16 experts held, 3 a token), "
-                        "'tiny-nemotron' (Nemotron-H: the pattern MEM*E at "
-                        "width 64, 2 groups, 2 of 8 experts held, 3 a token), "
-                        "'tiny-keye' (Keye-VL-2.0's language model: three "
-                        "layers at width 64, an indexer that keeps 24 keys a "
-                        "query, 4 of 16 experts held, 3 a token), "
-                        "'tiny-olmo' (Olmo-Hybrid: one period of three gated "
-                        "delta rule layers and a full attention layer at "
-                        "width 64) "
-                        "- the CPU tests' presets - or a JSON file with the "
-                        "published config.json keys, whose model_type "
-                        "(granitemoehybrid, deepseek_v2, nemotron_h, KeyeVL2, "
-                        "olmo_hybrid) picks the model: "
-                        "benchmark/configs/granite-4.0-h-micro-p1.json, "
-                        "benchmark/configs/deepseek-v2-lite-ep8.json, "
-                        "benchmark/configs/nemotron-3-nano-30b-ep16.json, "
-                        "benchmark/configs/keye-vl2-30b-a3b-ep8.json, "
-                        "benchmark/configs/olmo-hybrid-7b-p1.json")
+                   help=f"one of {presets} (the CPU tests' presets: each "
+                        "its model module's TINY) "
+                        "or a JSON file with the published config.json keys, "
+                        f"whose model_type ({', '.join(BY_TYPE)}) picks the "
+                        "model: the files of benchmark/configs/")
     g = lm.add_argument_group("data")
     g.add_argument("--seq-len", type=int, default=64,
                    help="tokens per packed sequence")

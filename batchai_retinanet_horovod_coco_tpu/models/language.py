@@ -13,12 +13,14 @@ PRESETS = {"tiny": ("granite_hybrid", "GraniteHybrid", "TINY"),
            "tiny-moe": ("deepseek_v2", "DeepseekV2", "TINY"),
            "tiny-nemotron": ("nemotron_h", "NemotronH", "TINY"),
            "tiny-keye": ("keye_vl2", "KeyeVL2", "TINY"),
-           "tiny-olmo": ("olmo_hybrid", "OlmoHybrid", "TINY")}
+           "tiny-olmo": ("olmo_hybrid", "OlmoHybrid", "TINY"),
+           "tiny-afmoe": ("afmoe", "Afmoe", "TINY")}
 BY_TYPE = {"granitemoehybrid": ("granite_hybrid", "GraniteHybrid", "GraniteHybridConfig"),
            "deepseek_v2": ("deepseek_v2", "DeepseekV2", "DeepseekV2Config"),
            "nemotron_h": ("nemotron_h", "NemotronH", "NemotronHConfig"),
            "KeyeVL2": ("keye_vl2", "KeyeVL2", "KeyeVL2Config"),
-           "olmo_hybrid": ("olmo_hybrid", "OlmoHybrid", "OlmoHybridConfig")}
+           "olmo_hybrid": ("olmo_hybrid", "OlmoHybrid", "OlmoHybridConfig"),
+           "afmoe": ("afmoe", "Afmoe", "AfmoeConfig")}
 
 
 def _load(entry):
@@ -27,8 +29,8 @@ def _load(entry):
 
 
 def build_language_model(spec: str | dict, **overrides):
-    """``spec``: ``tiny`` / ``tiny-moe`` / ``tiny-nemotron`` / ``tiny-keye`` / ``tiny-olmo``
-    (the CPU tests' presets), the path of a JSON file with the published keys, or those keys
+    """``spec``: ``tiny`` / ``tiny-moe`` / ``tiny-nemotron`` / ``tiny-keye`` / ``tiny-olmo`` /
+    ``tiny-afmoe`` (the CPU tests' presets), the path of a JSON file with the published keys, or those keys
     as a dict.  ``overrides`` replace fields of the model's config (``dtype``)."""
     if isinstance(spec, str) and spec in PRESETS:
         model, config = _load(PRESETS[spec])

@@ -1,6 +1,6 @@
 """What the language models share (models/granite_hybrid.py,
 models/deepseek_v2.py, models/nemotron_h.py, models/keye_vl2.py,
-models/olmo_hybrid.py): RMSNorm, the matmul with a weight, the gated SiLU MLP
+models/olmo_hybrid.py, models/afmoe.py): RMSNorm, the matmul with a weight, the gated SiLU MLP
 and the squared-ReLU MLP, a Mamba-2 mixer's depthwise convolution, the
 embedding lookup, the head, the next-token loss, and what a layer recomputed in
 the backward pass keeps (``layer_keeps``: the attention kernels' residuals, the
@@ -56,6 +56,10 @@ MLP_GATE_UP = "mlp_gate_up"  # ``gated_mlp``'s product with ``gate_up``, (batch,
 #   ``layer_keeps`` at 94%         KEEPS        KEEPS       nothing more
 # (keye's working set, the index scores of 16 384 keys a query and the widest vocabulary slice, reads 71 inputs and
 # enters no decision: it has no such product.)
+# (PR 46, trinity, the same way: P 2.822, arguments 8.466, temporaries 5.818 = 65.7 layer inputs over P / 2, the step
+# 14.586 where the model says 13.232; five products of 0.671 in all: temporaries 6.646, the step 15.409 = 91.1% of the
+# limit where the model says 13.903 = 82.2%: KEEPS, and it fits, but a 16 384-token step's working set is 66-71 inputs,
+# not 50, and here the products cost MORE than their bytes, 0.829.)
 # What is kept costs less than its bytes (granite 2.38 of 2.68 GB, dsv2 1.15 of 1.64) because it takes the room of
 # temporaries that died earlier (PR 39 found the same), and in olmo's step the compiler's count FALLS by 0.12 GB: by
 # that count olmo's products would fit at 92.8%.  The model cannot see that (it would take a compilation to find out,

@@ -2,16 +2,24 @@
 
     out_i = sum_j softmax_j(scale * q_i . k_j) v_j   over j <= i with seg_j == seg_i
 
+and, PER CALL (a model's layers may differ: models/afmoe.py's sliding layers
+pass ``window``, its full layers none), of those keys only the ``window``
+nearest, the query's own among them:
+
+    ... over j <= i with seg_j == seg_i and i - j < window
+
 (query head ``h`` reads key/value head ``h // (heads / kv_heads)``; every
 query sees itself, so no row is empty).  The value head may be narrower
 than the query/key head (latent attention: 192 against 128), natively in
-both blockings: nothing is padded.  One algorithm, two blockings:
+both blockings: nothing is padded.  One algorithm, two rules (causal in the
+document; that and the window), two blockings:
 
 - ``xla``: the queries in blocks of ``xla_q_block``, block ``i`` against the
   keys ``[0, end of block i)``, each block recomputed in the backward pass.
-  A block's float32 scores ``(heads, block, keys)`` go through HBM: written
-  by the first matmul, read by the mask and the softmax, written again as
-  probabilities and read by the second matmul.
+  Under a window the keys are ``[first key the block's first query reaches,
+  end of block i)``.  A block's float32 scores ``(heads, block, keys)`` go
+  through HBM: written by the first matmul, read by the mask and the softmax,
+  written again as probabilities and read by the second matmul.
 - ``kernel``: the blocked TPU kernel jax ships
   (``jax.experimental.pallas.ops.tpu.splash_attention``; causal mask, segment
   ids, grouped heads without repeating k and v, its own forward, dq and dk/dv
@@ -27,6 +35,14 @@ both blockings: nothing is padded.  One algorithm, two blockings:
   softmax (every query sees itself on the diagonal, which always runs), so
   the outputs and the three gradients are the static lists' numbers, bit
   for bit.  A sequence that is one document runs the causal lists.
+  A WINDOW is one more term of the static part and nothing new in the dynamic
+  one: a second kernel object (``_causal_kernel`` is keyed on the window too)
+  whose mask is the library's ``LocalMask((n, n), (window - 1, 0), 0)`` inside
+  each sequence, so its static lists hold only the key blocks a query block's
+  window reaches (at T = 16 384 in one document and a window of 2048, 45 of
+  the 136 causal pairs of 1024 x 1024 blocks), and ``_follow_documents``
+  multiplies them by the step's documents as it does the causal ones.  Window
+  layers run the same ``BLOCK_SIZES``.
   The backward kernels take the forward's output and log-sum-exp as
   residuals, and the library gives both the checkpoint name it is built
   with (``residual_checkpoint_name``: ``RESIDUALS``) inside its forward
@@ -62,7 +78,8 @@ import jax.numpy as jnp
 import numpy as np
 
 KERNEL, XLA = "kernel", "xla"
-RUN_SHARE = "attn/block_pairs_run_share"
+RUN_SHARE = "attn/block_pairs_run_share"  # of the layers without a window
+WINDOW_RUN_SHARE = "attn/window_block_pairs_run_share"  # of the layers with one
 # the checkpoint name of the forward kernel's output and log-sum-exp, the backward kernels' residuals
 # (ops/sparse_attention.py's kernels give theirs the same)
 RESIDUALS = "attention_residuals"
@@ -105,6 +122,27 @@ BLOCK_SIZES = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
                    block_q_dkv=1024, block_kv_dkv=1024, block_q_dq=1024, block_kv_dq=1024)
 
 
+# A layer with a WINDOW runs the same seven (PR 46: Trinity-Mini's sliding layers, 2048 keys).  At 1024 x 1024 a
+# window of 2048 runs 3 key blocks a query block (45 of the 136 causal pairs at T = 16 384), of which a third of the
+# pairs is masked away; at 512 x 512 it would run 5 (150 of 528), of which a fifth.
+#
+# MEASURED (v5e-1, PR 46: 32 / 4 heads of 128, T = 16 384, one document, a window of 2048; ms of device time a
+# forward + backward of one layer, the forward / dq / dk/dv kernels from a trace of five calls, beside 3.5 ms of
+# scaling and transposes; every window row's output and three gradients within 0.03 of the 1024 row's, bfloat16):
+#   no window, all 1024 (the full layer)      15.42 / 20.81 / 25.98 = 62.2
+#   all 1024                                   5.43 /  6.88 /  8.58 = 20.9   <- taken (0.336 of the full layer's; the pairs 0.331)
+#   all 512                                    5.12 /  6.61 /  8.15 = 19.9
+#   all 512, keys 256 a pass                   6.71 /  6.61 /  8.15
+#   queries 1024, keys 512                     5.77 /  7.19 /  9.05
+#   queries 512, keys 1024                     5.45 /  7.22 /  8.99
+#   forward 2048 x 512, backward 1024 x 512    7.29 /  7.16 /  8.97
+#   all 256                                   10.49 / 10.80 / 14.17
+#   all 2048: the forward kernel runs out of VMEM (compiled for a described v5e)
+# Alone the kernels are 1.0 ms a layer (4.8%) faster at 512; IN THE STEP the same seed read 1.54550 sequences a
+# second at 512 against 1.54538 at 1024 (+0.008%, trinity-mini-train-doc16k, my chip runs, PR 46): nothing end to
+# end, so no second table of blocks.  Ask again only if a trace shows the window kernels limiting a step.
+
+
 def lowering(backend: str, seq_len: int) -> str:
     """``kernel`` where the blocked kernel can run: a TPU backend and a
     sequence of whole blocks; ``xla`` everywhere else (the CPU, a short or
@@ -113,26 +151,46 @@ def lowering(backend: str, seq_len: int) -> str:
     return KERNEL if backend == "tpu" and whole_blocks else XLA
 
 
-def run_meta(backend: str, seq_len: int) -> dict[str, str]:
-    """What a model's ``run_meta`` says of its attention: the lowering, and
-    on the kernel path that the block lists follow the documents and that a
-    recomputed layer keeps the forward kernel's output and log-sum-exp."""
+def run_meta(backend: str, seq_len: int, window: int | None = None) -> dict:
+    """What a model's ``run_meta`` says of its attention: the lowering, on
+    the kernel path that the block lists follow the documents and that a
+    recomputed layer keeps the forward kernel's output and log-sum-exp, and
+    the window of the layers that have one."""
     path = lowering(backend, seq_len)
     kernel = {"attention_block_skip": "documents", "attention_residuals": "kept"} if path == KERNEL else {}
-    return {"attention_lowering": path, **kernel}
+    return {"attention_lowering": path, **kernel, **({} if window is None else {"attention_window": window})}
 
 
-def step_counters(segment_ids) -> dict:
+def step_counters(segment_ids, window: int | None = None, heads: int | None = None) -> dict:
     """The scalars a step's attention layers add to the model's (a model's
     ``loss`` merges them into what the loop logs): on the kernel path
     ``attn/block_pairs_run_share``, the share of the causal (query block,
     key block) pairs of the forward kernel that run, mean over the step's
     sequences (1.0 = nothing skipped; every attention layer of a step sees
     the same ``segment_ids`` and blocks, so one layer's share is the
-    mean over the layers); nothing on the xla path."""
+    mean over the layers), and for a model whose other layers (of ``heads``
+    query heads) have a ``window`` also ``attn/window_block_pairs_run_share``,
+    the same share of THEIR forward kernel, counted in the list that kernel
+    is given; nothing on the xla path."""
     if lowering(jax.default_backend(), segment_ids.shape[1]) != KERNEL:
         return {}
-    return {RUN_SHARE: block_pairs_run_share(segment_ids, BLOCK_SIZES["block_q"], BLOCK_SIZES["block_kv"])}
+    full = {RUN_SHARE: block_pairs_run_share(segment_ids, BLOCK_SIZES["block_q"], BLOCK_SIZES["block_kv"])}
+    return full if window is None else {**full, WINDOW_RUN_SHARE: _window_run_share(segment_ids, window, heads)}
+
+
+def _window_run_share(segment_ids, window: int, heads: int):
+    """The steps of the window layers' forward grid that run, over the causal
+    block pairs: read from the forward list of the kernel object those layers
+    call (``_causal_kernel``'s cache holds one a shape and window), cut to the
+    step's documents as ``_document_block_lists`` cuts it.  A library whose
+    local mask listed every causal block would read what a full layer reads."""
+    batch, t = segment_ids.shape
+    kernel = _causal_kernel(batch, t, heads, tuple(BLOCK_SIZES.items()), False, window)
+    bq, bkv = BLOCK_SIZES["block_q"], BLOCK_SIZES["block_kv"]
+    info = kernel.fwd_mask_info
+    runs = _runs(np.asarray(info.block_mask)[0], np.asarray(info.data_next)[0],
+                 _block_pairs(segment_ids.reshape(batch * t), bq, bkv)[1], False)
+    return jnp.sum(runs, dtype=jnp.float32) / (batch * int(_block_pairs(segment_ids, bq, bkv)[0].sum()))
 
 
 def block_pairs_run_share(segment_ids, block_q: int, block_kv: int):
@@ -142,17 +200,19 @@ def block_pairs_run_share(segment_ids, block_q: int, block_kv: int):
     return jnp.sum(shares, dtype=jnp.float32) / (segment_ids.shape[0] * int(causal.sum()))
 
 
-def packed_causal_attention(q, k, v, segment_ids, scale: float, xla_q_block: int):
+def packed_causal_attention(q, k, v, segment_ids, scale: float, xla_q_block: int, window: int | None = None):
     """``q`` (batch, T, heads, head size), ``k`` (batch, T, kv_heads, head
     size), ``v`` (batch, T, kv_heads, value head size), ``segment_ids``
     (batch, T) with every document a contiguous run of one id ->
-    (batch, T, heads, value head size) in ``q``'s dtype."""
+    (batch, T, heads, value head size) in ``q``'s dtype.  ``window``: a query
+    sees the ``window`` nearest keys of its causal past in its document,
+    itself among them; ``None``: all of it."""
     if lowering(jax.default_backend(), q.shape[1]) == KERNEL:
-        return _kernel_path(q, k, v, segment_ids, scale)
-    return _xla_path(q, k, v, segment_ids, scale, xla_q_block)
+        return _kernel_path(q, k, v, segment_ids, scale, window=window)
+    return _xla_path(q, k, v, segment_ids, scale, xla_q_block, window)
 
 
-def _xla_path(q, k, v, segment_ids, scale, q_block):
+def _xla_path(q, k, v, segment_ids, scale, q_block, window=None):
     batch, t, heads, hd = q.shape
     kvh = k.shape[2]
     q = q.reshape(batch, t, kvh, heads // kvh, hd)
@@ -160,28 +220,36 @@ def _xla_path(q, k, v, segment_ids, scale, q_block):
     def block(q_blk, seg_q, start, k_seen, v_seen, seg_k):
         scores = jnp.einsum("bqkgd,bskd->bkgqs", q_blk, k_seen, preferred_element_type=jnp.float32)
         pos_q = start + jnp.arange(q_blk.shape[1])
-        allowed = (pos_q[:, None] >= jnp.arange(k_seen.shape[1])[None, :]) & (
-            seg_q[:, :, None] == seg_k[:, None, :])  # (b, q, s)
+        if window is None:
+            allowed = (pos_q[:, None] >= jnp.arange(k_seen.shape[1])[None, :]) & (
+                seg_q[:, :, None] == seg_k[:, None, :])  # (b, q, s)
+        else:  # the keys seen end with the block's last query and begin where its first query's window does
+            pos_k = start + q_blk.shape[1] - k_seen.shape[1] + jnp.arange(k_seen.shape[1])
+            apart = pos_q[:, None] - pos_k[None, :]
+            allowed = (apart >= 0) & (apart < window) & (seg_q[:, :, None] == seg_k[:, None, :])
         scores = jnp.where(allowed[:, None, None], scores * scale, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(q_blk.dtype)
         return jnp.einsum("bkgqs,bskd->bqkgd", probs, v_seen)
 
     block = jax.checkpoint(block, static_argnums=(2,))  # scores are recomputed, never kept
-    out = [block(q[:, s:s + q_block], segment_ids[:, s:s + q_block], s, k[:, :min(s + q_block, t)],
-                 v[:, :min(s + q_block, t)], segment_ids[:, :min(s + q_block, t)])
-           for s in range(0, t, q_block)]
+    out = []
+    for s in range(0, t, q_block):
+        seen = slice(0 if window is None else max(0, s - window + 1), min(s + q_block, t))
+        out.append(block(q[:, s:s + q_block], segment_ids[:, s:s + q_block], s, k[:, seen], v[:, seen],
+                         segment_ids[:, seen]))
     return jnp.concatenate(out, axis=1).reshape(batch, t, heads, v.shape[-1])
 
 
-def _kernel_path(q, k, v, segment_ids, scale, interpret: bool = False):
+def _kernel_path(q, k, v, segment_ids, scale, interpret: bool = False, window: int | None = None):
     """The shipped kernel over the batch's sequences laid end to end (one
     call of each of the three kernels a batch, one transpose an array),
-    built from the mask that is causal inside each sequence, with its three
-    block lists cut to the documents of the step (``_document_block_lists``)."""
+    built from the mask that is causal inside each sequence (and reaches no
+    further back than ``window``), with its three block lists cut to the
+    documents of the step (``_document_block_lists``)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
     batch, t, heads, _ = q.shape
-    kernel = _causal_kernel(batch, t, heads, tuple(BLOCK_SIZES.items()), interpret)
+    kernel = _causal_kernel(batch, t, heads, tuple(BLOCK_SIZES.items()), interpret, window)
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     end_to_end = lambda x: x.transpose(2, 0, 1, 3).reshape(x.shape[2], batch * t, x.shape[3])
     seg = segment_ids.reshape(batch * t)
@@ -191,13 +259,14 @@ def _kernel_path(q, k, v, segment_ids, scale, interpret: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def _causal_kernel(sequences: int, t: int, heads: int, block_sizes: tuple, interpret: bool):
+def _causal_kernel(sequences: int, t: int, heads: int, block_sizes: tuple, interpret: bool, window: int | None = None):
     """The shipped kernel for ``sequences`` of ``t`` tokens laid end to end,
-    with the static block lists of the mask that is causal inside each: a
-    block pair of two sequences is in no list, and the library shrinks each
-    grid to one sequence's width.  Built once a shape and outside any trace,
-    so that its lists stay arrays that ``_follow_documents`` can read while a
-    step is traced."""
+    with the static block lists of the mask that is causal inside each (and,
+    given a ``window``, reaches no further back than that): a block pair of
+    two sequences is in no list, and the library shrinks each grid to the
+    widest row of blocks that run (one sequence's; a window's).  Built once a
+    shape and window and outside any trace, so that its lists stay arrays
+    that ``_follow_documents`` can read while a step is traced."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
     class SequencesCausalMask(splash.CausalMask):
@@ -209,10 +278,21 @@ def _causal_kernel(sequences: int, t: int, heads: int, block_sizes: tuple, inter
             rows, cols = self.q_sequence[idx[0]][:, None], self.q_sequence[idx[1]][None, :]
             return (rows >= cols) & (rows // t == cols // t)
 
+    class SequencesWindowMask(splash.LocalMask):
+        """The same with ``q - k < window``: the library's local mask (its
+        ``mask_function`` is the rule inside the kernel), cut to one sequence
+        for the block lists alone."""
+
+        def __getitem__(self, idx):
+            rows, cols = self.q_sequence[idx[0]][:, None], self.q_sequence[idx[1]][None, :]
+            return (rows >= cols) & (rows - cols < window) & (rows // t == cols // t)
+
     assert all(t % b == 0 for b in dict(block_sizes).values())
+    n = sequences * t
+    mask = SequencesCausalMask((n, n)) if window is None else SequencesWindowMask((n, n), (window - 1, 0), 0)
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mha(
-            splash.MultiHeadMask([SequencesCausalMask((sequences * t, sequences * t))] * heads),
+            splash.MultiHeadMask([mask] * heads),
             block_sizes=splash.BlockSizes(**dict(block_sizes)), head_shards=1, q_seq_shards=1,
             residual_checkpoint_name=RESIDUALS, interpret=interpret)
 
@@ -267,9 +347,7 @@ def _follow_documents(info, shares, heads: int, is_dkv: bool):
     first step of the SAME key block, and only after the last head's the
     next key block's, so its lists are by head."""
     static_mask, static_next = np.asarray(info.block_mask)[0], np.asarray(info.data_next)[0]
-    rows, cols = np.indices(static_mask.shape)
-    q_block, k_block = (static_next, cols) if is_dkv else (rows, static_next)
-    runs = jnp.asarray(static_mask > 0) & shares[q_block, k_block]
+    runs = _runs(static_mask, static_next, shares, is_dkv)
     block_mask = jnp.where(runs, static_mask, 0).astype(static_mask.dtype)
     in_grid_order = (lambda x: x.T.reshape(-1)) if is_dkv else (lambda x: x.reshape(-1))  # of one head's steps
     n = static_mask.size
@@ -284,6 +362,15 @@ def _follow_documents(info, shares, heads: int, is_dkv: bool):
     not_last_head = (np.arange(heads) < heads - 1)[:, None, None]
     data_next = jnp.where(none_later & not_last_head, columns_first, data_next)
     return info._replace(block_mask=jnp.broadcast_to(block_mask, data_next.shape), data_next=data_next)
+
+
+def _runs(static_mask, static_next, shares, is_dkv: bool):
+    """Bool, in the layout of a ``MaskInfo``'s ``block_mask`` and ``data_next``
+    (one head's, numpy): the grid steps of the static list whose two blocks
+    ``shares`` (query blocks, key blocks) says share a document."""
+    rows, cols = np.indices(static_mask.shape)
+    q_block, k_block = (static_next, cols) if is_dkv else (rows, static_next)
+    return jnp.asarray(static_mask > 0) & shares[q_block, k_block]
 
 
 def block_pair_counts(segment_ids, block_q: int, block_kv: int) -> tuple[int, int]:

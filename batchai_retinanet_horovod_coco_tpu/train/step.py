@@ -59,7 +59,8 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     # models/granite_hybrid.py
     "embed": (),
     "mamba": ("in_proj", "conv", "ssd", "gate_norm", "out_proj"),  # with its norm
-    "attention": ("indexer", "select", "attention_core", "indexer_loss"),  # beneath it in models/keye_vl2.py alone
+    # beneath it: the first four in models/keye_vl2.py alone, the last two in models/afmoe.py alone
+    "attention": ("indexer", "select", "attention_core", "indexer_loss", "window_core", "full_core"),
     "mlp": (),
     "lm_head": (),  # final norm, head (tied or not), 1 / logits_scaling
     # models/deepseek_v2.py (``embed`` and ``lm_head`` as above)
@@ -73,6 +74,9 @@ STEP_SCOPES: dict[str, tuple[str, ...]] = {
     # models/olmo_hybrid.py enters ``embed``, ``gdn``, ``attention``, ``mlp``, ``lm_head``: a layer's norm is on its
     # sublayer's OUTPUT and counts with the sublayer
     "gdn": ("in_proj", "conv", "delta_rule", "gate_norm", "out_proj"),  # gated delta rule (ops/delta_rule.py)
+    # models/afmoe.py enters ``embed``, ``attention`` (around the call of ops/attention.py ``window_core`` in a layer
+    # with a sliding window, ``full_core`` in one without), ``dense_mlp``, ``moe`` (without ``aux``), ``lm_head``: the
+    # norms on a sublayer's input AND output count with the sublayer
     # every task's
     "loss": (),  # focal, smooth-L1, target encoding; next-token cross-entropy
     "optimizer": (),  # clip, decay, momentum, apply, the numerics summary

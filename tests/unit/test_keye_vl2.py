@@ -400,8 +400,10 @@ def test_the_models_scopes_reach_the_compiled_step_through_recomputation():
         assert (s, "fwd") in filed, s
     assert {s for s, d in filed if d == "bwd"} >= {"embed", "attention", "moe", "lm_head", "loss"}
     assert not {"mamba", "mla", "mlp", "dense_mlp", "backbone", "heads"} & {s for s, _ in filed}
-    beneath = {"attention": STEP_SCOPES["attention"], "moe": tuple(n for n in STEP_SCOPES["moe"] if n != "shared")}
+    # the first four names beneath ``attention`` are this model's (the last two, since PR 46, models/afmoe.py's)
+    beneath = {"attention": STEP_SCOPES["attention"][:4], "moe": tuple(n for n in STEP_SCOPES["moe"] if n != "shared")}
     assert beneath["attention"] == ("indexer", "select", "attention_core", "indexer_loss")
+    assert STEP_SCOPES["attention"][4:] == ("window_core", "full_core")
     for slice_, names in beneath.items():
         paths = {p for t, _, p in table.values() if t == slice_}
         for name in names:

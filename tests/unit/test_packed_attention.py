@@ -139,13 +139,14 @@ def test_mixer_takes_the_xla_path_on_the_cpu(monkeypatch):
     assert hidden.shape == (1, 64, config.hidden_size)
 
 
-def _dense_counts(seg, block_q, block_kv):
+def _dense_counts(seg, block_q, block_kv, window=None):
     t = seg.shape[1]
     pos = np.arange(t)
     causal = pos[:, None] >= pos[None, :]
+    near = causal if window is None else causal & (pos[:, None] - pos[None, :] < window)
     computed = needed = 0
     for row in seg:
-        same = causal & (row[:, None] == row[None, :])
+        same = near & (row[:, None] == row[None, :])
         for i in range(0, t, block_q):
             for j in range(0, t, block_kv):
                 computed += bool(causal[i:i + block_q, j:j + block_kv].any())
@@ -293,3 +294,164 @@ def test_the_counter_and_run_meta_speak_of_skipping_on_the_kernel_path_alone():
     computed, needed = attention.block_pair_counts(np.asarray(seg), b["block_q"], b["block_kv"])
     assert name == "attn/block_pairs_run_share"
     np.testing.assert_allclose(float(share), needed / computed, rtol=1e-6)
+
+
+# ---- a window: the second rule, chosen per call (ISSUE 46) -------------------
+
+# windows of these tests: an edge INSIDE a block (100) and ON one (BLOCK); in "four_documents" a document is
+# equal to the first (100), shorter than both (12) and longer (200); "one_document" is several times either
+WINDOWS = {"edge_inside_a_block": 100, "edge_on_a_block": BLOCK}
+
+
+def _window_blocks():
+    blocks = {name: BLOCK for name in attention.BLOCK_SIZES}
+    return mock.patch.multiple(attention, BLOCK_SIZES=blocks)
+
+
+def _kernel_w(window):
+    def fn(q, k, v, seg):
+        with _window_blocks():
+            return attention._kernel_path(q, k, v, seg, SCALE, interpret=True, window=window)
+    return fn
+
+
+def _xla_w(window):
+    return lambda q, k, v, seg: attention._xla_path(q, k, v, seg, SCALE, 128, window)
+
+
+def _written_out(window):
+    """The mask written out as a comparison of positions and document ids, a dense float32 softmax."""
+    def fn(q, k, v, seg):
+        pos, ids = np.arange(T), np.asarray(seg[0])
+        mask = (pos[:, None] >= pos[None, :]) & (ids[:, None] == ids[None, :])
+        if window is not None:
+            mask &= pos[:, None] - pos[None, :] < window
+        q, k, v = (x[0].astype(jnp.float32) for x in (q, k, v))
+        k, v = jnp.repeat(k, HEADS // KV_HEADS, axis=1), jnp.repeat(v, HEADS // KV_HEADS, axis=1)
+        with jax.default_matmul_precision("highest"):
+            scores = jnp.where(mask[None], SCALE * jnp.einsum("qhd,shd->hqs", q, k), -jnp.inf)
+            return jnp.einsum("hqs,shd->qhd", jax.nn.softmax(scores, axis=-1), v)[None]
+    return fn
+
+
+def test_the_mask_written_out_without_a_window_is_the_reference():
+    seg = jnp.asarray(_segments(LAYOUTS["four_documents"]))
+    (q, k, v), _ = _qkv(4)
+    np.testing.assert_allclose(_written_out(None)(q, k, v, seg), _reference(q, k, v, seg), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("layout", ["one_document", "four_documents", "boundary_on_a_block_edge"])
+def test_window_in_both_lowerings_matches_the_mask_written_out(layout, window):
+    w = WINDOWS[window]
+    seg = jnp.asarray(_segments(LAYOUTS[layout]))
+    qkv, g = _qkv(5)
+    out_k, grads_k = _out_and_grads(_kernel_w(w), qkv, g, seg)
+    out_x, grads_x = _out_and_grads(_xla_w(w), qkv, g, seg)
+    out_r, grads_r = _out_and_grads(_written_out(w), qkv, g, seg)
+    full = _written_out(None)(*qkv, seg)
+    assert _rel(out_r, full) > 0.1  # the window is felt: these layouts have documents longer than it
+    for out in (out_k, out_x):
+        np.testing.assert_allclose(out, out_r, rtol=2 ** -7, atol=2 ** -7)
+        assert _rel(out, out_r) < 4e-3
+    for name, gk, gx, gr in zip("qkv", grads_k, grads_x, grads_r):
+        assert _rel(gk, gr) < 1e-2 and _rel(gx, gr) < 1e-2, name
+    # off by one either way is another function, further away than the rounding
+    for other in (w - 1, w + 1):
+        assert _rel(_written_out(other)(*qkv, seg), out_r) > 4 * _rel(out_k, out_r)
+
+
+def test_window_in_float32_gives_the_mask_written_out_closely():
+    seg = jnp.asarray(_segments(LAYOUTS["four_documents"]))
+    qkv, g = _qkv(6, jnp.float32)
+    out_r, grads_r = _out_and_grads(_written_out(100), qkv, g, seg)
+    with jax.default_matmul_precision("highest"):
+        for fn in (_kernel_w(100), _xla_w(100)):
+            out, grads = _out_and_grads(fn, qkv, g, seg)
+            np.testing.assert_allclose(out, out_r, rtol=1e-4, atol=1e-5)
+            for a, b in zip(grads, grads_r):
+                np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+def test_a_window_no_shorter_than_the_sequence_is_no_window_bit_for_bit():
+    seg = jnp.asarray(_segments(LAYOUTS["four_documents"]))
+    qkv, g = _qkv(7)
+    for with_window, without in ((_kernel_w(T), _kernel_w(None)), (_xla_w(T), _xla_w(None))):
+        for a, b in zip(jax.tree.leaves(_out_and_grads(with_window, qkv, g, seg)),
+                        jax.tree.leaves(_out_and_grads(without, qkv, g, seg))):
+            np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def test_without_a_window_the_kernel_and_its_lists_are_the_parents():
+    """``window=None`` asks for the kernel object the parent built (the cache's key
+    as the parent's call gave it, plus ``None``), whose lists are the causal mask's;
+    a window's are another object with fewer pairs."""
+    blocks = tuple(attention.BLOCK_SIZES.items())
+    parent = attention._causal_kernel(1, 8192, 2, blocks, False)
+    ours = attention._causal_kernel(1, 8192, 2, blocks, False, None)
+    windowed = attention._causal_kernel(1, 8192, 2, blocks, False, 2048)  # at the same blocks, to compare the lists
+    assert ours is attention._causal_kernel(1, 8192, 2, blocks, False, None) and windowed is not ours
+    for name in ("fwd_mask_info", "dq_mask_info", "dkv_mask_info"):
+        a, b, c = getattr(parent, name), getattr(ours, name), getattr(windowed, name)
+        for x, y in zip(a, b):
+            assert (x is None and y is None) or np.array_equal(np.asarray(x), np.asarray(y)), name
+        assert int((np.asarray(c.block_mask) > 0).sum()) < int((np.asarray(a.block_mask) > 0).sum()), name
+    assert parent.kwargs["mask_function"] is not None and windowed.kwargs["mask_function"] is not None
+
+
+def test_window_lists_run_the_pairs_the_count_needs_at_the_cells_sequence_length():
+    """At 16 384 tokens, one document (the Trinity cell's layout) and packed
+    documents: the window kernel's static lists hold the window's pairs and no
+    more, and followed through the documents they run what the count says."""
+    one = np.zeros((1, 16384), np.int32)
+    packed = np.concatenate([_cell_packings(0)[0], _cell_packings(1)[0]])[None]
+    b = attention.BLOCK_SIZES
+    kernel = attention._causal_kernel(1, 16384, 2, tuple(b.items()), False, 2048)
+    for seg in (one, packed):
+        followed = attention._document_block_lists(kernel, jnp.asarray(seg.reshape(-1)), 2)
+        for name, blocks in (("fwd", (b["block_q"], b["block_kv"])), ("dq", (b["block_q_dq"], b["block_kv_dq"])),
+                             ("dkv", (b["block_q_dkv"], b["block_kv_dkv"]))):
+            static = np.asarray(getattr(kernel, f"{name}_mask_info").block_mask)
+            mask = np.asarray(getattr(followed, f"{name}_mask_info").block_mask)
+            assert int((static > 0).sum()) == _dense_counts(one, *blocks, 2048)[1], name
+            assert int((mask[0] > 0).sum()) == _dense_counts(seg, *blocks, 2048)[1], name
+    n = 16384 // b["block_q"]  # a window of 2048 keys reaches 2048 / block + 1 key blocks a query block
+    reach = 2048 // b["block_kv"] + 1
+    assert _dense_counts(one, b["block_q"], b["block_kv"], 2048) == (
+        n * (n + 1) // 2, sum(min(i + 1, reach) for i in range(n)))
+
+
+def test_both_run_share_counters_and_run_meta_with_a_window():
+    one, packed = jnp.zeros((1, 16384), jnp.int32), jnp.asarray(_cell_packings(0))
+    assert attention.step_counters(one, 2048, 2) == {}  # the CPU
+    assert attention.run_meta("cpu", 16384, 2048) == {"attention_lowering": "xla", "attention_window": 2048}
+    assert attention.run_meta("tpu", 16384, 2048) == {
+        "attention_lowering": "kernel", "attention_block_skip": "documents", "attention_residuals": "kept",
+        "attention_window": 2048}
+    b = w = attention.BLOCK_SIZES  # window layers run the full layers' blocks
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        for seg in (one, packed):
+            counters = attention.step_counters(seg, 2048, 2)
+            assert set(counters) == {"attn/block_pairs_run_share", "attn/window_block_pairs_run_share"}
+            assert set(attention.step_counters(seg)) == {"attn/block_pairs_run_share"}  # a model without a window
+            causal, run = attention.block_pair_counts(np.asarray(seg), b["block_q"], b["block_kv"])
+            np.testing.assert_allclose(float(counters["attn/block_pairs_run_share"]), run / causal, rtol=1e-6)
+            causal, run = _dense_counts(np.asarray(seg), w["block_q"], w["block_kv"], 2048)
+            np.testing.assert_allclose(float(counters["attn/window_block_pairs_run_share"]), run / causal, rtol=1e-6)
+    assert float(attention.block_pairs_run_share(one, 1024, 1024)) == 1.0
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):  # one document: the window's 45 of the 136 causal pairs
+        np.testing.assert_allclose(float(attention.step_counters(one, 2048, 2)[attention.WINDOW_RUN_SHARE]), 45 / 136, rtol=1e-6)
+
+
+def test_window_kernel_lowers_for_tpu_at_the_trinity_cells_shape():
+    """32 / 4 heads of 128, one sequence of 16 384 tokens, a window of 2048 keys,
+    at the committed blocks of a window layer: JAX-level lowering only."""
+    spec = lambda h: jax.ShapeDtypeStruct((1, 16384, h, 128), jnp.bfloat16)
+
+    def fn(q, k, v, g, seg):
+        out, vjp = jax.vjp(lambda q, k, v: attention._kernel_path(q, k, v, seg, 128 ** -0.5, window=2048), q, k, v)
+        return out, vjp(g)
+
+    text = jax.jit(fn).trace(spec(32), spec(4), spec(4), spec(32), jax.ShapeDtypeStruct((1, 16384), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 3

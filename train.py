@@ -34,12 +34,18 @@ models/nemotron_h.py; benchmark/configs/nemotron-3-nano-30b-ep16.json) or
 ``KeyeVL2`` (Keye-VL-2.0's language model: grouped-query attention over the
 keys a learned indexer selects, exactly the top 2048 a query, and softmax-routed
 experts without a shared one, models/keye_vl2.py;
-benchmark/configs/keye-vl2-30b-a3b-ep8.json).
+benchmark/configs/keye-vl2-30b-a3b-ep8.json) or ``olmo_hybrid`` (gated-delta-rule
+linear attention beside full attention, models/olmo_hybrid.py;
+benchmark/configs/olmo-hybrid-7b-p1.json) or ``afmoe`` (Trinity: sliding-window
+and full attention layers mixed, an output gate, sigmoid-routed experts with a
+shared one, models/afmoe.py; benchmark/configs/trinity-mini-ep8.json).
 ``--model tiny`` (the default: one Granite period of ten layers at width 64),
 ``--model tiny-moe`` (one dense and two expert layers, 4 of 16 experts held),
 ``--model tiny-nemotron`` (the pattern MEM*E, 2 groups, 2 of 8 experts held)
-and ``--model tiny-keye`` (three layers, a query keeps 24 keys, 4 of 16 experts
-held) are what the CPU tests run.  The LM task is single-chip until an issue brings its sharding:
+``--model tiny-keye`` (three layers, a query keeps 24 keys, 4 of 16 experts
+held), ``--model tiny-olmo`` (one period of three delta-rule layers and an
+attention layer) and ``--model tiny-afmoe`` (a dense and three expert layers, a
+window of 16 keys in three of four, 2 of 8 experts held) are what the CPU tests run.  The LM task is single-chip until an issue brings its sharding:
 ``--num-devices`` above 1 is refused.
 """
 

@@ -10,7 +10,10 @@ MLPs' products with ``gate_up``, named ``MLP_GATE_UP``).
 A matmul's operands are rounded by the CALLER's ``cast`` (its module's
 ``_operand`` bound to its config): the benchmark's precision controls patch
 that one function of a model's module and nothing here.  Norms and the loss
-reduce in float32.
+reduce in float32.  The loss names the target's logit by comparison with the
+vocabulary's index, not by a gather (PR 47; ``LOSS_MEASURED`` beside
+``next_token_loss`` says what the gather's scatter-add cost on the chip, and what
+running over all T positions with the last one masked read there).
 """
 
 from __future__ import annotations
@@ -60,6 +63,11 @@ MLP_GATE_UP = "mlp_gate_up"  # ``gated_mlp``'s product with ``gate_up``, (batch,
 # 14.586 where the model says 13.232; five products of 0.671 in all: temporaries 6.646, the step 15.409 = 91.1% of the
 # limit where the model says 13.903 = 82.2%: KEEPS, and it fits, but a 16 384-token step's working set is 66-71 inputs,
 # not 50, and here the products cost MORE than their bytes, 0.829.)
+# (PR 47, the six steps the same way, parent 38762b9 -> ``next_token_loss`` by comparison; temporaries in GB:
+# granite 5.382 -> 5.123   dsv2 5.740 -> 5.740   olmo 4.621 -> 4.621   nemo3 5.847 -> 5.847   keye 6.091 -> 6.091
+# trinity 7.325 -> 6.349 (PR 46's builder read 6.646 of its tree; mine, the numerics plane on as the cells run it,
+# reads 7.325 of the same files: one script for both sides here).  The head's gradient was the step's peak in
+# granite and trinity alone; the constants below stay as they were.)
 # What is kept costs less than its bytes (granite 2.38 of 2.68 GB, dsv2 1.15 of 1.64) because it takes the room of
 # temporaries that died earlier (PR 39 found the same), and in olmo's step the compiler's count FALLS by 0.12 GB: by
 # that count olmo's products would fit at 92.8%.  The model cannot see that (it would take a compilation to find out,
@@ -163,12 +171,69 @@ def head_logits(cast, x, table):
     return jnp.einsum("btd,vd->btv", cast(x), cast(table), preferred_element_type=jnp.float32)
 
 
+# LOSS_MEASURED (PR 47).  Why ``next_token_loss`` is written as it is.  Until PR 47 it read the target's logit with
+# ``jnp.take_along_axis(logits[:, :-1], targets)``.  The gradient of that gather is a scatter-add of T - 1 numbers into
+# zeros of the logits' size, and XLA for this chip runs it on a FLAT array: with ONE sequence a step it keeps the
+# scatter (with two it rewrites it itself), and where the vocabulary slice is no whole lane tile of 128 (trinity's
+# 25 024, keye's 18 992) it also fills ``f32[V x (T - 1)]`` with zeros, copies the float32 softmax term into it with a
+# ``while`` of ``dynamic-update-slice`` steps, scatters, converts the flat array to bfloat16 and copies it back to
+# three dimensions with a second ``while``, and only then do the head's two gradient products read it: in trinity's
+# cell ``while.201`` over ``f32[1,25024,16383]`` (with ``dynamic-update-slice.168 f32[409968192]`` in its body) 13.78 ms
+# a step and 35.7 ms of ``unscoped`` in all (my traced pair, PR 47).  A comparison with the vocabulary's index and a
+# sum name the same number, and their gradient is a select: softmax minus one-hot, ONE fusion that writes the bfloat16
+# gradient the two products read.
+# TWO forms were compiled for a described v5e at the six cells' real steps and run on the chip:
+#   (A) over all T positions, the last masked (``jnp.roll`` for the targets): no slice of the logits either; XLA then
+#       puts the row maximum into the logits' product's epilogue and softmax-minus-one-hot into the PROLOGUE of both
+#       gradient products, and the float32 logits are the step's one logits-sized array;
+#   (B) the comparison over ``logits[:, :-1]``: what is below.  The bfloat16 gradient is written once and read twice.
+# The compiler's readings (``memory_analysis()``, ``cost_analysis()``, ``as_text()``; no timings), parent 38762b9 ->
+# A / B; "results" counts the entry computation's instructions whose RESULT has the logits' size (flat or not, either
+# dtype), "loops" the ``while`` / ``scatter`` over such arrays:
+#   cell's step (sequences, T, V / 8)  results     loops           temporaries GB          bytes accessed GB
+#   trinity (1, 16384, 25024)          10 -> 1 / 2  2 / 1 -> none  7.325 -> 6.349 / 6.349  396.6 -> 386.6 / 389.0
+#   keye    (1, 16384, 18992)          12 -> 1 / 2  2 / 1 -> none  6.091 -> 6.091 / 6.091  589.8 -> 582.0 / 583.9
+#   nemo3   (2,  8192, 16384)           2 -> 1 / 2  none           5.847 -> 5.847 / 5.847  365.5 -> 364.7 / 365.4
+#   dsv2    (2,  8192, 12800)           2 -> 1 / 2  none           5.740 -> 5.740 / 5.740  399.0 -> 398.5 / 398.9
+#   granite (1,  8192, 12544)           6 -> 1 / 2  0 / 1 -> none  5.382 -> 4.568 / 5.123  144.5 -> 140.8 / 142.3
+#   olmo    (1,  8192, 12544)           6 -> 1 / 2  0 / 1 -> none  4.621 -> 4.621 / 4.621  132.4 -> 129.6 / 130.2
+# ON THE CHIP (my chip runs, PR 47; ``train_img_per_s_chip`` of pairs of one seed, ``train_step.device_ms`` and the
+# slices of traced runs, ms a step):
+#   trinity  parent 1.5378 / 1.5413 -> A 1.6309 / 1.6337 (+6.05%, +6.00%); parent 1.5398 / 1.5383 / 1.5328 -> B 1.6275
+#            / 1.6233 / 1.6236 (+5.70%, +5.53%, +5.93%).  Traced (parent -> A; B at another seed): device 667.36 ->
+#            629.65; 624.77.  ``unscoped`` 56.88 -> 21.15; 21.12: the loops, scatter, convert and fills were 35.7.
+#            ``lm_head`` 32.76 -> 35.17; 32.76: A's gradient products 10.23 + 9.08 -> 12.26 + 9.31 under their prologue.
+#            ``loss`` 7.16 -> 2.17; 7.88: B's gradient fusion 3.54, the sums 2.17, the row maximum's pass 2.17.
+#   nemo3    parent 4.0273 -> A 4.0013 (-0.65%: every log window 3.4 ms a step SLOWER; A's dx product with the
+#            prologue, ``fusion.602``, 11.95 ms where the parent's reads 7.83); parent 4.0308 -> B 4.0310 (the parent's
+#            step but for the gather: the logits' product with the row maximum in its epilogue 9.16, the gradient's
+#            fusion 2.30).
+#   keye     parent 0.71617 -> B 0.73166 (+2.16%); A not paired (traced device 1353.48 at one seed, B 1376.16 at
+#            another).
+#   traced alone (the ledger's PR 46 parent lines, other seeds, in brackets): granite A 376.71, B 376.32 [379.8]; olmo
+#            A 551.42, B 550.17 [554.2]; dsv2 A 516.31, B 515.56 (its step follows the seed).
+#   ``memory_peak_bytes``, parent -> A / B: trinity 15 224 413 184 -> 14 949 998 080 / 14 950 328 832; granite
+#            14 339 112 960 -> 13 798 158 336 / 14 209 447 936; nemo3, dsv2, olmo and keye within 2 MB of the parent's.
+# B is kept: over all T positions XLA folds softmax-minus-one-hot into BOTH gradient products, which then compute every
+# exponent twice and run slower than the one fusion they absorbed wherever the step holds two sequences (nemo3 -0.65%,
+# bound 1%), for about 0.4% more than B in trinity (a step 612.6 against 615.4 ms over the untraced log windows).
+# What the ragged T - 1 still costs B: a pass for the row maximum in the one-sequence cells (``slice_reduce_fusion``,
+# 2.17 ms in trinity, 1.65 keye, 0.55 granite and olmo).
+# ``tests/unit/test_next_token_loss.py`` holds the value, the count and the gradient to the plain form, and the
+# gradient's jaxpr to no gather and no scatter.
+
+
 def next_token_loss(logits, tokens, segment_ids):
     """Mean cross-entropy of the next token over positions whose next token
-    lies in the same document; also the number of such positions."""
+    lies in the same document; also the number of such positions.
+
+    The target's logit is named by COMPARISON with the vocabulary's index, not by ``take_along_axis``: the
+    gradient of a gather is a scatter-add (``LOSS_MEASURED`` above says what that cost on the chip, and why the
+    positions are still ``[:, :-1]`` and not all T with the last masked)."""
     targets = tokens[:, 1:]
     counted = (segment_ids[:, 1:] == segment_ids[:, :-1]).astype(jnp.float32)
     logits = logits[:, :-1].astype(jnp.float32)
-    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) == targets[..., None]
+    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
     n = jnp.sum(counted)
     return jnp.sum(nll * counted) / jnp.maximum(n, 1.0), n

@@ -3,7 +3,12 @@
 lowered: the whole step (model, loss, counters, optimizer, numerics) lowered for
 the TPU platform at the cell's sizes, every Mosaic kernel's body printed without
 source locations (a moved line is no change), against the hashes recorded from
-the parent's tree (``tests/fixtures/splash_cells_step_lowering.json``)."""
+the parent's tree (``tests/fixtures/splash_cells_step_lowering.json``).
+PR 47 recorded the four again (the fixture says how and why): it rewrote
+``lm_layers.next_token_loss``, which every one of these steps ends in, and the texts
+differ from its parent's in the loss's lines and its gradient's alone; what PR 46
+held, that ``window=None`` changes nothing, the recorded texts still hold for every
+PR after it."""
 
 import base64
 import hashlib

@@ -17,7 +17,12 @@ PR 44 recorded ``tiny-moe``'s two again: ``gated_mlp`` names its product with
 the text of DeepSeek-V2's step is the old one but for the NUMBERS MLIR gives to
 repeated private functions from the first shared expert on (``@silu_208`` is
 ``@silu_209``, and so on: no line differs once ``_<n>`` is cut from the symbols);
-granite's and Nemotron-H's texts hold byte for byte."""
+granite's and Nemotron-H's texts hold byte for byte.
+PR 47 recorded all six again, this file's two functions run on its tree:
+``lm_layers.next_token_loss`` names the target's logit by comparison with the
+vocabulary's index (no gather whose gradient is a scatter-add), so every language
+model's step changes, in that function's lines and its gradient's alone; the parent's
+tree (38762b9) still gave the six hashes recorded before."""
 
 import hashlib
 import json

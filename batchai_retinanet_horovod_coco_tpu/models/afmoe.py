@@ -47,6 +47,24 @@ recomputed in the backward pass; of its inside the attention kernels' output
 and log-sum-exp are kept and, where the device has the room, the dense and
 shared MLPs' products with ``gate_up`` (``lm_layers.layer_keeps``).  Single
 device.
+
+Between its projections and the attention kernels a layer crosses in one of two
+ways, chosen from what the step sees (``_edges``: ``attention_edges.lowering``;
+``run_meta`` says ``attention_edges``).  Where the kernels run and a head is whole
+lane tiles (a TPU, T of whole blocks, ``head_dim % 128 == 0``: the published model
+at its cell's bucket) by ops/attention_edges.py's two passes
+(``_attention_by_passes``): per-head norm, rotation and softmax scale in float32
+inside ONE pass over ``config.dtype`` that also writes the kernels' head-major
+layout, rounded ONCE, and the way back with the gate likewise.  Everywhere else
+(the CPU, the tiny preset's heads of 16, a ragged T) by the lines of
+``_attention`` as written, which round q three times on the way (after the norm,
+after the rotation, after the scale: 128^-0.5 is no power of two), go through
+float32 copies in HBM, and are what the benchmark's mutations patch BY NAME
+(``lm_layers.rms_norm`` on four dimensions, ``rope.apply_rotary_halves``,
+``attention.packed_causal_attention``, ``jax.nn.sigmoid``): keep them calling
+those.  The passes are closer to the float32 reference, never further; what is
+float32 in the lines (the norms' reductions, angles, cosines, sines, the gate's
+argument, the norm scales' gradients) is float32 in the passes.
 """
 
 from __future__ import annotations
@@ -60,7 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from batchai_retinanet_horovod_coco_tpu.models import lm_layers
-from batchai_retinanet_horovod_coco_tpu.ops import attention, moe, rope
+from batchai_retinanet_horovod_coco_tpu.ops import attention, attention_edges, moe, rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 CORE_SCOPE = {SLIDING: "window_core", FULL: "full_core"}
@@ -180,9 +198,17 @@ def _matmul(config, x, w):
     return lm_layers.matmul(_cast(config), x, w)
 
 
+def _edges(config, t: int) -> str:
+    """How a layer crosses between its projections and its attention kernels over ``t`` tokens a sequence:
+    ``kernel`` (ops/attention_edges.py's two passes) or ``xla`` (the lines of ``_attention``)."""
+    return attention_edges.lowering(jax.default_backend(), t, config.head_dim)
+
+
 def _attention(config, kind: str, p, u, segment_ids, positions):
     batch, t, _ = u.shape
     hd, eps = config.head_dim, config.rms_norm_eps
+    if _edges(config, t) == attention_edges.KERNEL:
+        return _attention_by_passes(config, kind, p, u, segment_ids, positions)
     q = _matmul(config, u, p["q"]).reshape(batch, t, config.num_attention_heads, hd)
     k = _matmul(config, u, p["k"]).reshape(batch, t, config.num_key_value_heads, hd)
     v = _matmul(config, u, p["v"]).reshape(batch, t, config.num_key_value_heads, hd)
@@ -195,6 +221,23 @@ def _attention(config, kind: str, p, u, segment_ids, positions):
                                                 window=config.sliding_window if kind == SLIDING else None)
     gate = jax.nn.sigmoid(_matmul(config, u, p["gate"]).astype(jnp.float32))
     return _matmul(config, out.reshape(batch, t, -1).astype(jnp.float32) * gate, p["o"])
+
+
+def _attention_by_passes(config, kind: str, p, u, segment_ids, positions):
+    """``_attention`` where the attention kernels run and a head is whole lane tiles: the same equations
+    with each crossing ONE pass (ops/attention_edges.py: q and k normalised, rotated and scaled in float32,
+    rounded ONCE where the lines above round three times; the way back with the gate as above)."""
+    hd, eps = config.head_dim, config.rms_norm_eps
+    angles = window = None  # the full layers see no position at all
+    if kind == SLIDING:
+        angles = positions.astype(jnp.float32)[..., None] * rope.plain_inv_freq(hd, config.rope_theta)
+        window = config.sliding_window
+    q = attention_edges.heads_in(_matmul(config, u, p["q"]), p["q_norm"], angles, eps, hd ** -0.5)
+    k = attention_edges.heads_in(_matmul(config, u, p["k"]), p["k_norm"], angles, eps, 1.0)
+    v = attention_edges.head_major(_matmul(config, u, p["v"]), config.num_key_value_heads)
+    with jax.named_scope(CORE_SCOPE[kind]):
+        out = attention.head_major_attention(segment_ids, config.num_attention_heads, window=window)(q, k, v)
+    return _matmul(config, attention_edges.heads_out(out, _matmul(config, u, p["gate"])), p["o"])
 
 
 def _moe_lowering(config, batch: int, t: int) -> str:
@@ -335,7 +378,7 @@ class Afmoe:
         share of the experts held."""
         config, backend = self.config, jax.default_backend()
         params = lm_layers.param_shapes(init_params, config)
-        return {**attention.run_meta(backend, bucket[1], _window(config)),
+        return {**attention.run_meta(backend, bucket[1], _window(config)), "attention_edges": _edges(config, bucket[1]),
                 **lm_layers.run_meta(_keeps(config, params, bucket)),
                 "moe_lowering": _moe_lowering(config, *bucket),
                 "moe_rows_lowering": moe.rows_lowering(backend, bucket[0] * bucket[1], config.num_experts_per_tok,

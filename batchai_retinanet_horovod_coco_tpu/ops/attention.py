@@ -54,7 +54,12 @@ document; that and the window), two blockings:
   dsv2's cell written and read back in 0.2 ms against 3.4-3.6 ms to form again.  The xla blocking names nothing.
 
 Which one runs is ``lowering``'s answer, from the backend and the shapes
-alone.
+alone.  The kernel path has two doors: ``head_major_attention`` on operands that
+ARE in the kernels' layout ((heads, batch x T, head size), q already scaled:
+ops/attention_edges.py's passes write that), and ``_kernel_path``, its caller for
+operands as the models hold them, which scales q and transposes each array once
+each way, as it always did (``packed_causal_attention``'s callers lower to the
+operations they lowered to: tests/unit/test_packed_attention.py).
 
 Precision, both paths: q, k, v in the caller's dtype (``config.dtype``,
 bfloat16); scores, maximum, sum and the output accumulator float32; in the
@@ -241,21 +246,32 @@ def _xla_path(q, k, v, segment_ids, scale, q_block, window=None):
 
 
 def _kernel_path(q, k, v, segment_ids, scale, interpret: bool = False, window: int | None = None):
-    """The shipped kernel over the batch's sequences laid end to end (one
-    call of each of the three kernels a batch, one transpose an array),
-    built from the mask that is causal inside each sequence (and reaches no
-    further back than ``window``), with its three block lists cut to the
-    documents of the step (``_document_block_lists``)."""
-    from jax.experimental.pallas.ops.tpu import splash_attention as splash
-
+    """``head_major_attention`` for operands as the models hold them: ``q`` scaled (in float32, rounded
+    once), then one transpose an array to the kernels' layout and one back."""
     batch, t, heads, _ = q.shape
-    kernel = _causal_kernel(batch, t, heads, tuple(BLOCK_SIZES.items()), interpret, window)
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     end_to_end = lambda x: x.transpose(2, 0, 1, 3).reshape(x.shape[2], batch * t, x.shape[3])
-    seg = segment_ids.reshape(batch * t)
-    out = _document_block_lists(kernel, seg, heads)(
-        end_to_end(q), end_to_end(k), end_to_end(v), splash.SegmentIds(q=seg, kv=seg))
+    attend = head_major_attention(segment_ids, heads, interpret, window)
+    out = attend(end_to_end(q), end_to_end(k), end_to_end(v))
     return out.reshape(heads, batch, t, -1).transpose(1, 2, 0, 3)
+
+
+def head_major_attention(segment_ids, heads: int, interpret: bool = False, window: int | None = None):
+    """The kernel path on operands that ARE in the kernels' layout, for ``segment_ids`` (batch, T) of whole
+    blocks: ``attend(q, k, v)`` with ``q`` (heads, batch x T, head size) ALREADY SCALED, ``k`` and ``v``
+    (kv_heads, batch x T, head size / value head size), the batch's sequences laid end to end ->
+    (heads, batch x T, value head size).  The shipped kernel (one call of each of its three kernels a
+    batch), built from the mask that is causal inside each sequence (and reaches no further back than
+    ``window``), with its three block lists cut to the documents of the step (``_document_block_lists``:
+    computed here, before ``attend`` is called).  ``_kernel_path`` feeds it by a scale and transposes,
+    ops/attention_edges.py by its passes."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    batch, t = segment_ids.shape
+    kernel = _causal_kernel(batch, t, heads, tuple(BLOCK_SIZES.items()), interpret, window)
+    seg = segment_ids.reshape(batch * t)
+    with_lists = _document_block_lists(kernel, seg, heads)
+    return lambda q, k, v: with_lists(q, k, v, splash.SegmentIds(q=seg, kv=seg))
 
 
 @functools.lru_cache(maxsize=None)

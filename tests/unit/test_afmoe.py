@@ -493,7 +493,8 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
     assert not {"moe/aux_loss", attention.RUN_SHARE, attention.WINDOW_RUN_SHARE} & set(metrics)  # no balance loss; the CPU
     assert int(new_state.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert LMTask().run_meta(model, (2, T)) == {
-        "attention_lowering": "xla", "attention_window": 16, "moe_lowering": "xla", "moe_rows_lowering": "xla",
+        "attention_lowering": "xla", "attention_edges": "xla", "attention_window": 16, "moe_lowering": "xla",
+        "moe_rows_lowering": "xla",
         "experts_held": 2, "experts_total": 8, **NOTHING_MORE}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):  # the cell's model and bucket: the kernels
         published = build_language_model(CONFIG_FILE)
@@ -502,5 +503,5 @@ def test_one_step_through_the_train_step_logs_the_counters_and_every_groups_norm
             attention.RUN_SHARE, attention.WINDOW_RUN_SHARE}
     assert meta == {
         "attention_lowering": "kernel", "attention_block_skip": "documents", "attention_residuals": "kept",
-        "attention_window": 2048, "moe_lowering": "kernel", "moe_rows_lowering": "kernel", "experts_held": 16,
+        "attention_edges": "kernel", "attention_window": 2048, "moe_lowering": "kernel", "moe_rows_lowering": "kernel", "experts_held": 16,
         "experts_total": 128, **NOTHING_MORE}

@@ -455,3 +455,65 @@ def test_window_kernel_lowers_for_tpu_at_the_trinity_cells_shape():
     text = jax.jit(fn).trace(spec(32), spec(4), spec(4), spec(32), jax.ShapeDtypeStruct((1, 16384), jnp.int32)).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") >= 3
+
+
+# ---- the head-major entry (PR 48): what ``_kernel_path`` is now ----------------------------------------------------
+
+
+@pytest.mark.parametrize("window", [None, 100, BLOCK], ids=["no_window", "window_100", "window_of_a_block"])
+@pytest.mark.parametrize("batch", [1, 2])
+def test_kernel_path_is_the_head_major_entry_fed_the_scaled_and_transposed_operands(batch, window):
+    """Output and the three gradients, bit for bit: ``_kernel_path`` is the scale, one transpose an array,
+    ``head_major_attention`` and one transpose back."""
+    seg = jnp.asarray(np.concatenate([_segments(LAYOUTS["four_documents"]), _segments(LAYOUTS["one_document"])])[:batch])
+    qkv, g = _qkv(11, batch=batch)
+
+    def by_hand(q, k, v, seg):
+        q = (q.astype(jnp.float32) * SCALE).astype(q.dtype)
+        laid = lambda x: x.transpose(2, 0, 1, 3).reshape(x.shape[2], batch * T, x.shape[3])
+        with _window_blocks():
+            out = attention.head_major_attention(seg, HEADS, True, window)(laid(q), laid(k), laid(v))
+        assert out.shape == (HEADS, batch * T, HEAD)
+        return out.reshape(HEADS, batch, T, HEAD).transpose(1, 2, 0, 3)
+
+    for a, b in zip(jax.tree.leaves(_out_and_grads(_kernel_w(window), qkv, g, seg)),
+                    jax.tree.leaves(_out_and_grads(by_hand, qkv, g, seg))):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def _kernel_path_of_pr_47(q, k, v, segment_ids, scale, interpret=False, window=None):
+    """A literal copy of ``ops/attention.py::_kernel_path`` as PR 48 found it."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    batch, t, heads, _ = q.shape
+    kernel = attention._causal_kernel(batch, t, heads, tuple(attention.BLOCK_SIZES.items()), interpret, window)
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    end_to_end = lambda x: x.transpose(2, 0, 1, 3).reshape(x.shape[2], batch * t, x.shape[3])
+    seg = segment_ids.reshape(batch * t)
+    out = attention._document_block_lists(kernel, seg, heads)(
+        end_to_end(q), end_to_end(k), end_to_end(v), splash.SegmentIds(q=seg, kv=seg))
+    return out.reshape(heads, batch, t, -1).transpose(1, 2, 0, 3)
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["no_window", "window_2048"])
+def test_packed_causal_attention_lowers_to_the_operations_of_the_kernel_path_pr_48_found(window):
+    """At granite's shape (32 / 8 heads of 64, one sequence of 8192 tokens), output and gradients lowered for
+    the TPU platform, every Mosaic body printed without its source locations: the same text, so the same
+    operations in the same order, as the function this PR split in two."""
+    from test_accepted_steps_lowering import _without_locations
+
+    spec = lambda h: jax.ShapeDtypeStruct((1, 8192, h, 64), jnp.bfloat16)
+
+    def lowered(attend):
+        def fn(q, k, v, g, seg):
+            out, vjp = jax.vjp(lambda q, k, v: attend(q, k, v, seg), q, k, v)
+            return out, vjp(g)
+
+        text = jax.jit(fn).trace(spec(32), spec(8), spec(8), spec(32), jax.ShapeDtypeStruct((1, 8192), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        return _without_locations(text)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        ours, kernels = lowered(lambda q, k, v, seg: attention.packed_causal_attention(q, k, v, seg, 0.125, 1024, window=window))
+    theirs, _ = lowered(lambda q, k, v, seg: _kernel_path_of_pr_47(q, k, v, seg, 0.125, window=window))
+    assert kernels == 3 and ours == theirs
